@@ -194,9 +194,6 @@ declare("PT_ANOMALY_FLOOR_S", 0.05,
         "Step-stall anomaly sentinel: absolute floor of the "
         "slow-step threshold in seconds.",
         kind="float", section="serving")
-declare("PT_COMPILE_CACHE", "",
-        "Directory for the persistent XLA compile cache (empty "
-        "disables persistence).", kind="str", section="serving")
 
 # -- SLO targets -------------------------------------------------------
 declare("PT_SLO_*_TTFT_S", None,
@@ -307,9 +304,6 @@ declare("PADDLE_TPU_PEAK_BW", None,
 declare("PADDLE_TPU_RETRACE_WARN", 8,
         "Retrace count per function after which compile telemetry "
         "warns.", kind="int", section="observability")
-declare("PT_COMPILE_CACHE_HIT_S", 0.05,
-        "Compile wall time below which a compile counts as a "
-        "persistent-cache hit.", kind="float", section="observability")
 
 # -- kernels / tuning --------------------------------------------------
 declare("PT_DISABLE_PALLAS", False,

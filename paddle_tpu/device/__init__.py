@@ -40,14 +40,20 @@ class XPUPlace(Place):
 
 
 def _platform():
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    # a backend that fails to initialise raises: answering "cpu" would
+    # hide a broken accelerator behind a CPU run
+    return jax.default_backend()
 
 
 def set_device(device):
+    """Select "cpu" or the accelerator jax runs on ("tpu:0"; "gpu" and
+    "xpu" are parity spellings of the same). Asking for an accelerator
+    when jax runs on the CPU raises — it never quietly becomes "cpu"."""
     global _current_device
+    if not str(device).startswith("cpu") and _platform() == "cpu":
+        raise ValueError(
+            f"set_device({device!r}): jax runs on the CPU here, there is "
+            "no accelerator to select (JAX_PLATFORMS picks the platform)")
     _current_device = str(device)
     return get_device()
 

@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .._core.compat import axis_size
-
 from .._core.tensor import Tensor, apply, unwrap
 from . import env
 
@@ -144,16 +142,16 @@ def _eager_psum(raw, op, mesh, spec, axes):
     """Real reduction of a sharded eager array: each shard is one
     participant (paddle rank semantics); result is the reduced shard,
     replicated over the reduced axes."""
-    from .._core.compat import shard_map
-
     fn = {ReduceOp.SUM: lax.psum, ReduceOp.MAX: lax.pmax,
           ReduceOp.MIN: lax.pmin, ReduceOp.AVG: lax.pmean}.get(op)
     if fn is None:
         raise NotImplementedError(
             f"all_reduce op {op!r} has no XLA collective mapping "
             f"(SUM/MAX/MIN/AVG supported)")
-    reduced = shard_map(lambda s: fn(s, axes), mesh=mesh,
-                        in_specs=(spec,), out_specs=_drop_axes(spec, axes))(raw)
+    reduced = jax.shard_map(lambda s: fn(s, axes), mesh=mesh,
+                            in_specs=(spec,),
+                            out_specs=_drop_axes(spec, axes),
+                            check_vma=False)(raw)
     return reduced
 
 
@@ -267,16 +265,16 @@ def reduce_scatter(tensor, tensor_list=None, op=ReduceOp.SUM, group=None,
         return out
     mesh, spec, axes = _eager_mesh_axes(raw, ax)
     if mesh is not None and axes:
-        from .._core.compat import shard_map
         a, dim = _resolve_group_axis(mesh, spec, axes, ax, "reduce_scatter")
         if dim != 0:
             raise NotImplementedError(
                 f"eager reduce_scatter needs dim 0 sharded over the group "
                 f"axis {a!r} (got sharded dim {dim}); out_specs for other "
                 f"layouts would mislabel the scattered result")
-        out = shard_map(
+        out = jax.shard_map(
             lambda s: lax.psum_scatter(s, a, scatter_dimension=0, tiled=True),
-            mesh=mesh, in_specs=(spec,), out_specs=spec)(raw)
+            mesh=mesh, in_specs=(spec,), out_specs=spec,
+            check_vma=False)(raw)
         if isinstance(tensor, Tensor):
             tensor._replace(out)
             return tensor
@@ -328,7 +326,7 @@ def alltoall(in_tensor_list, out_tensor_list=None, group=None, sync_op=True):
             not isinstance(in_tensor_list, (list, tuple))):
         raw = unwrap(in_tensor_list)
         if ax is not None and _in_spmd(raw):
-            n = axis_size(ax)
+            n = lax.axis_size(ax)
             out = lax.all_to_all(raw.reshape((n, -1) + raw.shape[1:]), ax, 0, 0,
                                  tiled=False)
             return Tensor(out.reshape(raw.shape)) if isinstance(in_tensor_list,
@@ -345,7 +343,7 @@ def alltoall_single(in_tensor, out_tensor=None, in_split_sizes=None,
     ax = _axis(group)
     raw = unwrap(in_tensor)
     if ax is not None and _in_spmd(raw):
-        n = axis_size(ax)
+        n = lax.axis_size(ax)
         out = lax.all_to_all(raw, ax, split_axis=0, concat_axis=0, tiled=True)
         if out_tensor is not None and isinstance(out_tensor, Tensor):
             out_tensor._replace(out)

@@ -101,15 +101,6 @@ def get_topology():
 
 
 def inside_shard_map():
-    """True when executing under shard_map/pjit manual axes (collectives
+    """True when executing under shard_map manual axes (collectives
     with axis names are legal)."""
-    try:
-        from jax.core import get_axis_env  # may vary across jax versions
-    except Exception:
-        get_axis_env = None
-    try:
-        frame = jax.core.unsafe_get_axis_names() if \
-            hasattr(jax.core, "unsafe_get_axis_names") else []
-        return bool(frame)
-    except Exception:
-        return False
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)

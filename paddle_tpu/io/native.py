@@ -24,8 +24,10 @@ def _load():
     if _LIB is not None:
         return _LIB
     so = os.path.join(_CSRC, "libptio.so")
-    if not os.path.exists(so):
-        subprocess.run(["make", "-C", _CSRC], check=True, capture_output=True)
+    # make every time: the rule depends on ptio.cpp, so a fresh .so is
+    # a no-op and what loads is always built from the committed source
+    subprocess.run(["make", "-C", _CSRC, "libptio.so"], check=True,
+                   capture_output=True)
     lib = ctypes.CDLL(so)
     lib.ptio_open_records.restype = ctypes.c_void_p
     lib.ptio_open_records.argtypes = [ctypes.c_char_p, ctypes.c_int64]

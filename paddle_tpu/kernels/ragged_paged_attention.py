@@ -30,6 +30,12 @@ programs does not come for free — three things make it hold:
     barrier has no vmap batching rule, so the reference fans out over
     (token, head) with `lax.map` rather than vmap.
 
+The bit-identity contract is a CPU one (interpreted kernel vs
+reference). The Mosaic TPU lowering has no rule for
+`optimization_barrier`, so the kernel body compiled for the chip
+(`interpret=False`) carries none; there the check against the reference
+is by tolerance (chip_smoke.py, tools/validate_tpu_kernels.py).
+
 GQA: q is viewed (tokens, kv_heads, group, head_dim). int8 pools ride
 per-token fp32 scales dequantized inside `_page_update`.
 
@@ -54,15 +60,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.flash_attention import _fit_lanes
-from ..ops.paged_attention import LANES, MIN_GROUP, NEG_INF, Z, _on_tpu
+from ..ops.paged_attention import (F0, F1, LANES, MIN_GROUP, NEG_INF, Z,
+                                   _on_tpu)
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_reference"]
 
 _bar = jax.lax.optimization_barrier
 
 
+def _no_bar(x):
+    return x
+
+
 def _page_update(q, k, v, acc, m_prev, l_prev, limit, pi, scale,
-                 page_size, ks=None, vs=None):
+                 page_size, ks=None, vs=None, bar=_bar):
     """One online-softmax step over one KV page — THE arithmetic
     contract shared by the pallas kernel and the jnp reference.
 
@@ -72,25 +83,26 @@ def _page_update(q, k, v, acc, m_prev, l_prev, limit, pi, scale,
     (acc, m, l). The optimization barriers keep XLA from contracting
     the muls into the adds (or re-fusing the dots/exps) differently in
     the two compiled programs — without them the kernel and reference
-    drift by 1 ULP on CPU.
+    drift by 1 ULP on CPU. `bar=_no_bar` drops them for the Mosaic
+    build, which cannot lower the primitive.
     """
     if ks is not None:
         k = k * ks
         v = v * vs
-    s = _bar(jax.lax.dot_general(
+    s = bar(jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)) * scale
     cols = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(cols < limit, s, NEG_INF)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = _bar(jnp.exp(s - _fit_lanes(m_new, s.shape[-1])))
-    alpha = _bar(jnp.exp(m_prev - m_new))
-    al, sp = _bar((alpha * l_prev, jnp.sum(p, axis=1, keepdims=True)))
+    p = bar(jnp.exp(s - _fit_lanes(m_new, s.shape[-1])))
+    alpha = bar(jnp.exp(m_prev - m_new))
+    al, sp = bar((alpha * l_prev, jnp.sum(p, axis=1, keepdims=True)))
     l_new = al + sp
-    aa, pv = _bar((acc * _fit_lanes(alpha, acc.shape[-1]),
-                   jax.lax.dot_general(
-                       p, v, (((1,), (0,)), ((), ())),
-                       preferred_element_type=jnp.float32)))
+    aa, pv = bar((acc * _fit_lanes(alpha, acc.shape[-1]),
+                  jax.lax.dot_general(
+                      p, v, (((1,), (0,)), ((), ())),
+                      preferred_element_type=jnp.float32)))
     return aa + pv, m_new, l_new
 
 
@@ -153,7 +165,7 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                 jnp.zeros((gp, LANES), jnp.float32))
         (acc, m, l), _ = jax.lax.scan(
             body, init, (pages_t, jnp.arange(n_pages, dtype=jnp.int32)))
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        l_safe = jnp.where(l == F0, F1, l)
         return acc / _fit_lanes(l_safe, acc.shape[-1])
 
     ti_idx = jnp.repeat(jnp.arange(t), kvh)
@@ -183,7 +195,7 @@ def _resolve_block_q(block_q, group):
 
 
 def _ragged_kernel(slot_ref, pos_ref, ptab_ref, *refs, scale, page_size,
-                   n_pages, block_pages, quant):
+                   n_pages, block_pages, quant, bar):
     """Grid (T, KVH, ceil(pages_per_seq / block_pages));
     tok_slot/tok_pos/page_table ride scalar prefetch — each of the
     `block_pages` per-step page operands has its own BlockSpec index
@@ -231,7 +243,7 @@ def _ragged_kernel(slot_ref, pos_ref, ptab_ref, *refs, scale, page_size,
                 k_ref[0, 0].astype(jnp.float32),
                 v_ref[0, 0].astype(jnp.float32),
                 acc_ref[:], m_ref[:], l_ref[:], limit, ordinal, scale,
-                page_size, *sc)
+                page_size, *sc, bar=bar)
             acc_ref[:] = acc_new
             m_ref[:] = m_new
             l_ref[:] = l_new
@@ -239,7 +251,7 @@ def _ragged_kernel(slot_ref, pos_ref, ptab_ref, *refs, scale, page_size,
     @pl.when(pi == grid_pages - 1)
     def _fin():
         l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        l_safe = jnp.where(l == F0, F1, l)
         o_ref[0, 0] = (acc_ref[:] /
                        _fit_lanes(l_safe, o_ref.shape[-1])).astype(o_ref.dtype)
 
@@ -290,7 +302,8 @@ def _ragged_pallas(q4, k_pages, v_pages, page_table, tok_slot, tok_pos,
     )
     kernel = functools.partial(
         _ragged_kernel, scale=np.float32(scale), page_size=page_size,
-        n_pages=n_pages, block_pages=block_pages, quant=quant)
+        n_pages=n_pages, block_pages=block_pages, quant=quant,
+        bar=_bar if interpret else _no_bar)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability.compile_telemetry import ensure_compile_cache
 from ..ops.rope import rope_cos_sin, apply_rotary_emb
 from ..ops.flash_attention import flash_attention_bhsd
 from ..ops.flashmask_attention import flashmask_attention_bhsd
@@ -111,8 +112,21 @@ def doc_end_indices(doc_ids):
     return end.astype(jnp.int32)[:, None, :, None]
 
 
+def _sharded_attn(fn, mesh, attn_spec):
+    """Run an attention kernel over (B, H, S, D) operands under its own
+    shard_map (every mesh axis manual): GSPMD cannot partition a Mosaic
+    kernel — lowering one under a mesh of more than one device raises —
+    and attention is independent per (batch row, head), so each device
+    runs the unmodified kernel on its `attn_spec` block."""
+    if attn_spec is None:
+        return fn
+    return lambda *ops: jax.shard_map(
+        fn, mesh=mesh, in_specs=(attn_spec,) * len(ops),
+        out_specs=attn_spec, check_vma=False)(*ops)
+
+
 def decoder_layer(lp, h, rope, config: LlamaConfig, sp_axis=None,
-                  sp_impl="ring", mesh=None):
+                  sp_impl="ring", mesh=None, attn_spec=None):
     """One decoder layer, pure. h: (B, S, H). rope: (cos, sin) or
     (cos, sin, sri) where sri is a FlashMask startend_row_indices
     tensor (B, 1, S_k, n) for packed-document attention.
@@ -123,7 +137,12 @@ def decoder_layer(lp, h, rope, config: LlamaConfig, sp_axis=None,
     heads % sp == 0). See parallel/ulysses.py for the trade. The
     attention is wrapped in its own shard_map over `mesh` (required
     with sp_axis): plain jit/GSPMD never binds named axes, so the
-    _local collectives cannot be called bare from here."""
+    _local collectives cannot be called bare from here.
+
+    attn_spec: PartitionSpec of the (B, H, S, D) attention operands on
+    `mesh` (make_train_step derives it: batch over the batch axes,
+    heads over tp); the flash / flashmask kernel then runs under
+    `_sharded_attn`."""
     c = config
     cos, sin = rope[0], rope[1]
     sri = rope[2] if len(rope) > 2 else None
@@ -156,9 +175,13 @@ def decoder_layer(lp, h, rope, config: LlamaConfig, sp_axis=None,
         # packed-document pretraining: causal within each document,
         # blocked across documents — flashmask kernel, no dense mask
         sri_h = jnp.broadcast_to(sri, (b, nh, s, sri.shape[-1]))
-        o = flashmask_attention_bhsd(q, k, v, sri_h, causal=True)
+        o = _sharded_attn(
+            functools.partial(flashmask_attention_bhsd, causal=True),
+            mesh, attn_spec)(q, k, v, sri_h)
     else:
-        o = flash_attention_bhsd(q, k, v, causal=True)
+        o = _sharded_attn(
+            functools.partial(flash_attention_bhsd, causal=True),
+            mesh, attn_spec)(q, k, v)
     attn_out = o.swapaxes(1, 2).reshape(b, s, H) @ lp["wo"]
     h = h + attn_out
 
@@ -169,7 +192,7 @@ def decoder_layer(lp, h, rope, config: LlamaConfig, sp_axis=None,
 
 def forward(params, input_ids, config: LlamaConfig, mesh=None, n_micro=None,
             remat=True, sp_axis=None, doc_ids=None, return_hidden=False,
-            sp_impl="ring"):
+            sp_impl="ring", attn_spec=None):
     """→ logits (B, S, V). Uses pipeline when mesh has pp>1, else scan.
 
     doc_ids: optional (B, S) contiguous document ids for packed-sequence
@@ -209,7 +232,8 @@ def forward(params, input_ids, config: LlamaConfig, mesh=None, n_micro=None,
             "the pipeline's — shard sequence on a pp=1 mesh, or drop "
             "sp_axis")
     layer = functools.partial(decoder_layer, config=c, sp_axis=sp_axis,
-                              sp_impl=sp_impl, mesh=mesh)
+                              sp_impl=sp_impl, mesh=mesh,
+                              attn_spec=attn_spec)
     if remat == "dots":
         # save matmul outputs, recompute only elementwise — ~MFU win over
         # full remat when activations still fit in HBM
@@ -282,16 +306,18 @@ def _resolve_fused_ce(fused_ce):
 
 
 def loss_fn(params, batch, config, mesh=None, n_micro=None, remat=True,
-            sp_axis=None, fused_ce=False, sp_impl="ring"):
+            sp_axis=None, fused_ce=False, sp_impl="ring", attn_spec=None):
     """batch: (input_ids, labels) or (input_ids, labels, doc_ids) for
     packed-document pretraining. Labels < 0 are ignored (masked mean)."""
     s, n = loss_sum_fn(params, batch, config, mesh, n_micro, remat, sp_axis,
-                       fused_ce=fused_ce, sp_impl=sp_impl)
+                       fused_ce=fused_ce, sp_impl=sp_impl,
+                       attn_spec=attn_spec)
     return s / jnp.maximum(n, 1.0)
 
 
 def loss_sum_fn(params, batch, config, mesh=None, n_micro=None, remat=True,
-                sp_axis=None, fused_ce=False, sp_impl="ring"):
+                sp_axis=None, fused_ce=False, sp_impl="ring",
+                attn_spec=None):
     """(nll_sum, valid_count) variant — the grad-accumulation path
     accumulates these so microbatches are weighted by their VALID token
     counts, keeping n_micro=k exactly equal to the one-shot step even
@@ -304,10 +330,11 @@ def loss_sum_fn(params, batch, config, mesh=None, n_micro=None, remat=True,
     doc_ids = batch[2] if len(batch) > 2 else None
     if fused_ce:
         h = forward(params, input_ids, config, mesh, n_micro, remat, sp_axis,
-                    doc_ids=doc_ids, return_hidden=True, sp_impl=sp_impl)
+                    doc_ids=doc_ids, return_hidden=True, sp_impl=sp_impl,
+                    attn_spec=attn_spec)
         return _fused_masked_nll(h, params["lm_head"], labels)
     logits = forward(params, input_ids, config, mesh, n_micro, remat, sp_axis,
-                     doc_ids=doc_ids, sp_impl=sp_impl)
+                     doc_ids=doc_ids, sp_impl=sp_impl, attn_spec=attn_spec)
     return _masked_nll(logits, labels)
 
 
@@ -368,6 +395,7 @@ def make_train_step(config, mesh, batch_spec=P("dp"), n_micro=None, remat=True,
     phi/kernels/gpu/cross_entropy_kernel.cu fusion). None consults the
     PT_FUSED_CE env knob so bench.py/autotune can sweep it.
     """
+    ensure_compile_cache()
     fused_ce = _resolve_fused_ce(fused_ce)
     if schedule is None:
         schedule = "gpipe"
@@ -391,6 +419,20 @@ def make_train_step(config, mesh, batch_spec=P("dp"), n_micro=None, remat=True,
         except ImportError:  # pragma: no cover
             pass
     use_pp = mesh.shape.get("pp", 1) > 1
+    # on more than one device the attention kernel gets its own
+    # shard_map region (`_sharded_attn`): batch rows over the batch
+    # axes, heads over tp. The pp pipeline and the sp attentions are
+    # shard_maps already and keep their own arrangement.
+    attn_spec = None
+    if mesh.size > 1 and not use_pp and sp_axis is None:
+        batch_axes = batch_spec[0] \
+            if isinstance(batch_spec, P) and len(batch_spec) else None
+        attn_spec = P(batch_axes, "tp" if "tp" in mesh.shape else None)
+    # forward() gets the mesh only when something under it needs one
+    # (pipeline, sp attention, sharded attention kernel); None keeps
+    # the plain scan-over-layers path
+    fwd_mesh = mesh if (use_pp or sp_axis or attn_spec is not None) \
+        else None
     specs = param_specs(config, mesh, pp=use_pp)
     pshard = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
                                     is_leaf=lambda x: isinstance(x, P))
@@ -490,13 +532,10 @@ def make_train_step(config, mesh, batch_spec=P("dp"), n_micro=None, remat=True,
                 acc_s, acc_n, acc_g = acc
 
                 def sum_only(p):
-                    # mesh only when sp is on (the attention shard_map
-                    # needs it); None otherwise keeps the microbatch
-                    # forward off the pp pipeline path
-                    s, n = loss_sum_fn(p, mb_batch, config,
-                                       mesh if sp_axis else None, None,
-                                       remat, sp_axis, fused_ce=fused_ce,
-                                       sp_impl=sp_impl)
+                    s, n = loss_sum_fn(p, mb_batch, config, fwd_mesh,
+                                       None, remat, sp_axis,
+                                       fused_ce=fused_ce, sp_impl=sp_impl,
+                                       attn_spec=attn_spec)
                     return s, n
                 (s, n), g = jax.value_and_grad(sum_only, has_aux=True)(params)
                 acc_g = jax.tree_util.tree_map(
@@ -512,9 +551,8 @@ def make_train_step(config, mesh, batch_spec=P("dp"), n_micro=None, remat=True,
             grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
         else:
             loss, grads = jax.value_and_grad(loss_fn)(
-                params, batch, config,
-                mesh if (use_pp or sp_axis) else None, n_micro,
-                remat, sp_axis, fused_ce, sp_impl)
+                params, batch, config, fwd_mesh, n_micro,
+                remat, sp_axis, fused_ce, sp_impl, attn_spec)
         if clip_norm is not None:
             leaves = jax.tree_util.tree_leaves(grads)
             gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))

@@ -23,6 +23,15 @@ the churning signatures.
 Exposition: `render_prometheus()` emits `pt_compile_total`,
 `pt_compile_retraces_total`, `pt_compile_seconds_total` (+ per-function
 labelled series); the serving server appends it to `/metrics`.
+
+`ensure_compile_cache()` is the ONE place the persistent XLA compile
+cache is placed (the serving engine, the train-step builder and
+chip_smoke.py call it): where `JAX_COMPILATION_CACHE_DIR` is set, jax
+reads it itself and nothing is assigned here; otherwise the cache lives
+at `<checkout>/.jax_cache` — a fixed path, never one built from a temp
+name, pid or time: a directory that moves between runs never hits.
+Hits are counted from jax's own `/jax/compilation_cache/cache_hits`
+monitoring event, not guessed from wall time.
 """
 from __future__ import annotations
 
@@ -31,19 +40,18 @@ import os
 import threading
 import time
 
-from .._env import env_float, env_int
+from .._env import env_int
 
 __all__ = ["CompileRegistry", "REGISTRY", "tracked", "track_jit",
            "signature_of", "set_context", "snapshot",
-           "render_prometheus", "reset"]
+           "render_prometheus", "reset", "ensure_compile_cache"]
 
 DEFAULT_WARN_AFTER = env_int("PADDLE_TPU_RETRACE_WARN")
 
-# first-call wall time below which a compile is attributed to the
-# persistent XLA compilation cache (PT_COMPILE_CACHE): a real
-# trace+lower+compile of a serving program takes 100s of ms even for
-# toy models, a disk cache hit is a deserialize
-CACHE_HIT_S = env_float("PT_COMPILE_CACHE_HIT_S")
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_listening = False      # jax's listener list only grows: register once
 
 
 def signature_of(args, kwargs=None):
@@ -100,20 +108,17 @@ class CompileRegistry:
         # warn_hook(name, stats_dict) — default: structured log event +
         # flight-recorder entry (set at call time so tests can swap it)
         self.warn_hook = warn_hook
-        # persistent XLA compilation cache (PT_COMPILE_CACHE): set via
-        # note_persistent_cache() when the serving engine wires
-        # jax_compilation_cache_dir. While set, compiles whose
-        # first-call time beats CACHE_HIT_S are tagged cache hits —
-        # the restart-runbook signal that a warm restart skipped its
-        # recompiles (docs/reliability.md).
+        # persistent XLA compilation cache: the directory
+        # ensure_compile_cache() placed it in, and how many executables
+        # jax loaded from it instead of compiling (its own monitoring
+        # event) — the restart-runbook signal that a warm restart
+        # skipped its recompiles (docs/reliability.md).
         self.persistent_cache_dir = None
         self.cache_hits = 0
 
-    def note_persistent_cache(self, cache_dir):
-        """Record that jax's persistent compilation cache is active at
-        `cache_dir` — enables cache-hit attribution in note_call."""
+    def note_cache_hit(self):
         with self._lock:
-            self.persistent_cache_dir = str(cache_dir)
+            self.cache_hits += 1
 
     def set_context(self, **tags):
         """One-shot annotation consumed by the NEXT reported call: when
@@ -125,9 +130,10 @@ class CompileRegistry:
             self._context = tags or None
 
     # -- reporting -----------------------------------------------------
-    def note_call(self, name, signature, elapsed_s=None):
+    def note_call(self, name, signature, elapsed_s=None, cache_hit=False):
         """Record one call; returns True when it was a compile (the
-        signature was never seen for this function)."""
+        signature was never seen for this function). `cache_hit`: jax
+        loaded an executable from the persistent cache during it."""
         with self._lock:
             st = self._fns.get(name)
             if st is None:
@@ -141,11 +147,6 @@ class CompileRegistry:
                 st.compiles += 1
                 if elapsed_s is not None:
                     st.compile_seconds += elapsed_s
-                cache_hit = (self.persistent_cache_dir is not None
-                             and elapsed_s is not None
-                             and elapsed_s < CACHE_HIT_S)
-                if cache_hit:
-                    self.cache_hits += 1
                 retrace = st.compiles > 1
                 warn = (not st.warned and
                         st.compiles >= self.warn_after)
@@ -194,10 +195,12 @@ class CompileRegistry:
                     sig = signature_of(args, kwargs)
                 except Exception:   # never let telemetry break the call
                     sig = ("<unhashable>",)
+                hits0 = self.cache_hits
                 t0 = time.perf_counter()
                 out = fn(*args, **kwargs)
                 compiled = self.note_call(
-                    label, sig, elapsed_s=time.perf_counter() - t0)
+                    label, sig, elapsed_s=time.perf_counter() - t0,
+                    cache_hit=self.cache_hits > hits0)
                 # device cost accounting: a compile captures the new
                 # executable's XLA cost/memory analysis (shape-only
                 # AOT re-resolve — donated buffers are fine), and
@@ -245,7 +248,7 @@ class CompileRegistry:
             "# TYPE pt_compile_seconds_total counter",
             f"pt_compile_seconds_total {t['compile_seconds']:.6f}",
             "# HELP pt_compile_cache_hits_total compiles served from "
-            "the persistent XLA compilation cache (PT_COMPILE_CACHE).",
+            "the persistent XLA compilation cache.",
             "# TYPE pt_compile_cache_hits_total counter",
             f"pt_compile_cache_hits_total {t['cache_hits']}",
         ]
@@ -273,6 +276,33 @@ class CompileRegistry:
 
 
 REGISTRY = CompileRegistry()
+
+
+def _on_jax_event(event, **_):
+    if event == _CACHE_HIT_EVENT:
+        REGISTRY.note_cache_hit()
+
+
+def ensure_compile_cache():
+    """Place jax's persistent compilation cache, once per process, and
+    return its directory. `JAX_COMPILATION_CACHE_DIR` set: jax uses it
+    and `jax_compilation_cache_dir` is assigned nowhere; unset:
+    `<checkout>/.jax_cache`. Also starts counting jax's cache-hit
+    events into `REGISTRY.cache_hits`."""
+    global _listening
+    with REGISTRY._lock:
+        if REGISTRY.persistent_cache_dir is not None:
+            return REGISTRY.persistent_cache_dir
+        import jax
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not cache_dir:
+            cache_dir = os.path.join(_CHECKOUT, ".jax_cache")
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+        REGISTRY.persistent_cache_dir = cache_dir
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _listening = True
+    return cache_dir
 
 
 def tracked(name=None, registry=None):

@@ -25,13 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 # (8,128)-aligned tile sizes; overridable for on-chip tuning sweeps.
 # Canonical defaults live in _tuning_defaults (shared with autotune +
@@ -42,6 +36,8 @@ DEFAULT_BLOCK_K = flash_block_k()
 # np.float32: a bare Python float lowers as an f64 constant inside Mosaic,
 # and v5e libtpu rejects 'tpu.truncf f64->f32' — keep all kernel consts f32.
 NEG_INF = np.float32(-1e30)
+F0 = np.float32(0.0)
+F1 = np.float32(1.0)
 # index-map constants likewise must be i32: under jax_enable_x64 a literal 0
 # traces as i64 and Mosaic fails to legalize the index-map func.return.
 Z = np.int32(0)
@@ -61,10 +57,9 @@ def _fit_lanes(x128, n):
 
 
 def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialise raises here: answering False
+    # would silently select the jnp reference on a machine with a chip
+    return jax.default_backend() == "tpu"
 
 
 def pallas_disabled() -> bool:
@@ -122,10 +117,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         d = q.shape[-1]
         if sk % block_k != 0:
             km = _col_mask(ki * block_k, block_k, sk, d)
-            k = jnp.where(km, k, 0.0)
-            v = jnp.where(km, v, 0.0)
+            k = jnp.where(km, k, jnp.zeros_like(k))
+            v = jnp.where(km, v, jnp.zeros_like(v))
         if sq % block_q != 0:
-            q = jnp.where(_col_mask(qi * block_q, block_q, sq, d), q, 0.0)
+            q = jnp.where(_col_mask(qi * block_q, block_q, sq, d), q,
+                          jnp.zeros_like(q))
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         rows = qi * block_q + jax.lax.broadcasted_iota(
@@ -161,7 +157,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ki == n_k - 1)
     def _finalize():
         l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        l_safe = jnp.where(l == F0, F1, l)
         d = o_ref.shape[-1]
         o_ref[0] = (acc_ref[:] / _fit_lanes(l_safe, d)).astype(o_ref.dtype)
         lse_ref[0] = m_ref[:] + jnp.log(l_safe)
@@ -183,9 +179,7 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_k=n_k,
                                sq=sq, sk=sk)
-    mem = pltpu.VMEM if _HAS_PLTPU else None
-    spec = lambda bs, im: pl.BlockSpec(bs, im, memory_space=mem) if mem else \
-        pl.BlockSpec(bs, im)
+    spec = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
@@ -233,10 +227,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         d = q.shape[-1]
         if sk % block_k != 0:
             km = _col_mask(ki * block_k, block_k, sk, d)
-            k = jnp.where(km, k, 0.0)
-            v = jnp.where(km, v, 0.0)
+            k = jnp.where(km, k, jnp.zeros_like(k))
+            v = jnp.where(km, v, jnp.zeros_like(v))
         if sq % block_q != 0:
-            q = jnp.where(_col_mask(qi * block_q, block_q, sq, d), q, 0.0)
+            q = jnp.where(_col_mask(qi * block_q, block_q, sq, d), q,
+                          jnp.zeros_like(q))
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         rows = qi * block_q + jax.lax.broadcasted_iota(
@@ -248,7 +243,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             valid = valid & (rows >= cols)
         s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))
-        p = jnp.where(valid, p, 0.0)
+        p = jnp.where(valid, p, jnp.zeros_like(p))
         do = do_ref[0].astype(jnp.float32)
         dp = jax.lax.dot_general(do, v.astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
@@ -257,7 +252,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         # undefined lse/delta, and 0 * inf would poison the accumulator
         ds = jnp.where(valid,
                        p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1])) * scale,
-                       0.0)
+                       F0)
         dq_acc[:] += jax.lax.dot_general(ds, k.astype(jnp.float32),
                                          (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -292,12 +287,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         d = q.shape[-1]
         if sk % block_k != 0:
             km = _col_mask(ki * block_k, block_k, sk, d)
-            k = jnp.where(km, k, 0.0)
-            v = jnp.where(km, v, 0.0)
+            k = jnp.where(km, k, jnp.zeros_like(k))
+            v = jnp.where(km, v, jnp.zeros_like(v))
         qm = None
         if sq % block_q != 0:
             qm = _col_mask(qi * block_q, block_q, sq, d)
-            q = jnp.where(qm, q, 0.0)
+            q = jnp.where(qm, q, jnp.zeros_like(q))
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         rows = qi * block_q + jax.lax.broadcasted_iota(
@@ -309,10 +304,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             valid = valid & (rows >= cols)
         s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))  # (bq, bk)
-        p = jnp.where(valid, p, 0.0)
+        p = jnp.where(valid, p, jnp.zeros_like(p))
         do = do_ref[0].astype(jnp.float32)
         if qm is not None:
-            do = jnp.where(qm, do, 0.0)
+            do = jnp.where(qm, do, jnp.zeros_like(do))
         dv_acc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v.astype(jnp.float32),
@@ -320,7 +315,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  preferred_element_type=jnp.float32)
         ds = jnp.where(valid,
                        p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1])) * scale,
-                       0.0)
+                       F0)
         dk_acc[:] += jax.lax.dot_general(ds, q.astype(jnp.float32),
                                          (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -355,9 +350,7 @@ def _bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret)
     lser = jnp.broadcast_to(lse.reshape(bh, sq)[..., None], (bh, sq, LANES))
     deltar = jnp.broadcast_to(delta.reshape(bh, sq)[..., None], (bh, sq, LANES))
 
-    mem = pltpu.VMEM if _HAS_PLTPU else None
-    spec = lambda bs, im: pl.BlockSpec(bs, im, memory_space=mem) if mem else \
-        pl.BlockSpec(bs, im)
+    spec = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -374,7 +367,7 @@ def _bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret)
         ],
         out_specs=[spec((1, block_q, d), lambda b_, qi, ki: (b_, qi, Z))],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)] if _HAS_PLTPU else [],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)[0]
 
@@ -402,7 +395,7 @@ def _bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret)
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
-        ] if _HAS_PLTPU else [],
+        ],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
@@ -483,7 +476,7 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
             s = jnp.where(cm, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         keep = jax.random.bernoulli(prng.next_key(), 1.0 - dropout, p.shape)
-        p = jnp.where(keep, p / (1.0 - dropout), 0.0)
+        p = jnp.where(keep, p / (1.0 - dropout), F0)
         o = jnp.einsum("bhqk,bhkd->bhqd", p,
                        v.astype(jnp.float32)).astype(q.dtype)
     else:

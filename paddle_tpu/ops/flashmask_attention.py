@@ -38,8 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import (_HAS_PLTPU, pltpu, NEG_INF, Z, LANES,
+from .flash_attention import (F0, F1, NEG_INF, Z, LANES,
                               _col_mask, _fit_lanes, _on_tpu,
                               pallas_disabled, DEFAULT_BLOCK_Q,
                               DEFAULT_BLOCK_K)
@@ -52,13 +53,13 @@ def _zero_oob(qi, ki, q, k, v, do=None, *, block_q, block_k, sq, sk):
     d = q.shape[-1]
     if sk % block_k != 0:
         km = _col_mask(ki * block_k, block_k, sk, d)
-        k = jnp.where(km, k, 0.0)
-        v = jnp.where(km, v, 0.0)
+        k = jnp.where(km, k, jnp.zeros_like(k))
+        v = jnp.where(km, v, jnp.zeros_like(v))
     if sq % block_q != 0:
         qm = _col_mask(qi * block_q, block_q, sq, d)
-        q = jnp.where(qm, q, 0.0)
+        q = jnp.where(qm, q, jnp.zeros_like(q))
         if do is not None:
-            do = jnp.where(qm, do, 0.0)
+            do = jnp.where(qm, do, jnp.zeros_like(do))
     return (q, k, v) if do is None else (q, k, v, do)
 
 
@@ -212,7 +213,7 @@ def flashmask_reference(q, k, v, sri=None, causal=True, window=None,
     s = jnp.where(keep, s, NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
-    p = jnp.where(keep, p, 0.0)
+    p = jnp.where(keep, p, jnp.zeros_like(p))
     if dropout > 0.0:
         assert dropout_seed is not None, "dropout requires dropout_seed"
         B, H = p.shape[0], p.shape[1]
@@ -223,7 +224,7 @@ def flashmask_reference(q, k, v, sri=None, causal=True, window=None,
             jnp.broadcast_to(cols[None, None], p.shape),
             bh, jnp.asarray(dropout_seed, jnp.int32).reshape(()),
             dropout)
-        p = jnp.where(keep_p, p / (1.0 - dropout), 0.0)
+        p = jnp.where(keep_p, p / (1.0 - dropout), F0)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
     return o.astype(q.dtype), lse
 
@@ -276,7 +277,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, o_ref, lse_ref,
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - _fit_lanes(m_new, s.shape[-1]))
-        p = jnp.where(keep, p, 0.0)
+        p = jnp.where(keep, p, jnp.zeros_like(p))
         alpha = jnp.exp(m_prev - m_new)
         # l (→ lse) accumulates the UNdropped p: dropout applies to the
         # normalized probabilities (reference kernel semantics), which
@@ -286,7 +287,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, o_ref, lse_ref,
         if dropout > 0.0:
             dkeep, inv = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
                                     dropout)
-            pd = jnp.where(dkeep, p * inv, 0.0)
+            pd = jnp.where(dkeep, p * inv, F0)
         acc_ref[:] = acc_ref[:] * _fit_lanes(alpha, d) + jax.lax.dot_general(
             pd.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -296,7 +297,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, o_ref, lse_ref,
     @pl.when(ki == n_k - 1)
     def _finalize():
         l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        l_safe = jnp.where(l == F0, F1, l)
         d = o_ref.shape[-1]
         o_ref[0] = (acc_ref[:] / _fit_lanes(l_safe, d)).astype(o_ref.dtype)
         lse_ref[0] = m_ref[:] + jnp.log(l_safe)
@@ -327,7 +328,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
         keep = keep_mask()
         s = jnp.where(keep, s, NEG_INF)
         p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))
-        p = jnp.where(keep, p, 0.0)
+        p = jnp.where(keep, p, jnp.zeros_like(p))
         do = do.astype(jnp.float32)
         dp = jax.lax.dot_general(do, v.astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
@@ -337,10 +338,10 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
             # Σ_k p̃ dp (= do·o), so only dp gets the dropout mask
             dkeep, inv = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
                                     dropout)
-            dp = jnp.where(dkeep, dp * inv, 0.0)
+            dp = jnp.where(dkeep, dp * inv, F0)
         ds = jnp.where(keep,
                        p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1]))
-                       * scale, 0.0)
+                       * scale, F0)
         dq_acc[:] += jax.lax.dot_general(ds, k.astype(jnp.float32),
                                          (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -377,23 +378,23 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
         keep = keep_mask()
         s = jnp.where(keep, s, NEG_INF)
         p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))
-        p = jnp.where(keep, p, 0.0)
+        p = jnp.where(keep, p, jnp.zeros_like(p))
         do = do.astype(jnp.float32)
         pd = p
         if dropout > 0.0:
             dkeep, inv = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
                                     dropout)
-            pd = jnp.where(dkeep, p * inv, 0.0)
+            pd = jnp.where(dkeep, p * inv, F0)
         dv_acc[:] += jax.lax.dot_general(pd, do, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v.astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout > 0.0:
-            dp = jnp.where(dkeep, dp * inv, 0.0)
+            dp = jnp.where(dkeep, dp * inv, F0)
         ds = jnp.where(keep,
                        p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1]))
-                       * scale, 0.0)
+                       * scale, F0)
         dk_acc[:] += jax.lax.dot_general(ds, q.astype(jnp.float32),
                                          (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -425,9 +426,7 @@ def _prep(q, k, v, sri):
 
 
 def _mem_spec():
-    mem = pltpu.VMEM if _HAS_PLTPU else None
-    return (lambda bs, im: pl.BlockSpec(bs, im, memory_space=mem)
-            if mem else pl.BlockSpec(bs, im))
+    return functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
 
 def _mk_kernel(fn, have_sri, **kw):
@@ -442,14 +441,11 @@ def _mk_kernel(fn, have_sri, **kw):
 
 
 def _seed_spec():
-    if _HAS_PLTPU:
-        # explicit index map: a memory_space-only BlockSpec gets a
-        # pallas-default map whose 0 constant is i64 under x64 — Mosaic
-        # rejects the transform func returning i64 (chip-observed:
-        # "func.return (i64)" legalization failure, TPU_VALIDATION r5)
-        return pl.BlockSpec((1,), lambda *_: (Z,),
-                            memory_space=pltpu.SMEM)
-    return pl.BlockSpec((1,), lambda *_: (Z,))  # pragma: no cover
+    # explicit index map: a memory_space-only BlockSpec gets a
+    # pallas-default map whose 0 constant is i64 under x64 — Mosaic
+    # rejects the transform func returning i64 ("func.return (i64)"
+    # legalization failure)
+    return pl.BlockSpec((1,), lambda *_: (Z,), memory_space=pltpu.SMEM)
 
 
 def _seed_arr(seed):
@@ -558,8 +554,7 @@ def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
         in_specs=specs(dq_order),
         out_specs=[spec((1, block_q, d), dq_order("q"))],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
-        if _HAS_PLTPU else [],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(*base_args, dor, lser, deltar)[0]
 
@@ -581,7 +576,7 @@ def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
-        ] if _HAS_PLTPU else [],
+        ],
         interpret=interpret,
     )(*base_args, dor, lser, deltar)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),

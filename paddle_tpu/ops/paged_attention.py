@@ -28,19 +28,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _fit_lanes
+from .flash_attention import (F0, F1, LANES, NEG_INF, Z, _fit_lanes,
+                              _on_tpu)
 
-NEG_INF = np.float32(-1e30)  # f32: Mosaic rejects f64 consts under x64
-Z = np.int32(0)           # i32 index-map consts (x64 would make them i64)
-LANES = 128
 MIN_GROUP = 8  # TPU sublane minimum for the q-rows dim
-
-
-def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -151,7 +142,7 @@ def _decode_kernel(ptab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(pi == n_pages - 1)
     def _fin():
         l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        l_safe = jnp.where(l == F0, F1, l)
         o_ref[0, 0] = (acc_ref[:] /
                        _fit_lanes(l_safe, o_ref.shape[-1])).astype(o_ref.dtype)
 
@@ -312,8 +303,7 @@ def _verify_kernel(ptab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.int32, s.shape, 1)
         # np.int32 divisor, NOT the bare python int: `% n_tok` binds the
         # int as a strong i64 const under x64, and Mosaic's int64->int32
-        # convert recurses forever (chip-observed RecursionError,
-        # TPU_VALIDATION r5).
+        # convert recurses forever (chip-observed RecursionError).
         g_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
             % np.int32(n_tok)
         s = jnp.where(cols < base + g_row + 1, s, NEG_INF)
@@ -331,7 +321,7 @@ def _verify_kernel(ptab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(pi == n_pages - 1)
     def _fin():
         l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        l_safe = jnp.where(l == F0, F1, l)
         o_ref[0, 0] = (acc_ref[:] /
                        _fit_lanes(l_safe, o_ref.shape[-1])).astype(o_ref.dtype)
 

@@ -33,15 +33,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, LANES,
-                              NEG_INF, Z, _fit_lanes, _on_tpu)
+from .flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, F0, F1,
+                              LANES, NEG_INF, Z, _fit_lanes, _on_tpu)
 
 SUBLANES = 8
 
@@ -78,9 +73,9 @@ def varlen_reference(q, k, v, seg_q, seg_k, causal, scale):
     s = jnp.where(valid[None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
-    e = jnp.where(valid[None], e, 0.0)
+    e = jnp.where(valid[None], e, jnp.zeros_like(e))
     l = jnp.sum(e, axis=-1, keepdims=True)
-    l_safe = jnp.where(l == 0.0, 1.0, l)
+    l_safe = jnp.where(l == F0, F1, l)
     o = jnp.einsum("hqk,hkd->hqd", e / l_safe, v.astype(jnp.float32))
     lse = (m + jnp.log(l_safe))[..., 0]
     return o.astype(q.dtype), lse
@@ -124,7 +119,8 @@ def _vfwd_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, pq_ref, pk_ref,
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - _fit_lanes(m_new, s.shape[-1]))
-        p = jnp.where(valid, p, 0.0)      # rows with no valid col stay 0
+        # rows with no valid col stay 0
+        p = jnp.where(valid, p, jnp.zeros_like(p))
         alpha = jnp.exp(m_prev - m_new)
         l_ref[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * _fit_lanes(alpha, d) + jax.lax.dot_general(
@@ -143,7 +139,7 @@ def _vfwd_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, pq_ref, pk_ref,
     @pl.when(ki == n_k - 1)
     def _finalize():
         l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        l_safe = jnp.where(l == F0, F1, l)
         d = o_ref.shape[-1]
         o_ref[0] = (acc_ref[:] / _fit_lanes(l_safe, d)).astype(o_ref.dtype)
         lse_ref[0] = m_ref[:] + jnp.log(l_safe)
@@ -171,9 +167,7 @@ def _vfwd_pallas(q, k, v, seg_q, seg_k, pos_q, pos_k, causal, same_offsets,
     pq2 = jnp.broadcast_to(pos_q[:, None], (tq, LANES))
     pk2 = jnp.broadcast_to(pos_k[None, :], (SUBLANES, tk))
 
-    mem = pltpu.VMEM if _HAS_PLTPU else None
-    spec = (lambda bs, im: pl.BlockSpec(bs, im, memory_space=mem)
-            if mem else pl.BlockSpec(bs, im))
+    spec = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     kernel = functools.partial(_vfwd_kernel, scale=scale, causal=causal,
                                same_offsets=same_offsets,
                                block_q=block_q, block_k=block_k, n_k=n_k)
@@ -232,14 +226,14 @@ def _vbwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                                          s.shape[-1]))
         s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))
-        p = jnp.where(valid, p, 0.0)
+        p = jnp.where(valid, p, jnp.zeros_like(p))
         do = do_ref[0].astype(jnp.float32)
         dp = jax.lax.dot_general(do, v.astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = jnp.where(valid,
                        p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1]))
-                       * scale, 0.0)
+                       * scale, F0)
         dq_acc[:] += jax.lax.dot_general(ds, k.astype(jnp.float32),
                                          (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -280,7 +274,7 @@ def _vbwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                                          s.shape[-1]))
         s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))
-        p = jnp.where(valid, p, 0.0)
+        p = jnp.where(valid, p, jnp.zeros_like(p))
         do = do_ref[0].astype(jnp.float32)
         dv_acc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -289,7 +283,7 @@ def _vbwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  preferred_element_type=jnp.float32)
         ds = jnp.where(valid,
                        p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1]))
-                       * scale, 0.0)
+                       * scale, F0)
         dk_acc[:] += jax.lax.dot_general(ds, q.astype(jnp.float32),
                                          (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -322,9 +316,7 @@ def _vbwd_pallas(q, k, v, o, lse, do, seg_q, seg_k, pos_q, pos_k, causal,
     pq2 = jnp.broadcast_to(pos_q[:, None], (tq, LANES))
     pk2 = jnp.broadcast_to(pos_k[None, :], (SUBLANES, tk))
 
-    mem = pltpu.VMEM if _HAS_PLTPU else None
-    spec = (lambda bs, im: pl.BlockSpec(bs, im, memory_space=mem)
-            if mem else pl.BlockSpec(bs, im))
+    spec = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
     dq = pl.pallas_call(
         functools.partial(_vbwd_dq_kernel, scale=scale, causal=causal,
@@ -345,8 +337,7 @@ def _vbwd_pallas(q, k, v, o, lse, do, seg_q, seg_k, pos_q, pos_k, causal,
         ],
         out_specs=[spec((1, block_q, d), lambda hi, qi, ki: (hi, qi, Z))],
         out_shape=[jax.ShapeDtypeStruct((h, tq, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
-        if _HAS_PLTPU else [],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, do, lser, deltar, sq2, sk2, pq2, pk2)[0]
 
@@ -378,7 +369,7 @@ def _vbwd_pallas(q, k, v, o, lse, do, seg_q, seg_k, pos_q, pos_k, causal,
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
-        ] if _HAS_PLTPU else [],
+        ],
         interpret=interpret,
     )(q, k, v, do, lser, deltar, sq2, sk2, pq2, pk2)
     return dq, dk, dv
@@ -529,10 +520,10 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
                              rev_pos(seg_q)[:, None])
         s_ = jnp.where(valid[None], s_, NEG_INF)
         pmat = jax.nn.softmax(s_, axis=-1)
-        pmat = jnp.where(valid[None], pmat, 0.0)
+        pmat = jnp.where(valid[None], pmat, jnp.zeros_like(pmat))
         keep = jax.random.bernoulli(prng.next_key(), 1.0 - dropout,
                                     pmat.shape)
-        pmat = jnp.where(keep, pmat / (1.0 - dropout), 0.0)
+        pmat = jnp.where(keep, pmat / (1.0 - dropout), F0)
         oh = jnp.einsum("hqk,khd->hqd", pmat, vv.astype(jnp.float32))
         return (jnp.swapaxes(oh, 0, 1).astype(query.dtype), None)
     out = flash_attention_varlen(query, key, value, seg_q, seg_k,

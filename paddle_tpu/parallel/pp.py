@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from .._core.compat import shard_map
 
 
 def stack_layer_params(layer_params_list):
@@ -146,7 +145,7 @@ def pipeline_apply(stage_params, x, layer_fn, mesh, pp_axis="pp", n_micro=None,
                                   jnp.zeros_like(outs)), pp_axis)
         return outs
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         per_rank, mesh=mesh,
         in_specs=(P(pp_axis), P(), P()),
         out_specs=P(),
@@ -298,7 +297,7 @@ def pipeline_train_1f1b(stage_params, x, targets, layer_fn, head_fn,
             tick, carry0, jnp.arange(total))
         return _epilogue(s, S, pp_axis, gparams, ghead, dx, losses, wts)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         per_rank, mesh=mesh,
         in_specs=(P(pp_axis), P(), P(), P(), P()),
         out_specs=(P(pp_axis), P(), P(), P(), P()),
@@ -635,7 +634,7 @@ def pipeline_train_interleaved(stage_params, x, targets, layer_fn, head_fn,
             tick, carry0, tabs)
         return _epilogue(r, S, pp_axis, gparams, ghead, dx, losses, wts)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         per_rank, mesh=mesh,
         in_specs=(P(pp_axis), P(), P(), P(), P(), P()),
         out_specs=(P(pp_axis), P(), P(), P(), P()),

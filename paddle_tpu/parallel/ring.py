@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from .._core.compat import axis_size, shard_map
 
 NEG_INF = -1e30
 
@@ -45,7 +44,7 @@ def ring_attention_local(q, k, v, axis_name, causal=False, sm_scale=None,
     to 512 when S_local exceeds it). The chunk body is jax.checkpoint'd so
     the bound holds under AD too: backward recomputes each chunk's scores
     instead of stacking per-chunk softmax residuals."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     d = q.shape[-1]
     s_local = q.shape[-2]
@@ -127,9 +126,9 @@ def ring_attention(q, k, v, mesh, sp_axis="sp", causal=False, sm_scale=None,
                            causal=causal, sm_scale=sm_scale,
                            q_chunk=q_chunk)
     spec = P(None, None, sp_axis, None)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, axis_names=frozenset({sp_axis}),
-                     check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names=frozenset({sp_axis}),
+                         check_vma=False)(q, k, v)
 
 
 def sequence_shard(x, mesh, sp_axis="sp", seq_dim=1):

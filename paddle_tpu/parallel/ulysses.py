@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from .._core.compat import axis_size, shard_map
 
 from ..ops.flash_attention import flash_attention_bhsd
 
@@ -41,7 +40,7 @@ def ulysses_attention_local(q, k, v, axis_name, causal=False, sm_scale=None):
     ride the all-to-all at kv width and are repeated to full head count
     only AFTER the re-shard — nh/nkv times fewer K/V wire bytes than
     repeating up front. Returns (B, H, S_local, D), same sharding."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     H, Hkv = q.shape[1], k.shape[1]
     if H % n:
         raise ValueError(
@@ -77,6 +76,6 @@ def ulysses_attention(q, k, v, mesh, sp_axis="sp", causal=False,
     fn = functools.partial(ulysses_attention_local, axis_name=sp_axis,
                            causal=causal, sm_scale=sm_scale)
     spec = P(None, None, sp_axis, None)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, axis_names=frozenset({sp_axis}),
-                     check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names=frozenset({sp_axis}),
+                         check_vma=False)(q, k, v)

@@ -1747,7 +1747,13 @@ def spawn_worker(spec, *, python=None, env=None, stdout=None,
 
     The child builds its engine deterministically from
     (model, seed, dtype) — the cross-process token-identity
-    guarantee: same spec, same params, same trajectories."""
+    guarantee: same spec, same params, same trajectories.
+
+    An accelerator belongs to one process: a parent that has
+    initialised jax holds the chip, and a worker spawned from it that
+    needs the chip fails or hangs. Spawn accelerator workers from a
+    parent that stays off jax (one worker per host), or give the
+    workers `env={"JAX_PLATFORMS": "cpu"}`."""
     import subprocess
     cmd = [python or sys.executable, "-m", "paddle_tpu.serving.fleet",
            "--spec", json.dumps(spec)]

@@ -189,14 +189,25 @@ def build_replicas(engine_factory, n, *, max_queue=64, prefix="r",
                    idle_poll_s=0.02, pipeline=None, roles=None,
                    **sched_kw):
     """N independent replicas from an engine factory. The factory is
-    called once per replica — each gets its own params reference but
-    its own KV pool, prefix cache, scheduler, and metrics registry
-    (`engine_factory(i) -> ServingEngine`). `roles` is an optional
-    per-replica role list (short lists pad with "both") for a
-    disaggregated prefill/decode topology."""
+    called once per replica — each gets its own KV pool, prefix cache,
+    scheduler, and metrics registry (`engine_factory(i) ->
+    ServingEngine`). Replica i's factory runs under
+    `jax.default_device(jax.local_devices()[i % n_local])`, and an
+    engine lives on the device it was built on: on a four-chip host
+    four replicas take one chip each (weights included) instead of
+    sharing chip 0; with more replicas than devices they wrap around.
+    `roles` is an optional per-replica role list (short lists pad with
+    "both") for a disaggregated prefill/decode topology."""
+    import jax
+    devices = jax.local_devices()
     roles = list(roles or [])
     roles += ["both"] * (int(n) - len(roles))
-    return [Replica(f"{prefix}{i}", engine_factory(i),
-                    max_queue=max_queue, idle_poll_s=idle_poll_s,
-                    pipeline=pipeline, role=roles[i], **sched_kw)
-            for i in range(int(n))]
+    replicas = []
+    for i in range(int(n)):
+        with jax.default_device(devices[i % len(devices)]):
+            engine = engine_factory(i)
+        replicas.append(Replica(f"{prefix}{i}", engine,
+                                max_queue=max_queue,
+                                idle_poll_s=idle_poll_s, pipeline=pipeline,
+                                role=roles[i], **sched_kw))
+    return replicas

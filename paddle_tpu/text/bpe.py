@@ -29,9 +29,10 @@ def _load_lib():
     if _LIB is not None:
         return _LIB
     so = os.path.join(_CSRC, "libpttext.so")
-    if not os.path.exists(so):
-        subprocess.run(["make", "-C", _CSRC, "libpttext.so"], check=True,
-                       capture_output=True)
+    # make every time: the rule depends on pttext.cpp, so a fresh .so
+    # is a no-op and what loads is always built from the committed source
+    subprocess.run(["make", "-C", _CSRC, "libpttext.so"], check=True,
+                   capture_output=True)
     lib = ctypes.CDLL(so)
     lib.pttok_create.restype = ctypes.c_void_p
     lib.pttok_destroy.argtypes = [ctypes.c_void_p]

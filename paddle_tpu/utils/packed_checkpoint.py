@@ -28,9 +28,10 @@ def _load_lib():
     if _LIB is not None:
         return _LIB
     so = os.path.join(_CSRC, "libptckpt.so")
-    if not os.path.exists(so):
-        subprocess.run(["make", "-C", _CSRC, "libptckpt.so"], check=True,
-                       capture_output=True)
+    # make every time: the rule depends on ptckpt.cpp, so a fresh .so
+    # is a no-op and what loads is always built from the committed source
+    subprocess.run(["make", "-C", _CSRC, "libptckpt.so"], check=True,
+                   capture_output=True)
     lib = ctypes.CDLL(so)
     lib.ptckpt_writer_open.restype = ctypes.c_void_p
     lib.ptckpt_writer_open.argtypes = [ctypes.c_char_p]

@@ -204,7 +204,7 @@ class TestOrbaxInterop:
         monkeypatch.setattr(
             ocp.StandardCheckpointer, "save",
             lambda self, *a, **k: (_ for _ in ()).throw(
-                RuntimeError("tunnel died")))
+                RuntimeError("disk died")))
         with pytest.raises(RuntimeError):
             save_orbax(p, {"v": v + 1})
         assert np.allclose(load_orbax(p)["v"], v)

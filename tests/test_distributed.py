@@ -98,9 +98,9 @@ class TestCollectivesInsideShardMap:
 
         def f(a):
             return jax.lax.psum(a, "x")
-        from paddle_tpu._core.compat import shard_map
-        out = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(),
-                        axis_names=frozenset({"x"}))(jnp.arange(8.0))
+        out = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(),
+                            axis_names=frozenset({"x"}),
+                            check_vma=False)(jnp.arange(8.0))
         assert np.asarray(out).ravel()[0] == 28.0
 
     def test_eager_all_reduce_on_sharded_tensor(self):

@@ -1,8 +1,8 @@
-"""Perf-regression guard (VERDICT r1 item 9, SURVEY §4 'perf guard').
+"""Perf-regression guard (SURVEY §4 'perf guard').
 
-bench.py appends every run to BENCH_HISTORY.jsonl; this test compares
-the two most recent entries with the same backend + config and fails on
-a >25% throughput drop. Skips until two comparable datapoints exist
+bench.py appends every run to BENCH_HISTORY.jsonl (a run-time record,
+not committed); this test compares the two most recent entries with the
+same backend + config and fails on a >25% throughput drop. Skips until two comparable datapoints exist
 (e.g. first round on a machine, or CPU-only CI where only smoke entries
 accumulate — CPU smoke numbers on shared machines are too noisy, so
 only TPU entries are guarded).
@@ -39,38 +39,11 @@ def _entries():
     return out
 
 
-def test_tpu_history_skips_invalid_entries(tmp_path, monkeypatch):
-    """bench.py._tpu_history must never surface an extra.invalid entry
-    (the 2026-08-01 terminal-memoization phantoms) as last OR best."""
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(_ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    hist = tmp_path / "BENCH_HISTORY.jsonl"
-    rows = [
-        {"metric": "m", "value": 100.0, "unit": "t", "vs_baseline": 0.1,
-         "batch": 16, "seq": 2048,
-         "extra": {"backend": "tpu", "mfu": 0.30, "mfu_legacy": 0.33}},
-        {"metric": "m", "value": 9999.0, "unit": "t", "vs_baseline": 9.0,
-         "batch": 16, "seq": 2048,
-         "extra": {"backend": "tpu", "mfu": 2.4, "mfu_legacy": 2.7,
-                   "invalid": "terminal-memoization"}},
-    ]
-    hist.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-    # point the module at tmp_path via its __file__ (patching
-    # os.path.dirname would hijack the shared posixpath module)
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-    last, best = bench._tpu_history()
-    assert last["value"] == 100.0, "invalid entry served as last"
-    assert best["value"] == 100.0, "invalid entry served as best"
-
-
 def test_no_tpu_throughput_regression():
     tpu = [e for e in _entries()
            if e.get("extra", {}).get("backend") not in (None, "cpu")
-           # entries annotated invalid after the fact (the 2026-08-01
-           # terminal-memoization phantoms) must not serve as the
-           # regression baseline — bench.py._tpu_history skips them too
+           # entries annotated invalid after the fact must not serve
+           # as the regression baseline
            and not e.get("extra", {}).get("invalid")]
     # group by (model, batch, seq, remat) so config changes don't
     # false-alarm and bench_models.py entries (keyed by "model") never
@@ -83,8 +56,7 @@ def test_no_tpu_throughput_regression():
     # those knobs).
     # effective_knobs (shared with autotune + the kernel defaults)
     # normalizes absent/None to the kernel defaults so pre-r3 entries
-    # still compare against new same-config runs. A pallas_fallback run
-    # executed a different program — keep it out of normal groups.
+    # still compare against new same-config runs.
     by_cfg = {}
     for e in tpu:
         x = e.get("extra", {})
@@ -97,8 +69,7 @@ def test_no_tpu_throughput_regression():
                           # total; cross-regime steps/s must not
                           # regression-compare)
                           + (x.get("cache_dtype"), x.get("spec_decode"),
-                             x.get("new_tokens"), x.get("requests"))
-                          + (bool(x.get("pallas_fallback")),),
+                             x.get("new_tokens"), x.get("requests")),
                           []).append(e)
     comparable = [v for v in by_cfg.values() if len(v) >= 2]
     if not comparable:

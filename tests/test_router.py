@@ -43,6 +43,27 @@ def make_router(params, n=2, max_queue=16, **router_kw):
     return Router(reps, **router_kw)
 
 
+def test_replicas_sit_on_their_own_devices(params):
+    """build_replicas hands replica i local device i (the suite runs on
+    8 virtual devices): weights, KV pools and the token ring are all
+    committed there, and a served request leaves them there."""
+    import jax
+    reps = build_replicas(make_factory(params), 2)
+    try:
+        want = [{d} for d in jax.local_devices()[:2]]
+        assert [r.engine.device for r in reps] == jax.local_devices()[:2]
+        for attr in ("k_pool", "v_pool", "tok_buf"):
+            assert [getattr(r.engine, attr).devices() for r in reps] == want
+        assert [r.engine.params["lm_head"].devices() for r in reps] == want
+        for r in reps:
+            assert len(r.submit(header(1), max_new_tokens=2)
+                       .result(timeout=120)) == 2
+        assert [r.engine.k_pool.devices() for r in reps] == want
+    finally:
+        for r in reps:
+            r.shutdown(drain=False, timeout=10)
+
+
 def greedy_reference(params, prompt, n_new):
     ids = list(prompt)
     out = []

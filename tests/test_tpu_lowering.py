@@ -1,0 +1,165 @@
+"""Every Pallas family must LOWER for the TPU with x64 on, from a CPU host.
+
+The kernel tests run `interpret=True`, which never reaches the Mosaic
+lowering — a CPU-only change can therefore break the chip unnoticed (a
+Python float inside a kernel body becomes an f64 constant under
+`jax_enable_x64`; a primitive without a TPU rule raises). Cross-lowering
+(`lowering_platforms=("tpu",)`) builds the Mosaic module without a chip
+and without compiling, so this stays a few seconds. What it cannot see
+is Mosaic's own compile step: that is `chip_smoke.py`'s kernels phase.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu  # noqa: F401 — turns jax_enable_x64 on, as in production
+
+D, GROUP, PAGE = 128, 4, 16     # the serving shapes: head_dim, GQA group, page
+
+
+def _lower_tpu(fn, *args, **kwargs):
+    assert jax.config.jax_enable_x64
+    jitted = fn if hasattr(fn, "trace") else jax.jit(fn)
+    text = jitted.trace(*args, **kwargs).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _bhsd(s=256, h=2):
+    return [jnp.ones((1, h, s, D), jnp.bfloat16)] * 3
+
+
+def _pool(dtype, kvh=2, pages=9):
+    k = jnp.ones((kvh, pages, PAGE, D), dtype)
+    sc = jnp.ones((kvh, pages, PAGE, 1), jnp.float32) \
+        if dtype == jnp.int8 else None
+    return k, sc
+
+
+def test_flash_fwd_bwd_lowers():
+    from paddle_tpu.ops.flash_attention import flash_attention_bhsd
+
+    def loss(q, k, v):
+        return flash_attention_bhsd(q, k, v, causal=True, use_pallas=True,
+                                    interpret=False).astype(jnp.float32).sum()
+
+    _lower_tpu(jax.grad(loss, (0, 1, 2)), *_bhsd())
+    # ragged tail block: the masked-operand branches
+    _lower_tpu(jax.grad(loss, (0, 1, 2)), *_bhsd(s=200))
+
+
+def test_varlen_fwd_bwd_lowers():
+    from paddle_tpu.ops.varlen_attention import flash_attn_unpadded
+    cu = jnp.asarray([0, 100, 256], jnp.int32)
+    q = jnp.ones((256, 2, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        o, _ = flash_attn_unpadded(q, k, v, cu, cu, causal=True,
+                                   use_pallas=True, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    _lower_tpu(jax.grad(loss, (0, 1, 2)), q, q, q)
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3])
+def test_flashmask_fwd_bwd_lowers(dropout):
+    from paddle_tpu.ops.flashmask_attention import flashmask_attention_bhsd
+    s = 256
+    sri = jnp.full((1, 2, s, 1), s, jnp.int32)
+
+    def loss(q, k, v):
+        return flashmask_attention_bhsd(
+            q, k, v, sri, causal=True, use_pallas=True, interpret=False,
+            dropout=dropout, dropout_seed=7).astype(jnp.float32).sum()
+
+    _lower_tpu(jax.grad(loss, (0, 1, 2)), *_bhsd(s))
+
+
+@pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
+def test_paged_decode_and_verify_lower(cache):
+    from paddle_tpu.ops.paged_attention import (paged_attention,
+                                                paged_verify_attention)
+    kvh, b = 2, 2
+    kp, sc = _pool(cache, kvh)
+    table = jnp.zeros((b, 4), jnp.int32)
+    lens = jnp.asarray([40, 7], jnp.int32)
+    q = jnp.ones((b, kvh * GROUP, D), jnp.bfloat16)
+    _lower_tpu(lambda q, kp: paged_attention(
+        q, kp, kp, table, lens, use_pallas=True, interpret=False,
+        k_scale=sc, v_scale=sc), q, kp)
+    qv = jnp.ones((b, kvh * GROUP, 4, D), jnp.bfloat16)
+    _lower_tpu(lambda q, kp: paged_verify_attention(
+        q, kp, kp, table, lens, use_pallas=True, interpret=False,
+        k_scale=sc, v_scale=sc), qv, kp)
+
+
+@pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
+def test_ragged_lowers_without_barrier(cache):
+    from paddle_tpu.kernels.ragged_paged_attention import (
+        ragged_paged_attention)
+    kvh, t = 2, 16
+    kp, sc = _pool(cache, kvh)
+    table = jnp.zeros((2, 4), jnp.int32)
+    slot = jnp.zeros((t,), jnp.int32)
+    pos = jnp.arange(t, dtype=jnp.int32) - 2
+    q = jnp.ones((t, kvh * GROUP, D), jnp.bfloat16)
+    text = _lower_tpu(lambda q, kp: ragged_paged_attention(
+        q, kp, kp, table, slot, pos, use_pallas=True, interpret=False,
+        k_scale=sc, v_scale=sc, block_pages=2), q, kp)
+    assert "optimization_barrier" not in text
+
+
+@pytest.mark.parametrize("cache", [None, "int8"])
+def test_engine_unified_step_lowers(monkeypatch, cache):
+    """The engine's OWN first unified_step call (captured, not
+    re-imagined), re-lowered for the chip with use_pallas=True."""
+    from paddle_tpu.models import llama_serving as ls
+    from paddle_tpu.models import llama_spmd as spmd
+    from paddle_tpu.models.llama import LlamaConfig
+    cfg = LlamaConfig(vocab_size=256, hidden_size=GROUP * 2 * D,
+                      intermediate_size=256, num_hidden_layers=2,
+                      num_attention_heads=GROUP * 2,
+                      num_key_value_heads=2)
+    params = spmd.init_params(cfg, seed=0, dtype=jnp.bfloat16)
+    eng = ls.ServingEngine(params, cfg, max_seqs=2, max_seq_len=64,
+                           page_size=PAGE, dtype=jnp.bfloat16,
+                           cache_dtype=cache, use_pallas=False)
+    seen = []
+    real = ls.unified_step
+
+    def capture(*a, **kw):
+        seen.append((a, kw))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(ls, "unified_step", capture)
+    eng.submit(ls.Request(0, np.arange(1, 20).tolist(), max_new_tokens=2))
+    eng.step()
+    assert seen, "engine did not dispatch unified_step"
+    a, kw = seen[0]
+    kw = dict(kw, use_pallas=True, interpret=False)
+    text = _lower_tpu(real.__wrapped__, *a, **kw)
+    assert "optimization_barrier" not in text
+
+
+def test_mesh_train_step_lowers(monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: on a dp x tp mesh the
+    flash kernel must sit in its own shard_map or lowering raises."""
+    import importlib
+    from paddle_tpu.models import llama_spmd as spmd
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.parallel.mesh import create_mesh
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg = LlamaConfig(vocab_size=256, hidden_size=2 * D,
+                      intermediate_size=256, num_hidden_layers=1,
+                      num_attention_heads=2, num_key_value_heads=2)
+    mesh = create_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    params = spmd.place_params(
+        spmd.init_params(cfg, seed=0, dtype=jnp.bfloat16), cfg, mesh)
+    step = spmd.make_train_step(cfg, mesh, fused_ce=True, donate=False)
+    ids = np.zeros((4, 256), np.int32)
+    text = _lower_tpu(step, params, spmd.init_opt_state(params),
+                      jnp.asarray(0), (ids, ids))
+    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
