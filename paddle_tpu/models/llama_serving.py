@@ -1151,6 +1151,14 @@ class ServingEngine:
         # capacity (the kernel's early exit), not dispatched padding
         self.pad_tokens = 0
         self.ragged_tokens = 0
+        # what the ragged kernel has to do, from the same descriptors
+        # (pt_ragged_attn_pairs / pt_ragged_kv_tokens): the query-key
+        # pairs of one layer and head, and the tokens of K/V one layer
+        # must read at least once; `last_rows` is the newest wave's
+        # (decode, prefill) row mix for the pump's `serving.turn` span
+        self.ragged_attn_pairs = 0
+        self.ragged_kv_tokens = 0
+        self.last_rows = (0, 0)
         # lean row-sparse lm_head epilogue (docs/serving.md § Lean
         # epilogue): every unified/verify dispatch passes a `need_rows`
         # descriptor and the (T, vocab) logits buffer is never
@@ -2117,13 +2125,20 @@ class ServingEngine:
         Synchronous driver: launch + consume in one call. The pipelined
         pump calls `step_launch`/`step_finish` itself so the consume of
         step N overlaps the device executing step N+1."""
-        self._sweep_cancelled()
-        self._harvest_handoffs()
-        self._admit()
+        self._admit_turn()
         if self.spec_decode > 1:
             return self._spec_step()
         t = self.step_launch(_admitted=True)
         return 0 if t is None else self.step_finish(t)
+
+    def _admit_turn(self):
+        """The head of every turn: cancels, handoffs, admission (with
+        `_stage_tokbuf`'s device call) — the engine's share of the
+        turn's `admit` part."""
+        with record_span("serving.admit", part="admit"):
+            self._sweep_cancelled()
+            self._harvest_handoffs()
+            self._admit()
 
     def _note_launch_gap(self, depth):
         """Host-gap + pipeline-depth telemetry, taken at the instant a
@@ -2309,9 +2324,76 @@ class ServingEngine:
         wave — its first token is picked host-side at finish, the PR 8
         seeding convention, so outputs stay token-identical."""
         if not _admitted:
-            self._sweep_cancelled()
-            self._harvest_handoffs()
-            self._admit()
+            self._admit_turn()
+        with record_span("serving.plan", part="plan"):
+            plan = self._ragged_plan(carry)
+        if plan is None:
+            return None
+        (tokens, tok_slot, tok_pos, carry_mask, carry_gather, sampling,
+         need, flat, reqs, seeds, seed_flat, n_decode, slots) = plan
+        T = self.ragged_buf
+        with record_span("serving.stage", part="dispatch"):
+            # every host->device transfer of the wave, a dozen small
+            # arrays, BEFORE the launch-gap stamp: the gap then ends
+            # where the dispatch starts
+            sample = {k: jnp.asarray(v) for k, v in sampling.items()}
+            need_rows = None if need is None else jnp.asarray(need)
+            # page_table goes to the device as a SNAPSHOT (.copy()):
+            # see the bucketed `step_launch`
+            staged = (jnp.asarray(self.page_table.copy()),
+                      jnp.asarray(tokens), jnp.asarray(tok_slot),
+                      jnp.asarray(tok_pos))
+            if self.tok_buf is not None:
+                # device token ring: no carry operands (the ring's
+                # in-jit scatter/gather IS the carry) — decode rows
+                # write their sampled token for the next wave to read
+                bw = np.zeros((self.need_buf if self.lean else T,),
+                              bool)
+                bw[:n_decode] = True
+                carry_kw = {"tok_buf": self.tok_buf,
+                            "buf_write": jnp.asarray(bw)}
+            else:
+                carry_kw = {
+                    "carry_tok": carry.next_tok if carry is not None
+                    else jnp.zeros((self.need_buf if self.lean else T,),
+                                   jnp.int32),
+                    "carry_gather": jnp.asarray(carry_gather),
+                    "carry_mask": jnp.asarray(carry_mask)}
+        self._note_launch_gap(1 if carry is not None else 0)
+        with record_span("serving.unified_step", part="dispatch",
+                         ring=True):
+            out = unified_step(
+                self.params, self.k_pool, self.v_pool, *staged,
+                self.config, self.page_size,
+                use_pallas=self._use_pallas, interpret=self._interpret,
+                k_scale=self.k_scale, v_scale=self.v_scale,
+                sample=sample, need_rows=need_rows,
+                block_q=self._block_q, block_pages=self._block_pages,
+                **carry_kw)
+        (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
+         logits, rec) = out[:6]
+        if self.tok_buf is not None:
+            self.tok_buf = out[6]
+        seed_rows = None
+        if seeds:
+            with record_span("serving.seed_gather", part="dispatch"):
+                if need_rows is not None:
+                    # lean: seed rows were gathered into need positions
+                    # n_decode.. — the (T, vocab) buffer never existed
+                    seed_rows = logits[jnp.arange(
+                        n_decode, n_decode + len(seeds), dtype=jnp.int32)]
+                else:
+                    seed_rows = logits[jnp.asarray(seed_flat, jnp.int32)]
+        self._t_launch_end = time.perf_counter()
+        self.device_steps += 1
+        return RaggedTicket(reqs, flat, rec[0], rec[1], rec[2], seeds,
+                            seed_rows, slots)
+
+    def _ragged_plan(self, carry):
+        """The host half of `_ragged_launch` up to the transfers: page
+        growth, the decode and prefill plans, and the wave's numpy
+        descriptors. Advances the engine's state (lengths, cursors,
+        row counters); returns None when nothing runs this wave."""
         # decode-boundary page growth, bucketed logic verbatim (mid-
         # prefill slots grow against their own chunk below)
         for s in sorted(self._live):
@@ -2446,14 +2528,18 @@ class ServingEngine:
                     seed_flat.append(row + n - 1)
             row += n
         self.ragged_tokens += row
-        sample = {"temp": jnp.asarray(temps),
-                  "top_k": jnp.asarray(top_ks),
-                  "top_p": jnp.asarray(top_ps),
-                  "key": jnp.asarray(keys),
-                  "eos": jnp.asarray(eos),
-                  "remaining": jnp.asarray(remaining)}
-        need_rows = None
+        # the kernel's work from the same descriptors: unused tail rows
+        # carry pos=-1 and count for nothing
+        live = (tok_pos + 1).astype(np.int64)
+        self.ragged_attn_pairs += int(live.sum())
+        kv = np.zeros((B,), np.int64)
+        np.maximum.at(kv, tok_slot, live)
+        self.ragged_kv_tokens += int(kv.sum())
         n_decode = len(decode_plan)
+        self.last_rows = (n_decode, row - n_decode)
+        sampling = {"temp": temps, "top_k": top_ks, "top_p": top_ps,
+                    "key": keys, "eos": eos, "remaining": remaining}
+        need = None
         if self.lean:
             # need-row descriptor: decode rows sit at buffer rows
             # 0..n_decode-1 (so flat[s] doubles as the need index) and
@@ -2462,69 +2548,17 @@ class ServingEngine:
             need = np.full((self.need_buf,), -1, np.int32)
             need[:n_decode] = np.arange(n_decode, dtype=np.int32)
             need[n_decode:n_decode + len(seed_flat)] = seed_flat
-            need_rows = jnp.asarray(need)
             self.logit_rows += self.need_buf
             self.logit_rows_skipped += T - self.need_buf
         else:
             self.logit_rows += T
-        c_tok = carry.next_tok if carry is not None \
-            else jnp.zeros((self.need_buf if self.lean else T,),
-                           jnp.int32)
         self._fire("step_launch",
                    rids=[str(p[1].rid) for p in decode_plan] +
                         [str(p[1].rid) for p in prefill_plan])
-        self._note_launch_gap(1 if carry is not None else 0)
-        with record_span("serving.unified_step"):
-            if self.tok_buf is not None:
-                # device token ring: no carry operands (the ring's
-                # in-jit scatter/gather IS the carry) — decode rows
-                # write their sampled token for the next wave to read
-                bw = np.zeros((self.need_buf if self.lean else T,),
-                              bool)
-                bw[:n_decode] = True
-                (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-                 logits, rec, self.tok_buf) = unified_step(
-                    self.params, self.k_pool, self.v_pool,
-                    jnp.asarray(self.page_table.copy()),
-                    jnp.asarray(tokens), jnp.asarray(tok_slot),
-                    jnp.asarray(tok_pos), self.config, self.page_size,
-                    use_pallas=self._use_pallas,
-                    interpret=self._interpret,
-                    k_scale=self.k_scale, v_scale=self.v_scale,
-                    sample=sample, need_rows=need_rows,
-                    block_q=self._block_q,
-                    block_pages=self._block_pages,
-                    tok_buf=self.tok_buf, buf_write=jnp.asarray(bw))
-            else:
-                (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-                 logits, rec) = unified_step(
-                    self.params, self.k_pool, self.v_pool,
-                    jnp.asarray(self.page_table.copy()),
-                    jnp.asarray(tokens), jnp.asarray(tok_slot),
-                    jnp.asarray(tok_pos), self.config, self.page_size,
-                    use_pallas=self._use_pallas,
-                    interpret=self._interpret,
-                    k_scale=self.k_scale, v_scale=self.v_scale,
-                    sample=sample, carry_tok=c_tok,
-                    carry_gather=jnp.asarray(carry_gather),
-                    carry_mask=jnp.asarray(carry_mask),
-                    need_rows=need_rows, block_q=self._block_q,
-                    block_pages=self._block_pages)
-        if not seeds:
-            seed_rows = None
-        elif need_rows is not None:
-            # lean: seed rows were gathered into need positions
-            # n_decode.. — the (T, vocab) buffer never existed
-            seed_rows = logits[jnp.arange(
-                n_decode, n_decode + len(seeds), dtype=jnp.int32)]
-        else:
-            seed_rows = logits[jnp.asarray(seed_flat, jnp.int32)]
-        self._t_launch_end = time.perf_counter()
-        self.device_steps += 1
-        return RaggedTicket(reqs, flat, rec[0], rec[1], rec[2], seeds,
-                            seed_rows,
-                            sorted([p[0] for p in decode_plan] +
-                                   [p[0] for p in prefill_plan]))
+        return (tokens, tok_slot, tok_pos, carry_mask, carry_gather,
+                sampling, need, flat, reqs, seeds, seed_flat, n_decode,
+                sorted([p[0] for p in decode_plan] +
+                       [p[0] for p in prefill_plan]))
 
     def _ragged_finish(self, ticket, inflight=None):
         """Ragged twin of `step_finish`: ONE batched transfer (decode
@@ -2536,9 +2570,18 @@ class ServingEngine:
                    rids=[str(r.rid) for r in ticket.reqs.values()
                          if r is not None] +
                         [str(r.rid) for _, r in ticket.seeds])
-        nxt, done, lp, seed_rows = self._fetch_results(
-            (ticket.next_tok, ticket.done, ticket.logprob,
-             ticket.seed_rows))
+        with record_span("serving.fetch", part="fetch"):
+            nxt, done, lp, seed_rows = self._fetch_results(
+                (ticket.next_tok, ticket.done, ticket.logprob,
+                 ticket.seed_rows))
+        with record_span("serving.consume", part="consume"):
+            self._ragged_consume(ticket, inflight, nxt, done, lp,
+                                 seed_rows)
+        return len(ticket.slots)
+
+    def _ragged_consume(self, ticket, inflight, nxt, done, lp, seed_rows):
+        """The host bookkeeping of one fetched wave: seeding, per-slot
+        token accounting, releases."""
         if seed_rows is not None:
             for (s, req), rowv in zip(ticket.seeds, seed_rows):
                 if self._slots[s] is not req:
@@ -2563,7 +2606,6 @@ class ServingEngine:
                     self.lengths[s] -= 1
                 self._release(s)
         self._note_step(len(ticket.slots))
-        return len(ticket.slots)
 
     def _spec_step(self):
         """One speculative verify step: drafts up to G-1 tokens per

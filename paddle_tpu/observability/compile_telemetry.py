@@ -186,29 +186,36 @@ class CompileRegistry:
         callable to this registry under `name`."""
         def deco(fn):
             label = name or getattr(fn, "__name__", repr(fn))
+            from ..profiler import record_span
 
             @functools.wraps(fn)
             def wrapper(*args, **kwargs):
-                # signature BEFORE the call: donated buffers are
-                # invalid afterwards
-                try:
-                    sig = signature_of(args, kwargs)
-                except Exception:   # never let telemetry break the call
-                    sig = ("<unhashable>",)
-                hits0 = self.cache_hits
+                # this wrapper's own share of the caller's time, as two
+                # short `pt.track_jit` spans around the call and never
+                # the call itself (in a serving turn: part `telemetry`)
+                with record_span("pt.track_jit", part="telemetry"):
+                    # signature BEFORE the call: donated buffers are
+                    # invalid afterwards
+                    try:
+                        sig = signature_of(args, kwargs)
+                    except Exception:   # never let telemetry break the call
+                        sig = ("<unhashable>",)
+                    hits0 = self.cache_hits
                 t0 = time.perf_counter()
                 out = fn(*args, **kwargs)
-                compiled = self.note_call(
-                    label, sig, elapsed_s=time.perf_counter() - t0,
-                    cache_hit=self.cache_hits > hits0)
-                # device cost accounting: a compile captures the new
-                # executable's XLA cost/memory analysis (shape-only
-                # AOT re-resolve — donated buffers are fine), and
-                # every call adds its known FLOPs to the MFU window
-                from . import device_telemetry as _dt
-                if compiled:
-                    _dt.COSTS.capture(label, sig, fn, args, kwargs)
-                _dt.COSTS.note_executed(label, sig)
+                elapsed = time.perf_counter() - t0
+                with record_span("pt.track_jit", part="telemetry"):
+                    compiled = self.note_call(
+                        label, sig, elapsed_s=elapsed,
+                        cache_hit=self.cache_hits > hits0)
+                    # device cost accounting: a compile captures the new
+                    # executable's XLA cost/memory analysis (shape-only
+                    # AOT re-resolve — donated buffers are fine), and
+                    # every call adds its known FLOPs to the MFU window
+                    from . import device_telemetry as _dt
+                    if compiled:
+                        _dt.COSTS.capture(label, sig, fn, args, kwargs)
+                    _dt.COSTS.note_executed(label, sig)
                 return out
             wrapper.__wrapped__ = fn
             wrapper._pt_compile_name = label

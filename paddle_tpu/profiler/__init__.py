@@ -21,6 +21,7 @@ from __future__ import annotations
 import contextlib
 import enum
 import os
+import threading
 import time
 
 import jax
@@ -246,7 +247,12 @@ class Profiler:
         self.stop()
 
 
-def record_span(name, args=None):
+TURN = "turn"       # `part` of the root span of one pump turn
+
+_open = threading.local()    # .spans: this thread's open part spans
+
+
+def record_span(name, args=None, part=None, ring=None):
     """A RecordEvent as a with-block: annotates the device trace (when
     one is being captured), feeds the host event ring (when tracing
     is enabled), and drops a span — stamped with the current trace
@@ -257,28 +263,63 @@ def record_span(name, args=None):
 
         with profiler.record_span("serving.decode_step"):
             ...
-    """
-    return RecordEvent(name, args=args)
+
+    `part` makes the span one part of a serving pump turn
+    (docs/observability.md § A turn of the pump): it is timed on
+    `time.monotonic()` and, when it closes, its SELF seconds — its
+    duration less its part children's — are added under `part` to the
+    enclosing `part=TURN` span's `parts`. The annotation still lies in
+    the profiler's trace on the thread that opened it; the flight
+    recorder and the trace context get nothing unless `ring=True` (a
+    turn has nine parts, the crash ring 4,096 events)."""
+    return RecordEvent(name, args=args, part=part, ring=ring)
 
 
 class RecordEvent:
-    def __init__(self, name, event_type=None, args=None):
+    def __init__(self, name, event_type=None, args=None, part=None,
+                 ring=None):
         self.name = name
         self.args = args
+        self.part = part
+        self.ring = part is None if ring is None else ring
+        self.parts = {} if part == TURN else None
+        self.dur_s = None
         self._ctx = None
         self._span = None
 
+    def set_args(self, **kw):
+        """Arguments known only once the span is under way (a turn's
+        row mix): they ride the annotation into the profiler's trace."""
+        if self._ctx is not None:
+            self._ctx.set_metadata(**kw)
+
     def begin(self):
-        from ..observability import trace_context as _tc
-        self._span = _tc.span(self.name, args=self.args)
-        self._span.__enter__()
+        if self.ring:
+            from ..observability import trace_context as _tc
+            self._span = _tc.span(self.name, args=self.args)
+            self._span.__enter__()
         try:
             self._ctx = jax.profiler.TraceAnnotation(self.name)
             self._ctx.__enter__()
         except Exception:
             self._ctx = None
+        if self.part is not None:
+            spans = _open.__dict__.setdefault("spans", [])
+            spans.append(self)
+            self._children_s = 0.0
+            self._t0 = time.monotonic()
 
     def end(self):
+        if self.part is not None:
+            self.dur_s = time.monotonic() - self._t0
+            spans = _open.spans
+            spans.pop()
+            if spans:
+                spans[-1]._children_s += self.dur_s
+                parts = spans[0].parts
+                if parts is not None:
+                    parts[self.part] = parts.get(self.part, 0.0) + \
+                        self.dur_s - self._children_s
         if self._ctx is not None:
             self._ctx.__exit__(None, None, None)
             self._ctx = None

@@ -34,6 +34,12 @@ GAP_BUCKETS = (2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3,
                0.01, 0.025, 0.05, 0.1, 0.25, 1.0)
 
 
+# the parts of one pump turn (docs/observability.md § A turn of the
+# pump): every span of a turn adds its self seconds to one of these
+TURN_PARTS = ("admit", "plan", "dispatch", "fetch", "consume", "publish",
+              "telemetry")
+
+
 def _escape_label_value(v):
     """Prometheus text-format label-value escaping: backslash, double
     quote, and newline must be escaped or the exposition line is
@@ -386,8 +392,30 @@ class EngineMetrics:
             "pt_logit_rows_skipped",
             "Logit rows the lean row-sparse epilogue skipped (0 with "
             "PT_SERVE_LEAN=0).")
+        # what the ragged kernel has to do (ISSUE 25), from the same
+        # row descriptors: its needed operations and bytes are these
+        # times the model's head sizes
+        self.ragged_attn_pairs = r.counter(
+            "pt_ragged_attn_pairs",
+            "Query-key pairs of one layer and one head the unified "
+            "ragged steps attended: the sum over live rows of "
+            "tok_pos + 1.")
+        self.ragged_kv_tokens = r.counter(
+            "pt_ragged_kv_tokens",
+            "Tokens of keys and values one layer of the unified ragged "
+            "steps had to read at least once: the sum over slots with "
+            "a row of their highest tok_pos + 1.")
         self._tok_seen = {"pad_tokens": 0, "ragged_tokens": 0,
-                          "logit_rows": 0, "logit_rows_skipped": 0}
+                          "logit_rows": 0, "logit_rows_skipped": 0,
+                          "ragged_attn_pairs": 0, "ragged_kv_tokens": 0}
+        self.turn_seconds = {
+            part: r.counter(
+                "pt_serving_turn_seconds",
+                "Self seconds of the pump's turns by part, on "
+                "time.monotonic() at the boundaries of the turn's "
+                "spans; host work is the sum without part=fetch.",
+                labels={"part": part})
+            for part in TURN_PARTS}
         self.steps = r.counter(
             "pt_serving_device_steps", "Decode/verify device calls.")
         self.tokens = r.counter(
@@ -598,7 +626,9 @@ class EngineMetrics:
                               ("ragged_tokens", self.ragged_tokens),
                               ("logit_rows", self.logit_rows),
                               ("logit_rows_skipped",
-                               self.logit_rows_skipped)):
+                               self.logit_rows_skipped),
+                              ("ragged_attn_pairs", self.ragged_attn_pairs),
+                              ("ragged_kv_tokens", self.ragged_kv_tokens)):
             cur = getattr(engine, attr, 0)
             delta = cur - seen[attr]
             if delta > 0:
@@ -768,6 +798,13 @@ class EngineMetrics:
         """Running-slot mix sampled by the pump each step."""
         self._slot_mix["prefill"].set(prefill)
         self._slot_mix["decode"].set(decode)
+
+    def observe_turn(self, parts):
+        """One pump turn's self seconds by part, added once a turn."""
+        for part in TURN_PARTS:
+            s = parts.get(part, 0.0)
+            if s > 0:
+                self.turn_seconds[part].inc(s)
 
     def observe_scrape_self(self, dt):
         """Self-cost of one scrape/sample pass (scrape-thread side)."""

@@ -46,7 +46,8 @@ from ..observability import device_telemetry as _devtel
 from ..observability import flight_recorder as _flight
 from ..observability import trace_context as _tc
 from ..observability.logging import get_logger
-from .metrics import EngineMetrics, MetricsRegistry
+from ..profiler import TURN, record_span
+from .metrics import TURN_PARTS, EngineMetrics, MetricsRegistry
 from .timeline import StepAnomalySentinel, Timeline, judge_slo, \
     resolve_slo
 
@@ -205,6 +206,9 @@ class RequestScheduler:
         # (written outside the lock by design — _expire_and_cancel
         # just reads it to defer engine-side cancel application)
         self._pending = None
+        # the parts of the previous turn that came after its
+        # `serving.step` record (its publish): pump thread only
+        self._tail_parts = {}
         self.max_queue = int(max_queue)
         if self.max_queue < 1:
             raise ValueError(f"max_queue={max_queue}: want >= 1")
@@ -682,7 +686,7 @@ class RequestScheduler:
     def _publish(self):
         """Push newly emitted tokens to each in-flight handle and
         finalize whatever the engine finished. Pump-thread only."""
-        with self._cond:
+        with record_span("serving.publish", part="publish"), self._cond:
             for sr in list(self._inflight.values()):
                 n = len(sr.req.output)
                 if n > sr._emitted:
@@ -756,8 +760,9 @@ class RequestScheduler:
             sr.chunks.put(list(sr.req.output[sr._emitted:n]))
             sr._emitted = n
         sr.chunks.put(None)
-        self._account_slo(sr, state)
-        self._emit_request_spans(sr, state)
+        with record_span("serving.telemetry", part="telemetry"):
+            self._account_slo(sr, state)
+            self._emit_request_spans(sr, state)
         self._recent.append(self._timeline_entry(sr, state))
         sr._done.set()
 
@@ -921,53 +926,80 @@ class RequestScheduler:
 
     def _pump(self):
         while True:
-            if self._pending is not None and self._drain_needed():
-                # slow path (cancel/TTL/shutdown): catch the host up so
-                # releases/cancels operate on consumed state only —
-                # the one-step-deep pipeline drains, never leaks
-                try:
-                    self._finish_pending()
-                except Exception as e:  # noqa: BLE001 — fail requests
-                    self._recover(e)
-                self._publish()
-            with self._cond:
-                self._expire_and_cancel_locked()
-                self._feed_locked()
-                if not self._engine_has_work() and self._pending is None:
-                    if self._closed and not self._queued_locked():
-                        break
-                    # park until a submission/cancel/shutdown pokes us
-                    # (or queued work is unfeedable: paused / no slot);
-                    # the timeout bounds queued-deadline expiry latency
-                    self._cond.wait(timeout=self._idle_poll_s)
-                    continue
-            t0 = time.perf_counter()
+            with record_span("serving.turn", part=TURN) as turn:
+                if not self._turn(turn):
+                    break
+        if self._pending is not None:
             try:
-                if self._pipeline:
-                    n_active = self._step_pipelined()
-                else:
-                    n_active = self._engine.step()
-            except Exception as e:  # noqa: BLE001 — fail requests
-                self._pending = None
+                self._finish_pending()
+            except Exception as e:  # noqa: BLE001
                 self._recover(e)
-                continue
-            dt = time.perf_counter() - t0
+        self._publish()
+
+    def _turn(self, turn):
+        """One turn of the pump: feed, one engine step, the planes'
+        post-step block, publish. Every piece runs under a span of
+        `turn` (docs/observability.md § A turn of the pump), so the
+        turn's `parts` hold the self seconds of each. A pump with
+        nothing to do parks INSIDE its turn: the wait is the turn's own
+        time and no part's. False once the pump should stop."""
+        if self._pending is not None and self._drain_needed():
+            # slow path (cancel/TTL/shutdown): catch the host up so
+            # releases/cancels operate on consumed state only —
+            # the one-step-deep pipeline drains, never leaks
+            try:
+                self._finish_pending()
+            except Exception as e:  # noqa: BLE001 — fail requests
+                self._recover(e)
+            self._publish()
+        with self._cond:
+            while True:
+                with record_span("serving.sched_feed", part="admit"):
+                    self._expire_and_cancel_locked()
+                    self._feed_locked()
+                if self._engine_has_work() or self._pending is not None:
+                    break
+                if self._closed and not self._queued_locked():
+                    return False
+                # park until a submission/cancel/shutdown pokes us
+                # (or queued work is unfeedable: paused / no slot);
+                # the timeout bounds queued-deadline expiry latency
+                self._cond.wait(timeout=self._idle_poll_s)
+        t0 = time.perf_counter()
+        try:
+            if self._pipeline:
+                n_active = self._step_pipelined()
+            else:
+                n_active = self._engine.step()
+        except Exception as e:  # noqa: BLE001 — fail requests
+            self._pending = None
+            self._recover(e)
+            return True
+        dt = time.perf_counter() - t0
+        eng = self._engine
+        with record_span("serving.telemetry", part="telemetry"):
             self.metrics.observe_step(dt)
             # slot-mix sample: host-side slot walk, no device traffic —
             # feeds the pt_serving_slots{kind=} gauges (pulse plane)
             # and tags the sentinel sample with the step's phase mix
             npf = nact = 0
-            for r in self._engine._slots:
+            for r in eng._slots:
                 if r is not None:
                     nact += 1
-                    if self._engine._prefilling(r):
+                    if eng._prefilling(r):
                         npf += 1
             self.metrics.set_slot_mix(npf, nact - npf)
+            # the parts since the previous record: this turn's so far
+            # and the tail of the turn before (its publish), so the
+            # records tile the pump's time as the tokens' gaps do
+            seen, tail = dict(turn.parts), self._tail_parts
+            parts = {p: round(seen.get(p, 0.0) + tail.get(p, 0.0), 6)
+                     for p in TURN_PARTS}
             if self._timeline_on:
                 # anomaly sentinel sample: one deque append — no math,
                 # no locks, no device traffic on the pump (analysis
                 # runs on scrape)
-                self._sentinel.note(dt, npf, nact - npf)
+                self._sentinel.note(dt, npf, nact - npf, parts)
             # MFU: the tracked prefill/decode/verify calls this step
             # issued a known number of XLA-counted FLOPs; dividing by
             # the (synced) step wall time sets the pt_mfu gauge. Pure
@@ -978,16 +1010,18 @@ class RequestScheduler:
             self._log.event(
                 "serving.step", step_s=dt, active=n_active,
                 queue_depth=self.metrics.queue_depth.value,
-                device_steps=self._engine.device_steps,
-                host_gap_s=getattr(self._engine, "last_host_gap_s", 0.0),
-                pipeline_depth=getattr(self._engine, "pipeline_depth", 0))
-            self._publish()
-        if self._pending is not None:
-            try:
-                self._finish_pending()
-            except Exception as e:  # noqa: BLE001
-                self._recover(e)
+                device_steps=eng.device_steps,
+                host_gap_s=getattr(eng, "last_host_gap_s", 0.0),
+                pipeline_depth=getattr(eng, "pipeline_depth", 0),
+                parts=parts)
         self._publish()
+        rows = getattr(eng, "last_rows", (0, 0))
+        turn.set_args(step=eng.device_steps, decode_rows=rows[0],
+                      prefill_rows=rows[1])
+        self.metrics.observe_turn(turn.parts)
+        self._tail_parts = {p: s - seen.get(p, 0.0)
+                            for p, s in turn.parts.items()}
+        return True
 
     def _recover(self, exc):
         """An engine step blew up: warm-restart instead of failing

@@ -241,31 +241,47 @@ class StepAnomalySentinel:
         self._mean = None
         self._mad = 0.0
         self._n = 0
+        self._part_mean = {}        # part -> EWMA of its seconds
 
     # pump thread: append only
-    def note(self, dt, n_prefill=0, n_decode=0):
-        self._buf.append((dt, n_prefill, n_decode))
+    def note(self, dt, n_prefill=0, n_decode=0, parts=None):
+        """`parts`: the turn's self seconds by part (the pump's
+        `serving.step` record carries the same dict)."""
+        self._buf.append((dt, n_prefill, n_decode, parts))
 
     # scrape thread: drain + judge
     def scan(self):
         out = []
         while True:
             try:
-                dt, npf, ndc = self._buf.popleft()
+                dt, npf, ndc, parts = self._buf.popleft()
             except IndexError:
                 break
             if self._mean is not None and self._n >= self.warmup:
                 thresh = self._mean + max(self.k * self._mad,
                                           self.floor_s)
                 if dt > thresh:
-                    out.append({
+                    a = {
                         "step_s": round(dt, 6),
                         "mean_s": round(self._mean, 6),
                         "mad_s": round(self._mad, 6),
                         "threshold_s": round(thresh, 6),
                         "prefill_slots": npf,
                         "decode_slots": ndc,
-                    })
+                    }
+                    if parts:
+                        # the largest part is the blocking read under
+                        # the synchronous pump whatever stalled; the
+                        # part furthest over its own baseline names
+                        # the cause
+                        over = {p: s - self._part_mean.get(p, 0.0)
+                                for p, s in parts.items()}
+                        a.update(parts=parts,
+                                 largest_part=max(parts, key=parts.get),
+                                 stalled_part=max(over, key=over.get),
+                                 stalled_over_s=round(max(over.values()),
+                                                      6))
+                    out.append(a)
                     self._n += 1
                     continue
             if self._mean is None:
@@ -274,5 +290,9 @@ class StepAnomalySentinel:
                 self._mad += self.alpha * (abs(dt - self._mean)
                                            - self._mad)
                 self._mean += self.alpha * (dt - self._mean)
+            for p, s in (parts or {}).items():
+                m = self._part_mean.get(p)
+                self._part_mean[p] = s if m is None \
+                    else m + self.alpha * (s - m)
             self._n += 1
         return out
