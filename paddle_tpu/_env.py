@@ -316,12 +316,13 @@ declare("PT_FLASH_BLOCK_K", 128,
         "Flash attention key/value tile size.", kind="int",
         section="kernels")
 declare("PT_RAGGED_BLOCK_Q", None,
-        "Ragged paged-attention query tile override (0 derives the "
-        "seed shape; default: tuned per generation).",
+        "Ragged paged-attention q rows a block, override (0 derives "
+        "them from the shapes; default: tuned per generation).",
         kind="int", section="kernels")
 declare("PT_RAGGED_BLOCK_PAGES", None,
-        "Ragged paged-attention pages-per-step override (default: "
-        "tuned per generation).", kind="int", section="kernels")
+        "Ragged paged-attention pages a KV block, override (0 derives "
+        "one lane width of tokens; default: tuned per generation).",
+        kind="int", section="kernels")
 declare("PT_RAGGED_TILE_FILE", "",
         "Path of the persisted per-generation ragged kernel tile "
         "table (default: TUNED.kernels.json in the repo).",

@@ -20,10 +20,11 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_FLASH_BLOCK_Q = 128
 DEFAULT_FLASH_BLOCK_K = 128
 
-# ragged paged-attention serving kernel tile (0 = derive: the GQA
-# group sublane-padded / one page per grid step — the seed shape)
+# ragged paged-attention serving kernel tile: q rows a block and pages
+# a KV block (0 = derive from the shapes: rows x GQA group fill the
+# MXU's 128 rows, a KV block is one lane width of tokens)
 DEFAULT_RAGGED_BLOCK_Q = 0
-DEFAULT_RAGGED_BLOCK_PAGES = 1
+DEFAULT_RAGGED_BLOCK_PAGES = 0
 
 # per-TPU-generation winners persisted by tools/tune_ragged.py; the
 # engine loads this ONCE at construction (a static tile — no serving-
@@ -52,7 +53,7 @@ def generation_key(device_kind):
 def load_ragged_tile(device_kind, path=None):
     """Effective (block_q, block_pages) for the serving ragged kernel:
     env override > persisted per-generation winner > builtin default.
-    0 means 'derive the seed shape' throughout. Never raises — a
+    0 means 'derive from the shapes' throughout. Never raises — a
     missing/corrupt tile file silently falls back to the builtins (a
     serving engine must come up on an untuned chip)."""
     bq, bp = DEFAULT_RAGGED_BLOCK_Q, DEFAULT_RAGGED_BLOCK_PAGES

@@ -9,6 +9,8 @@ reference path; the pallas lowering is reached on TPU or under
 interpret mode in tests.
 """
 from .ragged_paged_attention import (ragged_paged_attention,
-                                     ragged_paged_attention_reference)
+                                     ragged_paged_attention_reference,
+                                     ragged_runs, ragged_tile)
 
-__all__ = ["ragged_paged_attention", "ragged_paged_attention_reference"]
+__all__ = ["ragged_paged_attention", "ragged_paged_attention_reference",
+           "ragged_runs", "ragged_tile"]

@@ -9,49 +9,51 @@ split-fuse / fixed-token-budget direction). Row i attends over slot
 `tok_slot[i]`'s paged KV through the page table, causally limited to
 columns `< tok_pos[i] + 1` (its own position included — the row's K/V
 was scattered into the pages beforehand). Inactive buffer slack rows
-carry `tok_pos = -1`: every page is skipped for them, which is the
-attention early-exit that makes the fixed buffer cheap.
+carry `tok_pos = -1` and come back exactly zero.
 
-Two implementations with ONE arithmetic contract, asserted BIT-identical
-on CPU in tests. Bit-exactness across two separately-compiled XLA
-programs does not come for free — three things make it hold:
+The Pallas kernel walks the WORK, not the page table (ISSUE 26). Its
+unit is a run: a stretch of buffer rows of one slot with consecutive
+positions (`ragged_runs`, derived inside the jitted step from the
+descriptors it already has). What bounds its iteration space:
 
-  * both run the SAME traced op sequence: `_page_update` below is the
-    single online-softmax page step, called from the pallas kernel body
-    and from the reference's page scan;
-  * the reference replays the kernel's exact operand SHAPES (q group
-    padded to the sublane minimum, m/l stats lane-replicated to
-    (group_pad, LANES) with `_fit_lanes` slicing) — XLA CPU picks
-    different vectorizations for different shapes and e.g. `exp` then
-    rounds differently;
-  * `lax.optimization_barrier` pins the contraction-sensitive spots
-    (the dots, the exps, each mul feeding an add) so neither compiled
-    loop body can FMA/fuse them into differently-rounded forms. The
-    barrier has no vmap batching rule, so the reference fans out over
-    (token, head) with `lax.map` rather than vmap.
+  * one program per q block of the buffer (`block_q` rows x the GQA
+    group, all KV heads; one block at the engine's 32 rows), which
+    walks the runs that lie in it — a prefill chunk's rows meet a KV
+    block together, causal by `column < position + 1` inside the block;
+  * per run, a loop over KV blocks of `block_pages` pages whose trip
+    count is the run's KV length (scalar prefetch). The pool stays in
+    HBM in its `(KVH, P, page, D)` layout; a page comes in for all KV
+    heads by one strided DMA through the page table, double-buffered,
+    the prefetch crossing from a run's last block to the next run's
+    first. No grid step, loop trip or DMA exists for a page a run does
+    not own;
+  * QK^T on the stored operands (bf16 products are exact in the f32
+    accumulator), running max, sum and accumulator in f32, P in f32
+    into the PV product; int8 pages are widened and scaled in VMEM, a
+    KV block at a time.
 
-The bit-identity contract is a CPU one (interpreted kernel vs
-reference). The Mosaic TPU lowering has no rule for
-`optimization_barrier`, so the kernel body compiled for the chip
-(`interpret=False`) carries none; there the check against the reference
-is by tolerance (chip_smoke.py, tools/validate_tpu_kernels.py).
+The jnp reference below is the CPU path every engine test runs: an
+online softmax one page at a time (`_page_update`). Kernel and
+reference differ in the order of summation (a block of pages against a
+page), so they are held together by tolerance: 1e-5 on float32 inputs
+in TPU interpret mode (tests/test_ragged_step.py), a bf16 ulp or two
+on the chip (chip_smoke.py, tools/validate_tpu_kernels.py). The
+reference keeps the `optimization_barrier`s that once made it
+bit-identical to an interpreted grid kernel; nothing depends on them
+now, and the kernel body has none (Mosaic has no rule for them).
 
-GQA: q is viewed (tokens, kv_heads, group, head_dim). int8 pools ride
-per-token fp32 scales dequantized inside `_page_update`.
-
-Tile shape is a STATIC parameter (`block_q` q-rows per block x
-`block_pages` KV pages per grid step, both sublane-legal), defaulting
-to the seed shape (GQA group padded to the sublane minimum x 1 page).
-Every legal config runs the identical `_page_update` call sequence over
-the same page ordinals with the same operand shapes, so the jnp
-reference stays the bit-identity oracle for all of them — what changes
-is only how the pallas grid batches DMA and compute. The per-TPU-
-generation winner is found offline by tools/tune_ragged.py and loaded
-through paddle_tpu/_tuning_defaults.load_ragged_tile.
+GQA: q is viewed (kv_heads, tokens x group, head_dim). The tile
+(`block_q` q rows a block x `block_pages` pages a KV block) is STATIC
+and derived from the shapes unless given (`ragged_tile`); a tile
+changes the order of summation and nothing else. The per-TPU-
+generation winner, where one beats the derived tile, is found offline
+by tools/tune_ragged.py and loaded through
+paddle_tpu/_tuning_defaults.load_ragged_tile.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import numpy as np
@@ -63,28 +65,24 @@ from ..ops.flash_attention import _fit_lanes
 from ..ops.paged_attention import (F0, F1, LANES, MIN_GROUP, NEG_INF, Z,
                                    _on_tpu)
 
-__all__ = ["ragged_paged_attention", "ragged_paged_attention_reference"]
+__all__ = ["ragged_paged_attention", "ragged_paged_attention_reference",
+           "ragged_runs", "ragged_tile"]
 
 _bar = jax.lax.optimization_barrier
 
 
-def _no_bar(x):
-    return x
-
-
 def _page_update(q, k, v, acc, m_prev, l_prev, limit, pi, scale,
                  page_size, ks=None, vs=None, bar=_bar):
-    """One online-softmax step over one KV page — THE arithmetic
-    contract shared by the pallas kernel and the jnp reference.
+    """One online-softmax step over one KV page: the jnp reference's
+    arithmetic.
 
     q/acc: (group_pad, d) f32; m_prev/l_prev: (group_pad, LANES) f32;
     k/v: (page_size, d) f32; ks/vs: (page_size, 1) dequant scales when
     the pool is int8; limit/pi: i32 scalars. Returns the updated
     (acc, m, l). The optimization barriers keep XLA from contracting
-    the muls into the adds (or re-fusing the dots/exps) differently in
-    the two compiled programs — without them the kernel and reference
-    drift by 1 ULP on CPU. `bar=_no_bar` drops them for the Mosaic
-    build, which cannot lower the primitive.
+    the muls into the adds (or re-fusing the dots/exps): they date from
+    the grid kernel this reference was bit-identical to on CPU, and
+    stay because the engine tests' token streams are this function's.
     """
     if ks is not None:
         k = k * ks
@@ -118,14 +116,10 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     row → zeros out). Returns (T, QH, D).
 
     This is NOT a dense-softmax shortcut: it replays `_page_update`
-    over page ordinals with the kernel's exact shapes (group padded,
-    lane-replicated stats), skipped pages carrying the previous stats
-    through unchanged, so CPU tests can assert the pallas kernel
-    bit-identical against it. `block_q` is the kernel's q-row block
-    (the q group's sublane padding) — the reference must replay the
-    same padded shape to stay the bit-identity oracle for a non-default
-    tile. `block_pages` has no reference twin: it only re-batches the
-    grid, the `_page_update` ordinal sequence is unchanged."""
+    over page ordinals (group padded, lane-replicated stats), skipped
+    pages carrying the previous stats through unchanged. `block_q` here
+    is the q group's sublane padding, the reference's own; the pallas
+    kernel's tile has no twin in it."""
     t, qh, d = q.shape
     kvh, _, page_size, _ = k_pages.shape
     group = qh // kvh
@@ -180,9 +174,9 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 def _resolve_block_q(block_q, group):
-    """Validated q-row block: None/0 derive the seed shape (group
-    padded to the sublane minimum); an explicit value must cover the
-    group and stay sublane-aligned or the block is not DMA-legal."""
+    """The reference's q-row block: None/0 derive the GQA group padded
+    to the sublane minimum; an explicit value must cover the group and
+    stay sublane-aligned."""
     gp_min = group + (-group) % MIN_GROUP
     if not block_q:
         return gp_min
@@ -194,128 +188,262 @@ def _resolve_block_q(block_q, group):
     return block_q
 
 
-def _ragged_kernel(slot_ref, pos_ref, ptab_ref, *refs, scale, page_size,
-                   n_pages, block_pages, quant, bar):
-    """Grid (T, KVH, ceil(pages_per_seq / block_pages));
-    tok_slot/tok_pos/page_table ride scalar prefetch — each of the
-    `block_pages` per-step page operands has its own BlockSpec index
-    map resolving `ptab[slot[ti], pi*block_pages + j]`, so one grid
-    step DMAs a strip of `block_pages` pages and the unrolled body
-    consumes them in ordinal order (the exact `_page_update` sequence
-    of the one-page kernel — bit-identity is tile-invariant). Scale
-    refs ride interleaved per page when the pool is int8, dequantized
-    inside `_page_update` so int8 is what rides HBM→VMEM."""
-    del slot_ref, ptab_ref  # consumed by the index maps
-    per = 4 if quant else 2
-    q_ref = refs[0]
-    page_refs = refs[1:1 + per * block_pages]
-    o_ref = refs[1 + per * block_pages]
-    acc_ref, m_ref, l_ref = refs[2 + per * block_pages:]
-    ti = pl.program_id(0)
-    pi = pl.program_id(2)
-    grid_pages = -(-n_pages // block_pages)
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    limit = pos_ref[ti] + 1  # -1 (inactive row) → 0: every page skips
-
-    for j in range(block_pages):
-        # ordinal*page_size < limit also masks the clamped
-        # past-the-end ordinals of the last grid step: limit <=
-        # n_pages*page_size always, so ordinal >= n_pages fails it —
-        # the same predicate the reference's `take` carry uses.
-        ordinal = pi * block_pages + j
-        k_ref = page_refs[per * j]
-        v_ref = page_refs[per * j + 1]
-        sc_refs = page_refs[per * j + 2:per * j + 4] if quant else None
-
-        @pl.when(ordinal * page_size < limit)
-        def _body(k_ref=k_ref, v_ref=v_ref, sc_refs=sc_refs,
-                  ordinal=ordinal):
-            sc = () if sc_refs is None else (sc_refs[0][0, 0],
-                                             sc_refs[1][0, 0])
-            acc_new, m_new, l_new = _page_update(
-                q_ref[0, 0].astype(jnp.float32),
-                k_ref[0, 0].astype(jnp.float32),
-                v_ref[0, 0].astype(jnp.float32),
-                acc_ref[:], m_ref[:], l_ref[:], limit, ordinal, scale,
-                page_size, *sc, bar=bar)
-            acc_ref[:] = acc_new
-            m_ref[:] = m_new
-            l_ref[:] = l_new
-
-    @pl.when(pi == grid_pages - 1)
-    def _fin():
-        l = l_ref[:]
-        l_safe = jnp.where(l == F0, F1, l)
-        o_ref[0, 0] = (acc_ref[:] /
-                       _fit_lanes(l_safe, o_ref.shape[-1])).astype(o_ref.dtype)
+def _q_rows(block_q, t, group):
+    """Validated q rows a block. None/0 derive them: rows x the GQA
+    group fill the MXU's 128 rows, never more than the buffer, always
+    a whole number of sublane tiles."""
+    unit = MIN_GROUP // math.gcd(MIN_GROUP, group)  # rows*group % 8 == 0
+    if not block_q:
+        return min(-(-t // unit) * unit,
+                   max(unit, LANES // group // unit * unit))
+    block_q = int(block_q)
+    if block_q < 1 or block_q % unit:
+        raise ValueError(
+            f"block_q={block_q}: q rows a block times the GQA group "
+            f"({group}) must be a positive multiple of the sublane tile "
+            f"({MIN_GROUP})")
+    return block_q
 
 
-def _ragged_pallas(q4, k_pages, v_pages, page_table, tok_slot, tok_pos,
-                   scale, interpret, k_scale=None, v_scale=None,
-                   block_pages=1):
-    t, kvh, group_pad, d = q4.shape
-    _, _, page_size, _ = k_pages.shape
-    n_pages = page_table.shape[1]
-    quant = k_scale is not None
-    grid_pages = -(-n_pages // block_pages)
+def _kv_pages(block_pages, page_size, n_pages):
+    """Validated pages a KV block. None/0 derive one lane width of
+    tokens, never more pages than a sequence owns."""
+    if not block_pages:
+        block_pages = max(1, LANES // page_size)
+    block_pages = int(block_pages)
+    if block_pages < 1:
+        raise ValueError(f"block_pages={block_pages}: want >= 1")
+    block_pages = min(block_pages, n_pages)
+    blk = block_pages * page_size
+    if blk > LANES and blk % LANES:
+        raise ValueError(
+            f"block_pages={block_pages}: a KV block of {blk} tokens must "
+            f"be at most or a multiple of the lane width ({LANES})")
+    return block_pages
 
-    # index maps receive grid indices first, then scalar-prefetch refs.
-    # Per-j maps pick page ordinal pi*block_pages + j, clamped on the
-    # ragged last strip (the kernel body masks those ordinals out).
-    def _page_map(j):
-        def m(ti, hi, pi, slot, pos, ptab):
-            o = jnp.minimum(pi * block_pages + j, n_pages - 1)
-            return (hi, ptab[slot[ti], o], Z, Z)
-        return m
 
-    in_specs = [
-        pl.BlockSpec((1, 1, group_pad, d),
-                     lambda ti, hi, pi, slot, pos, ptab: (ti, hi, Z, Z)),
-    ]
-    operands = [tok_slot, tok_pos, page_table, q4]
-    for j in range(block_pages):
-        page_spec = pl.BlockSpec((1, 1, page_size, d), _page_map(j))
-        in_specs += [page_spec, page_spec]
-        operands += [k_pages, v_pages]
+def ragged_tile(block_q, block_pages, t, group, page_size, n_pages):
+    """The kernel's effective static tile `(q rows a block, pages a KV
+    block)` for a buffer of `t` rows: the given values validated,
+    None/0 derived from the shapes."""
+    return (_q_rows(block_q, t, group),
+            _kv_pages(block_pages, page_size, n_pages))
+
+
+def ragged_runs(tok_slot, tok_pos, group, block_q=None):
+    """The step's row descriptors as RUNS, the kernel's unit of work.
+
+    A run is a maximal stretch of buffer rows of one slot with
+    consecutive positions that lies inside one q block (`block_q` rows,
+    the kernel's tile for a GQA group of `group`): a decode row, a
+    prefill chunk, a suffix tail, a verify grid, or a q block's piece
+    of one. Rows with `tok_pos = -1` belong to no run. A buffer that
+    breaks the engine's layout (a slot's rows apart, positions not
+    consecutive) only yields more, shorter runs.
+
+    Returns `(runs, qb_first)`, both i32: `runs` is (4, T) with rows
+    first-row / row-count / slot / KV length (= last position + 1),
+    valid in columns `< qb_first[-1]`, ordered by first row;
+    `qb_first` is (T_blocks + 1,) where q block j owns runs
+    `qb_first[j] .. qb_first[j + 1]`. A few integer ops on T elements:
+    derive it once a step, outside the layer scan.
+    """
+    t = tok_pos.shape[0]
+    block_q = _q_rows(block_q, t, group)
+    slot = tok_slot.astype(jnp.int32)
+    pos = tok_pos.astype(jnp.int32)
+    i = jnp.arange(t, dtype=jnp.int32)
+    on = pos >= 0
+    cont = (on[1:] & on[:-1] & (slot[1:] == slot[:-1])
+            & (pos[1:] == pos[:-1] + 1) & (i[1:] % block_q != 0))
+    start = on & ~jnp.concatenate([jnp.zeros((1,), bool), cont])
+    rid = jnp.cumsum(start, dtype=jnp.int32) - 1        # a row's run
+    member = on[None, :] & (rid[None, :] == i[:, None])  # (run, row)
+    first = member & start[None, :]
+    runs = jnp.stack([
+        jnp.sum(jnp.where(first, i[None, :], 0), axis=1),
+        jnp.sum(member, axis=1),
+        jnp.sum(jnp.where(first, slot[None, :], 0), axis=1),
+        jnp.max(jnp.where(member, pos[None, :] + 1, 0), axis=1),
+    ]).astype(jnp.int32)
+    edges = jnp.arange(-(-t // block_q) + 1, dtype=jnp.int32) * block_q
+    qb_first = jnp.sum(start[None, :] & (i[None, :] < edges[:, None]),
+                       axis=1).astype(jnp.int32)
+    return runs, qb_first
+
+
+def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
+                   page_size, block_pages, group, quant):
+    """Grid (q blocks,). Program j holds q block j (all KV heads, its
+    rows x the GQA group) in VMEM and walks the runs that lie in it;
+    per run, a loop over KV blocks of `block_pages` pages whose trip
+    count is the run's KV length. The pool stays in HBM: a page comes
+    in for all KV heads by one strided DMA through the page table,
+    into the buffer the next trip reads while this trip computes (the
+    prefetch crosses from a run's last block to the next run's first).
+    A page the run does not own is neither fetched nor waited for."""
+    n_pool = 3 if quant else 2
+    pools, o_ref = refs[:n_pool], refs[n_pool]
+    bufs = refs[n_pool + 1:2 * n_pool + 1]
+    sem, m_ref, l_ref, acc_ref = refs[2 * n_pool + 1:]
+    kvh, rows, d = q_ref.shape
+    block_q = rows // group
+    blk = block_pages * page_size
+    j = pl.program_id(0)
+    r_lo, r_hi = qb_ref[j], qb_ref[j + 1]
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j == 0)
+    def _finite_buffers():
+        # the tail of a run's last block is never fetched: what P = 0
+        # multiplies there must be finite, and stale pages are
+        for buf in bufs[1:]:
+            buf[...] = jnp.zeros_like(buf)
+
+    def for_pages(r, b, slot_, op):
+        """`start` or `wait` the DMA of every pool page of block b of
+        run r into buffer slot_."""
+        seq = runs_ref[2, r]
+        owned = pl.cdiv(runs_ref[3, r], np.int32(page_size))
+        for p in range(block_pages):
+            ordinal = b * np.int32(block_pages) + np.int32(p)
+
+            @pl.when(ordinal < owned)
+            def _(p=p, ordinal=ordinal):
+                page = ptab_ref[seq, ordinal]
+                for n, (pool, buf) in enumerate(zip(pools, bufs)):
+                    src, dst = (pool.at[:, page],
+                                buf.at[slot_, :, np.int32(p)]) if n < 2 \
+                        else (pool.at[page], buf.at[slot_, np.int32(p)])
+                    getattr(pltpu.make_async_copy(
+                        src, dst, sem.at[np.int32(n), slot_]), op)()
+
+    def block(r, b, slot_):
+        first, n_rows, kv_len = runs_ref[0, r], runs_ref[1, r], runs_ref[3, r]
+        row = j * np.int32(block_q) + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, blk), 0) // np.int32(group)
+        col = b * np.int32(blk) + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, blk), 1)
+        # a row's causal limit is its position + 1; positions are
+        # consecutive in a run and the last row's is kv_len - 1
+        live = ((row >= first) & (row < first + n_rows)
+                & (col < kv_len - (first + n_rows) + row + np.int32(1)))
         if quant:
-            scale_spec = pl.BlockSpec((1, 1, page_size, 1), _page_map(j))
-            in_specs += [scale_spec, scale_spec]
-            operands += [k_scale, v_scale]
+            sc = bufs[2][slot_].reshape(blk, bufs[2].shape[-1])
+        for h in range(kvh):
+            q = q_ref[h]
+            k = bufs[0][slot_, h]
+            v = bufs[1][slot_, h].astype(jnp.float32).reshape(blk, d)
+            if quant:
+                # lanes of the scale page: K's KV heads, then V's
+                k = k.astype(jnp.float32).reshape(blk, d) * sc[:, h:h + 1]
+                v = v * sc[:, kvh + h:kvh + h + 1]
+                q = q.astype(jnp.float32)
+            else:
+                k = k.reshape(blk, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(live, s, NEG_INF)
+            m_prev, l_prev = m_ref[h], l_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(live, jnp.exp(s - _fit_lanes(m_new, blk)),
+                          jnp.zeros_like(s))
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * _fit_lanes(alpha, d) + \
+                jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+    @pl.when(r_lo < r_hi)
+    def _first_fetch():
+        for_pages(r_lo, Z, Z, "start")
+
+    def run(r, slot_):
+        n_blocks = pl.cdiv(runs_ref[3, r], np.int32(blk))
+
+        def trip(b, slot_):
+            last = b + np.int32(1) >= n_blocks
+            r_next = jnp.where(last, r + np.int32(1), r)
+            b_next = jnp.where(last, Z, b + np.int32(1))
+
+            @pl.when(r_next < r_hi)
+            def _prefetch():
+                for_pages(r_next, b_next, np.int32(1) - slot_, "start")
+
+            for_pages(r, b, slot_, "wait")
+            block(r, b, slot_)
+            return np.int32(1) - slot_
+
+        return jax.lax.fori_loop(Z, n_blocks, trip, slot_)
+
+    jax.lax.fori_loop(r_lo, r_hi, run, Z)
+
+    for h in range(kvh):
+        l = l_ref[h]
+        l_safe = jnp.where(l == F0, F1, l)      # rows of no run: 0 / 1
+        o_ref[h] = (acc_ref[h] / _fit_lanes(l_safe, d)).astype(o_ref.dtype)
+
+
+def _scale_pages(k_scale, v_scale):
+    """(KVH, P, page, 1) x 2 -> (P, page, lanes): a page's scales for
+    every head of K then of V side by side on the lanes, padded to a
+    whole number of lane tiles. A manual DMA wants a minor dimension of
+    whole tiles, which the pools' trailing 1 is not; one page then
+    comes in by ONE copy and head h's column is a static lane."""
+    sc = jnp.concatenate([k_scale[..., 0], v_scale[..., 0]])
+    sc = sc.transpose(1, 2, 0)
+    return jnp.pad(sc, ((0, 0), (0, 0), (0, (-sc.shape[-1]) % LANES)))
+
+
+def _ragged_pallas(qg, pools, page_table, runs, qb_first, scale, interpret,
+                   block_pages, group):
+    kvh, rows_all, d = qg.shape
+    n_qb = qb_first.shape[0] - 1
+    rows = rows_all // n_qb
+    page_size = pools[0].shape[2]
+    quant = len(pools) == 3
+    q_spec = pl.BlockSpec((kvh, rows, d),
+                          lambda j, runs, qb, ptab: (Z, j, Z))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(t, kvh, grid_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group_pad, d),
-                               lambda ti, hi, pi, slot, pos, ptab:
-                               (ti, hi, Z, Z)),
+        grid=(n_qb,),
+        in_specs=[q_spec] + [hbm] * len(pools),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((group_pad, d), jnp.float32),
-            pltpu.VMEM((group_pad, LANES), jnp.float32),
-            pltpu.VMEM((group_pad, LANES), jnp.float32),
+            pltpu.VMEM((2, kvh, block_pages) + pool.shape[2:], pool.dtype)
+            for pool in pools[:2]
+        ] + [
+            pltpu.VMEM((2, block_pages) + pool.shape[1:], pool.dtype)
+            for pool in pools[2:]
+        ] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.VMEM((kvh, rows, LANES), jnp.float32),
+            pltpu.VMEM((kvh, rows, LANES), jnp.float32),
+            pltpu.VMEM((kvh, rows, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _ragged_kernel, scale=np.float32(scale), page_size=page_size,
-        n_pages=n_pages, block_pages=block_pages, quant=quant,
-        bar=_bar if interpret else _no_bar)
+        block_pages=block_pages, group=group, quant=quant)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, kvh, group_pad, d), q4.dtype),
-        interpret=interpret,
-    )(*operands)
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="ragged_paged_attention",
+    )(runs, qb_first, page_table, qg, *pools)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
                            tok_pos, sm_scale=None, use_pallas=None,
                            interpret=None, k_scale=None, v_scale=None,
-                           block_q=None, block_pages=None):
+                           block_q=None, block_pages=None, runs=None):
     """Ragged mixed prefill/decode attention over a paged KV cache.
 
     q: (T, QH, D) — T flat token rows; k_pages/v_pages:
@@ -325,47 +453,44 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
     Row i attends to slot tok_slot[i]'s cache columns < tok_pos[i]+1.
 
     int8 cache: pass int8 pages plus k_scale/v_scale fp32 per-token
-    scales (KVH, num_pages, page_size, 1), dequantized inside the
-    kernel. Off-TPU (and not under interpret) the jnp reference runs —
-    same arithmetic, bit-identical.
+    scales (KVH, num_pages, page_size, 1), applied to a KV block
+    inside the kernel. Off-TPU (and not under interpret) the jnp
+    reference runs; the two agree by tolerance, not bit for bit.
 
-    `block_q`/`block_pages` pick the STATIC kernel tile (q rows per
-    block x KV pages per grid step); None/0 keep the seed defaults
-    (sublane-padded group x 1). Any legal tile computes the same
-    `_page_update` sequence — outputs stay bit-identical to the
-    reference at the matching `block_q` — so the choice is purely a
-    DMA/occupancy trade tuned per TPU generation (tools/tune_ragged.py,
-    docs/tuning.md § Kernel autotune).
+    `block_q`/`block_pages` are the kernel's STATIC tile: q rows a
+    block and pages a KV block; None/0 derive them from the shapes
+    (`ragged_tile`). A tile changes the order of summation, nothing
+    else (tools/tune_ragged.py, docs/tuning.md § Serving
+    kernel autotune). `runs` takes `ragged_runs(tok_slot, tok_pos,
+    group, block_q)` from a caller that derives it once for many calls
+    (the layer scan); None derives it here.
     """
     t, qh, d = q.shape
-    kvh = k_pages.shape[0]
+    kvh, _, page_size, _ = k_pages.shape
     group = qh // kvh
     scale = sm_scale if sm_scale is not None else d ** -0.5
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
-    gp = _resolve_block_q(block_q, group)
-    n_pages = page_table.shape[1]
-    bp = int(block_pages or 1)
-    if bp < 1:
-        raise ValueError(f"block_pages={block_pages}: want >= 1")
-    bp = min(bp, n_pages)
+    bq, bp = ragged_tile(block_q, block_pages, t, group, page_size,
+                         page_table.shape[1])
     if use_pallas is None:
         use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = False
     if not use_pallas and not interpret:
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, tok_slot, tok_pos, scale,
-            k_scale, v_scale, block_q=gp)
-    q4 = q.reshape(t, kvh, group, d)
-    pad = gp - group
-    if pad:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    o = _ragged_pallas(q4, k_pages, v_pages,
-                       page_table.astype(jnp.int32),
-                       tok_slot.astype(jnp.int32),
-                       tok_pos.astype(jnp.int32), scale, interpret,
-                       k_scale=k_scale, v_scale=v_scale, block_pages=bp)
-    if pad:
-        o = o[:, :, :group]
-    return o.reshape(t, qh, d)
+            k_scale, v_scale)
+    if runs is None:
+        runs = ragged_runs(tok_slot, tok_pos, group, bq)
+    runs, qb_first = runs
+    t_pad = (qb_first.shape[0] - 1) * bq
+    assert 0 <= t_pad - t < bq, "runs derived for another block_q"
+    # (T, QH, D) -> (KVH, T x group, D): a q block is contiguous rows
+    qg = jnp.pad(q, ((0, t_pad - t), (0, 0), (0, 0))).reshape(
+        t_pad, kvh, group, d).swapaxes(0, 1).reshape(kvh, t_pad * group, d)
+    pools = (k_pages, v_pages)
+    if k_scale is not None:
+        pools += (_scale_pages(k_scale, v_scale),)
+    o = _ragged_pallas(qg, pools, page_table.astype(jnp.int32), runs,
+                       qb_first, scale, bool(interpret), bp, group)
+    return o.reshape(kvh, t_pad, group, d).swapaxes(0, 1).reshape(
+        t_pad, qh, d)[:t]

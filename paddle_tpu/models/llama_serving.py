@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import _tuning_defaults as _tuning
-from ..kernels.ragged_paged_attention import ragged_paged_attention
+from ..kernels.ragged_paged_attention import (ragged_paged_attention,
+                                              ragged_runs, ragged_tile)
 from ..observability import compile_telemetry as _compile
 from ..observability.device_telemetry import device_generation
 from ..observability import flight_recorder as _flight
@@ -642,6 +643,10 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
     page_ids = page_table[tok_slot, pos // page_size]
     page_ids = jnp.where(row_on, page_ids, Pn - 1)       # trash page
     off = pos % page_size
+    # the kernel's unit of work, from the descriptors the step already
+    # has: once a step, not once a layer (dead code, and dropped, where
+    # the jnp reference runs)
+    runs = ragged_runs(tok_slot, tok_pos, nh // nkv, block_q)
 
     def layer(carry, xs):
         h, kp, vp, ksp, vsp = carry
@@ -660,7 +665,8 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
                                    interpret=interpret,
                                    k_scale=ksl, v_scale=vsl,
                                    block_q=block_q,
-                                   block_pages=block_pages)  # (T, QH, D)
+                                   block_pages=block_pages,
+                                   runs=runs)            # (T, QH, D)
         h = h + o.reshape(t, -1).astype(h.dtype) @ lp["wo"]
         x = _rms(h, lp["ln2"], c.rms_norm_eps)
         mlp = (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
@@ -1158,6 +1164,11 @@ class ServingEngine:
         # (decode, prefill) row mix for the pump's `serving.turn` span
         self.ragged_attn_pairs = 0
         self.ragged_kv_tokens = 0
+        # ... and how the kernel goes about it (pt_ragged_runs /
+        # pt_ragged_kv_blocks): the runs of rows it launches a program
+        # for, and its loop trips over KV blocks
+        self.ragged_runs = 0
+        self.ragged_kv_blocks = 0
         self.last_rows = (0, 0)
         # lean row-sparse lm_head epilogue (docs/serving.md § Lean
         # epilogue): every unified/verify dispatch passes a `need_rows`
@@ -1180,11 +1191,12 @@ class ServingEngine:
         # avoided (full engines skip nothing)
         self.logit_rows = 0
         self.logit_rows_skipped = 0
-        # ragged kernel tile (docs/tuning.md § Serving kernel
-        # autotune): constructor args win, else the per-TPU-generation
-        # winner persisted by tools/tune_ragged.py, else the seed
-        # shape. Resolved ONCE here — a static jit arg, so the tile
-        # never retraces the serving trace mid-flight.
+        # ragged kernel tile, q rows a block x pages a KV block
+        # (docs/tuning.md § Serving kernel autotune): constructor args
+        # win, else the per-TPU-generation winner persisted by
+        # tools/tune_ragged.py, else (None) derived from the shapes.
+        # Resolved ONCE here — a static jit arg, so the tile never
+        # retraces the serving trace mid-flight.
         tq, tp_ = _tuning.load_ragged_tile(device_generation())
         if block_q is None:
             block_q = tq
@@ -1192,6 +1204,13 @@ class ServingEngine:
             block_pages = tp_
         self._block_q = int(block_q) or None
         self._block_pages = int(block_pages) or None
+        # the effective tile, for the plan's pt_ragged_kv_blocks
+        q_rows, kv_pages = ragged_tile(
+            self._block_q, self._block_pages, self.ragged_buf,
+            config.num_attention_heads // config.num_key_value_heads,
+            page_size, self.pages_per_seq)
+        self._ragged_q_rows = q_rows
+        self._ragged_kv_block = kv_pages * page_size
         # device-resident token ring (ROADMAP item-1 last follow-on):
         # (max_seqs, max_seq_len+1) i32 where column p holds the token
         # a slot CONSUMES at cache position p. `unified_step` gathers
@@ -2535,6 +2554,18 @@ class ServingEngine:
         kv = np.zeros((B,), np.int64)
         np.maximum.at(kv, tok_slot, live)
         self.ragged_kv_tokens += int(kv.sum())
+        # how the kernel's mechanism engages: its programs a KV head
+        # (maximal runs of one slot's consecutive positions) and its
+        # loop trips a layer (KV blocks over the kernel's own runs,
+        # which also end at a q block's edge)
+        on = tok_pos >= 0
+        cont = (on[1:] & on[:-1] & (tok_slot[1:] == tok_slot[:-1])
+                & (tok_pos[1:] == tok_pos[:-1] + 1))
+        self.ragged_runs += int(on.sum() - cont.sum())
+        edge = np.arange(1, len(tok_pos)) % self._ragged_q_rows == 0
+        ends = on & ~np.append(cont & ~edge, False)   # a run's last row
+        self.ragged_kv_blocks += int(
+            (-(-live[ends] // self._ragged_kv_block)).sum())
         n_decode = len(decode_plan)
         self.last_rows = (n_decode, row - n_decode)
         sampling = {"temp": temps, "top_k": top_ks, "top_p": top_ps,

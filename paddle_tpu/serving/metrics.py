@@ -405,9 +405,21 @@ class EngineMetrics:
             "Tokens of keys and values one layer of the unified ragged "
             "steps had to read at least once: the sum over slots with "
             "a row of their highest tok_pos + 1.")
+        # ... and how the kernel goes about it (ISSUE 26)
+        self.ragged_runs = r.counter(
+            "pt_ragged_runs",
+            "Runs of the unified ragged steps: maximal stretches of "
+            "buffer rows of one slot with consecutive positions, the "
+            "programs the kernel launches a KV head.")
+        self.ragged_kv_blocks = r.counter(
+            "pt_ragged_kv_blocks",
+            "KV blocks the ragged kernel walks in one layer: the sum "
+            "over its runs (which also end at a q block's edge) of "
+            "ceil(KV length / tokens a KV block).")
         self._tok_seen = {"pad_tokens": 0, "ragged_tokens": 0,
                           "logit_rows": 0, "logit_rows_skipped": 0,
-                          "ragged_attn_pairs": 0, "ragged_kv_tokens": 0}
+                          "ragged_attn_pairs": 0, "ragged_kv_tokens": 0,
+                          "ragged_runs": 0, "ragged_kv_blocks": 0}
         self.turn_seconds = {
             part: r.counter(
                 "pt_serving_turn_seconds",
@@ -628,7 +640,9 @@ class EngineMetrics:
                               ("logit_rows_skipped",
                                self.logit_rows_skipped),
                               ("ragged_attn_pairs", self.ragged_attn_pairs),
-                              ("ragged_kv_tokens", self.ragged_kv_tokens)):
+                              ("ragged_kv_tokens", self.ragged_kv_tokens),
+                              ("ragged_runs", self.ragged_runs),
+                              ("ragged_kv_blocks", self.ragged_kv_blocks)):
             cur = getattr(engine, attr, 0)
             delta = cur - seen[attr]
             if delta > 0:

@@ -163,3 +163,47 @@ def test_mesh_train_step_lowers(monkeypatch):
     text = _lower_tpu(step, params, spmd.init_opt_state(params),
                       jnp.asarray(0), (ids, ids))
     assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+
+
+# ---------------------------------------------------------------------------
+# Mosaic's own compile step, for a chip that is described and not attached
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
+def test_ragged_compiles_for_v5e_at_the_serving_shape(one_chip, cache):
+    """The serving cell's shape (32 rows, 32 / 8 heads of 128, pages of
+    16, 256 pages a sequence, 3,072 pages) through the TPU compiler:
+    what cross-lowering cannot see — a slice off the tiling, a manual
+    DMA Mosaic refuses, too much VMEM — raises here, at no chip time.
+    Both the derived tile and the one TUNED.kernels.json holds."""
+    from paddle_tpu.kernels import ragged_paged_attention
+    t, qh, kvh, pages, n_pages, slots = 32, 32, 8, 3072, 256, 32
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((kvh, pages, PAGE, D), cache)
+    sc = arg((kvh, pages, PAGE, 1), jnp.float32) if cache == jnp.int8 \
+        else None
+    for block_pages in (None, 16):
+        def fn(q, kp, vp, table, slot, pos, *scales):
+            kw = dict(zip(("k_scale", "v_scale"), scales))
+            return ragged_paged_attention(
+                q, kp, vp, table, slot, pos, use_pallas=True,
+                interpret=False, block_pages=block_pages, **kw)
+        args = [arg((t, qh, D), jnp.bfloat16), pool, pool,
+                arg((slots, n_pages), jnp.int32), arg((t,), jnp.int32),
+                arg((t,), jnp.int32)] + ([sc, sc] if sc is not None else [])
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text or "custom-call" in text

@@ -41,8 +41,8 @@ def test_smoke_sweep_verifies_persists_reloads(tmp_path):
     entry = data["ragged"]["cpu"]
     assert {"block_q", "block_pages", "smoke", "trials"} <= set(entry)
     assert entry["smoke"] is True
-    # every surviving trial was BIT-verified against the seed tile
-    assert all(t["exact"] for t in entry["trials"]
+    # every surviving trial was held to the reference by tolerance
+    assert all(t["close"] for t in entry["trials"]
                if t["time_s"] is not None)
     assert len(entry["trials"]) >= 3
     # the tool's machine-readable summary line is its contract
@@ -68,8 +68,8 @@ def test_tuner_refuses_real_run_without_tpu(tmp_path):
 
 def test_engine_picks_up_persisted_tile(tmp_path, monkeypatch, params):
     """A tuned tile file -> ServingEngine statics, and the tuned engine
-    is token-identical to the default-tile one (the sweep's bit-verify
-    contract, re-proven through the whole serving stack)."""
+    is token-identical to the default-tile one (on the CPU both run the
+    jnp reference: the tile is the kernel's alone)."""
     path = str(tmp_path / "tiles.json")
     TD.save_ragged_tile("cpu", 16, 2, path=path)
     monkeypatch.setattr(TD, "RAGGED_TILE_FILE", path)
@@ -84,8 +84,8 @@ def test_engine_picks_up_persisted_tile(tmp_path, monkeypatch, params):
                             page_size=8, use_pallas=False, ragged=True)
         if tuned:
             assert (eng._block_q, eng._block_pages) == (16, 2)
-        else:   # untuned chip: builtin seed defaults
-            assert (eng._block_q, eng._block_pages) == (None, 1)
+        else:   # untuned chip: both derived from the shapes
+            assert (eng._block_q, eng._block_pages) == (None, None)
         eng.submit(Request("g", [1, 5, 9, 3], max_new_tokens=8))
         eng.submit(Request("s", [2, 4, 6], max_new_tokens=8,
                            temperature=0.8, top_k=8, seed=7))
