@@ -165,7 +165,9 @@ def env_bool(name, default=_UNSET, env=None):
 # -- serving -----------------------------------------------------------
 declare("PT_SERVE_PIPELINE", False,
         "Run the scheduler pump one step deep (launch step N+1 before "
-        "reading step N's results).", kind="bool", section="serving")
+        "reading step N's results). Unset: on where the model's step "
+        "writes its page pools in place, off otherwise.",
+        kind="bool", section="serving")
 declare("PT_SERVE_TIMELINE", True,
         "Per-request timeline + SLO accounting plane (0 disables; "
         "token outputs are identical either way).",

@@ -72,13 +72,14 @@ _bar = jax.lax.optimization_barrier
 
 
 def _page_update(q, k, v, acc, m_prev, l_prev, limit, pi, scale,
-                 page_size, ks=None, vs=None, bar=_bar):
+                 page_size, ks=None, vs=None, bar=_bar, lo=None):
     """One online-softmax step over one KV page: the jnp reference's
     arithmetic.
 
     q/acc: (group_pad, d) f32; m_prev/l_prev: (group_pad, LANES) f32;
     k/v: (page_size, d) f32; ks/vs: (page_size, 1) dequant scales when
-    the pool is int8; limit/pi: i32 scalars. Returns the updated
+    the pool is int8; limit/pi: i32 scalars; lo: the first visible
+    column under a window, None without one. Returns the updated
     (acc, m, l). The optimization barriers keep XLA from contracting
     the muls into the adds (or re-fusing the dots/exps): they date from
     the grid kernel this reference was bit-identical to on CPU, and
@@ -91,7 +92,10 @@ def _page_update(q, k, v, acc, m_prev, l_prev, limit, pi, scale,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)) * scale
     cols = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(cols < limit, s, NEG_INF)
+    seen = cols < limit
+    if lo is not None:
+        seen &= cols >= lo
+    s = jnp.where(seen, s, NEG_INF)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = bar(jnp.exp(s - _fit_lanes(m_new, s.shape[-1])))
     alpha = bar(jnp.exp(m_prev - m_new))
@@ -110,10 +114,12 @@ def _page_update(q, k, v, acc, m_prev, l_prev, limit, pi, scale,
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      tok_slot, tok_pos, sm_scale=None,
                                      k_scale=None, v_scale=None,
-                                     block_q=None):
+                                     block_q=None, window=None):
     """q: (T, QH, D); pages: (KVH, P, page, D); page_table:
     (S, pages_per_seq); tok_slot/tok_pos: (T,) i32 (pos -1 = inactive
-    row → zeros out). Returns (T, QH, D).
+    row → zeros out). `window` (static): a row at position p sees
+    columns j with 0 <= p - j < window, and no page wholly behind them
+    is read. Returns (T, QH, D).
 
     This is NOT a dense-softmax shortcut: it replays `_page_update`
     over page ordinals (group padded, lane-replicated stats), skipped
@@ -130,11 +136,14 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
     pages = page_table[tok_slot].astype(jnp.int32)       # (T, n_pages)
     limit = (tok_pos + 1).astype(jnp.int32)              # (T,)
+    first_col = None if window is None else \
+        jnp.maximum(limit - np.int32(window), 0)
     qg = q.reshape(t, kvh, group, d).astype(jnp.float32)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
 
     def token_head(args):
-        qg_th, pages_t, limit_t, hi = args
+        qg_th, pages_t, limit_t, hi = args[:4]
+        lo_t = args[4] if window is not None else None
         k_h = k_pages[hi]
         v_h = v_pages[hi]
         sc = (k_scale[hi], v_scale[hi]) if quant else None
@@ -146,10 +155,12 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
             v = v_h[pg].astype(jnp.float32)
             acc_new, m_new, l_new = _page_update(
                 qg_th, k, v, acc, m, l, limit_t, pi, scale, page_size,
-                *( (sc[0][pg], sc[1][pg]) if quant else () ))
+                *( (sc[0][pg], sc[1][pg]) if quant else () ), lo=lo_t)
             # page skip: the kernel's @pl.when leaves the scratch
             # UNTOUCHED on a masked page — carry the old bits through
             take = pi * page_size < limit_t
+            if window is not None:
+                take &= (pi + 1) * page_size > lo_t
             return (jnp.where(take, acc_new, acc),
                     jnp.where(take, m_new, m),
                     jnp.where(take, l_new, l)), None
@@ -165,7 +176,8 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     ti_idx = jnp.repeat(jnp.arange(t), kvh)
     hi_idx = jnp.tile(jnp.arange(kvh), t)
     o = jax.lax.map(token_head, (qg.reshape(t * kvh, gp, d),
-                                 pages[ti_idx], limit[ti_idx], hi_idx))
+                                 pages[ti_idx], limit[ti_idx], hi_idx)
+                    + (() if window is None else (first_col[ti_idx],)))
     o = o.reshape(t, kvh, gp, d)[:, :, :group]
     return o.reshape(t, qh, d).astype(q.dtype)
 
@@ -273,7 +285,7 @@ def ragged_runs(tok_slot, tok_pos, group, block_q=None):
 
 
 def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
-                   page_size, block_pages, group, quant):
+                   page_size, block_pages, group, quant, window=None):
     """Grid (q blocks,). Program j holds q block j (all KV heads, its
     rows x the GQA group) in VMEM and walks the runs that lie in it;
     per run, a loop over KV blocks of `block_pages` pages whose trip
@@ -281,7 +293,10 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
     in for all KV heads by one strided DMA through the page table,
     into the buffer the next trip reads while this trip computes (the
     prefetch crosses from a run's last block to the next run's first).
-    A page the run does not own is neither fetched nor waited for."""
+    A page the run does not own is neither fetched nor waited for.
+    With a `window` a run's walk begins at the KV block that holds the
+    first column its first row sees, pages wholly behind that column
+    are not fetched either, and the mask cuts inside the block."""
     n_pool = 3 if quant else 2
     pools, o_ref = refs[:n_pool], refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
@@ -303,6 +318,14 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
         for buf in bufs[1:]:
             buf[...] = jnp.zeros_like(buf)
 
+    def first_col(r):
+        """The first column run r's first row sees (window only)."""
+        return jnp.maximum(
+            runs_ref[3, r] - runs_ref[1, r] - np.int32(window - 1), Z)
+
+    def first_block(r):
+        return Z if window is None else first_col(r) // np.int32(blk)
+
     def for_pages(r, b, slot_, op):
         """`start` or `wait` the DMA of every pool page of block b of
         run r into buffer slot_."""
@@ -310,8 +333,11 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
         owned = pl.cdiv(runs_ref[3, r], np.int32(page_size))
         for p in range(block_pages):
             ordinal = b * np.int32(block_pages) + np.int32(p)
+            held = ordinal < owned
+            if window is not None:
+                held &= ordinal >= first_col(r) // np.int32(page_size)
 
-            @pl.when(ordinal < owned)
+            @pl.when(held)
             def _(p=p, ordinal=ordinal):
                 page = ptab_ref[seq, ordinal]
                 for n, (pool, buf) in enumerate(zip(pools, bufs)):
@@ -331,6 +357,8 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
         # consecutive in a run and the last row's is kv_len - 1
         live = ((row >= first) & (row < first + n_rows)
                 & (col < kv_len - (first + n_rows) + row + np.int32(1)))
+        if window is not None:
+            live &= col > kv_len - (first + n_rows) + row - np.int32(window)
         if quant:
             sc = bufs[2][slot_].reshape(blk, bufs[2].shape[-1])
         for h in range(kvh):
@@ -361,7 +389,7 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
 
     @pl.when(r_lo < r_hi)
     def _first_fetch():
-        for_pages(r_lo, Z, Z, "start")
+        for_pages(r_lo, first_block(r_lo), Z, "start")
 
     def run(r, slot_):
         n_blocks = pl.cdiv(runs_ref[3, r], np.int32(blk))
@@ -369,7 +397,13 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
         def trip(b, slot_):
             last = b + np.int32(1) >= n_blocks
             r_next = jnp.where(last, r + np.int32(1), r)
-            b_next = jnp.where(last, Z, b + np.int32(1))
+            if window is None:
+                b_next = jnp.where(last, Z, b + np.int32(1))
+            else:
+                # the run after the last has no descriptor to read
+                b_next = jnp.where(
+                    last, first_block(jnp.minimum(r_next, r_hi - np.int32(1))),
+                    b + np.int32(1))
 
             @pl.when(r_next < r_hi)
             def _prefetch():
@@ -379,7 +413,7 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
             block(r, b, slot_)
             return np.int32(1) - slot_
 
-        return jax.lax.fori_loop(Z, n_blocks, trip, slot_)
+        return jax.lax.fori_loop(first_block(r), n_blocks, trip, slot_)
 
     jax.lax.fori_loop(r_lo, r_hi, run, Z)
 
@@ -401,7 +435,7 @@ def _scale_pages(k_scale, v_scale):
 
 
 def _ragged_pallas(qg, pools, page_table, runs, qb_first, scale, interpret,
-                   block_pages, group):
+                   block_pages, group, window=None):
     kvh, rows_all, d = qg.shape
     n_qb = qb_first.shape[0] - 1
     rows = rows_all // n_qb
@@ -430,7 +464,8 @@ def _ragged_pallas(qg, pools, page_table, runs, qb_first, scale, interpret,
     )
     kernel = functools.partial(
         _ragged_kernel, scale=np.float32(scale), page_size=page_size,
-        block_pages=block_pages, group=group, quant=quant)
+        block_pages=block_pages, group=group, quant=quant,
+        **({} if window is None else {"window": int(window)}))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -443,7 +478,8 @@ def _ragged_pallas(qg, pools, page_table, runs, qb_first, scale, interpret,
 def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
                            tok_pos, sm_scale=None, use_pallas=None,
                            interpret=None, k_scale=None, v_scale=None,
-                           block_q=None, block_pages=None, runs=None):
+                           block_q=None, block_pages=None, runs=None,
+                           window=None):
     """Ragged mixed prefill/decode attention over a paged KV cache.
 
     q: (T, QH, D) — T flat token rows; k_pages/v_pages:
@@ -464,6 +500,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
     kernel autotune). `runs` takes `ragged_runs(tok_slot, tok_pos,
     group, block_q)` from a caller that derives it once for many calls
     (the layer scan); None derives it here.
+
+    `window` (STATIC; None = every column up to the row's own): a row
+    at position p sees columns j with 0 <= p - j < window. A run's
+    walk then starts at the KV block holding the first column its
+    first row sees, so page-table entries wholly behind the window
+    (the engine has released those pages) are never read.
     """
     t, qh, d = q.shape
     kvh, _, page_size, _ = k_pages.shape
@@ -478,7 +520,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
     if not use_pallas and not interpret:
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, tok_slot, tok_pos, scale,
-            k_scale, v_scale)
+            k_scale, v_scale, window=window)
     if runs is None:
         runs = ragged_runs(tok_slot, tok_pos, group, bq)
     runs, qb_first = runs
@@ -491,6 +533,6 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
     if k_scale is not None:
         pools += (_scale_pages(k_scale, v_scale),)
     o = _ragged_pallas(qg, pools, page_table.astype(jnp.int32), runs,
-                       qb_first, scale, bool(interpret), bp, group)
+                       qb_first, scale, bool(interpret), bp, group, window)
     return o.reshape(kvh, t_pad, group, d).swapaxes(0, 1).reshape(
         t_pad, qh, d)[:t]

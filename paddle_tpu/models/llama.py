@@ -45,6 +45,12 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     dtype: str = "float32"
 
+    def serving_model(self):
+        """How `ServingEngine` serves this configuration: its cache spec
+        and step (serving/model_spec.py)."""
+        from .llama_serving import llama_serving_model
+        return llama_serving_model(self)
+
     @classmethod
     def llama3_8b(cls):
         return cls(vocab_size=128256, hidden_size=4096, intermediate_size=14336,

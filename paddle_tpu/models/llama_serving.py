@@ -43,6 +43,7 @@ from ..serving.faults import FaultPlan
 from ..serving.handoff import KVHandoff
 from ..serving.kvcache import PagePool, PrefixCache
 from ..serving.kvtier import HostTier, _dequantize_host, _quantize_host
+from ..serving.model_spec import CacheGroup, ServingModel
 from ..ops.rope import rope_cos_sin, apply_rotary_emb
 from ..ops.flash_attention import flash_attention_bhsd
 from ..ops.paged_attention import (paged_attention, paged_verify_attention,
@@ -59,28 +60,46 @@ def _rms(x, w, eps):
             * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def _scatter_kv(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant):
+def _scatter_kv(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant,
+                flat=False):
     """Write kt/vt (KVH, *idx, D) into layer li of the K/V pools at
     (page_ids, off) — *idx is page_ids/off's shape — quantizing on write
     when the pool is int8 (per-token scales ride in ksp/vsp). Single
     source for decode_step's one-token and verify_step's G-token
     scatters so the int8 path can never drift between them. Returns
     (kp, vp, ksp, vsp, kl, vl, ksl, vsl): the updated stacks plus this
-    layer's views for the attention read."""
+    layer's views for the attention read.
+
+    `flat` writes the same values as rows of the layer seen as
+    (KVH x pages x page, D), a scatter along the major dimension: the
+    TPU compiler then updates a donated pool where it lies, where the
+    three-index form has it transpose the whole pool to put the heads
+    inside the pages, and back (one-index `page_ids` only)."""
     kl = jax.lax.dynamic_index_in_dim(kp, li, 0, keepdims=False)
     vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
+    if flat:
+        kvh, n_pages, page = kl.shape[:3]
+        rows = ((jnp.arange(kvh, dtype=jnp.int32) * n_pages)[:, None]
+                + page_ids[None, :]) * page + off[None, :]
+
+        def put(pool, new):
+            return pool.reshape(-1, pool.shape[-1]).at[rows.reshape(-1)].set(
+                new.reshape(-1, new.shape[-1])).reshape(pool.shape)
+    else:
+        def put(pool, new):
+            return pool.at[:, page_ids, off].set(new)
     ksl = vsl = None
     if quant:
         kt, kts = quantize_kv(kt)
         vt, vts = quantize_kv(vt)
         ksl = jax.lax.dynamic_index_in_dim(ksp, li, 0, keepdims=False)
         vsl = jax.lax.dynamic_index_in_dim(vsp, li, 0, keepdims=False)
-        ksl = ksl.at[:, page_ids, off].set(kts)
-        vsl = vsl.at[:, page_ids, off].set(vts)
+        ksl = put(ksl, kts)
+        vsl = put(vsl, vts)
         ksp = jax.lax.dynamic_update_index_in_dim(ksp, ksl, li, 0)
         vsp = jax.lax.dynamic_update_index_in_dim(vsp, vsl, li, 0)
-    kl = kl.at[:, page_ids, off].set(kt.astype(kl.dtype))
-    vl = vl.at[:, page_ids, off].set(vt.astype(vl.dtype))
+    kl = put(kl, kt.astype(kl.dtype))
+    vl = put(vl, vt.astype(vl.dtype))
     kp = jax.lax.dynamic_update_index_in_dim(kp, kl, li, 0)
     vp = jax.lax.dynamic_update_index_in_dim(vp, vl, li, 0)
     return kp, vp, ksp, vsp, kl, vl, ksl, vsl
@@ -850,13 +869,14 @@ class RaggedTicket:
     record FLAT: `flat` maps a decode slot to its buffer row, `seeds`
     lists (slot, req) whose prefill completed this wave — their
     first-token logits rows ride `seed_rows` and are picked HOST-side
-    at finish (the PR 8 seeding convention)."""
+    at finish (the PR 8 seeding convention). `aux` is what the model's
+    step adds to the record (`ServingModel.step`), read with it."""
 
     __slots__ = ("reqs", "flat", "next_tok", "done", "logprob",
-                 "seeds", "seed_rows", "slots")
+                 "seeds", "seed_rows", "slots", "aux")
 
     def __init__(self, reqs, flat, next_tok, done, logprob, seeds,
-                 seed_rows, slots):
+                 seed_rows, slots, aux=None):
         self.reqs = reqs            # slot -> Request (decode rows only)
         self.flat = flat            # slot -> flat buffer row index
         self.next_tok = next_tok    # device (T,) i32
@@ -865,6 +885,7 @@ class RaggedTicket:
         self.seeds = seeds          # [(slot, req)] completed prefills
         self.seed_rows = seed_rows  # device (len(seeds), V) or None
         self.slots = slots          # slots with any row this wave
+        self.aux = aux or {}        # device arrays of the step's record
 
 
 class Request:
@@ -957,6 +978,96 @@ def _tl_count(req, phase, n=1):
         tl.count(phase, n)
 
 
+def _llama_step(params, caches, tables, tokens, tok_slot, tok_pos, config,
+                page_size, **kw):
+    """`unified_step` behind `ServingModel.step`'s contract: one cache
+    group, one stack of alike layers."""
+    ((k, v, ks, vs),), = caches
+    out = unified_step(params, k, v, tables[0], tokens, tok_slot, tok_pos,
+                       config, page_size, k_scale=ks, v_scale=vs, **kw)
+    return ((tuple(out[:4]),),), out[4], out[5], \
+        (out[6] if kw.get("tok_buf") is not None else None), {}
+
+
+def llama_serving_model(config: LlamaConfig):
+    """What `LlamaConfig.serving_model()` answers: every layer alike, so
+    one cache group scanned as one stack, no window, and every engine
+    feature (the bucketed entry points above are this family's)."""
+    c = config
+    return ServingModel(
+        groups=(CacheGroup("full", (c.num_hidden_layers,),
+                           c.num_key_value_heads,
+                           c.hidden_size // c.num_attention_heads),),
+        q_group=c.num_attention_heads // c.num_key_value_heads,
+        step=_llama_step)
+
+
+class _GroupCache:
+    """One cache group's share of the engine: its pool arrays on the
+    device, and on the host its page table, its allocator and the pages
+    each slot holds. `base[s]` is the ordinal of slot s's first HELD
+    page: 0 unless a window has given pages back."""
+
+    def __init__(self, spec, num_pages, slot_cap, max_seqs, pages_per_seq,
+                 page_size, pool_dtype, quant, placement, prefix_cache=None):
+        self.spec = spec
+        self.num_pages = num_pages
+        # most pages a slot can hold at once: its whole context, or what
+        # a window plus one buffer of rows can still see
+        self.slot_cap = slot_cap
+
+        def pools(dt, last):
+            return [jnp.zeros((n, spec.kv_heads, num_pages, page_size, last),
+                              dt, device=placement) for n in spec.stacks]
+        self.k = pools(pool_dtype, spec.head_dim)
+        self.v = pools(pool_dtype, spec.head_dim)
+        none = [None] * len(spec.stacks)
+        self.ks = pools(jnp.float32, 1) if quant else list(none)
+        self.vs = pools(jnp.float32, 1) if quant else list(none)
+        # unassigned entries point at the trash page (the last), never
+        # page 0: a stale row must alias a page no live slot reads
+        self.table = np.full((max_seqs, pages_per_seq), num_pages - 1,
+                             np.int32)
+        self.pool = PagePool(num_pages - 1, cache=prefix_cache)
+        self.seq_pages = {s: [] for s in range(max_seqs)}
+        self.base = np.zeros((max_seqs,), np.int64)
+        self.released = 0       # pages a window gave back
+
+    def device(self):
+        return tuple(zip(self.k, self.v, self.ks, self.vs))
+
+    def take(self, caches):
+        self.k, self.v, self.ks, self.vs = (list(x) for x in zip(*caches))
+
+    def end(self, s):
+        """Ordinal one past slot s's last held page."""
+        return int(self.base[s]) + len(self.seq_pages[s])
+
+    def gather(self, pg):
+        """Pages `pg` of every layer, to the host: (layers, KVH, n,
+        page, D) arrays, scales None unless the pool is int8."""
+        def cat(arrs):
+            return None if arrs[0] is None else np.concatenate(
+                [np.asarray(a[:, :, pg]) for a in arrs])
+        return {"k": cat(self.k), "v": cat(self.v),
+                "ks": cat(self.ks), "vs": cat(self.vs)}
+
+    def scatter(self, pg, k, v, ks, vs):
+        """The inverse of `gather`: host arrays into pages `pg`."""
+        def put(arrs, host, dt=None):
+            at = 0
+            for i, a in enumerate(arrs):
+                n = a.shape[0]
+                arrs[i] = a.at[:, :, pg].set(
+                    jnp.asarray(host[at:at + n], dt or a.dtype))
+                at += n
+        put(self.k, k)
+        put(self.v, v)
+        if self.ks[0] is not None:
+            put(self.ks, ks, jnp.float32)
+            put(self.vs, vs, jnp.float32)
+
+
 class ServingEngine:
     """Continuous-batching decode loop over the paged cache.
 
@@ -1023,6 +1134,21 @@ class ServingEngine:
                  block_q=None, block_pages=None, tokbuf=None, device=None):
         c = config
         _compile.ensure_compile_cache()
+        # the model seam (serving/model_spec.py): the configuration says
+        # how it is served, a cache spec and a step. Everything below is
+        # built from that answer, never from the configuration's type.
+        model = self.model = c.serving_model()
+        tp_ = mesh is not None and mesh.shape.get("tp", 1) > 1
+        if ragged is None and "bucketed" in model.unsupported:
+            ragged = True
+        for feature, asked in (
+                ("tensor_parallel", tp_), ("prefix_cache", prefix_cache),
+                ("host_tier", host_tier_bytes),
+                ("spec_decode", int(spec_decode) > 1 or chunked_prefill),
+                ("bucketed", ragged is not None and not ragged),
+                ("host_tokens", tokbuf is not None and not tokbuf)):
+            if asked and feature in model.unsupported:
+                raise ValueError(model.unsupported[feature])
         # mesh with a 'tp' axis: tensor-parallel serving — weights get
         # megatron NamedShardings (llama_spmd.param_specs), the KV pool
         # shards over its KV-head axis, the paged kernels run per-rank
@@ -1032,7 +1158,7 @@ class ServingEngine:
         # model larger than one chip serves (reference: fleet TP under
         # the predictor, mp_layers.py + block_multihead_attention).
         self._mesh = None
-        if mesh is not None and mesh.shape.get("tp", 1) > 1:
+        if tp_:
             tp = mesh.shape["tp"]
             if c.num_attention_heads % tp or c.num_key_value_heads % tp:
                 raise ValueError(
@@ -1067,16 +1193,16 @@ class ServingEngine:
         self.max_seqs = max_seqs
         self.max_seq_len = max_seq_len
         self.pages_per_seq = -(-max_seq_len // page_size)
-        # +1 trash page for masked writes of inactive slots
-        if num_pages is None:
-            num_pages = max_seqs * self.pages_per_seq + 1
-        else:
-            num_pages = int(num_pages)
-            if num_pages < self.pages_per_seq + 1:
+        # +1 trash page for masked writes of inactive slots. One count
+        # for the model's one group, or {group name: pages} where it has
+        # several (each group's pool has its own trash page).
+        if not isinstance(num_pages, dict):
+            if num_pages is not None and len(model.groups) > 1:
                 raise ValueError(
-                    f"num_pages={num_pages} cannot hold even one "
-                    f"max_seq_len sequence ({self.pages_per_seq} pages) "
-                    "+ the trash page")
+                    f"num_pages={num_pages}: this model keeps "
+                    f"{[g.name for g in model.groups]} pages apart; give "
+                    "a dict of pages by group name")
+            num_pages = {model.groups[0].name: num_pages}
         if cache_dtype not in (None, "int8", jnp.int8):
             # a silently-wrong pool dtype (e.g. 'int4', or a typo)
             # would truncate K/V writes with no scales and decode
@@ -1164,6 +1290,14 @@ class ServingEngine:
         # (decode, prefill) row mix for the pump's `serving.turn` span
         self.ragged_attn_pairs = 0
         self.ragged_kv_tokens = 0
+        # the same two by layer type (a windowed group's rows see at
+        # most the window), and what the step's record says of its
+        # expert layers: assignments made, experts that got a row, the
+        # fullest expert's rows, each summed over sparse layers and steps
+        self.ragged_by_type = {g.name: [0, 0] for g in model.groups}
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
+        self.moe_rows_max_expert = 0
         # ... and how the kernel goes about it (pt_ragged_runs /
         # pt_ragged_kv_blocks): the runs of rows it launches a program
         # for, and its loop trips over KV blocks
@@ -1197,18 +1331,17 @@ class ServingEngine:
         # tools/tune_ragged.py, else (None) derived from the shapes.
         # Resolved ONCE here — a static jit arg, so the tile never
         # retraces the serving trace mid-flight.
-        tq, tp_ = _tuning.load_ragged_tile(device_generation())
+        tq, tpg = _tuning.load_ragged_tile(device_generation())
         if block_q is None:
             block_q = tq
         if block_pages is None:
-            block_pages = tp_
+            block_pages = tpg
         self._block_q = int(block_q) or None
         self._block_pages = int(block_pages) or None
         # the effective tile, for the plan's pt_ragged_kv_blocks
         q_rows, kv_pages = ragged_tile(
             self._block_q, self._block_pages, self.ragged_buf,
-            config.num_attention_heads // config.num_key_value_heads,
-            page_size, self.pages_per_seq)
+            model.q_group, page_size, self.pages_per_seq)
         self._ragged_q_rows = q_rows
         self._ragged_kv_block = kv_pages * page_size
         # device-resident token ring (ROADMAP item-1 last follow-on):
@@ -1234,9 +1367,6 @@ class ServingEngine:
         # occupancy, page stats, and preemptions into it. None = free.
         self.metrics = None
         self._order = 0
-        kvh = c.num_key_value_heads
-        hd = c.hidden_size // c.num_attention_heads
-        L = c.num_hidden_layers
         # cache_dtype="int8": quantized KV pool with per-token fp32
         # scales (reference parity: cachekv-quant decode in
         # phi/kernels/fusion/gpu/block_attn.h) — 2x (bf16) / ~3.5x
@@ -1244,19 +1374,45 @@ class ServingEngine:
         self.cache_quant = cache_dtype in ("int8", jnp.int8)
         pool_dtype = jnp.int8 if self.cache_quant else \
             (cache_dtype or dtype)
-        self.num_pages = num_pages
-        pshape = (L, kvh, num_pages, page_size, hd)
-        # allocated where they live: on the engine's device, or laid
-        # out over the tp mesh (KV heads sharded)
-        def pool(shape, dt):
-            return jnp.zeros(shape, dt, device=self._pool_placement)
-        self.k_pool = pool(pshape, pool_dtype)
-        self.v_pool = pool(pshape, pool_dtype)
-        if self.cache_quant:
-            self.k_scale = pool(pshape[:-1] + (1,), jnp.float32)
-            self.v_scale = pool(pshape[:-1] + (1,), jnp.float32)
-        else:
-            self.k_scale = self.v_scale = None
+        # single ref-count-aware allocator a group for EVERY page-lifetime
+        # path (admission, finish, cancel sweep, offload/restore). The trash
+        # page (last id) is outside the pool: never allocated, shared,
+        # indexed, or evicted. prefix_cache=True additionally indexes
+        # full pages by chained block hash so admissions sharing a
+        # prompt prefix map the same physical pages and prefill only
+        # their suffix (serving/kvcache.py; docs/serving.md).
+        self.prefix_cache = PrefixCache(page_size) if prefix_cache else None
+        # pools by layer type (docs/serving.md § The cache spec): allocated
+        # where they live, on the engine's device or laid out over the
+        # tp mesh (KV heads sharded). The legacy names (`k_pool`,
+        # `page_table`, `pool`, `_seq_pages`, `num_pages`) are group 0's.
+        self._caches = []
+        for g in model.groups:
+            # a windowed slot holds what a row can still see plus the
+            # chunk being written: the window, one buffer of rows, and
+            # a page of slack at either end
+            cap = self.pages_per_seq if g.window is None else min(
+                self.pages_per_seq,
+                -(-(g.window + self.ragged_buf) // page_size) + 1)
+            n = num_pages.pop(g.name, None)
+            if n is None:
+                n = max_seqs * cap + 1
+            n = int(n)
+            if n < cap + 1:
+                raise ValueError(
+                    f"num_pages={n} cannot hold even one "
+                    f"max_seq_len sequence ({cap} pages) "
+                    "+ the trash page")
+            self._caches.append(_GroupCache(
+                g, n, cap, max_seqs, self.pages_per_seq, page_size,
+                pool_dtype, self.cache_quant, self._pool_placement,
+                self.prefix_cache))
+        if num_pages:
+            raise ValueError(
+                f"num_pages names {sorted(num_pages)}; this model's cache "
+                f"groups are {[g.name for g in model.groups]}")
+        self._windowed = [gc for gc in self._caches
+                          if gc.spec.window is not None]
         # page_table/lengths are HOST numpy state, transferred once per
         # device call: the admission/growth bookkeeping reads and writes
         # them element-wise every step, and each element access on a
@@ -1264,20 +1420,7 @@ class ServingEngine:
         # dispatches per step measured on CPU) — the whole tables are a
         # few hundred bytes, so one jnp.asarray per step is strictly
         # cheaper
-        # unassigned entries point at the trash page, never page 0: a
-        # stale or default row must alias a page no live slot reads
-        self.page_table = np.full((max_seqs, self.pages_per_seq),
-                                  self.num_pages - 1, np.int32)
         self.lengths = np.zeros((max_seqs,), np.int32)
-        # single ref-count-aware allocator for EVERY page-lifetime path
-        # (admission, finish, cancel sweep, offload/restore). The trash
-        # page (last id) is outside the pool: never allocated, shared,
-        # indexed, or evicted. prefix_cache=True additionally indexes
-        # full pages by chained block hash so admissions sharing a
-        # prompt prefix map the same physical pages and prefill only
-        # their suffix (serving/kvcache.py; docs/serving.md).
-        self.prefix_cache = PrefixCache(page_size) if prefix_cache else None
-        self.pool = PagePool(num_pages - 1, cache=self.prefix_cache)
         # host-RAM KV tier (serving/kvtier.py; docs/serving.md
         # § KV-cache tiering): one budgeted ledger for ALL
         # host-resident KV. The preemption offload stash always lives
@@ -1325,7 +1468,6 @@ class ServingEngine:
             if self.host_tier.enabled:
                 self.prefix_cache.on_spill = self._spill_page
         self._index_suspend = False  # set while releasing failed slots
-        self._seq_pages = {s: [] for s in range(max_seqs)}
         self._slots = [None] * max_seqs          # slot -> Request
         # occupied-slot set maintained by admit/release: the per-step
         # page-growth and batch-building passes iterate THIS, not all
@@ -1352,6 +1494,26 @@ class ServingEngine:
         self._use_pallas_prefill = False if self._mesh is not None \
             else use_pallas
         self._interpret = interpret
+
+    # group 0's share of the cache under the names the engine has always
+    # used (every Llama-only path, the tests and the tools go by them)
+    def _g0(name, stack=False):  # noqa: N805 - a property factory
+        def get(self):
+            v = getattr(self._caches[0], name)
+            return v[0] if stack else v
+
+        def put(self, value):
+            if stack:
+                getattr(self._caches[0], name)[0] = value
+            else:
+                setattr(self._caches[0], name, value)
+        return property(get, put)
+
+    k_pool, v_pool = _g0("k", True), _g0("v", True)
+    k_scale, v_scale = _g0("ks", True), _g0("vs", True)
+    page_table, pool = _g0("table"), _g0("pool")
+    _seq_pages, num_pages = _g0("seq_pages"), _g0("num_pages")
+    del _g0
 
     @property
     def _free(self):
@@ -1385,6 +1547,10 @@ class ServingEngine:
         self.validate(req)
         if req._t_submit is None:
             req._t_submit = time.perf_counter()
+        if getattr(req, "_handoff_export", False) or \
+                getattr(req, "_kv_import", None) is not None:
+            if "handoff" in self.model.unsupported:
+                raise ValueError(self.model.unsupported["handoff"])
         if getattr(req, "_handoff_export", False):
             self._handoff_pending += 1
         self._waiting.append(req)
@@ -1601,26 +1767,35 @@ class ServingEngine:
                 else:
                     horizon = min(int(self.lengths[s]) + G,
                                   self.max_seq_len)
-                return max(0, -(-horizon // self.page_size)
-                           - len(self._seq_pages[s]))
-            growth_need = sum(_reserve(s) for s in sorted(self._live))
+                # a windowed group never holds more than its cap
+                return [
+                    max(0, min(-(-horizon // self.page_size) - gc.end(s),
+                               gc.slot_cap - len(gc.seq_pages[s])))
+                    for gc in self._caches]
+            growth_need = [sum(col) for col in zip(
+                *(_reserve(s) for s in sorted(self._live)))] \
+                or [0] * len(self._caches)
         else:
-            growth_need = sum(
+            growth_need = [sum(
                 1 for s in self._live
                 if int(self.lengths[s]) > 0
                 and int(self.lengths[s]) % self.page_size == 0
                 and len(self._seq_pages[s]) * self.page_size
-                <= int(self.lengths[s]))
+                <= int(self.lengths[s]))]
+        # pages spoken for, a cache group (the bucketed paths serve
+        # one-group models only)
         reserve = growth_need
         take = 0
         for req in self._waiting[:len(free_slots)]:
             ofl = getattr(req, "_offload", None)
             hin = getattr(req, "_kv_import", None)
             if ofl is not None:
-                need = ofl["pages"]
+                need = list(ofl["pages"])
                 if ofl["len"] % self.page_size == 0 and \
-                        need * self.page_size <= ofl["len"]:
-                    need += 1  # boundary growth this same step
+                        (ofl["base"][0] + need[0]) * self.page_size \
+                        <= ofl["len"]:
+                    # boundary growth this same step
+                    need = [n + 1 for n in need]
             elif hin is not None:
                 # a handoff import scatters its shipped pages like a
                 # restore — no prefix probe (the payload IS the prefix)
@@ -1640,13 +1815,20 @@ class ServingEngine:
                     - len(req._kv_match[0])
                 if feed_len % self.page_size == 0:
                     need += 1  # its own first decode boundary, same step
+            # every group has to hold its share: the same pages a group,
+            # up to what a windowed slot can hold at once
+            if isinstance(need, int):
+                need = [need] * len(self._caches)
+            need = [min(n, gc.slot_cap)
+                    for n, gc in zip(need, self._caches)]
             # pool.available() counts free + reclaimable (rc==0 cached)
             # pages; reviving a matched page above already removed it
             # from the reclaimable side
-            if need > self.pool.available() - reserve:
+            if any(n > gc.pool.available() - r
+                   for n, gc, r in zip(need, self._caches, reserve)):
                 self._cache_unacquire(req)
                 break
-            reserve += need
+            reserve = [r + n for r, n in zip(reserve, need)]
             take += 1
         if take == 0:
             return
@@ -1803,16 +1985,19 @@ class ServingEngine:
         self._fill_indices(pg, off, slot, 0, S)
         self._scatter_packed(kq, vq, pg, off)
 
-    def _alloc_pages(self, slot, n):
-        if not self.pool.can_alloc(n):
+    def _alloc_pages(self, slot, n, gc=None):
+        """n more pages for `slot` in cache group `gc` (group 0 unless
+        given), at the ordinals after its last held page."""
+        gc = gc or self._caches[0]
+        if not gc.pool.can_alloc(n):
             raise RuntimeError("serving: out of KV pages")
-        if len(self._seq_pages[slot]) + n > self.pages_per_seq:
+        start = gc.end(slot)
+        if start + n > self.pages_per_seq:
             raise RuntimeError("serving: sequence exceeds max_seq_len")
-        pages = self.pool.alloc(n)
-        self._seq_pages[slot].extend(pages)
-        start = len(self._seq_pages[slot]) - n
+        pages = gc.pool.alloc(n)
+        gc.seq_pages[slot].extend(pages)
         for i, pg in enumerate(pages):
-            self.page_table[slot, start + i] = pg
+            gc.table[slot, start + i] = pg
         m = self.metrics
         if m is not None:
             m.on_page_alloc(n)
@@ -1864,33 +2049,37 @@ class ServingEngine:
         s = max(victims, key=lambda v: self._slots[v]._admit_order)
         req = self._slots[s]
         if self.preempt_policy == "offload":
-            n_pg = len(self._seq_pages[s])
-            # gather at the FIXED pages_per_seq width (tail reads the
-            # trash page, sliced off after the transfer): a per-count
-            # gather shape would be a fresh XLA compile per eviction size
-            pg = np.full((self.pages_per_seq,), self.num_pages - 1,
-                         np.int32)
-            pg[:n_pg] = self._seq_pages[s]
-            payload = {
-                "k": np.asarray(self.k_pool[:, :, pg])[:, :, :n_pg],
-                "v": np.asarray(self.v_pool[:, :, pg])[:, :, :n_pg],
-                "ks": None if self.k_scale is None else
-                      np.asarray(self.k_scale[:, :, pg])[:, :, :n_pg],
-                "vs": None if self.v_scale is None else
-                      np.asarray(self.v_scale[:, :, pg])[:, :, :n_pg],
-            }
+            payload = {}
+            for i, gc in enumerate(self._caches):
+                n_pg = len(gc.seq_pages[s])
+                # gather at the FIXED pages_per_seq width (tail reads
+                # the trash page, sliced off after the transfer): a
+                # per-count gather shape would be a fresh XLA compile
+                # per eviction size
+                pg = np.full((self.pages_per_seq,), gc.num_pages - 1,
+                             np.int32)
+                pg[:n_pg] = gc.seq_pages[s]
+                # group 0 under the bare names, as it always was
+                payload.update({
+                    k + (f".{i}" if i else ""):
+                    None if a is None else a[:, :, :n_pg]
+                    for k, a in gc.gather(pg).items()})
             # the KV itself parks in the host tier's PINNED stash —
             # one host-RAM ledger with the spilled prefix pages (no
             # second ad-hoc store); the request carries only shape
             # metadata. Stored verbatim: a resume must be exact.
-            self.host_tier.stash_put(id(req), payload, n_pg)
+            self.host_tier.stash_put(
+                id(req), payload,
+                sum(len(gc.seq_pages[s]) for gc in self._caches))
             _tl_mark(req, "spill")
             req._offload = {
                 "len": int(self.lengths[s]),
-                # actual page count, NOT ceil(len/page_size): a victim
-                # evicted right after its boundary growth already holds
-                # the next (still-empty) page
-                "pages": n_pg,
+                # actual page counts a group, NOT ceil(len/page_size):
+                # a victim evicted right after its boundary growth
+                # already holds the next (still-empty) page; and the
+                # ordinal its first held page had (a window's is not 0)
+                "pages": [len(gc.seq_pages[s]) for gc in self._caches],
+                "base": [int(gc.base[s]) for gc in self._caches],
             }
         req._resume = True
         req.slot = None
@@ -1919,11 +2108,16 @@ class ServingEngine:
         survived eviction on the Request itself."""
         o = req._offload
         S = o["len"]
-        n_pages = o["pages"]
-        self._seq_pages[slot] = []
-        pages = self._alloc_pages(slot, n_pages)
         p = self.host_tier.stash_take(id(req))
-        self._scatter_host_kv(pages, p["k"], p["v"], p["ks"], p["vs"])
+        for i, (gc, n_pages, base) in enumerate(zip(
+                self._caches, o["pages"], o["base"])):
+            part = {k: p[k + (f".{i}" if i else "")]
+                    for k in ("k", "v", "ks", "vs")}
+            gc.seq_pages[slot] = []
+            gc.base[slot] = base
+            pages = self._alloc_pages(slot, n_pages, gc)
+            self._scatter_host_kv(pages, part["k"], part["v"], part["ks"],
+                                  part["vs"], gc)
         self.lengths[slot] = S
         req._offload = None
         req._resume = False
@@ -1933,30 +2127,26 @@ class ServingEngine:
         self._attach(slot, req)
         self._stage_tokbuf(slot, req)
 
-    def _scatter_host_kv(self, pages, k, v, ks, vs):
+    def _scatter_host_kv(self, pages, k, v, ks, vs, gc=None):
         """Scatter host-resident page KV (np, (L, KVH, n, page, D))
-        into device `pages` — the single swap-in path shared by
-        preemption restore and host-tier restore. Scatters at the
-        fixed pages_per_seq width (tail -> trash page), mirroring the
-        offload gather: one compile total, not one per page count."""
+        into device `pages` of cache group `gc` (group 0 unless given)
+        — the single swap-in path shared by preemption restore and
+        host-tier restore. Scatters at the fixed pages_per_seq width
+        (tail -> trash page), mirroring the offload gather: one compile
+        total, not one per page count."""
+        gc = gc or self._caches[0]
         n = len(pages)
         ppseq = self.pages_per_seq
-        pg = np.full((ppseq,), self.num_pages - 1, np.int32)
+        pg = np.full((ppseq,), gc.num_pages - 1, np.int32)
         pg[:n] = pages
 
         def pad(a):
+            if a is None:
+                return None
             out = np.zeros(a.shape[:2] + (ppseq,) + a.shape[3:], a.dtype)
             out[:, :, :n] = a
             return out
-        self.k_pool = self.k_pool.at[:, :, pg].set(
-            jnp.asarray(pad(k), self.k_pool.dtype))
-        self.v_pool = self.v_pool.at[:, :, pg].set(
-            jnp.asarray(pad(v), self.v_pool.dtype))
-        if self.cache_quant:
-            self.k_scale = self.k_scale.at[:, :, pg].set(
-                jnp.asarray(pad(ks), jnp.float32))
-            self.v_scale = self.v_scale.at[:, :, pg].set(
-                jnp.asarray(pad(vs), jnp.float32))
+        gc.scatter(pg, pad(k), pad(v), pad(ks), pad(vs))
 
     def _drop_offload(self, req):
         """Forget a waiting request's host-stashed KV (cancel/failure
@@ -2359,8 +2549,9 @@ class ServingEngine:
             need_rows = None if need is None else jnp.asarray(need)
             # page_table goes to the device as a SNAPSHOT (.copy()):
             # see the bucketed `step_launch`
-            staged = (jnp.asarray(self.page_table.copy()),
-                      jnp.asarray(tokens), jnp.asarray(tok_slot),
+            tables = tuple(jnp.asarray(gc.table.copy())
+                           for gc in self._caches)
+            staged = (jnp.asarray(tokens), jnp.asarray(tok_slot),
                       jnp.asarray(tok_pos))
             if self.tok_buf is not None:
                 # device token ring: no carry operands (the ring's
@@ -2381,18 +2572,17 @@ class ServingEngine:
         self._note_launch_gap(1 if carry is not None else 0)
         with record_span("serving.unified_step", part="dispatch",
                          ring=True):
-            out = unified_step(
-                self.params, self.k_pool, self.v_pool, *staged,
-                self.config, self.page_size,
+            caches, logits, rec, tok_buf, aux = self.model.step(
+                self.params, tuple(gc.device() for gc in self._caches),
+                tables, *staged, self.config, self.page_size,
                 use_pallas=self._use_pallas, interpret=self._interpret,
-                k_scale=self.k_scale, v_scale=self.v_scale,
                 sample=sample, need_rows=need_rows,
                 block_q=self._block_q, block_pages=self._block_pages,
                 **carry_kw)
-        (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-         logits, rec) = out[:6]
+        for gc, got in zip(self._caches, caches):
+            gc.take(got)
         if self.tok_buf is not None:
-            self.tok_buf = out[6]
+            self.tok_buf = tok_buf
         seed_rows = None
         if seeds:
             with record_span("serving.seed_gather", part="dispatch"):
@@ -2406,32 +2596,70 @@ class ServingEngine:
         self._t_launch_end = time.perf_counter()
         self.device_steps += 1
         return RaggedTicket(reqs, flat, rec[0], rec[1], rec[2], seeds,
-                            seed_rows, slots)
+                            seed_rows, slots, aux)
 
-    def _ragged_plan(self, carry):
-        """The host half of `_ragged_launch` up to the transfers: page
-        growth, the decode and prefill plans, and the wave's numpy
-        descriptors. Advances the engine's state (lengths, cursors,
-        row counters); returns None when nothing runs this wave."""
-        # decode-boundary page growth, bucketed logic verbatim (mid-
-        # prefill slots grow against their own chunk below)
-        for s in sorted(self._live):
-            if self._prefilling(self._slots[s]):
-                continue
-            cur = int(self.lengths[s])
-            if cur % self.page_size == 0 and cur > 0 and \
-                    len(self._seq_pages[s]) * self.page_size <= cur:
-                while not self.pool.can_alloc(1):
+    def _grow_to(self, s, end, carry, what):
+        """Pages for slot s in every cache group up to ordinal `end`
+        (exclusive), evicting the newest admission where a pool is dry;
+        with a step in flight that is a PipelineStall, the victim's
+        pending token being still on the device."""
+        for gc in self._caches:
+            while gc.end(s) < end:
+                while not gc.pool.can_alloc(1):
                     if carry is not None:
                         raise PipelineStall(
-                            "page growth needs a preemption victim "
+                            f"{what} growth needs a preemption victim "
                             "with a step in flight")
                     if not self._preempt_one(exclude=s):
                         raise RuntimeError(
                             "serving: KV page pool exhausted with a "
                             "single active sequence — num_pages is too "
                             "small for max_seq_len")
-                self._alloc_pages(s, 1)
+                self._alloc_pages(s, 1, gc)
+
+    def _window_release(self):
+        """Give back, for every live slot and windowed group, the pages
+        that lie wholly behind what the slot's next row can see: the row
+        this turn feeds first sits at `lengths[s]` and sees columns from
+        `lengths[s] - (window - 1)` on, and every later row sees less.
+        The ragged kernel's walk starts at that column's block and its
+        page fetch at that column's page, so a page given back here is
+        never read again; the device runs the steps in order, so one
+        re-issued in this same turn is written only after the step in
+        flight (whose rows sat one position lower, and which snapshotted
+        its own page table) has read it."""
+        with record_span("serving.window_release", part="plan"):
+            live = np.fromiter(self._live, np.int64, len(self._live))
+            for gc in self._windowed:
+                # the first page each live slot still needs; most turns
+                # pass no page's edge, so only the slots that did loop
+                first = (self.lengths[live].astype(np.int64)
+                         - (gc.spec.window - 1)) // self.page_size
+                due = first > gc.base[live]
+                for s, f in zip(live[due].tolist(), first[due].tolist()):
+                    n = f - int(gc.base[s])
+                    pages = gc.seq_pages[s]
+                    gc.pool.decref(pages[:n])
+                    del pages[:n]
+                    gc.table[s, gc.base[s]:f] = gc.num_pages - 1
+                    gc.base[s] = f
+                    gc.released += n
+
+    def _ragged_plan(self, carry):
+        """The host half of `_ragged_launch` up to the transfers: page
+        growth, the decode and prefill plans, and the wave's numpy
+        descriptors. Advances the engine's state (lengths, cursors,
+        row counters); returns None when nothing runs this wave."""
+        if self._windowed:
+            self._window_release()
+        # decode-boundary page growth, bucketed logic verbatim (mid-
+        # prefill slots grow against their own chunk below)
+        for s in sorted(self._live):
+            if self._prefilling(self._slots[s]):
+                continue
+            cur = int(self.lengths[s])
+            if cur % self.page_size == 0 and cur > 0:
+                self._grow_to(s, cur // self.page_size + 1, carry, "page")
         if not self._live:
             self._t_launch_end = None
             return None
@@ -2461,19 +2689,9 @@ class ServingEngine:
             n = min(len(req._pf_feed) - req._pf_cursor, room)
             if n <= 0:
                 continue  # buffer full this wave; slot feeds next wave
-            need = -(-(int(self.lengths[s]) + n) // self.page_size)
-            while len(self._seq_pages[s]) < need:
-                while not self.pool.can_alloc(1):
-                    if carry is not None:
-                        raise PipelineStall(
-                            "prefill growth needs a preemption victim "
-                            "with a step in flight")
-                    if not self._preempt_one(exclude=s):
-                        raise RuntimeError(
-                            "serving: KV page pool exhausted with a "
-                            "single active sequence — num_pages is too "
-                            "small for max_seq_len")
-                self._alloc_pages(s, 1)
+            self._grow_to(
+                s, -(-(int(self.lengths[s]) + n) // self.page_size), carry,
+                "prefill")
             prefill_plan.append((s, req, n))
             room -= n
         # a preemption above may have evicted a planned slot
@@ -2550,15 +2768,31 @@ class ServingEngine:
         # the kernel's work from the same descriptors: unused tail rows
         # carry pos=-1 and count for nothing
         live = (tok_pos + 1).astype(np.int64)
-        self.ragged_attn_pairs += int(live.sum())
+        pairs = int(live.sum())
+        self.ragged_attn_pairs += pairs
         kv = np.zeros((B,), np.int64)
         np.maximum.at(kv, tok_slot, live)
-        self.ragged_kv_tokens += int(kv.sum())
+        kv_tokens = int(kv.sum())
+        self.ragged_kv_tokens += kv_tokens
+        on = tok_pos >= 0
+        for gc in self._caches:
+            # by layer type: a windowed row pairs with at most the
+            # window, and a slot's rows read from the first column its
+            # first row sees
+            w, by = gc.spec.window, self.ragged_by_type[gc.spec.name]
+            if w is None:
+                by[0] += kv_tokens
+                by[1] += pairs
+                continue
+            lo = np.full((B,), np.iinfo(np.int64).max)
+            np.minimum.at(lo, tok_slot[on], live[on] - 1)
+            by[0] += int(np.sum(np.where(
+                kv > 0, kv - np.maximum(lo - (w - 1), 0), 0)))
+            by[1] += int(np.minimum(live, w).sum())
         # how the kernel's mechanism engages: its programs a KV head
         # (maximal runs of one slot's consecutive positions) and its
         # loop trips a layer (KV blocks over the kernel's own runs,
         # which also end at a q block's edge)
-        on = tok_pos >= 0
         cont = (on[1:] & on[:-1] & (tok_slot[1:] == tok_slot[:-1])
                 & (tok_pos[1:] == tok_pos[:-1] + 1))
         self.ragged_runs += int(on.sum() - cont.sum())
@@ -2602,10 +2836,16 @@ class ServingEngine:
                          if r is not None] +
                         [str(r.rid) for _, r in ticket.seeds])
         with record_span("serving.fetch", part="fetch"):
-            nxt, done, lp, seed_rows = self._fetch_results(
+            nxt, done, lp, seed_rows, aux = self._fetch_results(
                 (ticket.next_tok, ticket.done, ticket.logprob,
-                 ticket.seed_rows))
+                 ticket.seed_rows, ticket.aux))
         with record_span("serving.consume", part="consume"):
+            if "moe_rows" in aux:
+                # (sparse layers, experts): the rows each expert got
+                rows = aux["moe_rows"]
+                self.moe_assignments += int(rows.sum())
+                self.moe_experts_touched += int((rows > 0).sum())
+                self.moe_rows_max_expert += int(rows.max(axis=1).sum())
             self._ragged_consume(ticket, inflight, nxt, done, lp,
                                  seed_rows)
         return len(ticket.slots)
@@ -2974,14 +3214,17 @@ class ServingEngine:
             # `lengths` — index its full pages so later admissions
             # sharing the prefix skip their prefill
             self._index_slot(slot, req)
-        # decref tail-first: deepest blocks park least-recently-used,
-        # so eviction reclaims children before the prefixes they need
-        self.pool.decref(reversed(self._seq_pages[slot]))
-        self._seq_pages[slot] = []
+        for gc in self._caches:
+            # decref tail-first: deepest blocks park least-recently-
+            # used, so eviction reclaims children before the prefixes
+            # they need
+            gc.pool.decref(reversed(gc.seq_pages[slot]))
+            gc.seq_pages[slot] = []
+            gc.base[slot] = 0
+            # re-point the freed row at the trash page: stale entries
+            # keep aliasing pages the pool may re-hand to other slots
+            gc.table[slot, :] = gc.num_pages - 1
         self.lengths[slot] = 0
-        # re-point the freed row at the trash page: stale entries keep
-        # aliasing pages the pool may re-hand to other slots
-        self.page_table[slot, :] = self.num_pages - 1
         self._slots[slot] = None
         self._live.discard(slot)
 

@@ -166,3 +166,50 @@ class MoELayer(Layer):
         out, aux = apply(fn, *args, name="moe", multi=True)
         self.aux_loss = aux
         return out
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer over flat rows (the serving step's; the
+# capacity-bounded dispatch above stays for the eager models)
+# ---------------------------------------------------------------------------
+def grouped_product(lhs, rhs, group_sizes):
+    """Rows of `lhs` (M, K), sorted by group, times their group's matrix
+    of `rhs` (G, K, N) -> (M, N) float32. `group_sizes` (G,) i32 says how
+    many consecutive rows each group owns; rows past their sum belong to
+    nobody (callers mask them). `jax.lax.ragged_dot`: the TPU compiler
+    lowers it to a grouped matmul (device operations `ragged-dot-*`)
+    that reads only the matrices of groups that own a row."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def dropless_experts(x, expert, weight, w_gate, w_up, w_down):
+    """Every assignment computed, none dropped: SwiGLU experts over the
+    step's flat rows.
+
+    x (T, H); expert (T, k) i32, the expert each of a row's k assignments
+    goes to (negative: routes nowhere, a slack row); weight (T, k) f32,
+    what its result is weighted by. w_gate / w_up (E, H, F) and w_down
+    (E, F, H): all the layer's experts.
+
+    Rows are sorted by expert, three grouped products run over the
+    experts, and each row gets the weighted sum of its assignments
+    back. -> (out (T, H) f32, rows (E,) i32: the rows each expert got).
+    """
+    T, k = expert.shape
+    E = w_gate.shape[0]
+    routed = expert >= 0
+    key = jnp.where(routed, expert, E).reshape(-1)    # E sorts last
+    order = jnp.argsort(key, stable=True)           # sorted -> assignment
+    rows = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+    xs = x[order // k]                              # (T*k, H)
+    h = jax.nn.silu(grouped_product(xs, w_gate, rows)) \
+        * grouped_product(xs, w_up, rows)
+    y = grouped_product(h.astype(x.dtype), w_down, rows)   # (T*k, H) f32
+    # rows past the experts' are nobody's: whatever is there, drop it
+    y = jnp.where((jnp.arange(T * k) < jnp.sum(rows))[:, None], y, 0.0)
+    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))         # assignment -> sorted
+    w = jnp.where(routed, weight, 0.0).astype(jnp.float32)
+    out = jnp.einsum("tkh,tk->th", y[back].reshape(T, k, -1), w)
+    return out, rows

@@ -416,10 +416,28 @@ class EngineMetrics:
             "KV blocks the ragged kernel walks in one layer: the sum "
             "over its runs (which also end at a q block's edge) of "
             "ceil(KV length / tokens a KV block).")
+        # what a step's record says of its expert layers (ISSUE 30),
+        # each summed over sparse layers and steps
+        self.moe_assignments = r.counter(
+            "pt_moe_assignments",
+            "Row-to-expert assignments the dropless expert layers "
+            "computed.")
+        self.moe_experts_touched = r.counter(
+            "pt_moe_experts_touched",
+            "Experts that got at least one row, a sparse layer and "
+            "step: whose weights the step had to read.")
+        self.moe_rows_max_expert = r.counter(
+            "pt_moe_rows_max_expert",
+            "Rows of the fullest expert, a sparse layer and step.")
         self._tok_seen = {"pad_tokens": 0, "ragged_tokens": 0,
                           "logit_rows": 0, "logit_rows_skipped": 0,
                           "ragged_attn_pairs": 0, "ragged_kv_tokens": 0,
-                          "ragged_runs": 0, "ragged_kv_blocks": 0}
+                          "ragged_runs": 0, "ragged_kv_blocks": 0,
+                          "moe_assignments": 0, "moe_experts_touched": 0,
+                          "moe_rows_max_expert": 0}
+        # by cache group, made when a group first reports (on_step):
+        # pages held and given back, the kernel's work by layer type
+        self._by_group = {}
         self.turn_seconds = {
             part: r.counter(
                 "pt_serving_turn_seconds",
@@ -642,12 +660,19 @@ class EngineMetrics:
                               ("ragged_attn_pairs", self.ragged_attn_pairs),
                               ("ragged_kv_tokens", self.ragged_kv_tokens),
                               ("ragged_runs", self.ragged_runs),
-                              ("ragged_kv_blocks", self.ragged_kv_blocks)):
+                              ("ragged_kv_blocks", self.ragged_kv_blocks),
+                              ("moe_assignments", self.moe_assignments),
+                              ("moe_experts_touched",
+                               self.moe_experts_touched),
+                              ("moe_rows_max_expert",
+                               self.moe_rows_max_expert)):
             cur = getattr(engine, attr, 0)
             delta = cur - seen[attr]
             if delta > 0:
                 counter.inc(delta)
                 seen[attr] = cur
+        for gc in getattr(engine, "_caches", ()):
+            self._on_group(gc, engine.ragged_by_type[gc.spec.name])
         self.on_handoff(engine)
         pc = getattr(engine, "prefix_cache", None)
         if pc is not None:
@@ -671,6 +696,40 @@ class EngineMetrics:
             depth = len(engine._waiting)
             self.queue_depth.set(depth)
             self.queue_depth_peak.set_to_max(depth)
+
+    def _on_group(self, gc, by_type):
+        """One cache group's gauges and counters (`pool=` /
+        `layer_type=` its name), mirrored from the engine's ints."""
+        name, r = gc.spec.name, self.registry
+        g = self._by_group.get(name)
+        if g is None:
+            g = self._by_group[name] = {
+                "in_use": r.gauge(
+                    "pt_kv_pages_in_use",
+                    "KV pages held by live slots, by cache group.",
+                    labels={"pool": name}),
+                "released": r.counter(
+                    "pt_kv_pages_released",
+                    "KV pages a sliding window gave back to the pool "
+                    "as its slot advanced.", labels={"pool": name}),
+                "kv": r.counter(
+                    "pt_ragged_kv_tokens",
+                    "pt_ragged_kv_tokens by layer type: under a window "
+                    "only the tokens a slot's rows can still see.",
+                    labels={"layer_type": name}),
+                "pairs": r.counter(
+                    "pt_ragged_attn_pairs",
+                    "pt_ragged_attn_pairs by layer type: under a "
+                    "window at most the window a row.",
+                    labels={"layer_type": name}),
+                "seen": [0, 0, 0]}
+        g["in_use"].set(gc.pool.num_pages - gc.pool.available())
+        for i, (key, cur) in enumerate((("released", gc.released),
+                                        ("kv", by_type[0]),
+                                        ("pairs", by_type[1]))):
+            if cur > g["seen"][i]:
+                g[key].inc(cur - g["seen"][i])
+                g["seen"][i] = cur
 
     def observe_ttft(self, dt):
         self.ttft.observe(dt)

@@ -193,13 +193,24 @@ class RequestScheduler:
         # § Pipelined step loop) — launch device step N+1 before
         # consuming step N's result record, so host bookkeeping and
         # next-wave admission overlap the in-flight device program.
-        # Default comes from PT_SERVE_PIPELINE. Spec-decode engines
+        # Unasked (pipeline=None, PT_SERVE_PIPELINE unset) the pump is
+        # one step deep exactly where a second step in flight costs no
+        # memory: the model's step writes its page pools in place
+        # (`ServingModel.in_place`). Llama's `unified_step` returns new
+        # pools, so a launch beside a running step waits for a third
+        # copy of them (v5e, Mistral-7B x 16 layers: peak 13.97 ->
+        # 15.59 GB, the launch blocked in the allocator, itl p99 +15%;
+        # PERF.md Findings, PR 30) and it keeps the synchronous pump
+        # until its pools are donated. Spec-decode engines
         # stay synchronous (drafting needs host-current context);
         # slow-path events (cancel/TTL/preempt/failure/shutdown) drain
         # the one-step-deep pipeline before acting, so every mode is
         # token-identical to the synchronous pump.
         if pipeline is None:
-            pipeline = env_bool("PT_SERVE_PIPELINE")
+            model = getattr(engine, "model", None)
+            pipeline = env_bool(
+                "PT_SERVE_PIPELINE",
+                default=bool(getattr(model, "in_place", False)))
         self._pipeline = bool(pipeline) and \
             getattr(engine, "spec_decode", 0) <= 1
         # the launched-but-unconsumed StepTicket; pump-thread only

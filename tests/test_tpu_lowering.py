@@ -207,3 +207,56 @@ def test_ragged_compiles_for_v5e_at_the_serving_shape(one_chip, cache):
                 arg((t,), jnp.int32)] + ([sc, sc] if sc is not None else [])
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text or "custom-call" in text
+
+
+@pytest.mark.parametrize("heads, window", [(48, None), (64, 512)],
+                         ids=["full_group6", "window_group8"])
+@pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
+def test_ragged_compiles_for_v5e_at_lagunas_shapes(one_chip, cache, heads,
+                                                   window):
+    """`reason_saturated`'s two attention shapes (128 rows over 8 KV heads
+    of 128, pages of 16, 512 pages a sequence): a query group of 6 over
+    the full context in a pool of 18,432 pages, and a group of 8 under
+    the static window of 512 in a pool of 4,096, at the tile
+    TUNED.kernels.json holds."""
+    from paddle_tpu.kernels import ragged_paged_attention
+    t, kvh, n_pages, slots = 128, 8, 512, 96
+    pages = 4096 if window else 18432
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((kvh, pages, PAGE, D), cache)
+    sc = arg((kvh, pages, PAGE, 1), jnp.float32) if cache == jnp.int8 \
+        else None
+
+    def fn(q, kp, vp, table, slot, pos, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        return ragged_paged_attention(
+            q, kp, vp, table, slot, pos, use_pallas=True, interpret=False,
+            block_pages=16, window=window, **kw)
+    args = [arg((t, heads, D), jnp.bfloat16), pool, pool,
+            arg((slots, n_pages), jnp.int32), arg((t,), jnp.int32),
+            arg((t,), jnp.int32)] + ([sc, sc] if sc is not None else [])
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text or "custom-call" in text
+
+
+def test_window_none_traces_the_program_it_always_did():
+    """`window=None` is no argument at all: the kernel's jaxpr, equation
+    for equation, is what it is without the argument."""
+    from paddle_tpu.kernels.ragged_paged_attention import (
+        ragged_paged_attention)
+    kvh, t = 2, 16
+    kp, _ = _pool(jnp.bfloat16, kvh)
+    table = jnp.zeros((2, 4), jnp.int32)
+    slot = jnp.zeros((t,), jnp.int32)
+    pos = jnp.arange(t, dtype=jnp.int32) - 2
+    q = jnp.ones((t, kvh * GROUP, D), jnp.bfloat16)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda q, kp: ragged_paged_attention(
+            q, kp, kp, table, slot, pos, use_pallas=True, interpret=False,
+            block_pages=2, **kw))(q, kp))
+    assert text() == text(window=None)
+    assert text(window=8) != text()
