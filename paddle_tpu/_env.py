@@ -165,8 +165,9 @@ def env_bool(name, default=_UNSET, env=None):
 # -- serving -----------------------------------------------------------
 declare("PT_SERVE_PIPELINE", False,
         "Run the scheduler pump one step deep (launch step N+1 before "
-        "reading step N's results). Unset: on where the model's step "
-        "writes its page pools in place, off otherwise.",
+        "reading step N's results). Unset: on for a ragged engine, "
+        "whose step writes its page pools in place; off for the bucketed "
+        "entry points.",
         kind="bool", section="serving")
 declare("PT_SERVE_TIMELINE", True,
         "Per-request timeline + SLO accounting plane (0 disables; "

@@ -26,7 +26,10 @@ descriptors it already has). What bounds its iteration space:
     heads by one strided DMA through the page table, double-buffered,
     the prefetch crossing from a run's last block to the next run's
     first. No grid step, loop trip or DMA exists for a page a run does
-    not own;
+    not own. Given `layer=`, the pool is a whole stack `(layers, KVH, P,
+    page, D)` and the layer one more prefetched scalar in the DMA's
+    source, so a scan over layers hands the kernel its carry as it lies
+    and slices nothing out of it (`unified_step`, ROADMAP [donate-pools]);
   * QK^T on the stored operands (bf16 products are exact in the f32
     accumulator), running max, sum and accumulator in f32, P in f32
     into the PV product; int8 pages are widened and scaled in VMEM, a
@@ -114,18 +117,24 @@ def _page_update(q, k, v, acc, m_prev, l_prev, limit, pi, scale,
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      tok_slot, tok_pos, sm_scale=None,
                                      k_scale=None, v_scale=None,
-                                     block_q=None, window=None):
+                                     block_q=None, window=None, layer=None):
     """q: (T, QH, D); pages: (KVH, P, page, D); page_table:
     (S, pages_per_seq); tok_slot/tok_pos: (T,) i32 (pos -1 = inactive
     row → zeros out). `window` (static): a row at position p sees
     columns j with 0 <= p - j < window, and no page wholly behind them
-    is read. Returns (T, QH, D).
+    is read. `layer` (i32 scalar): pages and scales are stacks with a
+    leading layer dimension, and this layer's are read. Returns
+    (T, QH, D).
 
     This is NOT a dense-softmax shortcut: it replays `_page_update`
     over page ordinals (group padded, lane-replicated stats), skipped
     pages carrying the previous stats through unchanged. `block_q` here
     is the q group's sublane padding, the reference's own; the pallas
     kernel's tile has no twin in it."""
+    if layer is not None:
+        k_pages, v_pages, k_scale, v_scale = (
+            None if a is None else a[layer]
+            for a in (k_pages, v_pages, k_scale, v_scale))
     t, qh, d = q.shape
     kvh, _, page_size, _ = k_pages.shape
     group = qh // kvh
@@ -284,8 +293,8 @@ def ragged_runs(tok_slot, tok_pos, group, block_q=None):
     return runs, qb_first
 
 
-def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
-                   page_size, block_pages, group, quant, window=None):
+def _ragged_kernel(runs_ref, qb_ref, ptab_ref, *refs, scale, page_size,
+                   block_pages, group, quant, window=None, stacked=False):
     """Grid (q blocks,). Program j holds q block j (all KV heads, its
     rows x the GQA group) in VMEM and walks the runs that lie in it;
     per run, a loop over KV blocks of `block_pages` pages whose trip
@@ -296,7 +305,14 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
     A page the run does not own is neither fetched nor waited for.
     With a `window` a run's walk begins at the KV block that holds the
     first column its first row sees, pages wholly behind that column
-    are not fetched either, and the mask cuts inside the block."""
+    are not fetched either, and the mask cuts inside the block.
+    `stacked`: one more prefetched scalar, the layer, comes before the
+    q block, and the K and V pools are whole stacks `(layers, KVH, P,
+    page, D)` of which a page's DMA reads that layer."""
+    layer_ref = None
+    if stacked:
+        layer_ref, *refs = refs
+    q_ref, *refs = refs
     n_pool = 3 if quant else 2
     pools, o_ref = refs[:n_pool], refs[n_pool]
     bufs = refs[n_pool + 1:2 * n_pool + 1]
@@ -341,9 +357,12 @@ def _ragged_kernel(runs_ref, qb_ref, ptab_ref, q_ref, *refs, scale,
             def _(p=p, ordinal=ordinal):
                 page = ptab_ref[seq, ordinal]
                 for n, (pool, buf) in enumerate(zip(pools, bufs)):
-                    src, dst = (pool.at[:, page],
-                                buf.at[slot_, :, np.int32(p)]) if n < 2 \
-                        else (pool.at[page], buf.at[slot_, np.int32(p)])
+                    if n == 2:      # the scales: a page of one layer
+                        src, dst = pool.at[page], buf.at[slot_, np.int32(p)]
+                    else:
+                        src = pool.at[layer_ref[0], :, page] if stacked \
+                            else pool.at[:, page]
+                        dst = buf.at[slot_, :, np.int32(p)]
                     getattr(pltpu.make_async_copy(
                         src, dst, sem.at[np.int32(n), slot_]), op)()
 
@@ -435,22 +454,26 @@ def _scale_pages(k_scale, v_scale):
 
 
 def _ragged_pallas(qg, pools, page_table, runs, qb_first, scale, interpret,
-                   block_pages, group, window=None):
+                   block_pages, group, window=None, layer=None):
     kvh, rows_all, d = qg.shape
     n_qb = qb_first.shape[0] - 1
     rows = rows_all // n_qb
-    page_size = pools[0].shape[2]
+    page_size = pools[0].shape[-2]
     quant = len(pools) == 3
-    q_spec = pl.BlockSpec((kvh, rows, d),
-                          lambda j, runs, qb, ptab: (Z, j, Z))
+    # the scalars every index map is handed after the grid index: the
+    # runs, the q blocks' first runs, the page table and, for stacked
+    # pools, the layer
+    scalars = (runs, qb_first, page_table) + (
+        () if layer is None else (jnp.reshape(layer, (1,)).astype(jnp.int32),))
+    q_spec = pl.BlockSpec((kvh, rows, d), lambda j, *_: (Z, j, Z))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(n_qb,),
         in_specs=[q_spec] + [hbm] * len(pools),
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, kvh, block_pages) + pool.shape[2:], pool.dtype)
+            pltpu.VMEM((2, kvh, block_pages) + pool.shape[-2:], pool.dtype)
             for pool in pools[:2]
         ] + [
             pltpu.VMEM((2, block_pages) + pool.shape[1:], pool.dtype)
@@ -465,21 +488,22 @@ def _ragged_pallas(qg, pools, page_table, runs, qb_first, scale, interpret,
     kernel = functools.partial(
         _ragged_kernel, scale=np.float32(scale), page_size=page_size,
         block_pages=block_pages, group=group, quant=quant,
-        **({} if window is None else {"window": int(window)}))
+        **({} if window is None else {"window": int(window)}),
+        **({} if layer is None else {"stacked": True}))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="ragged_paged_attention",
-    )(runs, qb_first, page_table, qg, *pools)
+    )(*scalars, qg, *pools)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
                            tok_pos, sm_scale=None, use_pallas=None,
                            interpret=None, k_scale=None, v_scale=None,
                            block_q=None, block_pages=None, runs=None,
-                           window=None):
+                           window=None, layer=None):
     """Ragged mixed prefill/decode attention over a paged KV cache.
 
     q: (T, QH, D) — T flat token rows; k_pages/v_pages:
@@ -506,9 +530,16 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
     walk then starts at the KV block holding the first column its
     first row sees, so page-table entries wholly behind the window
     (the engine has released those pages) are never read.
+
+    `layer` (traced i32 scalar; None = the pools are one layer's): the
+    pools, and the scales, are whole stacks `(layers, KVH, num_pages,
+    page_size, D)` and this layer's pages are read. The kernel takes
+    the stack as it lies in HBM and the layer as one more prefetched
+    scalar, so a caller that scans over layers slices nothing out of
+    its carry (`unified_step`); the reference indexes the layer.
     """
     t, qh, d = q.shape
-    kvh, _, page_size, _ = k_pages.shape
+    kvh, _, page_size, _ = k_pages.shape[-4:]
     group = qh // kvh
     scale = sm_scale if sm_scale is not None else d ** -0.5
     if (k_scale is None) != (v_scale is None):
@@ -520,7 +551,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
     if not use_pallas and not interpret:
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, tok_slot, tok_pos, scale,
-            k_scale, v_scale, window=window)
+            k_scale, v_scale, window=window, layer=layer)
     if runs is None:
         runs = ragged_runs(tok_slot, tok_pos, group, bq)
     runs, qb_first = runs
@@ -531,8 +562,11 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, tok_slot,
         t_pad, kvh, group, d).swapaxes(0, 1).reshape(kvh, t_pad * group, d)
     pools = (k_pages, v_pages)
     if k_scale is not None:
+        if layer is not None:   # the scales are re-laid a layer anyway
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
         pools += (_scale_pages(k_scale, v_scale),)
     o = _ragged_pallas(qg, pools, page_table.astype(jnp.int32), runs,
-                       qb_first, scale, bool(interpret), bp, group, window)
+                       qb_first, scale, bool(interpret), bp, group, window,
+                       layer)
     return o.reshape(kvh, t_pad, group, d).swapaxes(0, 1).reshape(
         t_pad, qh, d)[:t]

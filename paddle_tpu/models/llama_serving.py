@@ -60,6 +60,23 @@ def _rms(x, w, eps):
             * w.astype(jnp.float32)).astype(x.dtype)
 
 
+def _flat_rows(heads, n_pages, page, page_ids, off):
+    """Where (head, page_ids[i], off[i]) lies in a pool seen as rows
+    (heads x pages x page, D): (len(heads), len(page_ids)) i32. `heads`
+    are the head ordinals, a stack's layer folded in
+    (`layer * KVH + head`)."""
+    return ((heads * n_pages)[:, None] + page_ids[None, :]) * page \
+        + off[None, :]
+
+
+def _put_rows(pool, rows, new):
+    """`new` (*rows.shape, D) into `rows` of `pool` seen as (rows, D): a
+    scatter along the major dimension, which the TPU compiler performs
+    where a donated or carried pool lies."""
+    return pool.reshape(-1, pool.shape[-1]).at[rows.reshape(-1)].set(
+        new.reshape(-1, new.shape[-1])).reshape(pool.shape)
+
+
 def _scatter_kv(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant,
                 flat=False):
     """Write kt/vt (KVH, *idx, D) into layer li of the K/V pools at
@@ -70,6 +87,12 @@ def _scatter_kv(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant,
     (kp, vp, ksp, vsp, kl, vl, ksl, vsl): the updated stacks plus this
     layer's views for the attention read.
 
+    The layer is taken out of the stack, written and put back: in a
+    scan over a stack of layers that is two copies of a layer's pool a
+    layer, which the bucketed entry points pay and `unified_step` does
+    not (`_scatter_kv_stacked`). A pool with one layer (`laguna_step`'s,
+    li = 0) loses nothing by it.
+
     `flat` writes the same values as rows of the layer seen as
     (KVH x pages x page, D), a scatter along the major dimension: the
     TPU compiler then updates a donated pool where it lies, where the
@@ -79,12 +102,11 @@ def _scatter_kv(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant,
     vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
     if flat:
         kvh, n_pages, page = kl.shape[:3]
-        rows = ((jnp.arange(kvh, dtype=jnp.int32) * n_pages)[:, None]
-                + page_ids[None, :]) * page + off[None, :]
+        rows = _flat_rows(jnp.arange(kvh, dtype=jnp.int32), n_pages, page,
+                          page_ids, off)
 
         def put(pool, new):
-            return pool.reshape(-1, pool.shape[-1]).at[rows.reshape(-1)].set(
-                new.reshape(-1, new.shape[-1])).reshape(pool.shape)
+            return _put_rows(pool, rows, new)
     else:
         def put(pool, new):
             return pool.at[:, page_ids, off].set(new)
@@ -103,6 +125,28 @@ def _scatter_kv(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant,
     kp = jax.lax.dynamic_update_index_in_dim(kp, kl, li, 0)
     vp = jax.lax.dynamic_update_index_in_dim(vp, vl, li, 0)
     return kp, vp, ksp, vsp, kl, vl, ksl, vsl
+
+
+def _scatter_kv_stacked(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant):
+    """`_scatter_kv(flat=True)` for a stack of layers written where it
+    lies: kt/vt (KVH, T, D) go into layer li (a traced i32) of the 5-D
+    pools `(layers, KVH, pages, page, D)` as rows of the WHOLE stack
+    seen as (layers x KVH x pages x page, D), the layer folded into the
+    row. No layer is taken out of the stack or put back, so a scan
+    that carries the pools copies none of them; the attention then
+    reads the stack through the same layer index
+    (`ragged_paged_attention(layer=)`). int8 pools quantize on write
+    and their scales take the same path. Returns (kp, vp, ksp, vsp)."""
+    kvh, n_pages, page = kp.shape[1:4]
+    rows = _flat_rows(li * kvh + jnp.arange(kvh, dtype=jnp.int32), n_pages,
+                      page, page_ids, off)
+    if quant:
+        kt, kts = quantize_kv(kt)
+        vt, vts = quantize_kv(vt)
+        ksp = _put_rows(ksp, rows, kts)
+        vsp = _put_rows(vsp, rows, vts)
+    return (_put_rows(kp, rows, kt.astype(kp.dtype)),
+            _put_rows(vp, rows, vt.astype(vp.dtype)), ksp, vsp)
 
 
 def _sample_record(logits, lengths, active, sample):
@@ -574,7 +618,9 @@ def verify_step(params, k_pool, v_pool, page_table, lengths, tokens,
 @functools.partial(jax.jit,
                    static_argnames=("config", "page_size", "use_pallas",
                                     "interpret", "block_q",
-                                    "block_pages"))
+                                    "block_pages"),
+                   donate_argnames=("k_pool", "v_pool", "k_scale",
+                                    "v_scale", "tok_buf"))
 def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
                  tok_pos, config: LlamaConfig, page_size,
                  use_pallas=False, interpret=False, k_scale=None,
@@ -633,6 +679,15 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
     token values device-resident: rows gather their embedding input
     from it and decode rows (`buf_write`) scatter their sampled token
     back — the in-jit twin of the carry operands, which it replaces.
+
+    The pools are never copied (ROADMAP [donate-pools]): `k_pool`,
+    `v_pool`, `k_scale`, `v_scale` and `tok_buf` are DONATED and come
+    back where they lay; the layer scan carries the stacks, writes a
+    layer's rows flat into them (`_scatter_kv_stacked`) and the kernel
+    reads them through the layer index. So the arrays a caller passed
+    are gone when the call returns: rebind from the result (the engine
+    does), and copy first what must outlive the call (`jnp.copy(pool)`).
+    `tok_buf` comes back only with `sample`; give none without.
     """
     c = config
     nh, nkv = c.num_attention_heads, c.num_key_value_heads
@@ -677,15 +732,16 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
         q, k = apply_rotary_emb(q, k, cos[:, None], sin[:, None])
         kt = k.swapaxes(0, 1)                            # (KVH, T, D)
         vt = v.swapaxes(0, 1)
-        kp, vp, ksp, vsp, kl, vl, ksl, vsl = _scatter_kv(
+        # the carried stacks are written, and then read, where they lie
+        kp, vp, ksp, vsp = _scatter_kv_stacked(
             kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant)
-        o = ragged_paged_attention(q, kl, vl, page_table, tok_slot,
+        o = ragged_paged_attention(q, kp, vp, page_table, tok_slot,
                                    tok_pos, use_pallas=use_pallas,
                                    interpret=interpret,
-                                   k_scale=ksl, v_scale=vsl,
+                                   k_scale=ksp, v_scale=vsp,
                                    block_q=block_q,
                                    block_pages=block_pages,
-                                   runs=runs)            # (T, QH, D)
+                                   runs=runs, layer=li)  # (T, QH, D)
         h = h + o.reshape(t, -1).astype(h.dtype) @ lp["wo"]
         x = _rms(h, lp["ln2"], c.rms_norm_eps)
         mlp = (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
@@ -694,7 +750,7 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
     L = k_pool.shape[0]
     (h, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
         layer, (h, k_pool, v_pool, k_scale, v_scale),
-        (params["layers"], jnp.arange(L)))
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
     h = _rms(h, params["final_norm"], c.rms_norm_eps)
     if need_rows is not None:
         # lean epilogue: gather the needed rows FIRST — the unembed
@@ -999,7 +1055,8 @@ def llama_serving_model(config: LlamaConfig):
                            c.num_key_value_heads,
                            c.hidden_size // c.num_attention_heads),),
         q_group=c.num_attention_heads // c.num_key_value_heads,
-        step=_llama_step)
+        step=_llama_step,
+        in_place=True)        # `unified_step` donates its pools
 
 
 class _GroupCache:

@@ -22,8 +22,9 @@ class CacheGroup:
 
     `stacks`: how the group's layers are laid out in pool arrays, each
     `(layers, kv_heads, pages, page, head_dim)`: `(L,)` is one array
-    the model scans over, `(1,) * L` one array a layer (an unrolled
-    model, whose donated pools are then updated in place).
+    the model scans over (`unified_step`, which writes and reads the
+    carried stack through a layer index), `(1,) * L` one array a layer
+    (an unrolled model); donated, either is updated in place.
     `window`: None, a slot holds every page of its context; W, a row at
     position p sees columns j with 0 <= p - j < W, and the engine gives
     a page back in the turn its last column falls behind every row the
@@ -57,13 +58,14 @@ class ServingModel:
     `unsupported`: engine feature -> why this model cannot run under it;
     the engine refuses at construction with that reason.
     `in_place`: a fact about `step`, not a wish: it donates `caches` and
-    the pools come back where they lay. A second step in flight then
-    needs no further copy of them, so the scheduler's pump is one step
-    deep for such a model unless told otherwise (`RequestScheduler`).
-    A step that returns its pools as new buffers (`unified_step` until
-    ROADMAP Queue 1 item 3) would hold a third copy of them while two
-    steps are in flight, and the runtime makes the second launch wait
-    for that memory."""
+    the pools come back where they lay (`laguna_step`; `unified_step`
+    since ROADMAP [donate-pools]). A second step in flight then needs
+    no further copy of them, so the scheduler's pump is one step deep
+    for an engine that runs such a step unless told otherwise
+    (`RequestScheduler`). A step that returns its pools as new buffers
+    (the default here, for a model yet to come) would hold a third copy
+    of them while two steps are in flight, and the runtime makes the
+    second launch wait for that memory."""
     groups: Tuple[CacheGroup, ...]
     q_group: int
     step: Callable

@@ -195,22 +195,27 @@ class RequestScheduler:
         # next-wave admission overlap the in-flight device program.
         # Unasked (pipeline=None, PT_SERVE_PIPELINE unset) the pump is
         # one step deep exactly where a second step in flight costs no
-        # memory: the model's step writes its page pools in place
-        # (`ServingModel.in_place`). Llama's `unified_step` returns new
-        # pools, so a launch beside a running step waits for a third
-        # copy of them (v5e, Mistral-7B x 16 layers: peak 13.97 ->
-        # 15.59 GB, the launch blocked in the allocator, itl p99 +15%;
-        # PERF.md Findings, PR 30) and it keeps the synchronous pump
-        # until its pools are donated. Spec-decode engines
-        # stay synchronous (drafting needs host-current context);
-        # slow-path events (cancel/TTL/preempt/failure/shutdown) drain
-        # the one-step-deep pipeline before acting, so every mode is
-        # token-identical to the synchronous pump.
+        # memory: the engine runs the model's step (`engine.ragged`) and
+        # that step writes its page pools in place
+        # (`ServingModel.in_place`: `laguna_step`, and `unified_step`
+        # since ROADMAP [donate-pools]). A step that returns new pools
+        # makes a launch beside a running step wait for a third copy of
+        # them (v5e, Mistral-7B x 16 layers before the donation: peak
+        # 13.97 -> 15.59 GB, the launch blocked in the allocator, itl
+        # p99 +15%; PERF.md Findings, PR 30): the bucketed entry points
+        # (`decode_step`, `verify_step`: ragged=False, tensor-parallel
+        # engines) donate nothing and keep the synchronous pump.
+        # Spec-decode engines stay synchronous (drafting needs
+        # host-current context); slow-path events (cancel/TTL/preempt/
+        # failure/shutdown) drain the one-step-deep pipeline before
+        # acting, so every mode is token-identical to the synchronous
+        # pump.
         if pipeline is None:
             model = getattr(engine, "model", None)
             pipeline = env_bool(
                 "PT_SERVE_PIPELINE",
-                default=bool(getattr(model, "in_place", False)))
+                default=bool(getattr(model, "in_place", False)
+                             and getattr(engine, "ragged", False)))
         self._pipeline = bool(pipeline) and \
             getattr(engine, "spec_decode", 0) <= 1
         # the launched-but-unconsumed StepTicket; pump-thread only

@@ -219,6 +219,89 @@ def test_every_wave_is_dispatched_with_what_both_page_tables_hold(
     assert [r.output for r in reqs] == [r.output for r in served[1]]
 
 
+def _parents_scatter_kv(kp, vp, ksp, vsp, li, page_ids, off, kt, vt, quant,
+                        flat=False):
+    """`llama_serving._scatter_kv` as PR 30 left it (commit e1dc66b), the
+    flat form `laguna_step` calls, line for line."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.paged_attention import quantize_kv
+    assert flat
+    kl = jax.lax.dynamic_index_in_dim(kp, li, 0, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
+    kvh, n_pages, page = kl.shape[:3]
+    rows = ((jnp.arange(kvh, dtype=jnp.int32) * n_pages)[:, None]
+            + page_ids[None, :]) * page + off[None, :]
+
+    def put(pool, new):
+        return pool.reshape(-1, pool.shape[-1]).at[rows.reshape(-1)].set(
+            new.reshape(-1, new.shape[-1])).reshape(pool.shape)
+    ksl = vsl = None
+    if quant:
+        kt, kts = quantize_kv(kt)
+        vt, vts = quantize_kv(vt)
+        ksl = jax.lax.dynamic_index_in_dim(ksp, li, 0, keepdims=False)
+        vsl = jax.lax.dynamic_index_in_dim(vsp, li, 0, keepdims=False)
+        ksl = put(ksl, kts)
+        vsl = put(vsl, vts)
+        ksp = jax.lax.dynamic_update_index_in_dim(ksp, ksl, li, 0)
+        vsp = jax.lax.dynamic_update_index_in_dim(vsp, vsl, li, 0)
+    kl = put(kl, kt.astype(kl.dtype))
+    vl = put(vl, vt.astype(vl.dtype))
+    kp = jax.lax.dynamic_update_index_in_dim(kp, kl, li, 0)
+    vp = jax.lax.dynamic_update_index_in_dim(vp, vl, li, 0)
+    return kp, vp, ksp, vsp, kl, vl, ksl, vsl
+
+
+@pytest.mark.parametrize("cache", [None, "int8"])
+def test_laguna_step_traces_the_program_it_did_before_the_stacked_path(
+        model, monkeypatch, cache):
+    """`unified_step` now writes its carried stack flat and reads it
+    through a layer index ([donate-pools]); `laguna_step` shares the
+    scatter's helpers and the kernel and must not have moved. The engine's
+    own first step, traced with the Pallas kernel in it: its jaxpr,
+    kernel body included, is character for character what it is over
+    PR 30's `_scatter_kv`, and what the kernel is handed in HBM is still a
+    layer's pool (four dimensions; an int8 pool's scales three), never a
+    stack."""
+    import re
+    import jax
+    from paddle_tpu.models import laguna
+    m, params = model
+    eng = engine(m, params, cache_dtype=cache)
+    real, seen = eng.model.step, []
+
+    def step(*a, **kw):
+        seen.append((a, kw))
+        return real(*a, **kw)
+    object.__setattr__(eng.model, "step", step)
+    for r in requests([(9, 3)]):
+        eng.submit(r)
+    eng.step()
+    # shapes in the arrays' place: the pools themselves are gone
+    a, kw = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+        if hasattr(x, "shape") else x, seen[0])
+    config, page_size = a[6:8]
+    static = dict(kw, use_pallas=True, interpret=False)   # the kernel in
+    static = {k: static[k] for k in ("block_q", "block_pages", "use_pallas",
+                                     "interpret")}
+    kw = {k: v for k, v in kw.items() if k not in static}
+    step_fn = laguna.laguna_step.__wrapped__.__wrapped__
+
+    def text():
+        jaxpr = jax.make_jaxpr(
+            lambda *arrays, **kws: step_fn(*arrays, config, page_size,
+                                           **static, **kws))(*a[:6], **kw)
+        return re.sub(r" at /\S+?:\d+", " at FILE", str(jaxpr))
+    now = text()
+    monkeypatch.setattr(laguna, "_scatter_kv", _parents_scatter_kv)
+    assert now == text()
+    in_hbm = re.findall(r"Ref<any>\{\w+\[([\d,]+)\]\}", now)
+    assert "pallas_call" in now and in_hbm
+    assert {len(dims.split(",")) for dims in in_hbm} <= {3, 4}
+
+
 def _first_pool_after_a_step(eng, submit):
     """-> the array that held the first group's first K pool BEFORE one
     step: deleted by now iff the step donated it."""
@@ -232,11 +315,12 @@ def _first_pool_after_a_step(eng, submit):
 def test_in_place_is_a_fact_of_the_step_and_decides_the_unasked_pump(
         model, monkeypatch, family):
     """`ServingModel.in_place` says what the program does: Laguna's step
-    donates its pools (the array that held one is gone after a step),
-    Llama's `unified_step` returns new ones. Unasked, the scheduler runs
-    one step deep for the first and synchronously for the second, where a
-    second step in flight would hold a third copy of the pools; the
-    environment and the argument overrule both ways."""
+    and, since [donate-pools], Llama's `unified_step` donate their pools
+    (the array that held one is gone after a step). Unasked, the
+    scheduler then runs one step deep, a second step in flight holding no
+    further copy of the pools; the environment and the argument overrule
+    both ways."""
+    import dataclasses
     import jax.numpy as jnp
     from paddle_tpu.models import llama_spmd
     from paddle_tpu.models.llama import LlamaConfig
@@ -257,11 +341,13 @@ def test_in_place_is_a_fact_of_the_step_and_decides_the_unasked_pump(
         submit = lambda e: e.submit(                         # noqa: E731
             Request("a", [1, 2, 3], max_new_tokens=4))
     eng = make()
-    in_place = family == "laguna"
-    assert eng.model.in_place is in_place
-    assert _first_pool_after_a_step(eng, submit).is_deleted() is in_place
+    assert eng.model.in_place is True
+    assert _first_pool_after_a_step(eng, submit).is_deleted()
     monkeypatch.delenv("PT_SERVE_PIPELINE", raising=False)
-    assert RequestScheduler(make(), start=False)._pipeline is in_place
+    assert RequestScheduler(make(), start=False)._pipeline is True
+    copies = make()     # a step that returned new pools: synchronous
+    copies.model = dataclasses.replace(copies.model, in_place=False)
+    assert RequestScheduler(copies, start=False)._pipeline is False
     for env in ("0", "1"):
         monkeypatch.setenv("PT_SERVE_PIPELINE", env)
         assert RequestScheduler(make(), start=False)._pipeline is (env == "1")

@@ -364,6 +364,71 @@ def test_run_derivation_matches_a_numpy_loop():
 
 
 # ---------------------------------------------------------------------------
+# The stack written and read where it lies (ISSUE 31, [donate-pools])
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+def test_stacked_scatter_and_layer_indexed_read_give_the_per_layer_bits(
+        cache):
+    """`unified_step`'s scan writes a layer's rows flat into the carried
+    5-D stack and hands the kernel the stack and a layer index. Both are
+    the per-layer path's bits: the stack after `_scatter_kv_stacked` is
+    the stack after `_scatter_kv`'s three-index form, the trash page's
+    rows and the int8 scales included, and attention through `layer=`
+    (the interpreted kernel and the reference) is attention over the
+    layer sliced out."""
+    from paddle_tpu.models.llama_serving import (_scatter_kv,
+                                                 _scatter_kv_stacked)
+    L, kvh, qh, pages, page, d, t = 3, 2, 4, 12, 8, 16, 10
+    quant = cache == "int8"
+    rng = np.random.default_rng(31)
+    shape = (L, kvh, pages, page, d)
+    if quant:
+        kp = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        ksp = jnp.asarray(rng.uniform(0.01, 0.1, shape[:4] + (1,)),
+                          jnp.float32)
+        vsp = jnp.asarray(rng.uniform(0.01, 0.1, shape[:4] + (1,)),
+                          jnp.float32)
+    else:
+        kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        vp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        ksp = vsp = None
+    ptab = jnp.asarray(rng.permutation(pages - 1)[:8].reshape(2, 4),
+                       jnp.int32)
+    # a 5-row prefill chunk, two decode rows, three slack rows that all
+    # land on the trash page's first row
+    slot = jnp.asarray([0, 0, 0, 0, 0, 1, 0, 0, 0, 0], jnp.int32)
+    pos = jnp.asarray([9, 10, 11, 12, 13, 21, 14, -1, -1, -1], jnp.int32)
+    on = pos >= 0
+    page_ids = jnp.where(on, ptab[slot, jnp.maximum(pos, 0) // page],
+                         pages - 1)
+    off = jnp.maximum(pos, 0) % page
+    q = jnp.asarray(rng.standard_normal((t, qh, d)), jnp.bfloat16)
+    stacked, each = (kp, vp, ksp, vsp), (kp, vp, ksp, vsp)
+    for li in range(L):
+        kt = jnp.asarray(rng.standard_normal((kvh, t, d)), jnp.bfloat16)
+        vt = jnp.asarray(rng.standard_normal((kvh, t, d)), jnp.bfloat16)
+        stacked = _scatter_kv_stacked(*stacked, jnp.int32(li), page_ids,
+                                      off, kt, vt, quant)
+        *each, kl, vl, ksl, vsl = _scatter_kv(*each, li, page_ids, off, kt,
+                                              vt, quant)
+        for a, b in zip(stacked, each):
+            assert (a is None and b is None) or np.array_equal(
+                np.asarray(a), np.asarray(b))
+        for kernel in (dict(use_pallas=False),
+                       dict(use_pallas=True, interpret=True)):
+            kw = dict(kernel, block_pages=2)
+            by_index = ragged_paged_attention(
+                q, stacked[0], stacked[1], ptab, slot, pos,
+                k_scale=stacked[2], v_scale=stacked[3],
+                layer=jnp.int32(li), **kw)
+            sliced = ragged_paged_attention(
+                q, kl, vl, ptab, slot, pos, k_scale=ksl, v_scale=vsl, **kw)
+            assert np.asarray(by_index[:7]).any()
+            assert np.array_equal(np.asarray(by_index), np.asarray(sliced))
+
+
+# ---------------------------------------------------------------------------
 # Token identity: ragged == bucketed, every mode, both pumps
 # ---------------------------------------------------------------------------
 def _submit_mixed(eng, max_new=8):

@@ -413,6 +413,43 @@ class TestCrashLoopBreaker:
             router.shutdown(drain=False, timeout=30)
 
 
+    def test_a_failed_step_that_consumed_its_pools_ends_in_the_breaker(
+            self, params):
+        """`unified_step` donates its pools ([donate-pools]): a step that
+        dies AFTER the runtime took them leaves the engine holding deleted
+        arrays, and a warm restart cannot give them back. The engine does
+        not serve from them: every retry raises, the restarts trip the
+        crash-loop breaker, the request fails with the cause and admission
+        refuses, as for any persistent fault (a router then fails over)."""
+        import dataclasses
+        eng = _engine(params)
+        real, died = eng.model.step, []
+
+        def step(p, caches, *a, **kw):
+            if not died:
+                died.append(1)
+                for stack in caches[0]:
+                    for arr in stack:
+                        if arr is not None:
+                            arr.delete()
+                raise RuntimeError("the device lost the step")
+            return real(p, caches, *a, **kw)
+        eng.model = dataclasses.replace(eng.model, step=step)
+        rep = Replica("r0", eng, max_restarts=3, restart_window_s=60.0,
+                      poison_after=99)
+        try:
+            h = rep.submit([1, 2, 3], max_new_tokens=8)
+            with pytest.raises(SchedulerError, match="has been deleted"):
+                h.result(timeout=60)
+            assert eng.restarts == 3 and not rep.ready()
+            assert rep.scheduler.readiness()[1] == "crash_loop"
+            with pytest.raises(CrashLoopError):
+                rep.submit([1, 2, 3], max_new_tokens=2)
+            assert _pool_conserved(eng, drained=True)
+        finally:
+            rep.scheduler.shutdown(drain=False, timeout=30)
+
+
 # ---------------------------------------------------------------------------
 # Fault points beyond the decode dispatch
 # ---------------------------------------------------------------------------

@@ -293,7 +293,8 @@ def test_step_record_and_stall_carry_the_parts_and_the_ring_gains_nothing(
     assert a["stalled_part"] == "plan" and a["stalled_over_s"] > 0.4
     assert a["parts"]["plan"] > 0.4 and a["largest_part"] in TURN_PARTS
     # the ring: one span (`serving.unified_step`) and one log record a
-    # turn as before, nothing per part
+    # turn as before, nothing per part (a turn of the one-step-deep pump,
+    # which this engine runs unasked, may finish a step and launch none)
     names = {e.get("name") for e in evs if e.get("kind") == "span"}
     assert "serving.unified_step" in names
     assert not names & (set(SPAN_PART) - {"serving.unified_step"})
@@ -301,4 +302,4 @@ def test_step_record_and_stall_carry_the_parts_and_the_ring_gains_nothing(
     per_turn = [e for e in evs if e.get("kind") in ("span", "log")
                 and (e.get("name") or e.get("event", "")).startswith(
                     "serving.")]
-    assert len(per_turn) <= 2 * steps
+    assert steps <= len(records) and len(per_turn) <= 2 * len(records)
