@@ -111,14 +111,14 @@ def _window(ctx, step, params, state, feed, annotate, tracer):
         if pending is not None:
             wait()
         pending, i = loss, i + 1
-        if tracer and tracer.window_s is None and \
+        if tracer and not tracer.stopped and \
                 now() - t_open >= tracer.seconds:
             wait()                      # the trace ends on a step's end
             pending = None
             tracer.stop()
     if pending is not None:
         wait()
-    if tracer and tracer.window_s is None:
+    if tracer and not tracer.stopped:
         tracer.stop()
     return t_open, done_at, float(loss)
 

@@ -102,13 +102,16 @@ def memory_peak_bytes():
 
 class Tracer:
     """A short profiler window inside the measured one. The reduction runs
-    after the system under test is gone."""
+    after the system under test is gone. How long the window was is read
+    from the trace (`xplane.window_seconds`), on the clock the busy time is
+    on: the host's clock here starts after the profiler has and stops before
+    it does, so `host_timed_s` is printed and divides nothing."""
 
     def __init__(self, ctx, spec):
         self.ctx = ctx
         self.dir = os.path.join(OUT_DIR, "trace", ctx.cell["name"])
         self.seconds = float(min(spec.get("trace_s", 8), ctx.args.seconds))
-        self.window_s = None
+        self.host_timed_s = None        # None until stop()
         self._t = None
 
     def start(self):
@@ -131,9 +134,13 @@ class Tracer:
         self.ctx.say("tracer: python_tracer_level 0")
         return {"profiler_options": options}
 
+    @property
+    def stopped(self):
+        return self.host_timed_s is not None
+
     def stop(self):
         import jax
-        self.window_s = time.monotonic() - self._t
+        self.host_timed_s = time.monotonic() - self._t
         jax.profiler.stop_trace()
 
     def load(self):

@@ -1,4 +1,6 @@
-"""1 - union of busy intervals over the traced window, in %."""
+"""1 - union of busy intervals over the traced window, in %. The window
+(`trace_window_s`) is the trace's own extent, `xplane.window_seconds`, so
+the share lies in [0, 100]."""
 from benchmarks import xplane
 
 

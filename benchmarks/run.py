@@ -103,16 +103,33 @@ def reports(entry, cell):
     return cell["name"] in entry.get("workloads", [cell["name"]])
 
 
+def traced_device(ctx, device, trace, host_timed_s):
+    """`device` with `busy_s` and `window_s`, both read from the trace on
+    its own clock, so that 0 < busy_s <= window_s; where that does not hold
+    the trace is empty and the run ends here, with no result line."""
+    from benchmarks import xplane
+    busy_s, window_s = xplane.busy_seconds(trace), xplane.window_seconds(trace)
+    ctx.say(f"tracer: host-timed {host_timed_s:.6f} s, trace extent "
+            f"{window_s:.6f} s, device busy {busy_s:.6f} s")
+    if not 0 < busy_s <= window_s:
+        sys.exit(f"run.py: the trace holds {busy_s!r} s of device operations "
+                 f"in a window of {window_s!r} s: busy_s has to lie above 0 "
+                 "and at most at window_s, so the profiler recorded nothing "
+                 "on the device and no result is printed")
+    return dict(device, busy_s=busy_s, window_s=window_s)
+
+
 def layer_metrics(ctx, outcome, device):
     """The cell's per-layer metrics: each from the small reader its file
     names. A reader that finds nothing to read returns None and the metric
     is left out of the line."""
     from benchmarks import harness, xplane
     trace = outcome.tracer.load()
+    device = traced_device(ctx, device, trace, outcome.tracer.host_timed_s)
     facts = dict(ctx.facts, trace=trace, config=ctx.config,
                  traffic=ctx.traffic, chips=ctx.cell["chips"],
                  peaks=harness.peaks_for(device["kind"]),
-                 trace_window_s=outcome.tracer.window_s)
+                 trace_window_s=device["window_s"])
     base = os.path.join(ctx.bench_dir, "layer_metrics")
     out = {}
     for entry in ctx.manifest["per_layer"]:
@@ -123,8 +140,6 @@ def layer_metrics(ctx, outcome, device):
         value = reader.reduce(facts, **spec.get("args", {}))
         if value is not None:
             out[entry["name"]] = {"value": float(value), "unit": entry["unit"]}
-    device = dict(device, busy_s=xplane.busy_seconds(trace),
-                  window_s=outcome.tracer.window_s)
     return out, device, xplane.breakdown(trace)
 
 

@@ -1,5 +1,6 @@
-"""Reduction from a profiler trace (.xplane.pb) to busy and idle time,
-per-operation time, gap attribution and exposed collective time.
+"""Reduction from a profiler trace (.xplane.pb) to busy and idle time, the
+traced window's length, per-operation time, gap attribution and exposed
+collective time.
 
 `load()` turns the profiler's file into a plain dictionary (the form the
 recorded trace under tests/benchmarks/data/ is kept in); every reduction
@@ -113,6 +114,21 @@ def busy_seconds(trace):
     devices in the trace."""
     per = [_total(union(_spans(d["ops"]))) for d in trace["devices"].values()]
     return float(np.mean(per)) / 1e9 if per else 0.0
+
+
+def window_seconds(trace):
+    """Seconds the profiler recorded: earliest start to latest end over the
+    devices' operations and step programs AND the host's events, on the
+    trace's own clock. It covers everything `busy_seconds` sums, so busy
+    time cannot pass it; the host's events (the program's own spans run
+    through the whole session) keep it from shrinking to the device's
+    extent where a device idles at an edge. 0.0 for an empty trace."""
+    spans = [sp for d in trace["devices"].values()
+             for sp in _spans(d["ops"]) + _spans(d["modules"])]
+    spans += [sp for evs in trace["host"].values() for sp in _spans(evs)]
+    if not spans:
+        return 0.0
+    return (max(e for _, e in spans) - min(s for s, _ in spans)) / 1e9
 
 
 def self_times(events):

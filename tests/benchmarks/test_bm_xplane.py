@@ -1,5 +1,6 @@
-"""The reduction from a trace to busy and idle time, per-operation time, gap
-attribution and exposed collective time; the operation and byte functions
+"""The reduction from a trace to busy and idle time, the traced window's
+length (never under the busy time), per-operation time, gap attribution and
+exposed collective time; the operation and byte functions
 against hand-worked shapes. Nothing here describes a chip topology: the
 recorded traces under data/ were cut from chip runs of PR 24."""
 import json
@@ -10,6 +11,8 @@ import numpy as np
 import pytest
 
 from benchmarks import costs, harness, xplane
+from benchmarks import run as bench_run
+from benchmarks.reducers import device_idle
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -151,6 +154,134 @@ def test_recorded_trace_against_brute_force(name):
     else:
         assert xplane.matching_op_seconds(tr, "custom-call") > \
             0.5 * xplane.busy_seconds(tr)
+
+
+# ------------------------------------------------- the traced window
+S = 1e9                                         # nanoseconds a second
+RECORDED = ["trace_serve_small.json", "trace_serve_spans_small.json",
+            "trace_train_small.json"]
+# what PR 31's chat_saturated printed, made by hand: the host's clock read
+# 8.001 s between start_trace's return (2 ms) and stop_trace's call
+# (8,003 ms); the device worked from inside the one to inside the other
+OVERHANG = {
+    "devices": {"/device:TPU:0": {
+        "ops": [["fusion_bf16_32_14336", 0.0, 4.0 * S],
+                ["fusion_bf16_32_4096", 4.0 * S, 4.005 * S]],
+        "modules": [["jit_unified_step(1)", 0.0, 8.005 * S]]}},
+    "host": {"python3#0": [["serving.turn", 0.002 * S, 8.001 * S]]}}
+# a device with nothing to run at either edge, the pump's spans running on
+IDLE_EDGES = {
+    "devices": {"/device:TPU:0": {
+        "ops": [["fusion_bf16_32_4096", 2.0 * S, 3.0 * S]],
+        "modules": [["jit_unified_step(1)", 2.0 * S, 3.0 * S]]}},
+    "host": {"python3#0": [["serving.turn", 0.001 * S, 0.004 * S],
+                           ["serving.turn", 7.9 * S, 0.1 * S]],
+             "pjrt-tpu-tasks/1#1": [["PjRt wait", 0.5 * S, 1.0 * S]]}}
+HAND_TRACES = {"hand": HAND, "overhang": OVERHANG, "idle_edges": IDLE_EDGES}
+
+
+def _trace(name):
+    return HAND_TRACES[name] if name in HAND_TRACES else _recorded(name)
+
+
+def test_window_covers_operations_that_overhang_the_hosts_clock():
+    """Two clocks gave busy 8.005 s of 8.001; one gives a share in [0, 1]."""
+    host_timed_s = 8.001
+    busy, window = xplane.busy_seconds(OVERHANG), xplane.window_seconds(OVERHANG)
+    assert busy == pytest.approx(8.005) and busy > host_timed_s
+    assert window == pytest.approx(8.005) and window >= busy
+    assert 0.0 <= 1.0 - busy / window <= 1.0
+
+
+def test_window_is_the_host_spans_extent_where_the_device_idles_at_the_edges():
+    assert xplane.busy_seconds(IDLE_EDGES) == pytest.approx(3.0)
+    assert xplane.window_seconds(IDLE_EDGES) == pytest.approx(8.0 - 0.001)
+    # the hand trace's last host event ends at 400 ns, its last operation at 350
+    assert xplane.window_seconds(HAND) == pytest.approx(400e-9)
+    assert xplane.window_seconds({"devices": {}, "host": {}}) == 0.0
+
+
+@pytest.mark.parametrize("name", RECORDED + sorted(HAND_TRACES))
+def test_busy_time_never_passes_the_window(name):
+    tr = _trace(name)
+    busy, window = xplane.busy_seconds(tr), xplane.window_seconds(tr)
+    assert 0 < busy <= window
+    # every device's own extent lies inside it, not only their mean's
+    for dev in tr["devices"].values():
+        spans = [(s, s + d) for _, s, d in dev["ops"] + dev["modules"]]
+        assert (max(e for _, e in spans) - min(s for s, _ in spans)) / 1e9 \
+            <= window
+
+
+@pytest.mark.parametrize("name", RECORDED + sorted(HAND_TRACES))
+def test_device_idle_is_never_negative(name):
+    tr = _trace(name)
+    idle = device_idle.reduce({"trace": tr,
+                               "trace_window_s": xplane.window_seconds(tr)})
+    assert 0.0 <= idle <= 100.0
+    assert device_idle.reduce({"trace": tr}) is None
+
+
+def _stub_run(trace, host_timed_s=0.777):
+    said = []
+    ctx = types.SimpleNamespace(
+        say=said.append, facts={}, config={}, traffic={},
+        cell={"name": "x", "chips": 1}, manifest={"per_layer": []},
+        bench_dir=os.path.join(harness.ROOT, "benchmarks"))
+    tracer = types.SimpleNamespace(load=lambda: trace, host_timed_s=host_timed_s)
+    return ctx, types.SimpleNamespace(tracer=tracer), said
+
+
+@pytest.mark.parametrize("name", RECORDED + ["overhang"])
+def test_the_result_lines_device_block_holds_the_drivers_contract(name):
+    tr = _trace(name)
+    ctx, outcome, said = _stub_run(tr, host_timed_s=8.001)
+    given = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    metrics, device, breakdown = bench_run.layer_metrics(ctx, outcome, given)
+    assert set(device) == set(given) | {"busy_s", "window_s"}
+    assert 0 < device["busy_s"] <= device["window_s"]
+    assert device["busy_s"] == xplane.busy_seconds(tr)
+    assert device["window_s"] == xplane.window_seconds(tr)
+    assert "busy_s" not in given            # the caller's block is not written to
+    # the host's figure is said on a line and is in nothing that is reported
+    assert said == [f"tracer: host-timed 8.001000 s, trace extent "
+                    f"{device['window_s']:.6f} s, device busy "
+                    f"{device['busy_s']:.6f} s"]
+    assert metrics == {} and breakdown["device_ops"]
+
+
+@pytest.mark.parametrize("trace", [
+    {"devices": {}, "host": {}},
+    {"devices": {}, "host": {"python3#0": [["serving.turn", 0.0, 8.0 * S]]}},
+    {"devices": {"/device:TPU:0": {"ops": [], "modules": []}}, "host": {}}],
+    ids=["nothing", "host_only", "device_without_operations"])
+def test_an_empty_trace_ends_the_run_with_no_result_line(trace, capsys):
+    ctx, outcome, _ = _stub_run(trace)
+    with pytest.raises(SystemExit) as stop:
+        bench_run.layer_metrics(ctx, outcome, {"kind": "TPU v5 lite"})
+    assert stop.value.code not in (0, None)
+    assert "busy_s has to lie above 0 and at most at window_s" in str(stop.value)
+    assert "0.0 s of device operations" in str(stop.value)
+    assert capsys.readouterr().out == ""
+
+
+def test_the_tracer_keeps_the_hosts_figure_apart(monkeypatch):
+    """`stop()` times the host's clock for a line to print; the window's
+    length is nothing the tracer holds."""
+    import jax
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: calls.append("stop"))
+    ctx = types.SimpleNamespace(cell={"name": "x"}, say=lambda m: None,
+                                args=types.SimpleNamespace(seconds=2))
+    tracer = harness.Tracer(ctx, {"trace_s": 1})
+    assert not tracer.stopped and tracer.host_timed_s is None
+    tracer.start()
+    tracer.stop()
+    assert calls == ["start", "stop"]
+    assert tracer.stopped and tracer.host_timed_s >= 0
+    assert not hasattr(tracer, "window_s")
 
 
 # ------------------------------------------------- operations and bytes
