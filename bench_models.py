@@ -210,11 +210,6 @@ def bench_serving(on_tpu):
     # vs a tier-off baseline at token-identical outputs
     if (os.environ.get("PT_SERVE_MULTITURN", "") or "0") not in ("", "0"):
         return _bench_serving_multiturn(on_tpu, params, cfg, dtype)
-    # PT_SERVE_PIPELINE=1: the double-buffered pump + device-side
-    # sampling vs the synchronous pump at equal config and
-    # token-identical outputs (serving/scheduler.py; ROADMAP item 4)
-    if (os.environ.get("PT_SERVE_PIPELINE", "") or "0") not in ("", "0"):
-        return _bench_serving_pipeline(on_tpu, params, cfg, dtype)
     # PT_SERVE_CHAOS=1: crash-recovery drill — a seeded fault plan
     # injects a device failure mid-run; survivors must be
     # token-identical to an undisturbed baseline and the artifact
@@ -227,12 +222,6 @@ def bench_serving(on_tpu):
     # (docs/serving.md § Unified ragged step)
     if (os.environ.get("PT_SERVE_RAGGED", "") or "0") not in ("", "0"):
         return _bench_serving_ragged(on_tpu, params, cfg, dtype)
-    # PT_SERVE_LEAN=1 (bench mode): the row-sparse lm_head epilogue vs
-    # the full-logits step at equal config and token-identical outputs
-    # — unembed FLOPs saved, logit rows skipped, tok/s for both sides
-    # (docs/serving.md § Lean epilogue)
-    if (os.environ.get("PT_SERVE_LEAN", "") or "0") not in ("", "0"):
-        return _bench_serving_lean(on_tpu, params, cfg, dtype)
     # PT_SERVE_SLO=1: the SLO/goodput accounting plane — a mixed
     # interactive + batch workload measured through the per-request
     # timeline ledger: goodput ratio, attained/violated by class,
@@ -501,205 +490,14 @@ def _bench_serving_ragged(on_tpu, params, cfg, dtype):
     }
 
 
-def _bench_serving_lean(on_tpu, params, cfg, dtype):
-    """PT_SERVE_LEAN=1: the row-sparse lm_head epilogue (ISSUE 12) vs
-    the full-logits unified step at equal config and TOKEN-IDENTICAL
-    outputs. Prefill-heavy shared-prefix workload — the regime the
-    epilogue targets: chunked prefill runs push T far past the handful
-    of rows that actually sample, so the full step burns a
-    (T, vocab) unembed mostly on rows nobody reads. The artifact
-    carries `outputs_match`, the unembed FLOPs both sides issued
-    through `serving.unified_step` (CostRegistry per-fn XLA analysis,
-    not an analytic formula), the pt_logit_rows(_skipped) ledgers, and
-    tok/s for both sides."""
-    from paddle_tpu.models.llama_serving import Request, ServingEngine
-    from paddle_tpu.observability import compile_telemetry as _ct
-    from paddle_tpu.observability import device_telemetry as _dt
-    from paddle_tpu.serving.metrics import EngineMetrics, MetricsRegistry
-
-    if on_tpu:
-        max_seqs, new_tok, nreq = 8, 64, 12
-        max_seq_len, page = 1024, 16
-    else:
-        max_seqs, new_tok, nreq = 2, 8, 4
-        max_seq_len, page = 64, 8
-    rng = _data_rng()
-    header = list(map(int, rng.randint(1, cfg.vocab_size, 3 * page)))
-    prompts = [header + list(map(int, rng.randint(
-        1, cfg.vocab_size, 16 if on_tpu else 4))) for _ in range(nreq)]
-
-    def run_once(lean, nt):
-        eng = ServingEngine(params, cfg, max_seqs=max_seqs,
-                            max_seq_len=max_seq_len, page_size=page,
-                            dtype=dtype, prefix_cache=True, ragged=True,
-                            lean=lean,
-                            use_pallas=None if on_tpu else False)
-        reg = MetricsRegistry()
-        eng.metrics = EngineMetrics(reg)
-        for i, p in enumerate(prompts):
-            eng.submit(Request(f"r{i}", p, max_new_tokens=nt))
-        mark = _dt.COSTS.issued_totals()
-        t0 = time.perf_counter()
-        done = eng.run()
-        dt = time.perf_counter() - t0
-        issued = _dt.COSTS.issued_totals()
-
-        def fn_flops(name):
-            return issued["per_fn"].get(name, {"flops": 0.0})["flops"] \
-                - mark["per_fn"].get(name, {"flops": 0.0})["flops"]
-        snap = reg.snapshot()
-        return {"outs": {r.rid: r.output for r in done},
-                "new_tokens": sum(len(r.output) for r in done),
-                "tok_s": sum(len(r.output) for r in done) / dt,
-                "step_flops": fn_flops("serving.unified_step"),
-                "logit_rows": int(eng.logit_rows),
-                "logit_rows_skipped": int(eng.logit_rows_skipped),
-                "pt_logit_rows": snap["pt_logit_rows"]["value"],
-                "pt_logit_rows_skipped":
-                    snap["pt_logit_rows_skipped"]["value"]}
-
-    def run_mode(lean):
-        # cold pass (short generations, same admission mix) pays and
-        # COUNTS the mode's compiles; the timed pass runs warm
-        c0 = _ct.REGISTRY.totals()["compiles"]
-        run_once(lean, min(new_tok, 2))
-        compiles = _ct.REGISTRY.totals()["compiles"] - c0
-        res = run_once(lean, new_tok)
-        res["compiles"] = compiles
-        return res
-
-    full = run_mode(False)
-    lean = run_mode(True)
-    # the epilogue's whole claim, asserted in the artifact path itself:
-    # identical tokens from a strictly cheaper step program
-    assert lean["step_flops"] < full["step_flops"], (
-        lean["step_flops"], full["step_flops"])
-    assert lean["logit_rows_skipped"] > 0
-    return {
-        "workload": "lean-vs-full epilogue (shared-prefix)",
-        "outputs_match": full["outs"] == lean["outs"],
-        "requests": nreq, "new_tokens": lean["new_tokens"],
-        "batch": max_seqs,
-        "decode_tokens_per_sec": round(lean["tok_s"], 1),
-        "step_time_s": round(1.0 / max(lean["tok_s"], 1e-9), 5),
-        "full_decode_tokens_per_sec": round(full["tok_s"], 1),
-        "tok_s_delta": round(
-            lean["tok_s"] / max(full["tok_s"], 1e-9) - 1.0, 4),
-        "unified_step_flops": lean["step_flops"],
-        "full_unified_step_flops": full["step_flops"],
-        "unembed_flops_saved": round(
-            1.0 - lean["step_flops"] / max(full["step_flops"], 1e-9), 4),
-        "logit_rows": lean["logit_rows"],
-        "logit_rows_skipped": lean["logit_rows_skipped"],
-        "pt_logit_rows_total": lean["pt_logit_rows"],
-        "pt_logit_rows_skipped_total": lean["pt_logit_rows_skipped"],
-        "compiles": lean["compiles"],
-        "full_compiles": full["compiles"],
-        "loss": 0.0,
-    }
-
-
-def _bench_serving_pipeline(on_tpu, params, cfg, dtype):
-    """PT_SERVE_PIPELINE=1: kill the per-step host round-trip. The same
-    workload — a mix of greedy and seeded-sampling requests — runs
-    through the RequestScheduler twice at equal engine config: once
-    with the synchronous pump (launch -> blocked read -> bookkeeping ->
-    launch) and once with the double-buffered pump (launch N+1 before
-    consuming N; sampling/stop conditions already evaluated on device).
-    The artifact carries `outputs_match` (token-identical is the
-    contract, greedy AND seeded sampling), the measured
-    pt_step_host_gap_seconds distribution for both pumps, and the
-    tok/s delta."""
-    from paddle_tpu.models.llama_serving import ServingEngine
-    from paddle_tpu.serving.metrics import MetricsRegistry
-    from paddle_tpu.serving.scheduler import RequestScheduler
-
-    if on_tpu:
-        max_seqs, new_tok, nreq = 8, 128, 16
-        max_seq_len, page = 1024, 16
-    else:
-        max_seqs, new_tok, nreq = 4, 32, 8
-        max_seq_len, page = 128, 8
-    rng = _data_rng()
-    reqs = []
-    for i in range(nreq):
-        prompt = list(map(int, rng.randint(
-            1, cfg.vocab_size, int(rng.randint(8, 48)) if on_tpu else 4)))
-        kw = {"max_new_tokens": new_tok}
-        if i % 3 == 2:   # every third request samples, seeded
-            kw.update(temperature=0.8, top_k=8, top_p=0.95, seed=100 + i)
-        reqs.append((prompt, kw))
-
-    def run_pump(pipeline, warm=True):
-        if warm:
-            # full-trajectory warmup (same pattern as the multiturn
-            # bench): admission-wave composition decides which varlen
-            # prefill buckets compile, so a scaled-down warm run leaves
-            # a first-wave compile inside the timed region — and the
-            # sync-vs-pipelined comparison must time both sides warm
-            run_pump(pipeline, warm=False)
-        # lean=False: this bench isolates the PUMP variable — the
-        # double-buffered pump hides the blocked device read inside the
-        # step gap, and the lean epilogue shrinks that same read, so
-        # with lean on there is little left to hide at smoke scale and
-        # the sync-vs-pipelined gap ordering becomes noise. The lean
-        # epilogue has its own A/B mode (PT_SERVE_LEAN=1).
-        eng = ServingEngine(params, cfg, max_seqs=max_seqs,
-                            max_seq_len=max_seq_len, page_size=page,
-                            dtype=dtype, lean=False,
-                            use_pallas=None if on_tpu else False)
-        sched = RequestScheduler(eng, max_queue=nreq,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=pipeline)
-        # submit under pause(): the pump sees the whole queue at once,
-        # so the admission-wave composition — and with it the varlen
-        # prefill bucket set — is identical for every run instead of a
-        # race against the submitting thread (a wave-size change is a
-        # fresh prefill bucket, i.e. an XLA compile inside the timing)
-        sched.pause()
-        t0 = time.perf_counter()
-        handles = [sched.submit(prompt, **kw) for prompt, kw in reqs]
-        sched.resume()
-        outs = [h.result(timeout=600) for h in handles]
-        dt = time.perf_counter() - t0
-        snap = sched.metrics_snapshot()
-        sched.shutdown(drain=True, timeout=60)
-        total = sum(len(o) for o in outs)
-        return outs, total / dt, snap
-
-    sync_outs, sync_tps, sync_snap = run_pump(False)
-    pipe_outs, pipe_tps, pipe_snap = run_pump(True)
-
-    def gap(snap):
-        h = snap["pt_step_host_gap_seconds"]
-        return {"p50_s": round(h["p50"], 6), "p99_s": round(h["p99"], 6),
-                "mean_s": round(h["sum"] / max(h["count"], 1), 6),
-                "count": h["count"]}
-    sync_gap, pipe_gap = gap(sync_snap), gap(pipe_snap)
-    return {
-        "workload": "pipelined-pump",
-        "outputs_match": sync_outs == pipe_outs,
-        "requests": nreq, "new_tokens": sum(len(o) for o in pipe_outs),
-        "batch": max_seqs,
-        "decode_tokens_per_sec": round(pipe_tps, 1),
-        "sync_decode_tokens_per_sec": round(sync_tps, 1),
-        "tok_s_delta": round(pipe_tps / max(sync_tps, 1e-9) - 1.0, 4),
-        "host_gap_sync": sync_gap,
-        "host_gap_pipelined": pipe_gap,
-        "host_gap_reduction": round(
-            1.0 - pipe_gap["mean_s"] / max(sync_gap["mean_s"], 1e-12), 4),
-        "pipeline_depth": pipe_snap["pt_pipeline_depth"]["value"],
-        "loss": 0.0,
-    }
-
-
 def _bench_serving_chaos(on_tpu, params, cfg, dtype):
     """PT_SERVE_CHAOS=1: the crash-recovery drill (ISSUE 9). The same
     mixed greedy + seeded-sampling workload runs three times at equal
     engine config: once undisturbed (the baseline), then under a
-    seeded `FaultPlan` that kills a device step mid-run — once with
-    the synchronous pump and once with the pipelined pump (a pending
-    step_finish ticket in flight at crash time). Warm restart must
+    seeded `FaultPlan` that kills a device step mid-run — once on a
+    bucketed engine (the synchronous pump) and once on a ragged one
+    (the pump one step deep: a pending step_finish ticket in flight at
+    crash time). Warm restart must
     requeue every victim and finish them token-identical to the
     baseline; the artifact asserts `outputs_match`, carries the
     restart/requeue ledger, and reports goodput retained (completed
@@ -726,20 +524,18 @@ def _bench_serving_chaos(on_tpu, params, cfg, dtype):
             kw.update(temperature=0.8, top_k=8, top_p=0.95, seed=200 + i)
         reqs.append((prompt, kw))
 
-    def run_drill(spec, pipeline, warm=True):
+    def run_drill(spec, ragged, warm=True):
         if warm:
             # full-trajectory warmup: the chaos-vs-baseline comparison
-            # must time both sides with identical compile caches (same
-            # reasoning as the pipeline bench)
-            run_drill(spec, pipeline, warm=False)
+            # must time both sides with identical compile caches
+            run_drill(spec, ragged, warm=False)
         eng = ServingEngine(params, cfg, max_seqs=max_seqs,
                             max_seq_len=max_seq_len, page_size=page,
-                            dtype=dtype, prefix_cache=True,
+                            dtype=dtype, prefix_cache=True, ragged=ragged,
                             use_pallas=None if on_tpu else False,
                             faults=FaultPlan(spec) if spec else None)
         sched = RequestScheduler(eng, max_queue=nreq,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=pipeline)
+                                 metrics=MetricsRegistry())
         # submit under pause(): deterministic admission waves (and so a
         # deterministic Nth-device-step crash position) per run
         sched.pause()
@@ -767,8 +563,8 @@ def _bench_serving_chaos(on_tpu, params, cfg, dtype):
            "batch": max_seqs, "fault_plan": fault_spec,
            "baseline_tokens_per_sec": round(base_tokens / base_dt, 1),
            "loss": 0.0}
-    for name, pipeline in (("sync", False), ("pipelined", True)):
-        outs, failed, dt, st, snap = run_drill(fault_spec, pipeline)
+    for name, ragged in (("sync", False), ("pipelined", True)):
+        outs, failed, dt, st, snap = run_drill(fault_spec, ragged)
         done_tokens = sum(len(o) for o in outs if o is not None)
         led = st["requests"]
         out[name] = {
@@ -1371,8 +1167,7 @@ def _bench_serving_pulse(on_tpu, params, cfg, dtype):
                             use_pallas=None if on_tpu else False,
                             faults=FaultPlan(faults) if faults else None)
         return RequestScheduler(eng, max_queue=nreq + 1,
-                                metrics=MetricsRegistry(),
-                                pipeline=True)
+                                metrics=MetricsRegistry())
 
     cap_dir = tempfile.mkdtemp(prefix="pt_pulse_bench_")
     knobs = {"PT_PULSE_INTERVAL_S": "0.05", "PT_CAPTURE_DIR": cap_dir,

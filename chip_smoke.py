@@ -9,7 +9,7 @@ supports, with seeded random weights and the depth cut:
   kernels   every Pallas family, compiled by Mosaic, vs its jnp reference
   serve     Llama-3-8B widths x 8 layers behind ServingEngine +
             RequestScheduler + ServingServer, driven over HTTP by
-            ServingClient: bf16 cache, pipelined pump, int8 cache
+            ServingClient: bf16 cache, the same mix again, int8 cache
   serve4    (>= 4 devices, else "skipped: 1 device") the same model as
             four one-chip replicas behind the Router, and with mesh= tp=4
   train     llama_spmd.make_train_step, 4 steps on one repeated batch
@@ -263,7 +263,7 @@ class Smoke:
             f"{tag}: failed/restarts/quarantined={bad}; "
             f"{flight_recorder.RECORDER.events(kind='engine.restart')[-2:]}")
 
-    def _serve_config(self, tag, cache, pipeline):
+    def _serve_config(self, tag, cache):
         from paddle_tpu.observability import compile_telemetry
         from paddle_tpu.serving import (RequestScheduler, ServingClient,
                                         ServingServer)
@@ -272,9 +272,8 @@ class Smoke:
         engine = self._engine(cache_dtype=cache)
         assert engine._use_pallas is True
         assert engine._interpret is self.dry
-        assert engine.ragged and engine.lean and engine.tok_buf is not None
-        server = ServingServer(
-            RequestScheduler(engine, pipeline=pipeline), port=0).start()
+        assert engine.ragged and engine.tok_buf is not None
+        server = ServingServer(RequestScheduler(engine), port=0).start()
         try:
             client = ServingClient(port=server.port, timeout=900.0)
             lens = sz.burst_prompts
@@ -336,18 +335,16 @@ class Smoke:
 
     def serve(self):
         self._build_serve_model()
-        lp_bf16, _, _ = self._serve_config("bf16 cache", None, False)
-        # the same mix again, pipelined pump: a repeated mix must not
+        lp_bf16, _, _ = self._serve_config("bf16 cache", None)
+        # the same mix again on a new engine: a repeated mix must not
         # compile anything (every engine program is shape-stable)
-        lp_pipe, compiles, _ = self._serve_config(
-            "bf16 cache, pipeline=True", None, True)
+        lp_again, compiles, _ = self._serve_config("bf16 cache, again", None)
         assert compiles == 0, f"repeated mix compiled {compiles} programs"
-        lp_int8, _, in_burst = self._serve_config("int8 cache", "int8", False)
+        lp_int8, _, in_burst = self._serve_config("int8 cache", "int8")
         assert in_burst == 0, f"int8 burst compiled {in_burst} programs"
         ref = self._reference_logprobs()
         self._compare_logprobs("serve[bf16 cache]", lp_bf16, ref)
-        self._compare_logprobs("serve[bf16 cache, pipeline=True]", lp_pipe,
-                               ref)
+        self._compare_logprobs("serve[bf16 cache, again]", lp_again, ref)
         self._compare_logprobs("serve[int8 cache]", lp_int8, ref)
         self.first_logprobs = lp_bf16
         self.phase_done("serve")

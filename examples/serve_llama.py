@@ -50,12 +50,6 @@ def main():
                          "cache evictions demote pages to host memory "
                          "instead of discarding them (implies "
                          "--prefix-cache); 0 disables")
-    ap.add_argument("--pipeline", action="store_true",
-                    help="double-buffered pump: launch device step N+1 "
-                         "before consuming step N (device-side sampling "
-                         "makes the carry possible); token-identical to "
-                         "the synchronous pump. PT_SERVE_PIPELINE=1 is "
-                         "the env spelling")
     ap.add_argument("--replicas", type=int, default=0,
                     help="N>1: router mode — N independent engine "
                          "replicas behind the prefix-affinity router "
@@ -85,19 +79,14 @@ def main():
             host_tier_bytes=args.host_tier_mb << 20,
             faults=FaultPlan(args.faults) if args.faults else None)
 
-    pipeline = True if args.pipeline else None  # None -> env default
     if args.replicas > 1:
         from paddle_tpu.serving import Router, build_replicas
         sched = Router(build_replicas(make_engine, args.replicas,
-                                      max_queue=args.max_queue,
-                                      pipeline=pipeline))
+                                      max_queue=args.max_queue))
         mode = f"router x{args.replicas} replicas"
     else:
-        sched = RequestScheduler(make_engine(), max_queue=args.max_queue,
-                                 pipeline=pipeline)
+        sched = RequestScheduler(make_engine(), max_queue=args.max_queue)
         mode = "single engine"
-    if pipeline:
-        mode += " [pipelined pump]"
     srv = ServingServer(sched, host=args.host, port=args.port).start()
     print(f"serving on {srv.url} [{mode}]  "
           f"(POST /v1/completions, GET /healthz, GET /readyz, "

@@ -163,12 +163,6 @@ def env_bool(name, default=_UNSET, env=None):
 # docs to ONE line — gen_env_docs renders them into a table cell.
 
 # -- serving -----------------------------------------------------------
-declare("PT_SERVE_PIPELINE", False,
-        "Run the scheduler pump one step deep (launch step N+1 before "
-        "reading step N's results). Unset: on for a ragged engine, "
-        "whose step writes its page pools in place; off for the bucketed "
-        "entry points.",
-        kind="bool", section="serving")
 declare("PT_SERVE_TIMELINE", True,
         "Per-request timeline + SLO accounting plane (0 disables; "
         "token outputs are identical either way).",
@@ -183,12 +177,6 @@ declare("PT_SERVE_TIMING", False,
 declare("PT_SERVE_RAGGED", True,
         "Serve through the unified ragged step (0 falls back to the "
         "padded batch step).", kind="bool", section="serving")
-declare("PT_SERVE_LEAN", True,
-        "Lean epilogue: gather only host-read rows before lm_head "
-        "(no (T, vocab) logits buffer).", kind="bool", section="serving")
-declare("PT_SERVE_TOKBUF", True,
-        "Device token ring: keep emitted tokens on device between "
-        "steps (0 ships every token).", kind="bool", section="serving")
 declare("PT_FAULTS", "",
         "Fault-injection plan spec, e.g. 'crash@step:p=0.01;seed=7' "
         "(empty disables; see serving/faults.py).",

@@ -218,13 +218,15 @@ def _swiglu(x, w_gate, w_up, w_down):
                                     "interpret", "block_q", "block_pages"),
                    donate_argnames=("caches",))
 def laguna_step(params, caches, tables, tokens, tok_slot, tok_pos,
-                config: LagunaConfig, page_size, use_pallas=False,
-                interpret=False, sample=None, need_rows=None, block_q=None,
-                block_pages=None, tok_buf=None, buf_write=None):
+                config: LagunaConfig, page_size, *, sample, need_rows,
+                tok_buf, buf_write, use_pallas=False, interpret=False,
+                block_q=None, block_pages=None):
     """`unified_step`'s contract (flat `tokens` / `tok_slot` / `tok_pos`
-    rows, -1 an inactive row; `need_rows` the lean epilogue; `tok_buf` /
-    `buf_write` the device token ring; `sample` the per-slot sampling
-    arrays) over Laguna's layers, unrolled. `caches` / `tables`: one
+    rows, -1 an inactive row; `need_rows` the epilogue's rows; `tok_buf`
+    / `buf_write` the device token ring, from which the rows' tokens
+    are read (`tokens` is the contract's place for them and unused);
+    `sample` the per-slot sampling arrays) over Laguna's layers,
+    unrolled. `caches` / `tables`: one
     entry a cache group (`_serving_model`), a group's caches one
     `(k, v, k_scale, v_scale)` a layer with a leading 1; they are
     DONATED and come back in place. Returns `(caches, logits, rec,
@@ -235,8 +237,7 @@ def laguna_step(params, caches, tables, tokens, tok_slot, tok_pos,
     t = tokens.shape[0]
     row_on = tok_pos >= 0
     pos = jnp.maximum(tok_pos, 0)
-    if tok_buf is not None:
-        tokens = tok_buf[tok_slot, pos]
+    tokens = tok_buf[tok_slot, pos]
     # the residual stream is float32 (T x H: nothing beside the weights);
     # the products take it in the weights' type and add to it unrounded
     wdt = params["embed"].dtype
@@ -292,20 +293,18 @@ def laguna_step(params, caches, tables, tokens, tok_slot, tok_pos,
         moe_rows.append(rows)
         h = h + routed + _swiglu(x, lp["s_gate"], lp["s_up"], lp["s_down"])
     h = _rms(h, params["final_norm"], c.rms_norm_eps).astype(wdt)
-    if need_rows is not None:       # the lean epilogue, as unified_step's
-        idx = jnp.maximum(need_rows, 0)
-        h = h[idx]
-        tok_slot = tok_slot[idx]
-        tok_pos = tok_pos[idx]
-        row_on = (need_rows >= 0) & (tok_pos >= 0)
+    idx = jnp.maximum(need_rows, 0)         # the epilogue, as unified_step's
+    h = h[idx]
+    tok_slot = tok_slot[idx]
+    tok_pos = tok_pos[idx]
+    row_on = (need_rows >= 0) & (tok_pos >= 0)
     logits = jnp.dot(h, params["lm_head"],
-                     preferred_element_type=jnp.float32)  # (T|N, V)
+                     preferred_element_type=jnp.float32)  # (N, V)
     rec = _sample_flat(logits, tok_slot, tok_pos, row_on, sample)
-    if tok_buf is not None:
-        B = tok_buf.shape[0]
-        wslot = jnp.where(buf_write & row_on, tok_slot, B)
-        tok_buf = tok_buf.at[wslot, jnp.maximum(tok_pos, 0) + 1].set(
-            rec[0].astype(jnp.int32), mode="drop")
+    B = tok_buf.shape[0]
+    wslot = jnp.where(buf_write & row_on, tok_slot, B)
+    tok_buf = tok_buf.at[wslot, jnp.maximum(tok_pos, 0) + 1].set(
+        rec[0].astype(jnp.int32), mode="drop")
     aux = {"moe_rows": jnp.stack(moe_rows)} if moe_rows else {}
     return (tuple(tuple(g) for g in caches), logits, rec, tok_buf, aux)
 
@@ -334,8 +333,6 @@ _NOT_YET = {
     "tensor_parallel": "the step is written for one chip",
     "bucketed": "it has no bucketed prefill or decode entry points, only "
                 "the ragged step (ragged=True)",
-    "host_tokens": "the step reads its tokens from the device token ring "
-                   "(tokbuf=True)",
     "handoff": "a handoff ships one pool's pages; this model has two",
 }
 
@@ -347,5 +344,4 @@ def _serving_model(c: LagunaConfig):
         // c.num_key_value_heads,
         step=laguna_step,
         unsupported={k: f"LagunaConfig does not serve under {k}: {v}"
-                     for k, v in _NOT_YET.items()},
-        in_place=True)        # `laguna_step` donates `caches`
+                     for k, v in _NOT_YET.items()})

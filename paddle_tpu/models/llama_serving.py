@@ -50,7 +50,6 @@ from ..ops.paged_attention import (paged_attention, paged_verify_attention,
                                    quantize_kv)
 from ..ops.varlen_attention import (flash_attention_varlen,
                                     seg_ids_from_cu_seqlens)
-from .generation import filtered_probs_np
 from .llama import LlamaConfig
 
 
@@ -415,7 +414,7 @@ def prefill_varlen(params, input_ids, cu_seqlens, config: LlamaConfig,
 def decode_step(params, k_pool, v_pool, page_table, lengths, tokens,
                 active, config: LlamaConfig, page_size, use_pallas=False,
                 interpret=False, k_scale=None, v_scale=None, mesh=None,
-                sample=None, carry_tok=None, carry_mask=None):
+                sample=None):
     """One token for every slot.
 
     k_pool/v_pool: (L, KVH, P, page, D); tokens: (B,) current input token;
@@ -428,15 +427,9 @@ def decode_step(params, k_pool, v_pool, page_table, lengths, tokens,
     `sample` (traced pytree, see `_sample_record`) moves sampling and
     stop-condition evaluation INTO this program: the return gains a
     compact (next_token, done, logprob) record and the host never
-    needs a logits row. `carry_tok`/`carry_mask` ((B,) i32 / bool,
-    both traced) let the pipelined pump feed slot s the PREVIOUS
-    step's device-resident next_token (mask true) instead of a host
-    value — the autoregressive dependency stays on device, so step
-    N+1 launches before the host has read step N.
+    needs a logits row.
     """
     c = config
-    if carry_tok is not None:
-        tokens = jnp.where(carry_mask, carry_tok, tokens)
     nh, nkv = c.num_attention_heads, c.num_key_value_heads
     hd = c.hidden_size // nh
     B = tokens.shape[0]
@@ -587,11 +580,11 @@ def verify_step(params, k_pool, v_pool, page_table, lengths, tokens,
         (params["layers"], jnp.arange(L)))
     h = _rms(h, params["final_norm"], c.rms_norm_eps)
     if need_rows is not None:
-        # lean epilogue (suffix-prefill path): gather the needed flat
-        # (B*G)-space rows before the unembed matmul — a bucket-G
-        # chunk pays len(need_rows) rows of lm_head FLOPs, not B*G.
-        # Callers pass sample=None here (the seed token is picked
-        # host-side at finish, the PR 8 convention).
+        # the suffix-prefill path: gather the needed flat (B*G)-space
+        # rows before the unembed matmul — a bucket-G chunk pays
+        # len(need_rows) rows of lm_head FLOPs, not B*G. Callers pass
+        # sample=None here (the seed token is picked host-side at
+        # finish, the PR 8 convention).
         hf = h.reshape(B * G, -1)[jnp.maximum(need_rows, 0)]
         logits = hf @ params["lm_head"]              # (M, V)
         return k_pool, v_pool, k_scale, v_scale, logits
@@ -622,12 +615,10 @@ def verify_step(params, k_pool, v_pool, page_table, lengths, tokens,
                    donate_argnames=("k_pool", "v_pool", "k_scale",
                                     "v_scale", "tok_buf"))
 def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
-                 tok_pos, config: LlamaConfig, page_size,
+                 tok_pos, config: LlamaConfig, page_size, *, need_rows,
                  use_pallas=False, interpret=False, k_scale=None,
-                 v_scale=None, sample=None, carry_tok=None,
-                 carry_gather=None, carry_mask=None, need_rows=None,
-                 cand_tok=None, block_q=None, block_pages=None,
-                 tok_buf=None, buf_write=None):
+                 v_scale=None, sample=None, cand_tok=None, block_q=None,
+                 block_pages=None, tok_buf=None, buf_write=None):
     """ONE device program for an arbitrary prefill/decode mix (ROADMAP
     item 1; "Ragged Paged Attention" + the MPK fewer-bigger-programs
     direction): a FLAT token buffer replaces the (batch, seq) grids of
@@ -649,36 +640,34 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
 
     `sample` (traced pytree, `_sample_flat`) keeps the PR 8 device-side
     sampling contract: per-slot params gathered per row, PRNG fold =
-    tok_pos + 1. `carry_tok`/`carry_gather`/`carry_mask` feed a row the
-    PREVIOUS unified step's device-resident record
-    (`carry_tok[carry_gather[i]]`), so the pipelined pump launches wave
-    N+1 before the host has read wave N. Attention runs the pallas
-    ragged paged kernel on TPU and its bit-identical jnp reference on
-    CPU (paddle_tpu/kernels/ragged_paged_attention.py);
+    tok_pos + 1. Attention runs the pallas ragged paged kernel on TPU
+    and its bit-identical jnp reference on CPU
+    (paddle_tpu/kernels/ragged_paged_attention.py);
     `block_q`/`block_pages` (static) pick its tile — the engine
     resolves them ONCE at construction, so a tuned tile never retraces
     the serving trace.
 
-    `need_rows` ((N,) i32, -1 = inactive) is the LEAN epilogue (docs/
-    serving.md § Lean epilogue): the final-norm hidden states gather
+    `need_rows` ((N,) i32, -1 = inactive) is the epilogue (docs/
+    serving.md § The epilogue): the final-norm hidden states gather
     down to exactly those buffer rows BEFORE the lm_head matmul, so a
-    64-token prefill chunk pays one row of unembed FLOPs and the
-    (T, vocab) buffer is never materialized. Sampling rides the sparse
-    rows with the row's own (tok_slot, tok_pos) — the PRNG fold does
-    not move, so tokens and logprobs are bit-identical to the full
-    epilogue; the returned logits and rec are N-row (the caller
-    indexes them in need-row space). `cand_tok` (same leading shape as
-    the epilogue rows) appends per-row filtered-distribution
+    64-token prefill chunk pays one row of unembed FLOPs and no
+    (T, vocab) buffer exists in this program; a caller that wants every
+    row names every row. Sampling rides the gathered rows with the
+    row's own (tok_slot, tok_pos), so the PRNG fold is the row's
+    position whatever its place in `need_rows`; the returned logits
+    and rec are N-row (the caller indexes them in need-row space).
+    `cand_tok` ((N,)) appends per-row filtered-distribution
     probabilities of a candidate token to the record — the spec-decode
     rejection sampler's accept tests then ride the compact record
     instead of pulling vocab rows (docs/serving.md § Speculative
     decoding).
 
-    Returns (k_pool, v_pool, k_scale, v_scale, logits (T|N, V)[, rec]
+    Returns (k_pool, v_pool, k_scale, v_scale, logits (N, V)[, rec]
     [, tok_buf]). `tok_buf` ((B, max_seq_len+1) i32 device ring) makes
     token values device-resident: rows gather their embedding input
-    from it and decode rows (`buf_write`) scatter their sampled token
-    back — the in-jit twin of the carry operands, which it replaces.
+    from it and decode rows (`buf_write`, (N,) bool) scatter their
+    sampled token back, which is how wave N+1 reads wave N's tokens
+    before the host has.
 
     The pools are never copied (ROADMAP [donate-pools]): `k_pool`,
     `v_pool`, `k_scale`, `v_scale` and `tok_buf` are DONATED and come
@@ -695,8 +684,6 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
     t = tokens.shape[0]
     Pn = k_pool.shape[2]
     quant = k_scale is not None
-    if carry_tok is not None:
-        tokens = jnp.where(carry_mask, carry_tok[carry_gather], tokens)
     row_on = tok_pos >= 0
     pos = jnp.maximum(tok_pos, 0)
     if tok_buf is not None:
@@ -704,9 +691,9 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
         # column p of a slot's ring row holds the token CONSUMED at
         # cache position p, so the host ships only (slot, pos)
         # descriptors — token values (and the embedding gather below)
-        # never leave the device. Subsumes the pipelined carry: wave
-        # N's own scatter (bottom of this program) is device-ordered
-        # before wave N+1's gather. Inactive rows read column 0 of
+        # never leave the device. Wave N's own scatter (bottom of this
+        # program) is device-ordered before wave N+1's gather, so a
+        # second wave launches unread. Inactive rows read column 0 of
         # slot 0 — their K/V lands on the trash page and sampling
         # masks them, so the garbage value is never observed.
         tokens = tok_buf[tok_slot, pos]
@@ -752,17 +739,16 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
         layer, (h, k_pool, v_pool, k_scale, v_scale),
         (params["layers"], jnp.arange(L, dtype=jnp.int32)))
     h = _rms(h, params["final_norm"], c.rms_norm_eps)
-    if need_rows is not None:
-        # lean epilogue: gather the needed rows FIRST — the unembed
-        # matmul and everything downstream run at (N, ...), and the
-        # (T, vocab) buffer never exists in this program
-        idx = jnp.maximum(need_rows, 0)
-        need_on = need_rows >= 0
-        h = h[idx]
-        tok_slot = tok_slot[idx]
-        tok_pos = tok_pos[idx]
-        row_on = need_on & (tok_pos >= 0)
-    logits = h @ params["lm_head"]                       # (T|N, V)
+    # the epilogue: gather the needed rows FIRST — the unembed matmul
+    # and everything downstream run at (N, ...), and no (T, vocab)
+    # buffer exists in this program
+    idx = jnp.maximum(need_rows, 0)
+    need_on = need_rows >= 0
+    h = h[idx]
+    tok_slot = tok_slot[idx]
+    tok_pos = tok_pos[idx]
+    row_on = need_on & (tok_pos >= 0)
+    logits = h @ params["lm_head"]                       # (N, V)
     if sample is None:
         return k_pool, v_pool, k_scale, v_scale, logits
     rec = _sample_flat(logits, tok_slot, tok_pos, row_on, sample)
@@ -776,7 +762,7 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
         # rows park on an out-of-bounds slot and drop.
         B = tok_buf.shape[0]
         wslot = jnp.where(buf_write & row_on, tok_slot, B)
-        # tok_pos/tok_slot are already in epilogue space here (the lean
+        # tok_pos/tok_slot are already in epilogue space here (the
         # gather above re-indexed them), matching rec's rows
         pos_w = jnp.maximum(tok_pos, 0)
         tok_buf = tok_buf.at[wslot, pos_w + 1].set(
@@ -901,13 +887,12 @@ class PipelineStall(RuntimeError):
 
 
 class StepTicket:
-    """One launched-but-unconsumed decode step: the device-resident
-    result record plus the host metadata needed to apply it one step
-    later. `reqs` maps slot -> the Request that occupied it at launch;
-    `step_finish` applies a slot's result only while that identity
-    still holds (a slot released/reused in between makes the in-flight
-    result a discarded zombie), and marks a finishing slot's entry None
-    in the NEXT ticket so its overrun token is never emitted."""
+    """One launched-but-unconsumed bucketed decode step: the
+    device-resident result record plus the host metadata needed to
+    apply it. `reqs` maps slot -> the Request that occupied it at
+    launch; `step_finish` applies a slot's result only while that
+    identity still holds. A bucketed engine is driven synchronously, so
+    the ticket is consumed before the next launch."""
 
     __slots__ = ("slots", "reqs", "next_tok", "done", "logprob")
 
@@ -920,9 +905,13 @@ class StepTicket:
 
 
 class RaggedTicket:
-    """One launched-but-unconsumed `unified_step` wave. Same contract
-    as StepTicket (zombie checks, carry, eos length rollback) with the
-    record FLAT: `flat` maps a decode slot to its buffer row, `seeds`
+    """One launched-but-unconsumed `unified_step` wave, applied one
+    step later under the deep pump: `step_finish` applies a slot's
+    result only while `reqs[slot]` still occupies it (a slot released
+    or reused in between makes the in-flight result a discarded
+    zombie) and marks a finishing slot's entry None in the NEXT ticket,
+    rolling its length back, so its overrun token is never emitted.
+    The record is FLAT: `flat` maps a decode slot to its buffer row, `seeds`
     lists (slot, req) whose prefill completed this wave — their
     first-token logits rows ride `seed_rows` and are picked HOST-side
     at finish (the PR 8 seeding convention). `aux` is what the model's
@@ -1041,8 +1030,7 @@ def _llama_step(params, caches, tables, tokens, tok_slot, tok_pos, config,
     ((k, v, ks, vs),), = caches
     out = unified_step(params, k, v, tables[0], tokens, tok_slot, tok_pos,
                        config, page_size, k_scale=ks, v_scale=vs, **kw)
-    return ((tuple(out[:4]),),), out[4], out[5], \
-        (out[6] if kw.get("tok_buf") is not None else None), {}
+    return ((tuple(out[:4]),),), out[4], out[5], out[6], {}
 
 
 def llama_serving_model(config: LlamaConfig):
@@ -1055,8 +1043,7 @@ def llama_serving_model(config: LlamaConfig):
                            c.num_key_value_heads,
                            c.hidden_size // c.num_attention_heads),),
         q_group=c.num_attention_heads // c.num_key_value_heads,
-        step=_llama_step,
-        in_place=True)        # `unified_step` donates its pools
+        step=_llama_step)
 
 
 class _GroupCache:
@@ -1165,10 +1152,10 @@ class ServingEngine:
     key (fold_in(seed_key, position)) and returns a compact
     (next_token, done, logprob) record — the host transfer is a few
     ints per slot, never a `[vocab]` row. `step_launch`/`step_finish`
-    split the step so a pipelined driver (the scheduler's
-    double-buffered pump, or `run_pipelined`) can consume step N's
-    record while step N+1 — fed step N's tokens directly from the
-    device record — is already running.
+    split the step so a pipelined driver (the scheduler's pump over a
+    ragged engine, or `run_pipelined`) can consume step N's record
+    while step N+1 — fed step N's tokens from the device token ring —
+    is already running.
 
     `host_tier_bytes>0` (serving/kvtier.py; docs/serving.md § KV-cache
     tiering) adds a bounded host-RAM tier under that LRU: evictions
@@ -1187,8 +1174,8 @@ class ServingEngine:
                  spec_decode=0, spec_ngram=2, chunked_prefill=False,
                  spec_sample=False, mesh=None, prefix_cache=False,
                  host_tier_bytes=0, tier_quantize=True, faults=None,
-                 ragged=None, ragged_tokens=None, lean=None,
-                 block_q=None, block_pages=None, tokbuf=None, device=None):
+                 ragged=None, ragged_tokens=None, block_q=None,
+                 block_pages=None, device=None):
         c = config
         _compile.ensure_compile_cache()
         # the model seam (serving/model_spec.py): the configuration says
@@ -1202,8 +1189,7 @@ class ServingEngine:
                 ("tensor_parallel", tp_), ("prefix_cache", prefix_cache),
                 ("host_tier", host_tier_bytes),
                 ("spec_decode", int(spec_decode) > 1 or chunked_prefill),
-                ("bucketed", ragged is not None and not ragged),
-                ("host_tokens", tokbuf is not None and not tokbuf)):
+                ("bucketed", ragged is not None and not ragged)):
             if asked and feature in model.unsupported:
                 raise ValueError(model.unsupported[feature])
         # mesh with a 'tp' axis: tensor-parallel serving — weights get
@@ -1361,25 +1347,19 @@ class ServingEngine:
         self.ragged_runs = 0
         self.ragged_kv_blocks = 0
         self.last_rows = (0, 0)
-        # lean row-sparse lm_head epilogue (docs/serving.md § Lean
-        # epilogue): every unified/verify dispatch passes a `need_rows`
-        # descriptor and the (T, vocab) logits buffer is never
-        # materialized — only the rows a wave actually samples, seeds,
-        # or rejection-tests pay unembed FLOPs. Token- and logprob-
-        # identical to the full epilogue; default ON (PT_SERVE_LEAN=0
-        # or lean=False restores full logits for A/B baselines).
-        if lean is None:
-            lean = os.environ.get("PT_SERVE_LEAN", "1") not in ("", "0")
-        self.lean = bool(lean)
-        # the lean need-row buffer: a wave needs at most one sampled
-        # row per decoding slot (x chunk width G under spec) plus one
-        # seed row per prefilling slot — and a slot is never both, so
-        # max_seqs * G bounds it. Fixed shape => zero retrace as the
-        # mix changes.
+        # the row-sparse lm_head epilogue (docs/serving.md § The
+        # epilogue): every unified dispatch and the suffix prefill pass a
+        # `need_rows` descriptor and no (T, vocab) logits buffer exists
+        # — only the rows a wave actually samples, seeds, or
+        # rejection-tests pay unembed FLOPs. A wave needs at most one
+        # sampled row per decoding slot (x chunk width G under spec)
+        # plus one seed row per prefilling slot — and a slot is never
+        # both, so max_seqs * G bounds it. Fixed shape => zero retrace
+        # as the mix changes.
         self.need_buf = max_seqs * G_
         # pt_logit_rows_total / pt_logit_rows_skipped_total telemetry:
-        # unembed rows actually computed vs rows the lean epilogue
-        # avoided (full engines skip nothing)
+        # unembed rows actually computed vs dispatched rows the
+        # epilogue never unembedded
         self.logit_rows = 0
         self.logit_rows_skipped = 0
         # ragged kernel tile, q rows a block x pages a KV block
@@ -1406,19 +1386,15 @@ class ServingEngine:
         # a slot CONSUMES at cache position p. `unified_step` gathers
         # its embedding input from it (host ships only slot/pos
         # descriptors) and scatters each wave's sampled tokens back
-        # in-jit, replacing the pipelined-carry operands. Host writes
-        # ride two fixed-shape jitted setters (`_tokbuf_stage` at
-        # admission/restore/import, `_tokbuf_poke` for host-picked
-        # seeds) — zero retrace. Ragged plain-decode engines only: the
-        # spec verify chunk keeps host-fed token values.
-        # PT_SERVE_TOKBUF=0 (or tokbuf=False) restores the host token
-        # path for A/B baselines.
-        if tokbuf is None:
-            tokbuf = os.environ.get("PT_SERVE_TOKBUF", "1") \
-                not in ("", "0")
+        # in-jit, which is what lets wave N+1 launch before wave N is
+        # read. Host writes ride two fixed-shape jitted setters
+        # (`_tokbuf_stage` at admission/restore/import, `_tokbuf_poke`
+        # for host-picked seeds) — zero retrace. Ragged plain-decode
+        # engines only: the spec verify chunk keeps host-fed token
+        # values.
         self.tok_buf = jnp.zeros((max_seqs, max_seq_len + 1), jnp.int32,
                                  device=self.device) \
-            if tokbuf and self.ragged and self.spec_decode <= 1 else None
+            if self.ragged and self.spec_decode <= 1 else None
         # optional telemetry sink (paddle_tpu.serving.metrics
         # EngineMetrics duck type): the step loop reports TTFT/TPOT,
         # occupancy, page stats, and preemptions into it. None = free.
@@ -2422,12 +2398,14 @@ class ServingEngine:
             m.set_pipeline_depth(depth)
 
     def step_launch(self, carry=None, _admitted=False):
-        """Admission + page growth + ONE decode_step dispatch, with NO
-        device read: returns a StepTicket whose result record is still
-        on device (None when nothing runs). `carry` is the previous,
-        still-unconsumed ticket — continuing slots take their input
-        token from its device record (`carry_mask` inside the step), so
-        the host launches step N+1 knowing nothing about step N.
+        """Admission + page growth + ONE step dispatch, with NO device
+        read: returns a ticket whose result record is still on device
+        (None when nothing runs). `carry` is the previous, still-
+        unconsumed ticket of a RAGGED engine — continuing slots take
+        their input token from the device token ring, so the host
+        launches step N+1 knowing nothing about step N. A bucketed
+        engine's step returns new pools and is driven synchronously:
+        launch, finish, launch.
 
         A carried slot that will exhaust max_new_tokens in the
         in-flight step is NOT launched (its finish is host-predictable);
@@ -2437,6 +2415,9 @@ class ServingEngine:
         victim's pending token is still in flight."""
         if self.ragged:
             return self._ragged_launch(carry=carry, _admitted=_admitted)
+        if carry is not None:
+            raise ValueError("a bucketed engine is driven synchronously: "
+                             "finish the step in flight before the next")
         if not _admitted:
             self._sweep_cancelled()
             self._harvest_handoffs()
@@ -2450,10 +2431,6 @@ class ServingEngine:
             if cur % self.page_size == 0 and cur > 0 and \
                     len(self._seq_pages[s]) * self.page_size <= cur:
                 while not self.pool.can_alloc(1):
-                    if carry is not None:
-                        raise PipelineStall(
-                            "page growth needs a preemption victim "
-                            "with a step in flight")
                     if not self._preempt_one(exclude=s):
                         raise RuntimeError(
                             "serving: KV page pool exhausted with a "
@@ -2465,7 +2442,6 @@ class ServingEngine:
             return None
         B = self.max_seqs
         tokens = np.zeros((B,), np.int32)
-        carry_mask = np.zeros((B,), bool)
         temps = np.zeros((B,), np.float32)
         top_ks = np.zeros((B,), np.int32)
         top_ps = np.ones((B,), np.float32)
@@ -2475,17 +2451,9 @@ class ServingEngine:
         launch, reqs = [], {}
         for s in sorted(self._live):
             req = self._slots[s]
-            carried = carry is not None and carry.reqs.get(s) is req
-            left = req.max_new_tokens - len(req.output) \
-                - (1 if carried else 0)
-            if left <= 0:
-                continue  # the in-flight step emits its last token
             launch.append(s)
             reqs[s] = req
-            if carried:
-                carry_mask[s] = True
-            else:
-                tokens[s] = req.next_token
+            tokens[s] = req.next_token
             temps[s] = req.temperature
             top_ks[s] = req.top_k
             top_ps[s] = req.top_p
@@ -2493,9 +2461,7 @@ class ServingEngine:
                 keys[s] = req._base_key
             if req.eos_id is not None:
                 eos[s] = int(req.eos_id)
-            remaining[s] = left
-        if not launch:
-            return None  # every occupied slot is finishing in flight
+            remaining[s] = req.max_new_tokens - len(req.output)
         active = np.zeros((B,), bool)
         active[launch] = True
         self.lengths = np.where(active, self.lengths + 1, self.lengths)
@@ -2505,15 +2471,10 @@ class ServingEngine:
                   "key": jnp.asarray(keys),
                   "eos": jnp.asarray(eos),
                   "remaining": jnp.asarray(remaining)}
-        # always pass the carry operands (zeros when none): an arity
-        # flip between the first pipelined launch and the rest would be
-        # a second trace signature for no reason
-        c_tok = carry.next_tok if carry is not None \
-            else jnp.zeros((B,), jnp.int32)
         # fault point: one hit per decode dispatch, with the launched
         # request ids so rid-scoped rules can model a poison request
         self._fire("step_launch", rids=[str(reqs[s].rid) for s in launch])
-        self._note_launch_gap(1 if carry is not None else 0)
+        self._note_launch_gap(0)
         # bucketed decode is one row per slot already — no rows to skip
         self.logit_rows += B
         # page_table/lengths go to the device as SNAPSHOTS (.copy(), a
@@ -2532,9 +2493,7 @@ class ServingEngine:
                 jnp.asarray(tokens), jnp.asarray(active),
                 self.config, self.page_size, use_pallas=self._use_pallas,
                 interpret=self._interpret, k_scale=self.k_scale,
-                v_scale=self.v_scale, mesh=self._mesh,
-                sample=sample, carry_tok=c_tok,
-                carry_mask=jnp.asarray(carry_mask))
+                v_scale=self.v_scale, mesh=self._mesh, sample=sample)
         self._t_launch_end = time.perf_counter()
         self.device_steps += 1
         return StepTicket(launch, reqs, rec[0], rec[1], rec[2])
@@ -2543,11 +2502,11 @@ class ServingEngine:
         """Consume a launched step: ONE batched transfer of a few ints
         per slot (the device already sampled and evaluated the stop
         conditions), then the host bookkeeping. `inflight` is the
-        ticket launched AFTER this one (pipelined pump): a slot that
-        finishes here already ran one step past its end in `inflight`,
-        so its entry there is zombied and its length rolled back —
-        release/indexing then see exactly the synchronous loop's
-        state."""
+        ticket launched AFTER this one (a ragged engine under the deep
+        pump): a slot that finishes here already ran one step past its
+        end in `inflight`, so its entry there is zombied and its length
+        rolled back — release/indexing then see exactly the synchronous
+        loop's state."""
         if self.ragged:
             return self._ragged_finish(ticket, inflight=inflight)
         self._fire("step_finish",
@@ -2568,9 +2527,6 @@ class ServingEngine:
             if bool(done[s]):
                 self.finished.append(req)
                 self._note_finish(req)
-                if inflight is not None and inflight.reqs.get(s) is req:
-                    inflight.reqs[s] = None
-                    self.lengths[s] -= 1
                 self._release(s)
         self._note_step(len(ticket.slots))
         return len(ticket.slots)
@@ -2595,61 +2551,43 @@ class ServingEngine:
             plan = self._ragged_plan(carry)
         if plan is None:
             return None
-        (tokens, tok_slot, tok_pos, carry_mask, carry_gather, sampling,
-         need, flat, reqs, seeds, seed_flat, n_decode, slots) = plan
-        T = self.ragged_buf
+        (tokens, tok_slot, tok_pos, sampling, need, flat, reqs, seeds,
+         n_decode, slots) = plan
         with record_span("serving.stage", part="dispatch"):
             # every host->device transfer of the wave, a dozen small
             # arrays, BEFORE the launch-gap stamp: the gap then ends
             # where the dispatch starts
             sample = {k: jnp.asarray(v) for k, v in sampling.items()}
-            need_rows = None if need is None else jnp.asarray(need)
+            need_rows = jnp.asarray(need)
             # page_table goes to the device as a SNAPSHOT (.copy()):
             # see the bucketed `step_launch`
             tables = tuple(jnp.asarray(gc.table.copy())
                            for gc in self._caches)
             staged = (jnp.asarray(tokens), jnp.asarray(tok_slot),
                       jnp.asarray(tok_pos))
-            if self.tok_buf is not None:
-                # device token ring: no carry operands (the ring's
-                # in-jit scatter/gather IS the carry) — decode rows
-                # write their sampled token for the next wave to read
-                bw = np.zeros((self.need_buf if self.lean else T,),
-                              bool)
-                bw[:n_decode] = True
-                carry_kw = {"tok_buf": self.tok_buf,
-                            "buf_write": jnp.asarray(bw)}
-            else:
-                carry_kw = {
-                    "carry_tok": carry.next_tok if carry is not None
-                    else jnp.zeros((self.need_buf if self.lean else T,),
-                                   jnp.int32),
-                    "carry_gather": jnp.asarray(carry_gather),
-                    "carry_mask": jnp.asarray(carry_mask)}
+            # device token ring: decode rows write their sampled token
+            # for the next wave to read
+            bw = np.zeros((self.need_buf,), bool)
+            bw[:n_decode] = True
+            buf_write = jnp.asarray(bw)
         self._note_launch_gap(1 if carry is not None else 0)
         with record_span("serving.unified_step", part="dispatch",
                          ring=True):
-            caches, logits, rec, tok_buf, aux = self.model.step(
+            caches, logits, rec, self.tok_buf, aux = self.model.step(
                 self.params, tuple(gc.device() for gc in self._caches),
                 tables, *staged, self.config, self.page_size,
                 use_pallas=self._use_pallas, interpret=self._interpret,
                 sample=sample, need_rows=need_rows,
                 block_q=self._block_q, block_pages=self._block_pages,
-                **carry_kw)
+                tok_buf=self.tok_buf, buf_write=buf_write)
         for gc, got in zip(self._caches, caches):
             gc.take(got)
-        if self.tok_buf is not None:
-            self.tok_buf = tok_buf
         seed_rows = None
         if seeds:
             with record_span("serving.seed_gather", part="dispatch"):
-                if need_rows is not None:
-                    # lean: seed rows were gathered into need positions
-                    # n_decode.. — the (T, vocab) buffer never existed
-                    seed_rows = logits[jnp.arange(
-                        n_decode, n_decode + len(seeds), dtype=jnp.int32)]
-                else:
-                    seed_rows = logits[jnp.asarray(seed_flat, jnp.int32)]
+                # seed rows were gathered into need positions n_decode..
+                seed_rows = logits[jnp.arange(
+                    n_decode, n_decode + len(seeds), dtype=jnp.int32)]
         self._t_launch_end = time.perf_counter()
         self.device_steps += 1
         return RaggedTicket(reqs, flat, rec[0], rec[1], rec[2], seeds,
@@ -2734,7 +2672,7 @@ class ServingEngine:
                 - (1 if carried else 0)
             if left <= 0:
                 continue  # the in-flight step emits its last token
-            decode_plan.append((s, req, carried, left))
+            decode_plan.append((s, req, left))
         # plan prefill feeds into the remaining buffer rows, growing
         # pages for every real chunk position now
         room = self.ragged_buf - len(decode_plan)
@@ -2762,8 +2700,6 @@ class ServingEngine:
         tokens = np.zeros((T,), np.int32)
         tok_slot = np.zeros((T,), np.int32)
         tok_pos = np.full((T,), -1, np.int32)
-        carry_mask = np.zeros((T,), bool)
-        carry_gather = np.zeros((T,), np.int32)
         temps = np.zeros((B,), np.float32)
         top_ks = np.zeros((B,), np.int32)
         top_ps = np.ones((B,), np.float32)
@@ -2772,19 +2708,12 @@ class ServingEngine:
         remaining = np.ones((B,), np.int32)
         flat, reqs = {}, {}
         row = 0
-        for s, req, carried, left in decode_plan:
+        for s, req, left in decode_plan:
             tok_slot[row] = s
             tok_pos[row] = int(self.lengths[s])
-            if self.tok_buf is None:
-                if carried:
-                    carry_mask[row] = True
-                    carry_gather[row] = carry.flat[s]
-                else:
-                    tokens[row] = req.next_token
-            # tokbuf engines ship NO token values: the row's token is
-            # device-resident (staged at admission, scattered by the
-            # previous wave, or poked at seeding) — which also subsumes
-            # the pipelined carry gather
+            # NO token values are shipped: the row's token is
+            # device-resident in the ring (staged at admission,
+            # scattered by the previous wave, or poked at seeding)
             temps[s] = req.temperature
             top_ks[s] = req.top_k
             top_ps[s] = req.top_p
@@ -2799,10 +2728,8 @@ class ServingEngine:
             row += 1
         seeds, seed_flat = [], []
         for s, req, n in prefill_plan:
-            feed, cur = req._pf_feed, req._pf_cursor
+            feed = req._pf_feed
             base = int(self.lengths[s])
-            if self.tok_buf is None:
-                tokens[row:row + n] = feed[cur:cur + n]
             tok_slot[row:row + n] = s
             tok_pos[row:row + n] = base + np.arange(n, dtype=np.int32)
             req._pf_cursor += n
@@ -2861,24 +2788,20 @@ class ServingEngine:
         self.last_rows = (n_decode, row - n_decode)
         sampling = {"temp": temps, "top_k": top_ks, "top_p": top_ps,
                     "key": keys, "eos": eos, "remaining": remaining}
-        need = None
-        if self.lean:
-            # need-row descriptor: decode rows sit at buffer rows
-            # 0..n_decode-1 (so flat[s] doubles as the need index) and
-            # completed-prefill seed rows follow; -1 pads the fixed
-            # shape, so the mix changing never retraces
-            need = np.full((self.need_buf,), -1, np.int32)
-            need[:n_decode] = np.arange(n_decode, dtype=np.int32)
-            need[n_decode:n_decode + len(seed_flat)] = seed_flat
-            self.logit_rows += self.need_buf
-            self.logit_rows_skipped += T - self.need_buf
-        else:
-            self.logit_rows += T
+        # need-row descriptor: decode rows sit at buffer rows
+        # 0..n_decode-1 (so flat[s] doubles as the need index) and
+        # completed-prefill seed rows follow; -1 pads the fixed shape,
+        # so the mix changing never retraces
+        need = np.full((self.need_buf,), -1, np.int32)
+        need[:n_decode] = np.arange(n_decode, dtype=np.int32)
+        need[n_decode:n_decode + len(seed_flat)] = seed_flat
+        self.logit_rows += self.need_buf
+        self.logit_rows_skipped += T - self.need_buf
         self._fire("step_launch",
                    rids=[str(p[1].rid) for p in decode_plan] +
                         [str(p[1].rid) for p in prefill_plan])
-        return (tokens, tok_slot, tok_pos, carry_mask, carry_gather,
-                sampling, need, flat, reqs, seeds, seed_flat, n_decode,
+        return (tokens, tok_slot, tok_pos, sampling, need, flat, reqs,
+                seeds, n_decode,
                 sorted([p[0] for p in decode_plan] +
                        [p[0] for p in prefill_plan]))
 
@@ -3057,28 +2980,21 @@ class ServingEngine:
                 fpos[b:b + n] = int(self.lengths[s]) + \
                     np.arange(n, dtype=np.int32)
             self.ragged_tokens += row
-            need_desc = cand = None
-            if self.lean:
-                # lean epilogue: the wave's rows ARE the needed rows
-                # (every chunk position feeds the verify record), so
-                # the descriptor is the identity over the packed rows
-                # — the unembed runs at need_buf rows, never T. cand
-                # carries each row's FOLLOWING draft token so the
-                # rejection sampler's accept tests ride the record.
-                need = np.full((self.need_buf,), -1, np.int32)
-                need[:row] = np.arange(row, dtype=np.int32)
-                need_desc = jnp.asarray(need)
-                cand_np = np.zeros((self.need_buf,), np.int32)
-                for s in active_slots:
-                    n = int(n_tok[s])
-                    if n > 1:
-                        cand_np[base[s]:base[s] + n - 1] = tokens[s, 1:n]
-                cand = jnp.asarray(cand_np)
-                self.logit_rows += self.need_buf
-                self.logit_rows_skipped += T - self.need_buf
-            else:
-                self.logit_rows += T
-            c_shape = self.need_buf if self.lean else T
+            # the wave's rows ARE the needed rows (every chunk position
+            # feeds the verify record), so the descriptor is the
+            # identity over the packed rows — the unembed runs at
+            # need_buf rows, never T. cand carries each row's FOLLOWING
+            # draft token so the rejection sampler's accept tests ride
+            # the record.
+            need = np.full((self.need_buf,), -1, np.int32)
+            need[:row] = np.arange(row, dtype=np.int32)
+            cand_np = np.zeros((self.need_buf,), np.int32)
+            for s in active_slots:
+                n = int(n_tok[s])
+                if n > 1:
+                    cand_np[base[s]:base[s] + n - 1] = tokens[s, 1:n]
+            self.logit_rows += self.need_buf
+            self.logit_rows_skipped += T - self.need_buf
             with record_span("serving.unified_step"):
                 (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
                  logits, rec) = unified_step(
@@ -3089,10 +3005,8 @@ class ServingEngine:
                     use_pallas=self._use_pallas,
                     interpret=self._interpret, k_scale=self.k_scale,
                     v_scale=self.v_scale, sample=sample,
-                    carry_tok=jnp.zeros((c_shape,), jnp.int32),
-                    carry_gather=jnp.zeros((T,), jnp.int32),
-                    carry_mask=jnp.zeros((T,), bool),
-                    need_rows=need_desc, cand_tok=cand,
+                    need_rows=jnp.asarray(need),
+                    cand_tok=jnp.asarray(cand_np),
                     block_q=self._block_q,
                     block_pages=self._block_pages)
             self._t_launch_end = time.perf_counter()
@@ -3100,20 +3014,12 @@ class ServingEngine:
             self._fire("step_finish",
                        rids=[str(self._slots[s].rid)
                              for s in active_slots])
-            need_idx = np.concatenate(
-                [np.arange(base[s], base[s] + int(n_tok[s]),
-                           dtype=np.int32) for s in need_rows]) \
-                if need_rows and not self.lean else None
             seed_idx = [base[s] + int(n_tok[s]) - 1 for s in seed_slots]
-            # lean narrowing: the sampling slots' pull is rec[3]'s
-            # candidate probabilities (a float per draft) instead of
-            # vocab rows; divergence/final rows come lazily through
-            # `_spec_row_dist`
-            tok_f, lp_f, row_f, cand_f, seed_vals = self._fetch_results(
-                (rec[0], rec[2],                          # (T|N,) each
-                 logits[jnp.asarray(need_idx)]
-                 if need_idx is not None else None,
-                 rec[3] if self.lean else None,
+            # the sampling slots' pull is rec[3]'s candidate
+            # probabilities (a float per draft), never vocab rows;
+            # divergence/final rows come lazily through `_spec_row_dist`
+            tok_f, lp_f, cand_f, seed_vals = self._fetch_results(
+                (rec[0], rec[2], rec[3],                  # (N,) each
                  logits[jnp.asarray(seed_idx, jnp.int32)]
                  if seed_slots else None))
             grid = np.zeros((self.max_seqs, G), np.int64)
@@ -3122,27 +3028,19 @@ class ServingEngine:
                 n = int(n_tok[s])
                 grid[s, :n] = tok_f[base[s]:base[s] + n]
                 lp_grid[s, :n] = lp_f[base[s]:base[s] + n]
-            rows_by_slot, cand_by_slot = {}, {}
-            if row_f is not None:
-                off = 0
-                for s in need_rows:
-                    n = int(n_tok[s])
-                    rows_by_slot[s] = row_f[off:off + n]
-                    off += n
-            if cand_f is not None:
-                for s in need_rows:
-                    n = int(n_tok[s])
-                    cand_by_slot[s] = cand_f[base[s]:base[s] + n - 1]
+            cand_by_slot = {
+                s: cand_f[base[s]:base[s] + int(n_tok[s]) - 1]
+                for s in need_rows}
             flat_logits = logits
             row_of = {s: base[s] for s in active_slots}
             seed_rows = {} if seed_vals is None else \
                 dict(zip(seed_slots, seed_vals))
         else:
             cand = None
-            if self.lean and need_rows:
-                # bucketed narrowing: the verify grid's record carries
-                # candidate probabilities, so sampling slots pull
-                # (B, G) floats instead of (n, V) vocab rows
+            if need_rows:
+                # the verify grid's record carries candidate
+                # probabilities, so sampling slots pull (B, G) floats
+                # instead of (n, V) vocab rows
                 cand_np = np.zeros((self.max_seqs, G), np.int32)
                 for s in need_rows:
                     n = int(n_tok[s])
@@ -3167,19 +3065,14 @@ class ServingEngine:
             self._fire("step_finish",
                        rids=[str(self._slots[s].rid)
                              for s in active_slots])
-            grid, lp_grid, row_vals, cand_vals, seed_vals = \
+            grid, lp_grid, cand_vals, seed_vals = \
                 self._fetch_results(
                     (grid_dev, lp_dev,                    # (B, G) each
-                     logits[jnp.asarray(need_rows, jnp.int32)]
-                     if need_rows and cand is None else None,
                      rec[2] if cand is not None else None,
                      logits[jnp.asarray(seed_slots, jnp.int32),
                             jnp.asarray([int(n_tok[s]) - 1
                                          for s in seed_slots], jnp.int32)]
                      if seed_slots else None))
-            rows_by_slot = {} if row_vals is None else \
-                {s: row_vals[i][:int(n_tok[s])]
-                 for i, s in enumerate(need_rows)}
             cand_by_slot = {} if cand_vals is None else \
                 {s: cand_vals[s, :int(n_tok[s]) - 1] for s in need_rows}
             V = logits.shape[-1]
@@ -3199,26 +3092,17 @@ class ServingEngine:
                 if req._pf_cursor >= len(req._pf_feed) and req._pf_sample:
                     self._seed_first_token(s, req, seed_rows[s])
                 continue
-            rows = rows_by_slot.get(s)
             if req.temperature > 0.0 and n > 1:
-                # speculative sampling: distributionally exact; rows
-                # filter lazily (rejection at g touches g+1 rows only).
-                # Lean engines accept against the record's candidate
-                # probabilities and materialize a distribution row
+                # speculative sampling: distributionally exact. The
+                # accept tests ride the record's candidate
+                # probabilities; a distribution row is materialized
                 # (device-filtered, `_spec_row_dist`) only on
                 # divergence or the final draw.
-                if s in cand_by_slot:
-                    outs, a = speculative_sample(
-                        lambda g: self._spec_row_dist(
-                            flat_logits, row_of[s] + g, req),
-                        tokens[s, 1:n], req.rng,
-                        cand_probs=cand_by_slot[s])
-                else:
-                    outs, a = speculative_sample(
-                        lambda g: filtered_probs_np(
-                            rows[g], req.temperature,
-                            req.top_k, req.top_p),
-                        tokens[s, 1:n], req.rng)
+                outs, a = speculative_sample(
+                    lambda g: self._spec_row_dist(
+                        flat_logits, row_of[s] + g, req),
+                    tokens[s, 1:n], req.rng,
+                    cand_probs=cand_by_slot[s])
             elif req.temperature > 0.0:
                 # un-drafted sampled slot: the device already drew the
                 # token with the SAME (seed, position) key the plain
@@ -3237,12 +3121,10 @@ class ServingEngine:
                 req.output.append(tok)
                 req.next_token = tok
                 if req.want_logprobs:
-                    if rows is not None:
-                        req.note_logprob(tok, rows[j])
-                    elif s in cand_by_slot:
-                        # lean sampled slot: pull THIS emission's raw
-                        # row (logprobs opt-in pays per-token, the
-                        # default path stays narrow)
+                    if s in cand_by_slot:
+                        # sampled slot: pull THIS emission's raw row
+                        # (logprobs opt-in pays per-token, the default
+                        # path stays narrow)
                         req.note_logprob(tok, self._fetch_results(
                             flat_logits[row_of[s] + j]))
                     else:
@@ -3471,15 +3353,11 @@ class ServingEngine:
         active = np.zeros((self.max_seqs,), bool)
         active[slot] = True
         self._fire("suffix_prefill", rids=[str(req.rid)])
-        need = None
-        if self.lean:
-            # lean epilogue: only the chunk's final row seeds the first
-            # generated token — one row of unembed FLOPs, not B*G
-            need = jnp.asarray([slot * G + n - 1], jnp.int32)
-            self.logit_rows += 1
-            self.logit_rows_skipped += self.max_seqs * G - 1
-        else:
-            self.logit_rows += self.max_seqs * G
+        # only the chunk's final row seeds the first generated token —
+        # one row of unembed FLOPs, not B*G
+        need = jnp.asarray([slot * G + n - 1], jnp.int32)
+        self.logit_rows += 1
+        self.logit_rows_skipped += self.max_seqs * G - 1
         with record_span("serving.prefill"):
             (self.k_pool, self.v_pool, self.k_scale, self.v_scale,
              logits) = verify_step(
@@ -3501,9 +3379,8 @@ class ServingEngine:
         if getattr(req, "_resume", False):
             req._resume = False  # next_token survives from before eviction
         else:
-            row = self._fetch_results(
-                logits[0] if need is not None else logits[slot, n - 1])
-            self._seed_first_token(slot, req, row)
+            self._seed_first_token(slot, req,
+                                   self._fetch_results(logits[0]))
 
     def run(self, max_steps=10000):
         steps = 0
@@ -3519,11 +3396,12 @@ class ServingEngine:
         the host bookkeeping overlaps the in-flight device program.
         Token-identical to `run()` — greedy and seeded sampling both,
         because sampling happens inside the step keyed by (seed,
-        position). Spec-decode engines fall back to the synchronous
-        loop (drafting needs host-current context). Cancellation must
-        only be applied between consumed steps — drive cancels through
-        the scheduler, which drains the pipeline first."""
-        if self.spec_decode > 1:
+        position). Bucketed engines (their step returns new pools) and
+        spec-decode engines (drafting needs host-current context) fall
+        back to the synchronous loop. Cancellation must only be applied
+        between consumed steps — drive cancels through the scheduler,
+        which drains the pipeline first."""
+        if not self.ragged or self.spec_decode > 1:
             return self.run(max_steps=max_steps)
         pending = None
         steps = 0

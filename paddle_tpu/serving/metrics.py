@@ -352,7 +352,7 @@ class EngineMetrics:
         self.pipeline_depth = r.gauge(
             "pt_pipeline_depth",
             "Device steps in flight beyond the one the host has "
-            "consumed (1 = double-buffered pump, 0 = synchronous).")
+            "consumed (1 = the pump one step deep, 0 = synchronous).")
         self.queue_depth = r.gauge(
             "pt_serving_queue_depth", "Requests waiting for a slot.")
         self.queue_depth_peak = r.gauge(
@@ -390,8 +390,7 @@ class EngineMetrics:
             "lm_head logit rows computed by serving device programs.")
         self.logit_rows_skipped = r.counter(
             "pt_logit_rows_skipped",
-            "Logit rows the lean row-sparse epilogue skipped (0 with "
-            "PT_SERVE_LEAN=0).")
+            "Dispatched rows the row-sparse epilogue never unembedded.")
         # what the ragged kernel has to do (ISSUE 25), from the same
         # row descriptors: its needed operations and bytes are these
         # times the model's head sizes

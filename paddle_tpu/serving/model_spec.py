@@ -55,19 +55,14 @@ class ServingModel:
     returns `(caches, logits, rec, tok_buf, aux)`, `aux` a dict of
     small device arrays the step's record carries beside the tokens
     (`moe_rows`: a sparse layer x the rows each expert got).
+    `step` DONATES `caches` and the pools come back where they lay
+    (`unified_step`, `laguna_step`; held to the compiled programs by
+    `tests/test_tpu_lowering.py`), so a second step in flight needs no
+    further copy of them: that is what lets the scheduler run a ragged
+    engine one step deep. A caller rebinds the pools from the result.
     `unsupported`: engine feature -> why this model cannot run under it;
-    the engine refuses at construction with that reason.
-    `in_place`: a fact about `step`, not a wish: it donates `caches` and
-    the pools come back where they lay (`laguna_step`; `unified_step`
-    since ROADMAP [donate-pools]). A second step in flight then needs
-    no further copy of them, so the scheduler's pump is one step deep
-    for an engine that runs such a step unless told otherwise
-    (`RequestScheduler`). A step that returns its pools as new buffers
-    (the default here, for a model yet to come) would hold a third copy
-    of them while two steps are in flight, and the runtime makes the
-    second launch wait for that memory."""
+    the engine refuses at construction with that reason."""
     groups: Tuple[CacheGroup, ...]
     q_group: int
     step: Callable
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
-    in_place: bool = False

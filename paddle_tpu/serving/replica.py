@@ -66,8 +66,8 @@ class Replica:
     host = None
 
     def __init__(self, replica_id, engine, *, max_queue=64,
-                 metrics=None, idle_poll_s=0.02, pipeline=None,
-                 role="both", **sched_kw):
+                 metrics=None, idle_poll_s=0.02, role="both",
+                 **sched_kw):
         self.replica_id = str(replica_id)
         if role not in self.ROLES:
             raise ValueError(
@@ -78,7 +78,7 @@ class Replica:
         self.scheduler = RequestScheduler(engine, max_queue=max_queue,
                                           metrics=registry,
                                           idle_poll_s=idle_poll_s,
-                                          pipeline=pipeline, **sched_kw)
+                                          **sched_kw)
 
     # -- identity / introspection -------------------------------------
     @property
@@ -186,8 +186,7 @@ class Replica:
 
 
 def build_replicas(engine_factory, n, *, max_queue=64, prefix="r",
-                   idle_poll_s=0.02, pipeline=None, roles=None,
-                   **sched_kw):
+                   idle_poll_s=0.02, roles=None, **sched_kw):
     """N independent replicas from an engine factory. The factory is
     called once per replica — each gets its own KV pool, prefix cache,
     scheduler, and metrics registry (`engine_factory(i) ->
@@ -208,6 +207,6 @@ def build_replicas(engine_factory, n, *, max_queue=64, prefix="r",
             engine = engine_factory(i)
         replicas.append(Replica(f"{prefix}{i}", engine,
                                 max_queue=max_queue,
-                                idle_poll_s=idle_poll_s, pipeline=pipeline,
-                                role=roles[i], **sched_kw))
+                                idle_poll_s=idle_poll_s, role=roles[i],
+                                **sched_kw))
     return replicas

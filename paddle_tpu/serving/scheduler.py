@@ -185,39 +185,19 @@ class RequestScheduler:
     """Thread-safe frontend over one ServingEngine (see module doc)."""
 
     def __init__(self, engine, max_queue=64, metrics=None,
-                 idle_poll_s=0.02, start=True, pipeline=None,
-                 poison_after=3, max_restarts=5, restart_window_s=10.0,
+                 idle_poll_s=0.02, start=True, poison_after=3,
+                 max_restarts=5, restart_window_s=10.0,
                  breaker_retry_after_s=1.0):
         self._engine = engine
-        # pipeline=True: double-buffered pump (docs/serving.md
-        # § Pipelined step loop) — launch device step N+1 before
-        # consuming step N's result record, so host bookkeeping and
-        # next-wave admission overlap the in-flight device program.
-        # Unasked (pipeline=None, PT_SERVE_PIPELINE unset) the pump is
-        # one step deep exactly where a second step in flight costs no
-        # memory: the engine runs the model's step (`engine.ragged`) and
-        # that step writes its page pools in place
-        # (`ServingModel.in_place`: `laguna_step`, and `unified_step`
-        # since ROADMAP [donate-pools]). A step that returns new pools
-        # makes a launch beside a running step wait for a third copy of
-        # them (v5e, Mistral-7B x 16 layers before the donation: peak
-        # 13.97 -> 15.59 GB, the launch blocked in the allocator, itl
-        # p99 +15%; PERF.md Findings, PR 30): the bucketed entry points
-        # (`decode_step`, `verify_step`: ragged=False, tensor-parallel
-        # engines) donate nothing and keep the synchronous pump.
-        # Spec-decode engines stay synchronous (drafting needs
-        # host-current context); slow-path events (cancel/TTL/preempt/
-        # failure/shutdown) drain the one-step-deep pipeline before
-        # acting, so every mode is token-identical to the synchronous
-        # pump.
-        if pipeline is None:
-            model = getattr(engine, "model", None)
-            pipeline = env_bool(
-                "PT_SERVE_PIPELINE",
-                default=bool(getattr(model, "in_place", False)
-                             and getattr(engine, "ragged", False)))
-        self._pipeline = bool(pipeline) and \
-            getattr(engine, "spec_decode", 0) <= 1
+        # The pump runs one step deep (docs/serving.md § Pipelined step
+        # loop: step N+1 launched before step N's record is consumed)
+        # for a ragged, non-speculative engine, and synchronously
+        # otherwise: a bucketed step returns new pools, so a second one
+        # in flight would wait for a third copy of them, and drafting
+        # needs host-current context. Slow-path events (cancel/TTL/
+        # preempt/failure/shutdown) drain the step in flight before
+        # acting, so both are token-identical.
+        self._pipeline = engine.ragged and engine.spec_decode <= 1
         # the launched-but-unconsumed StepTicket; pump-thread only
         # (written outside the lock by design — _expire_and_cancel
         # just reads it to defer engine-side cancel application)
@@ -918,15 +898,13 @@ class RequestScheduler:
 
     def _step_pipelined(self):
         """One pipelined pump turn: launch step N+1 FIRST (its input
-        tokens come from step N's device record via the carry mask),
-        then consume step N — the host bookkeeping overlaps the device
-        executing N+1. Page-growth preemption raises PipelineStall
-        inside the launch (the victim's pending token is still on
-        device): drain, then relaunch against host-current state.
-        Tickets are opaque here: a ragged engine hands back
-        RaggedTickets (every wave is ONE `unified_step` dispatch,
-        prefill and decode mixed), a bucketed one StepTickets — the
-        pump logic is identical for both."""
+        tokens come from the device token ring, where step N put
+        them), then consume step N — the host bookkeeping overlaps the
+        device executing N+1. Page-growth preemption raises
+        PipelineStall inside the launch (the victim's pending token is
+        still on device): drain, then relaunch against host-current
+        state. Tickets are opaque here (every wave is ONE step
+        dispatch, prefill and decode mixed)."""
         from ..models.llama_serving import PipelineStall
         eng = self._engine
         try:

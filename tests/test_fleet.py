@@ -487,6 +487,13 @@ class TestFleetPages:
                 return block, key
         raise AssertionError("no owned block found")
 
+    @staticmethod
+    def _until(cond, deadline):
+        """A counter on the far side of a socket is bumped after the
+        bytes it counts are visible on this side: poll, do not assume."""
+        while not cond() and time.monotonic() < deadline:
+            time.sleep(0.05)
+
     def test_spill_lands_at_owner_and_fetch_returns(self, make_fleet):
         fl = make_fleet(("prefill", "prefill"),
                         host_tier_bytes=10_000)
@@ -510,6 +517,10 @@ class TestFleetPages:
         assert landed is not None and landed["block"] == block
         np.testing.assert_array_equal(landed["payload"]["k"],
                                       payload["k"])
+        # the page is visible at B before B counts it, and B's
+        # acknowledgement is what A counts on: wait for the books too
+        self._until(lambda: (wa.pages.spill_pages.value,
+                             wb.pages.recv_pages.value) == (1, 1), deadline)
         assert wa.pages.spill_pages.value == 1
         assert wa.pages.spill_bytes.value > 0
         assert wb.pages.recv_pages.value == 1
@@ -520,6 +531,9 @@ class TestFleetPages:
         assert len(got) == 1
         np.testing.assert_array_equal(got[0]["k"], payload["k"])
         assert wa.pages.fetch_pages.value == 1
+        # (B counts a serve once the last array is sent, A has it by then)
+        self._until(lambda: wb.pages.page_serves.value == 1,
+                    time.monotonic() + 15)
         assert wb.pages.page_serves.value == 1
         # fetched page is now local: the next match is a pure local hit
         assert len(wa.replica.engine.host_tier.match(tokens, 0)) == 1

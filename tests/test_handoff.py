@@ -4,7 +4,8 @@ here:
 
   * a prefill+decode topology is TOKEN-IDENTICAL to the greedy
     reference across plain / int8 / prefix / chunked engine modes,
-    under both the sync and the pipelined pump, with every request
+    under both the synchronous pump (bucketed engines) and the deep
+    one (ragged engines), with every request
     actually migrating (exports > 0 and prefill-side ledgers closing
     as "handoff");
   * page-ledger conservation under handoff: exported pages leave the
@@ -20,9 +21,9 @@ here:
   * the router refuses to drain the LAST prefill-eligible replica of a
     non-empty pool (queued work would strand behind decode-only
     replicas), while draining the very last replica stays allowed;
-  * the in-jit token-embedding gather (device token ring): tokbuf
-    engines are token-identical to the host-fed carry path and a mix
-    change never retraces `serving.unified_step`.
+  * the in-jit token-embedding gather (device token ring): ring
+    engines are token-identical to the bucketed, host-fed engine and a
+    mix change never retraces `serving.unified_step`.
 """
 import jax.numpy as jnp
 import pytest
@@ -81,11 +82,15 @@ def assert_drained_conserved(rep):
 def run_disagg(params, prompts, n_new=6, roles=("prefill", "decode"),
                pipeline=False, faults_for=None, **engine_kw):
     """Submit `prompts` through a 2-replica router, return
-    (router, reps, outputs) with the router still up."""
+    (router, reps, outputs) with the router still up. The pump follows
+    the engines: `pipeline` asks for ragged ones (one step deep unless
+    speculative), else bucketed ones (synchronous)."""
     reps = build_replicas(make_factory(params, faults_for=faults_for,
-                                       **engine_kw),
-                          2, roles=list(roles), max_queue=len(prompts),
-                          pipeline=pipeline)
+                                       ragged=pipeline, **engine_kw),
+                          2, roles=list(roles), max_queue=len(prompts))
+    for rep in reps:
+        assert rep.scheduler._pipeline is (
+            pipeline and not engine_kw.get("spec_decode"))
     router = Router(reps)
     handles = [router.submit(p, max_new_tokens=n_new) for p in prompts]
     outs = [h.result(timeout=120) for h in handles]
@@ -266,22 +271,30 @@ class TestRouterRoles:
 
 class TestTokbufGather:
     """Satellite 1: the in-jit token-embedding gather from the device
-    token ring (PT_SERVE_TOKBUF)."""
+    token ring, which every ragged non-speculative engine has."""
 
-    @pytest.mark.parametrize("pipeline", [False, True])
-    def test_tokbuf_token_identical(self, params, pipeline):
+    @pytest.mark.parametrize("driver", ["engine", "scheduler"])
+    def test_tokbuf_token_identical(self, params, driver):
+        """The ring engine (no token value shipped) against the bucketed
+        one (every token host-fed), bare and behind the scheduler."""
         from paddle_tpu.serving.scheduler import RequestScheduler
 
         outs = {}
-        for tokbuf in (False, True):
+        for ring in (False, True):
             eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
                                 page_size=PAGE, use_pallas=False,
-                                prefix_cache=True, tokbuf=tokbuf)
-            assert (eng.tok_buf is not None) == tokbuf
-            sched = RequestScheduler(eng, max_queue=8,
-                                     pipeline=pipeline)
+                                prefix_cache=True, ragged=ring)
+            assert (eng.tok_buf is not None) == ring
+            if driver == "engine":
+                for i, p in enumerate(PROMPTS):
+                    eng.submit(Request(i, p, max_new_tokens=6))
+                done = {r.rid: r.output for r in eng.run_pipelined()}
+                outs[ring] = [done[i] for i in range(len(PROMPTS))]
+                continue
+            sched = RequestScheduler(eng, max_queue=8)
+            assert sched._pipeline is ring
             srs = [sched.submit(p, max_new_tokens=6) for p in PROMPTS]
-            outs[tokbuf] = [sr.result(timeout=120) for sr in srs]
+            outs[ring] = [sr.result(timeout=120) for sr in srs]
             sched.shutdown(drain=True, timeout=60)
         assert outs[True] == outs[False]
         for p, o in zip(PROMPTS, outs[True]):
@@ -293,8 +306,7 @@ class TestTokbufGather:
         from paddle_tpu.observability.compile_telemetry import REGISTRY
 
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
-                            page_size=PAGE, use_pallas=False,
-                            tokbuf=True)
+                            page_size=PAGE, use_pallas=False)
         assert eng.tok_buf is not None
         eng.submit(Request("warm", [1, 2, 3], max_new_tokens=2))
         eng.run()
@@ -309,4 +321,4 @@ class TestTokbufGather:
         fns = REGISTRY.snapshot()
         fns = fns.get("functions", fns)
         assert fns["serving.unified_step"]["compiles"] == before, \
-            "tokbuf mix change retraced unified_step"
+            "a mix change retraced unified_step"

@@ -174,7 +174,6 @@ def test_the_steps_record_counts_the_experts_rows(served):
     (dict(prefix_cache=True, host_tier_bytes=1 << 20), "prefix_cache"),
     (dict(spec_decode=4), "spec_decode"),
     (dict(ragged=False), "bucketed"),
-    (dict(tokbuf=False), "host_tokens"),
     (dict(num_pages=64), "pages apart"),
     (dict(num_pages={"full": 65, "ring": 9}), "ring"),
 ])
@@ -311,65 +310,73 @@ def _first_pool_after_a_step(eng, submit):
     return k0
 
 
-@pytest.mark.parametrize("family", ["laguna", "llama"])
-def test_in_place_is_a_fact_of_the_step_and_decides_the_unasked_pump(
-        model, monkeypatch, family):
-    """`ServingModel.in_place` says what the program does: Laguna's step
-    and, since [donate-pools], Llama's `unified_step` donate their pools
-    (the array that held one is gone after a step). Unasked, the
-    scheduler then runs one step deep, a second step in flight holding no
-    further copy of the pools; the environment and the argument overrule
-    both ways."""
-    import dataclasses
+def _llama_engine(**kw):
     import jax.numpy as jnp
     from paddle_tpu.models import llama_spmd
     from paddle_tpu.models.llama import LlamaConfig
-    from paddle_tpu.models.llama_serving import Request, ServingEngine
-    from paddle_tpu.serving import RequestScheduler
+    from paddle_tpu.models.llama_serving import ServingEngine
+    cfg = LlamaConfig.tiny(vocab=64, hidden=32, layers=2, heads=4,
+                           kv_heads=2)
+    return ServingEngine(
+        llama_spmd.init_params(cfg, seed=0, dtype=jnp.float32), cfg,
+        max_seqs=2, max_seq_len=64, page_size=8, use_pallas=False, **kw)
+
+
+@pytest.mark.parametrize("family", ["laguna", "llama"])
+def test_the_step_donates_its_pools(model, family):
+    """`ServingModel.step`'s contract: Laguna's step and Llama's
+    `unified_step` donate their pools (the array that held one is gone
+    after a step), so a second step in flight holds no further copy of
+    them."""
+    from paddle_tpu.models.llama_serving import Request
     if family == "laguna":
-        m, params = model
-        make = lambda: engine(m, params)                     # noqa: E731
+        eng = engine(*model)
         submit = lambda e: [e.submit(r)                      # noqa: E731
                             for r in requests([(5, 4)])]
     else:
-        cfg = LlamaConfig.tiny(vocab=64, hidden=32, layers=2, heads=4,
-                               kv_heads=2)
-        lp = llama_spmd.init_params(cfg, seed=0, dtype=jnp.float32)
-        make = lambda: ServingEngine(                        # noqa: E731
-            lp, cfg, max_seqs=2, max_seq_len=64, page_size=8,
-            use_pallas=False)
+        eng = _llama_engine()
         submit = lambda e: e.submit(                         # noqa: E731
             Request("a", [1, 2, 3], max_new_tokens=4))
-    eng = make()
-    assert eng.model.in_place is True
     assert _first_pool_after_a_step(eng, submit).is_deleted()
-    monkeypatch.delenv("PT_SERVE_PIPELINE", raising=False)
-    assert RequestScheduler(make(), start=False)._pipeline is True
-    copies = make()     # a step that returned new pools: synchronous
-    copies.model = dataclasses.replace(copies.model, in_place=False)
-    assert RequestScheduler(copies, start=False)._pipeline is False
-    for env in ("0", "1"):
-        monkeypatch.setenv("PT_SERVE_PIPELINE", env)
-        assert RequestScheduler(make(), start=False)._pipeline is (env == "1")
-        assert RequestScheduler(make(), start=False,
-                                pipeline=env == "0")._pipeline is (env == "0")
 
 
-@pytest.mark.parametrize("pipeline", [False, True],
-                         ids=["sync", "one_step_deep"])
-def test_behind_the_scheduler_either_pump_serves_the_engines_tokens(
-        model, served, monkeypatch, pipeline):
-    """Unasked, the scheduler's pump is one step deep for a step that
-    writes its pools in place (the test below); asked for either pump, it
-    serves the same tokens."""
+@pytest.mark.parametrize("family, kw, deep", [
+    ("llama", dict(), True),
+    ("llama", dict(ragged=False), False),
+    ("llama", dict(spec_decode=4), False),
+    ("laguna", dict(), True),
+], ids=["llama-ragged", "llama-bucketed", "llama-spec", "laguna"])
+def test_the_pump_follows_what_the_engine_is(model, monkeypatch, family, kw,
+                                             deep):
+    """The one rule: one step deep for a ragged, non-speculative engine
+    (a bucketed step returns new pools; drafting needs host-current
+    context), and no variable of the environment has a say."""
+    from paddle_tpu.serving import RequestScheduler
+    make = _llama_engine if family == "llama" else \
+        (lambda **k: engine(*model, **k))
+    assert RequestScheduler(make(**kw), start=False)._pipeline is deep
+    monkeypatch.setenv("PT_SERVE_PIPELINE", "0" if deep else "1")
+    assert RequestScheduler(make(**kw), start=False)._pipeline is deep
+
+
+@pytest.mark.parametrize("cache", [None, "int8"], ids=["bf16", "int8"])
+def test_behind_the_scheduler_the_deep_pump_serves_the_sync_loops_tokens(
+        model, cache):
+    """The scheduler drives this engine one step deep (the rule above);
+    `engine.run()` is the synchronous loop. Both serve the same tokens,
+    over plain and over int8 pages."""
     from paddle_tpu.serving import RequestScheduler
     m, params = model
-    sched = RequestScheduler(engine(m, params), pipeline=pipeline)
-    assert sched._pipeline is pipeline
+    eng, reqs = engine(m, params, cache_dtype=cache), requests(MIX)
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    sched = RequestScheduler(engine(m, params, cache_dtype=cache))
+    assert sched._pipeline is True
     try:
         handles = [sched.submit(r.prompt, max_new_tokens=r.max_new_tokens,
-                                eos_id=None) for r in served[1]]
+                                eos_id=None) for r in reqs]
         outs = [[t for chunk in h.stream() for t in chunk] for h in handles]
     finally:
         sched.shutdown(drain=False, timeout=60)
-    assert outs == [r.output for r in served[1]]
+    assert outs == [r.output for r in reqs]

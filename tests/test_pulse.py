@@ -462,7 +462,7 @@ class TestStallCaptureE2E:
         sched = RequestScheduler(
             _engine(params, faults=FaultPlan(
                 "step_launch:delay@30:delay=0.5")),
-            max_queue=8, metrics=MetricsRegistry(), pipeline=True)
+            max_queue=8, metrics=MetricsRegistry())
         srv = ServingServer(sched, port=0).start()
         try:
             cl = ServingClient(port=srv.port, timeout=300)

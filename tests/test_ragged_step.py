@@ -549,6 +549,39 @@ class TestRaggedTelemetry:
         assert fns["serving.unified_step"]["compiles"] == before, \
             "mix change retraced unified_step"
 
+    @pytest.mark.parametrize("mode", ["plain", "int8", "prefix"])
+    def test_one_program_serves_all_of_a_modes_traffic(self, params,
+                                                       monkeypatch, mode):
+        """One serving step (ISSUE 37): whatever a ragged engine is
+        asked to serve, every wave calls `unified_step` with ONE
+        signature, arrays' shapes and static arguments alike, so it
+        compiles exactly one program. Seen at the call, not in the
+        process-wide registry, which other tests' engines share."""
+        from paddle_tpu.models import llama_serving
+        from paddle_tpu.observability.compile_telemetry import signature_of
+        real, sigs = llama_serving.unified_step, []
+
+        def spy(*a, **kw):
+            sigs.append(signature_of(a, kw))
+            return real(*a, **kw)
+        monkeypatch.setattr(llama_serving, "unified_step", spy)
+        eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
+                            page_size=8, use_pallas=False, **MODES[mode])
+        assert eng.ragged and eng.tok_buf is not None
+        head = list(range(1, 18))           # two whole pages to share
+        eng.submit(Request("a", head + [20], max_new_tokens=6))
+        eng.submit(Request("b", [5], max_new_tokens=9, temperature=0.7,
+                           top_k=4, seed=3, logprobs=True))
+        eng.run_pipelined()
+        eng.submit(Request("c", head + [21, 22], max_new_tokens=4))
+        eng.submit(Request("d", [8] * 7, max_new_tokens=3, logprobs=True))
+        eng.submit(Request("e", [3, 1], max_new_tokens=12))
+        done = eng.run()
+        assert len(done) == 5 and len(sigs) > 12
+        assert len(set(sigs)) == 1, set(sigs)
+        if mode == "prefix":
+            assert eng.prefix_cache.tokens_reused >= 16
+
     def test_pad_counters(self, params):
         """ragged: zero pad tokens ever booked, ragged rows counted;
         bucketed: the same workload pads. Counters surface through
@@ -635,11 +668,14 @@ class TestFaultDrill:
     N = 4
 
     def _drill(self, params, pipelined):
+        # the pump follows the engine: bucketed -> synchronous, ragged
+        # -> one step deep
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
-                            page_size=8, use_pallas=False, ragged=True)
+                            page_size=8, use_pallas=False,
+                            ragged=pipelined)
         sched = RequestScheduler(eng, max_queue=16,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=pipelined)
+                                 metrics=MetricsRegistry())
+        assert sched._pipeline is pipelined
         sched.pause()
         hs = [sched.submit([1 + i, 5, 9, 3], rid=f"r{i}",
                            max_new_tokens=8) for i in range(self.N)]
@@ -671,89 +707,106 @@ class TestFaultDrill:
 
 
 # ---------------------------------------------------------------------------
-# Lean row-sparse lm_head epilogue (ISSUE 12)
+# The row-sparse lm_head epilogue (ISSUE 12)
 # ---------------------------------------------------------------------------
-class TestLeanEpilogue:
-    """lean=True (the default) vs lean=False at equal config: tokens
-    AND logprobs identical, the step program strictly cheaper, the
-    skipped unembed rows booked in pt_logit_rows(_skipped)."""
+class TestEpilogue:
+    """`need_rows` is the ragged step's epilogue: tokens AND logprobs
+    equal the bucketed entry points', which unembed every row they are
+    given (`decode_step`, `verify_step`: an independent full-logits
+    implementation); the program holds no (T, vocab) buffer; the
+    skipped unembed rows are booked in pt_logit_rows(_skipped)."""
 
-    def _run(self, params, lean, kw, pipelined, spec_workload):
+    def _run(self, params, ragged, kw, pipelined, spec_workload):
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
-                            page_size=8, use_pallas=False, ragged=True,
-                            lean=lean, **kw)
+                            page_size=8, use_pallas=False, ragged=ragged,
+                            **kw)
         if spec_workload:
-            # spec modes draft off n-gram repeats; the lean engine
-            # consumes device candidate probs in the rejection sampler
-            # (a documented sampling-trajectory change, docs/serving.md
-            # § Speculative row narrowing), so the lean-vs-full
-            # identity contract is asserted on the greedy verify path
-            eng.submit(Request("g0", [1, 5, 1, 5, 1, 5], max_new_tokens=8))
+            # n-gram repeats, so the greedy verify path really drafts
+            eng.submit(Request("g0", [1, 5, 1, 5, 1, 5], max_new_tokens=8,
+                               logprobs=True))
             eng.submit(Request("g1", [9, 9, 9, 2], max_new_tokens=8,
                                logprobs=True))
             eng.submit(Request("g2", [2, 4, 2, 4, 2], max_new_tokens=8,
                                logprobs=True))
         else:
-            _submit_mixed(eng)
+            # `_submit_mixed`'s requests, every one with its logprobs
+            eng.submit(Request("g0", [1, 5, 9, 3, 7], max_new_tokens=8,
+                               logprobs=True))
+            eng.submit(Request("s0", [2, 4, 6], max_new_tokens=8,
+                               temperature=0.8, top_k=8, top_p=0.9,
+                               seed=123, logprobs=True))
+            eng.submit(Request("g1", [9, 9, 2], max_new_tokens=8,
+                               logprobs=True))
+            eng.submit(Request("s1", [7, 1], max_new_tokens=8,
+                               temperature=1.1, seed=7, logprobs=True))
         done = eng.run_pipelined() if pipelined else eng.run()
         return eng, _outputs(done)
 
     @pytest.mark.parametrize("mode,pipelined", _PARAMS)
-    def test_lean_equals_full(self, params, mode, pipelined):
+    def test_need_rows_equal_the_bucketed_full_logits(self, params, mode,
+                                                      pipelined):
         kw = MODES[mode]
         spec_workload = bool(kw.get("spec_decode"))
-        outs = []
-        for lean in (False, True):
-            eng, out = self._run(params, lean, kw, pipelined,
-                                 spec_workload)
-            if lean:
-                assert eng.logit_rows_skipped > 0
+        full_eng, full = self._run(params, False, kw, pipelined,
+                                   spec_workload)
+        eng, sparse = self._run(params, True, kw, pipelined, spec_workload)
+        assert eng.logit_rows_skipped > 0
+        if spec_workload:
+            assert eng.spec_accepted > 0
+        elif mode == "plain":
+            # bucketed decode is a row a slot: it has none to skip
+            assert full_eng.logit_rows_skipped == 0
+        assert sorted(full) == sorted(sparse) and len(full) >= 3
+        for rid, (toks, lps) in full.items():
+            s_toks, s_lps = sparse[rid]
+            assert toks == s_toks, f"mode {mode} rid {rid} diverged"
+            assert len(s_lps) == len(toks)
+            if mode == "int8":
+                # int8 dequantizes inside the ragged attention kernel
+                # but ahead of it in the bucketed one
+                assert np.allclose(lps, s_lps, atol=1e-3), rid
             else:
-                assert eng.logit_rows_skipped == 0
-            outs.append(out)
-        for rid, (toks, lps) in outs[0].items():
-            l_toks, l_lps = outs[1][rid]
-            assert toks == l_toks, f"mode {mode} rid {rid} diverged"
-            assert lps == l_lps, f"mode {mode} rid {rid} logprobs"
+                assert lps == s_lps, f"mode {mode} rid {rid} logprobs"
 
-    def test_lean_under_preemption(self, params):
-        outs = []
-        for lean in (False, True):
-            eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=32,
-                                page_size=8, num_pages=6,
-                                use_pallas=False, ragged=True, lean=lean)
-            eng.submit(Request("s", [3, 7, 2, 9], max_new_tokens=20,
-                               temperature=0.8, top_k=8, seed=123))
-            eng.submit(Request("g", [1, 4, 6, 2], max_new_tokens=20))
-            done = eng.run(max_steps=500)
-            assert eng.preemptions > 0
-            outs.append({r.rid: r.output for r in done})
-        assert outs[0] == outs[1]
+    def test_step_program_holds_no_full_logits(self):
+        """An absolute statement about the one program: what
+        `unified_step` returns as logits is `(need_buf, vocab)`, and no
+        value anywhere in its jaxpr is `(T, vocab)`, with T the flat
+        buffer's rows, over twice `need_buf` here."""
+        import jax
+        from paddle_tpu.models import llama_serving
+        # a vocabulary no other width of the model shares
+        cfg = LlamaConfig.tiny(vocab=96, hidden=32, layers=2, heads=4,
+                               kv_heads=2, ffn=64, seq=128)
+        eng = ServingEngine(M.init_params(cfg, seed=0, dtype=jnp.float32),
+                            cfg, max_seqs=2, max_seq_len=64, page_size=8,
+                            use_pallas=False, ragged=True, ragged_tokens=16)
+        T, N, V = eng.ragged_buf, eng.need_buf, cfg.vocab_size
+        assert (T, N) == (16, 2)
+        real, seen = eng.model.step, []
 
-    def test_step_program_strictly_cheaper(self, params):
-        """The whole point, asserted at the XLA cost-analysis layer:
-        the lean `unified_step` issues FEWER flops AND touches fewer
-        bytes than the full one on the same workload — the (T, vocab)
-        unembed buffer is gone, not merely masked."""
-        from paddle_tpu.observability import device_telemetry as _dt
-
-        def step_cost(lean):
-            eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
-                                page_size=8, use_pallas=False,
-                                ragged=True, lean=lean)
-            zero = {"flops": 0.0, "bytes": 0.0}
-            mark = _dt.COSTS.issued_totals()["per_fn"].get(
-                "serving.unified_step", zero)
-            _submit_mixed(eng)
-            eng.run()
-            now = _dt.COSTS.issued_totals()["per_fn"][
-                "serving.unified_step"]
-            return (now["flops"] - mark["flops"],
-                    now["bytes"] - mark["bytes"])
-
-        full, lean = step_cost(False), step_cost(True)
-        assert 0 < lean[0] < full[0], (lean, full)
-        assert 0 < lean[1] < full[1], (lean, full)
+        def step(*a, **kw):
+            seen.append((a, kw))
+            return real(*a, **kw)
+        object.__setattr__(eng.model, "step", step)
+        _submit_mixed(eng)
+        eng.step()
+        (params_, caches, tables, *rows, config, page_size), kw = seen[0]
+        assert kw["need_rows"].shape == (N,)
+        ((k, v, ks, vs),), = caches
+        spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+        static = {n: kw.pop(n) for n in ("use_pallas", "interpret",
+                                         "block_q", "block_pages")}
+        jaxpr, out = jax.make_jaxpr(
+            lambda *a, **kws: llama_serving.unified_step.__wrapped__
+            .__wrapped__(*a, config, page_size, k_scale=ks, v_scale=vs,
+                         **static, **kws), return_shape=True)(
+            *jax.tree_util.tree_map(spec, (params_, k, v, tables[0], *rows)),
+            **jax.tree_util.tree_map(spec, kw))
+        assert out[4].shape == (N, V)
+        text = str(jaxpr)       # nested jaxprs (the layer scan) included
+        assert f"[{T},{cfg.hidden_size}]" in text      # the flat rows
+        assert f"[{N},{V}]" in text and f"[{T},{V}]" not in text
 
     def test_row_ledger_reaches_metrics(self, params):
         """pt_logit_rows / pt_logit_rows_skipped mirror the engine's
@@ -761,7 +814,6 @@ class TestLeanEpilogue:
         `_total` suffix."""
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
                             page_size=8, use_pallas=False, ragged=True)
-        assert eng.lean   # PT_SERVE_LEAN defaults ON
         reg = MetricsRegistry()
         sched = RequestScheduler(eng, max_queue=8, metrics=reg)
         hs = [sched.submit([1 + i, 5, 9], rid=f"r{i}",
@@ -785,8 +837,7 @@ class TestLeanEpilogue:
         `serving.unified_step` trace."""
         from paddle_tpu.observability.compile_telemetry import REGISTRY
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
-                            page_size=8, use_pallas=False, ragged=True,
-                            lean=True)
+                            page_size=8, use_pallas=False, ragged=True)
         eng.submit(Request("warm", [1, 2, 3], max_new_tokens=2))
         eng.run()
         before = REGISTRY.snapshot()["serving.unified_step"]["compiles"]

@@ -155,10 +155,12 @@ class TestChaosDrillHTTP:
     N = 5
 
     def _drill(self, params, faults, pipeline):
-        eng = _engine(params, faults=faults)
+        # the pump follows the engine: ragged -> one step deep,
+        # bucketed -> synchronous
+        eng = _engine(params, faults=faults, ragged=pipeline)
         sched = RequestScheduler(eng, max_queue=32,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=pipeline)
+                                 metrics=MetricsRegistry())
+        assert sched._pipeline is pipeline
         srv = ServingServer(sched, port=0).start()
         cl = ServingClient(port=srv.port)
         sched.pause()
@@ -221,10 +223,9 @@ class TestChaosDrillHTTP:
 # ---------------------------------------------------------------------------
 class TestPoisonQuarantine:
     def _run(self, params, faults, pipeline, poison_after=2):
-        eng = _engine(params, faults=faults)
+        eng = _engine(params, faults=faults, ragged=pipeline)
         sched = RequestScheduler(eng, max_queue=16,
                                  metrics=MetricsRegistry(),
-                                 pipeline=pipeline,
                                  poison_after=poison_after,
                                  max_restarts=50)
         sched.pause()
@@ -462,12 +463,12 @@ class TestOtherFaultPoints:
         token-identically."""
         outs = []
         for spec in (None, "step_finish:raise@3"):
-            eng = _engine(params,
+            eng = _engine(params, ragged=pipeline,
                           faults=None if spec is None
                           else FaultPlan(spec))
             sched = RequestScheduler(eng, max_queue=8,
-                                     metrics=MetricsRegistry(),
-                                     pipeline=pipeline)
+                                     metrics=MetricsRegistry())
+            assert sched._pipeline is pipeline
             sched.pause()
             hs = [sched.submit([2 + i, 7, 1], max_new_tokens=8,
                                **({"temperature": 0.7, "seed": 42}

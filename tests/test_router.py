@@ -449,9 +449,10 @@ class TestSchedulerLedger:
 
     def test_ledger_counts_lifecycle(self, params):
         from paddle_tpu.serving import RequestScheduler
-        eng = make_factory(params)(0)
-        # the synchronous pump: it is `eng.step` that dies below
-        sched = RequestScheduler(eng, max_queue=8, pipeline=False)
+        # a bucketed engine, so the synchronous pump: it is `eng.step`
+        # that dies below
+        eng = make_factory(params, ragged=False)(0)
+        sched = RequestScheduler(eng, max_queue=8)
         try:
             sched.submit([1, 2, 3], max_new_tokens=3).result(timeout=60)
             sched.submit([4, 5, 6], max_new_tokens=3).result(timeout=60)

@@ -524,8 +524,10 @@ class TestSpeculativeDecoding:
         seq_logits = []
         for g in range(3):
             lens[0] += 1
+            # a snapshot: the dispatch may read a numpy operand in place
+            # after it returns, and the next turn of this loop writes it
             ks, vs, _, _, lg = decode_step(
-                eng.params, ks, vs, eng.page_table, lens,
+                eng.params, ks, vs, eng.page_table, lens.copy(),
                 jnp.asarray([chunk[g], 0], jnp.int64), active, CFG,
                 eng.page_size, use_pallas=False)
             seq_logits.append(lg[0])

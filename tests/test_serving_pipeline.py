@@ -161,17 +161,19 @@ class TestTokenIdentity:
                 {"one": 1, "two": 6}
 
     def test_seeded_sampling_reproducible_across_pumps(self, params):
-        """Same seed -> same trajectory, and the scheduler pumps agree
-        with the bare engine drivers."""
+        """Same seed -> same trajectory, and the scheduler's pumps (the
+        synchronous one over a bucketed engine, the deep one over a
+        ragged engine) agree with the bare engine drivers."""
         ref = None
         for driver in ("run", "run_pipelined", "sched", "sched_pipe"):
             if driver.startswith("sched"):
                 eng = ServingEngine(params, CFG, max_seqs=2,
                                     max_seq_len=64, page_size=8,
-                                    use_pallas=False)
+                                    use_pallas=False,
+                                    ragged=driver == "sched_pipe")
                 sch = RequestScheduler(eng, max_queue=8,
-                                       metrics=MetricsRegistry(),
-                                       pipeline=driver == "sched_pipe")
+                                       metrics=MetricsRegistry())
+                assert sch._pipeline is (driver == "sched_pipe")
                 h = sch.submit([2, 4, 6], max_new_tokens=10,
                                temperature=0.8, top_k=8, top_p=0.9,
                                seed=123)
@@ -188,6 +190,71 @@ class TestTokenIdentity:
             if ref is None:
                 ref = out
             assert out == ref, driver
+
+
+class TestOneStepOneRule:
+    """ISSUE 37: nothing is left to choose between serving steps or
+    between pumps; what decides is what the engine is."""
+
+    @pytest.mark.parametrize("target,word", [
+        ("ServingEngine", "lean"), ("ServingEngine", "tokbuf"),
+        ("RequestScheduler", "pipeline"), ("Replica", "pipeline"),
+        ("build_replicas", "pipeline"), ("_env", "PT_SERVE_LEAN"),
+        ("_env", "PT_SERVE_TOKBUF"), ("_env", "PT_SERVE_PIPELINE")])
+    def test_no_switch_is_left(self, params, monkeypatch, target, word):
+        import inspect
+        from paddle_tpu import _env
+        from paddle_tpu.serving.replica import build_replicas
+        if target == "_env":
+            assert not _env.is_declared(word)
+            # and setting it changes nothing an engine or a pump is
+            monkeypatch.setenv(word, "0")
+            eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
+                                page_size=8, use_pallas=False)
+            assert eng.tok_buf is not None
+            assert RequestScheduler(eng, start=False)._pipeline is True
+            return
+        fn = {"ServingEngine": ServingEngine,
+              "RequestScheduler": RequestScheduler, "Replica": Replica,
+              "build_replicas": build_replicas}[target]
+        assert word not in inspect.signature(fn).parameters
+
+        def make(**kw):
+            return ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
+                                 page_size=8, use_pallas=False, **kw)
+        with pytest.raises(TypeError, match=word):
+            if target == "ServingEngine":
+                make(**{word: True})
+            elif target == "RequestScheduler":
+                RequestScheduler(make(), start=False, **{word: True})
+            elif target == "Replica":
+                # (what it does not know it hands to its scheduler)
+                Replica("r0", make(), start=False, **{word: True})
+            else:
+                build_replicas(lambda i: make(), 1, start=False,
+                               **{word: True})
+
+    def test_run_pipelined_on_a_bucketed_engine_is_run(self, params):
+        """A bucketed step returns new pools, so it is never driven one
+        step deep: `run_pipelined()` is `run()` there, tokens, logprobs
+        and device steps, and no launch ever saw a step in flight."""
+        outs = []
+        for driver in ("run", "run_pipelined"):
+            eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
+                                page_size=8, use_pallas=False,
+                                ragged=False)
+            depths = []
+            note = eng._note_launch_gap
+            eng._note_launch_gap = lambda d, note=note, depths=depths: (
+                depths.append(d), note(d))
+            _submit_mixed(eng)
+            done = getattr(eng, driver)()
+            assert len(done) == 4 and set(depths) == {0}
+            outs.append((_outputs(done), eng.device_steps))
+        assert outs[0] == outs[1]
+        with pytest.raises(ValueError, match="synchronously"):
+            eng.submit(Request("x", [1, 2], max_new_tokens=4))
+            eng.step_launch(carry=eng.step_launch())
 
 
 class TestDeviceSampler:
@@ -320,8 +387,7 @@ class TestPipelineDraining:
     def test_cancel_with_step_in_flight(self, params):
         eng = self._engine(params)
         sched = RequestScheduler(eng, max_queue=8,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=True)
+                                 metrics=MetricsRegistry())
         h = sched.submit([1, 2, 3], max_new_tokens=400)
         # stream a few chunks so the pipeline is demonstrably rolling
         got = []
@@ -351,8 +417,7 @@ class TestPipelineDraining:
     def test_ttl_expiry_with_step_in_flight(self, params):
         eng = self._engine(params)
         sched = RequestScheduler(eng, max_queue=8,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=True)
+                                 metrics=MetricsRegistry())
         h = sched.submit([4, 5, 6], max_new_tokens=400, ttl_s=0.25)
         with pytest.raises(DeadlineExceededError):
             h.result(timeout=30)
@@ -367,7 +432,8 @@ class TestPipelineDraining:
         sched.shutdown(drain=True, timeout=30)
 
     def test_replica_kill_with_step_in_flight(self, params):
-        rep = Replica("r0", self._engine(params), pipeline=True)
+        rep = Replica("r0", self._engine(params))
+        assert rep.scheduler._pipeline
         h = rep.submit([7, 8, 9], max_new_tokens=400)
         # wait until it is demonstrably mid-decode
         deadline = time.time() + 15
@@ -396,8 +462,7 @@ class TestPipelineDraining:
         exactly once."""
         eng = self._engine(params)
         sched = RequestScheduler(eng, max_queue=8,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=True)
+                                 metrics=MetricsRegistry())
         h = sched.submit([1, 2, 3], max_new_tokens=400)
         deadline = time.time() + 15
         while not h.output and time.time() < deadline:
@@ -418,8 +483,7 @@ class TestPipelineDraining:
     def test_shutdown_drains_pipeline(self, params):
         eng = self._engine(params)
         sched = RequestScheduler(eng, max_queue=8,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=True)
+                                 metrics=MetricsRegistry())
         hs = [sched.submit([i + 1, 2], max_new_tokens=20)
               for i in range(4)]
         assert sched.shutdown(drain=True, timeout=60)
@@ -433,8 +497,7 @@ class TestPipelineMetrics:
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
                             page_size=8, use_pallas=False)
         sched = RequestScheduler(eng, max_queue=8,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=True)
+                                 metrics=MetricsRegistry())
         hs = [sched.submit([i + 1, 2, 3], max_new_tokens=12)
               for i in range(3)]
         [h.result(timeout=60) for h in hs]
@@ -447,11 +510,12 @@ class TestPipelineMetrics:
         sched.shutdown(drain=True, timeout=30)
 
     def test_sync_pump_reports_depth_zero(self, params):
+        # a bucketed engine: the scheduler drives it synchronously
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
-                            page_size=8, use_pallas=False)
+                            page_size=8, use_pallas=False, ragged=False)
         sched = RequestScheduler(eng, max_queue=8,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=False)
+                                 metrics=MetricsRegistry())
+        assert sched._pipeline is False
         sched.submit([1, 2, 3], max_new_tokens=8).result(timeout=60)
         snap = sched.metrics_snapshot()
         assert snap["pt_pipeline_depth"]["value"] == 0
@@ -459,13 +523,12 @@ class TestPipelineMetrics:
         sched.shutdown(drain=True, timeout=30)
 
     def test_spec_engine_forces_sync_pump(self, params):
-        """spec_decode engines fall back to the synchronous pump even
-        with pipeline=True (drafting needs host-current context)."""
+        """spec_decode engines are driven by the synchronous pump
+        (drafting needs host-current context)."""
         eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=64,
                             page_size=8, use_pallas=False, spec_decode=4)
         sched = RequestScheduler(eng, max_queue=8,
-                                 metrics=MetricsRegistry(),
-                                 pipeline=True)
+                                 metrics=MetricsRegistry())
         assert sched._pipeline is False
         out = sched.submit([3, 9, 4, 3, 9, 4, 3, 9],
                            max_new_tokens=8).result(timeout=60)

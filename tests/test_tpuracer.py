@@ -740,13 +740,13 @@ class TestEnvAccessors:
                             env={"PT_PULSE_DEPTH": " "}) == 240
 
     def test_bool_semantics(self):
-        assert _env.env_bool("PT_SERVE_PIPELINE", env={}) is False
-        assert _env.env_bool("PT_SERVE_PIPELINE",
-                             env={"PT_SERVE_PIPELINE": "1"}) is True
-        assert _env.env_bool("PT_SERVE_PIPELINE",
-                             env={"PT_SERVE_PIPELINE": "0"}) is False
-        assert _env.env_bool("PT_SERVE_PIPELINE",
-                             env={"PT_SERVE_PIPELINE": ""}) is False
+        assert _env.env_bool("PT_SERVE_TIMING", env={}) is False
+        assert _env.env_bool("PT_SERVE_TIMING",
+                             env={"PT_SERVE_TIMING": "1"}) is True
+        assert _env.env_bool("PT_SERVE_TIMING",
+                             env={"PT_SERVE_TIMING": "0"}) is False
+        assert _env.env_bool("PT_SERVE_TIMING",
+                             env={"PT_SERVE_TIMING": ""}) is False
 
     def test_undeclared_name_raises(self):
         with pytest.raises(KeyError):
