@@ -18,8 +18,18 @@ blockwise online-softmax structure, plus
     work, mirroring the reference kernel's block-skip. Aggregates over
     the ragged tail's padding lanes only weaken the skip predicate
     (max grows / min shrinks), never falsify it;
+  * the same aggregates, taken once outside the kernels, give every
+    line of the grid its live range: the first and last inner block
+    that may hold an unmasked pair (`_live_ranges`). The ranges are
+    prefetched scalars; the index maps clamp into them, so a step
+    outside its range names the block already resident (no copy) and
+    its body is skipped on a comparison of two scalars. The grid stays
+    (bh, outer, inner): a dead step still costs a pipeline step, about
+    0.3 us on a v5e, which is why the blocks are large;
   * surviving blocks apply the exact per-pair mask built from row iota
-    vs the streamed start/end columns.
+    vs the streamed start/end columns;
+  * blocks come from the shapes (`derived_blocks`) unless the caller
+    or PT_FLASH_BLOCK_Q / _K name them.
 
 Mask semantics (n = trailing dim of startend_row_indices), matching the
 reference docstring:
@@ -40,10 +50,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu._env import env_int
 from .flash_attention import (F0, F1, NEG_INF, Z, LANES,
                               _col_mask, _fit_lanes, _on_tpu,
-                              pallas_disabled, DEFAULT_BLOCK_Q,
-                              DEFAULT_BLOCK_K)
+                              pallas_disabled)
 
 
 def _zero_oob(qi, ki, q, k, v, do=None, *, block_q, block_k, sq, sk):
@@ -107,14 +117,11 @@ def _sri_masked(rows, srib, causal, n):
                      f"causal={causal}")
 
 
-def _sri_all_masked(r_first, r_last, srib, causal, n):
-    """Scalar bool: every (row, col) pair of this block is masked —
-    safe to skip. Conservative under ragged-tail padding garbage in
-    srib (max only grows, min only shrinks)."""
-    def mx(i):
-        return jnp.max(srib[i:i + 1, :])
-    def mn(i):
-        return jnp.min(srib[i:i + 1, :])
+def _sri_all_masked(r_first, r_last, mx, mn, causal, n):
+    """Every (row, col) pair of a block is masked by the start/end
+    indices — safe to skip. mx(i) / mn(i): max / min of index column i
+    over the block's key columns. Conservative under ragged-tail
+    padding garbage (max only grows, min only shrinks)."""
     if causal and n == 1:
         return r_first >= mx(0)
     if causal and n == 2:
@@ -127,22 +134,30 @@ def _sri_all_masked(r_first, r_last, srib, causal, n):
     raise ValueError(f"n={n} invalid for causal={causal}")
 
 
+def _base_live(r_first, r_last, c_first, c_last, causal, window):
+    """A block may hold a pair the causal triangle / window keeps."""
+    live = True
+    if causal:
+        live = live & (r_last >= c_first)
+    if window is not None:
+        live = live & (c_last >= r_first - window[0])
+        if not causal:
+            live = live & (c_first <= r_last + window[1])
+    return live
+
+
 def _block_keep(qi, ki, block_q, block_k, sq, sk, causal, window, srib, n):
     """(compute_predicate, per-pair keep mask builder) for one block."""
     r_first = qi * block_q
     r_last = qi * block_q + block_q - 1
     c_first = ki * block_k
     c_last = ki * block_k + block_k - 1
-    compute = jnp.bool_(True)
-    if causal:
-        compute = compute & (r_last >= c_first)
-    if window is not None:
-        compute = compute & (c_last >= r_first - window[0])
-        if not causal:
-            compute = compute & (c_first <= r_last + window[1])
+    compute = jnp.bool_(True) & _base_live(r_first, r_last, c_first, c_last,
+                                           causal, window)
     if srib is not None:
-        compute = compute & ~_sri_all_masked(r_first, r_last, srib,
-                                             causal, n)
+        compute = compute & ~_sri_all_masked(
+            r_first, r_last, lambda i: jnp.max(srib[i:i + 1, :]),
+            lambda i: jnp.min(srib[i:i + 1, :]), causal, n)
 
     def keep_mask():
         rows = r_first + jax.lax.broadcasted_iota(
@@ -163,6 +178,91 @@ def _block_keep(qi, ki, block_q, block_k, sq, sk, causal, window, srib, n):
 
 
 # ---------------------------------------------------------------------------
+# The live ranges: which blocks the kernels walk
+# ---------------------------------------------------------------------------
+def _live_ranges(srir, bh, causal, window, block_q, block_k, sq, sk):
+    """-> ((k_first, k_last), (q_first, q_last)): per (bh, q block) the
+    first and last k block that may hold an unmasked pair, flat
+    (bh * n_q,) int32, and per (bh, k block) the first and last q
+    block, (bh * n_k,). An empty range reads (0, -1).
+
+    srir: (bh, n, S_k) int32 start/end indices or None. The block
+    predicate is `_block_keep`'s, on the max / min of each index column
+    over a k block's real columns, so a block outside a range holds no
+    unmasked pair; inside, a general mask may still have dead blocks
+    (the range is an envelope: the body keeps the exact predicate).
+    For causal document masks (n = 1) the range is exact."""
+    n_q, n_k = pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)
+    r_first = jnp.arange(n_q, dtype=jnp.int32)[:, None] * block_q
+    r_last = jnp.minimum(r_first + block_q, sq) - 1
+    c_first = jnp.arange(n_k, dtype=jnp.int32)[None, :] * block_k
+    c_last = jnp.minimum(c_first + block_k, sk) - 1
+    live = jnp.ones((n_q, n_k), bool) & _base_live(
+        r_first, r_last, c_first, c_last, causal, window)
+    live = live[None]
+    if srir is not None:
+        pad = n_k * block_k - sk
+
+        def agg(op, fill):
+            def of(i):
+                col = jnp.pad(srir[:, i], ((0, 0), (0, pad)),
+                              constant_values=fill)
+                return op(col.reshape(bh, 1, n_k, block_k), axis=-1)
+            return of
+        info = jnp.iinfo(jnp.int32)
+        live = live & ~_sri_all_masked(
+            r_first[None], r_last[None], agg(jnp.max, info.min),
+            agg(jnp.min, info.max), causal, srir.shape[1])
+    live = jnp.broadcast_to(live, (bh, n_q, n_k))
+
+    def first_last(axis, n):
+        idx = jnp.arange(n, dtype=jnp.int32).reshape(
+            (1, n, 1) if axis == 1 else (1, 1, n))
+        last = jnp.max(jnp.where(live, idx, -1), axis=axis)
+        first = jnp.min(jnp.where(live, idx, n), axis=axis)
+        first = jnp.where(last < 0, 0, first)
+        return first.reshape(-1), last.reshape(-1)
+    return first_last(2, n_k), first_last(1, n_q)
+
+
+def _sri_rows(sri):
+    """(B, H, S_k, n) -> (bh, n, S_k) int32: the kernels read (n,
+    block_k) tiles whose LANE dim is the 128-aligned key axis."""
+    b, h, sk, n = sri.shape
+    return jnp.swapaxes(sri, -1, -2).reshape(b * h, n, sk).astype(jnp.int32)
+
+
+def _window_pair(window):
+    """None, an int (symmetric) or (left, right) -> None or (int, int)."""
+    if window is None:
+        return None
+    return (int(window), int(window)) if np.isscalar(window) \
+        else (int(window[0]), int(window[1]))
+
+
+def flashmask_live_blocks(startend_row_indices, causal=True, window=None,
+                          block_q=None, block_k=None):
+    """-> (live, grid): how many (q block, k block) steps of the
+    flashmask kernels' grids do work for this mask, and how many the
+    grid has, summed over batch and heads. `live` counts the blocks
+    inside the ranges the kernels walk (`_live_ranges`): for causal
+    document masks exactly the blocks that hold an unmasked pair, for
+    a general mask an envelope of them. startend_row_indices:
+    (B, H, S, n), queries and keys both S long; block_q / block_k
+    default to what the kernel entry derives for S at head 128 in
+    bfloat16."""
+    b, h, s, _ = startend_row_indices.shape
+    dq, dk = derived_blocks(s, s, LANES, jnp.bfloat16)
+    block_q = min(block_q or dq, s)
+    block_k = min(block_k or dk, s)
+    (first, last), _ = _live_ranges(
+        _sri_rows(jnp.asarray(startend_row_indices)), b * h, causal,
+        _window_pair(window), block_q, block_k, s, s)
+    grid = b * h * pl.cdiv(s, block_q) * pl.cdiv(s, block_k)
+    return int(jnp.sum(last - first + 1)), grid
+
+
+# ---------------------------------------------------------------------------
 # Reference (dense XLA) — correctness baseline + off-TPU fallback.
 # ---------------------------------------------------------------------------
 def flashmask_reference(q, k, v, sri=None, causal=True, window=None,
@@ -177,8 +277,7 @@ def flashmask_reference(q, k, v, sri=None, causal=True, window=None,
     *_, sq, d = q.shape
     sk = k.shape[-2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    if window is not None and np.isscalar(window):
-        window = (int(window), int(window))
+    window = _window_pair(window)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     rows = jnp.arange(sq)[:, None]
@@ -246,9 +345,28 @@ def _drop_keep(seed_ref, bh, qi, ki, block_q, block_k, dropout):
     return keep, np.float32(1.0 / (1.0 - dropout))
 
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, causal, window, n_sri,
-                block_q, block_k, n_k, sq, sk, dropout):
+def _when_live(first_ref, last_ref, line, inner, sri_ref, qi, ki, *,
+               block_q, block_k, sq, sk, causal, window, n_sri):
+    """Decorator: run body(keep_mask) when block (qi, ki) holds an
+    unmasked pair. First a comparison of prefetched scalars: `inner`
+    (the grid's inner block index) lies in line `line`'s live range;
+    outside it the index maps name the block already resident
+    (`_index_maps`), so the step moves no bytes and reads nothing. Then
+    `_block_keep`'s exact predicate on the start/end block, since a
+    general mask's range is an envelope."""
+    def deco(body):
+        @pl.when((inner >= first_ref[line]) & (inner <= last_ref[line]))
+        def _in_range():
+            srib = sri_ref[0] if sri_ref is not None else None
+            compute, keep_mask = _block_keep(qi, ki, block_q, block_k, sq,
+                                             sk, causal, window, srib, n_sri)
+            pl.when(compute)(lambda: body(keep_mask))
+    return deco
+
+
+def _fwd_kernel(first_ref, last_ref, seed_ref, q_ref, k_ref, v_ref, sri_ref,
+                o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale, causal,
+                window, n_sri, block_q, block_k, n_q, n_k, sq, sk, dropout):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -259,12 +377,10 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    srib = sri_ref[0] if sri_ref is not None else None
-    compute, keep_mask = _block_keep(qi, ki, block_q, block_k, sq, sk,
-                                     causal, window, srib, n_sri)
-
-    @pl.when(compute)
-    def body():
+    @_when_live(first_ref, last_ref, bh * n_q + qi, ki, sri_ref, qi, ki,
+                block_q=block_q, block_k=block_k, sq=sq, sk=sk, causal=causal,
+                window=window, n_sri=n_sri)
+    def body(keep_mask):
         q, k, v = _zero_oob(qi, ki, q_ref[0], k_ref[0], v_ref[0],
                             block_q=block_q, block_k=block_k, sq=sq, sk=sk)
         d = q.shape[-1]
@@ -303,9 +419,33 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, o_ref, lse_ref,
         lse_ref[0] = m_ref[:] + jnp.log(l_safe)
 
 
-def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dq_acc, *, scale, causal, window,
-                   n_sri, block_q, block_k, n_k, sq, sk, dropout):
+def _p_and_ds(q, k, v, do, lse, delta, keep, dkeep_inv, scale):
+    """The backward's two tiles for one block, float32: p (undropped
+    probabilities) and ds = p ∘ (D∘dp − delta) · scale. Operands go to
+    the MXU in their own type with float32 accumulation: a product of
+    two bfloat16 values is exact in float32, so `do · vᵀ` is the
+    number an up-cast would give."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(keep, s, NEG_INF)
+    p = jnp.exp(s - _fit_lanes(lse, s.shape[-1]))
+    p = jnp.where(keep, p, jnp.zeros_like(p))
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    if dkeep_inv is not None:
+        # delta already equals Σ_k p̃ dp (= do·o), so only dp gets the
+        # dropout mask
+        dkeep, inv = dkeep_inv
+        dp = jnp.where(dkeep, dp * inv, F0)
+    ds = jnp.where(keep, p * (dp - _fit_lanes(delta, dp.shape[-1])) * scale,
+                   F0)
+    return p, ds
+
+
+def _bwd_dq_kernel(first_ref, last_ref, seed_ref, q_ref, k_ref, v_ref,
+                   sri_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc, *,
+                   scale, causal, window, n_sri, block_q, block_k, n_q, n_k,
+                   sq, sk, dropout):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -314,47 +454,30 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    srib = sri_ref[0] if sri_ref is not None else None
-    compute, keep_mask = _block_keep(qi, ki, block_q, block_k, sq, sk,
-                                     causal, window, srib, n_sri)
-
-    @pl.when(compute)
-    def body():
+    @_when_live(first_ref, last_ref, bh * n_q + qi, ki, sri_ref, qi, ki,
+                block_q=block_q, block_k=block_k, sq=sq, sk=sk, causal=causal,
+                window=window, n_sri=n_sri)
+    def body(keep_mask):
         q, k, v, do = _zero_oob(qi, ki, q_ref[0], k_ref[0], v_ref[0],
-                                do_ref[0], block_q=block_q,
-                                block_k=block_k, sq=sq, sk=sk)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        keep = keep_mask()
-        s = jnp.where(keep, s, NEG_INF)
-        p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))
-        p = jnp.where(keep, p, jnp.zeros_like(p))
-        do = do.astype(jnp.float32)
-        dp = jax.lax.dot_general(do, v.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout > 0.0:
-            # ds = p ∘ (D∘dp − delta): delta already equals
-            # Σ_k p̃ dp (= do·o), so only dp gets the dropout mask
-            dkeep, inv = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
-                                    dropout)
-            dp = jnp.where(dkeep, dp * inv, F0)
-        ds = jnp.where(keep,
-                       p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1]))
-                       * scale, F0)
-        dq_acc[:] += jax.lax.dot_general(ds, k.astype(jnp.float32),
-                                         (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+                                do_ref[0], block_q=block_q, block_k=block_k,
+                                sq=sq, sk=sk)
+        drop = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
+                          dropout) if dropout > 0.0 else None
+        _, ds = _p_and_ds(q, k, v, do, lse_ref[0], delta_ref[0], keep_mask(),
+                          drop, scale)
+        dq_acc[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(ki == n_k - 1)
     def _fin():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                    causal, window, n_sri, block_q, block_k, n_q, sq, sk,
-                    dropout):
+def _bwd_dkv_kernel(first_ref, last_ref, seed_ref, q_ref, k_ref, v_ref,
+                    sri_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                    dk_acc, dv_acc, *, scale, causal, window, n_sri, block_q,
+                    block_k, n_q, n_k, sq, sk, dropout):
     bh = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -364,40 +487,24 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    srib = sri_ref[0] if sri_ref is not None else None
-    compute, keep_mask = _block_keep(qi, ki, block_q, block_k, sq, sk,
-                                     causal, window, srib, n_sri)
-
-    @pl.when(compute)
-    def body():
+    @_when_live(first_ref, last_ref, bh * n_k + ki, qi, sri_ref, qi, ki,
+                block_q=block_q, block_k=block_k, sq=sq, sk=sk, causal=causal,
+                window=window, n_sri=n_sri)
+    def body(keep_mask):
         q, k, v, do = _zero_oob(qi, ki, q_ref[0], k_ref[0], v_ref[0],
-                                do_ref[0], block_q=block_q,
-                                block_k=block_k, sq=sq, sk=sk)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        keep = keep_mask()
-        s = jnp.where(keep, s, NEG_INF)
-        p = jnp.exp(s - _fit_lanes(lse_ref[0], s.shape[-1]))
-        p = jnp.where(keep, p, jnp.zeros_like(p))
-        do = do.astype(jnp.float32)
-        pd = p
-        if dropout > 0.0:
-            dkeep, inv = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
-                                    dropout)
-            pd = jnp.where(dkeep, p * inv, F0)
-        dv_acc[:] += jax.lax.dot_general(pd, do, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v.astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout > 0.0:
-            dp = jnp.where(dkeep, dp * inv, F0)
-        ds = jnp.where(keep,
-                       p * (dp - _fit_lanes(delta_ref[0], dp.shape[-1]))
-                       * scale, F0)
-        dk_acc[:] += jax.lax.dot_general(ds, q.astype(jnp.float32),
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+                                do_ref[0], block_q=block_q, block_k=block_k,
+                                sq=sq, sk=sk)
+        drop = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
+                          dropout) if dropout > 0.0 else None
+        p, ds = _p_and_ds(q, k, v, do, lse_ref[0], delta_ref[0], keep_mask(),
+                          drop, scale)
+        pd = p if drop is None else jnp.where(drop[0], p * drop[1], F0)
+        dv_acc[:] += jax.lax.dot_general(
+            pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(qi == n_q - 1)
     def _fin():
@@ -408,6 +515,68 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, sri_ref, do_ref, lse_ref,
 # ---------------------------------------------------------------------------
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
+_BLOCKS = (512, 256, 128)
+# what one kernel may hold of VMEM: v5e's scoped default is 16 MiB, the
+# smallest of the generations this runs on
+_VMEM_BUDGET = 16 * 2 ** 20
+
+
+def _vmem_bytes(block_q, block_k, d, itemsize, n_sri=1):
+    """VMEM the backward dK/dV kernel, the largest of the three, holds
+    at one block: its operands and outputs twice (the pipeline's double
+    buffer), its float32 accumulators, and four (block_q, block_k)
+    float32 tiles (scores, p, dp, ds)."""
+    sub = lambda n: -(-n // 8) * 8
+    operands = (2 * block_q * d * itemsize            # q, do
+                + 2 * block_k * d * itemsize          # k, v
+                + 2 * block_q * LANES * 4             # lse, delta
+                + sub(n_sri) * block_k * 4)           # start/end rows
+    outputs = 2 * block_k * d * itemsize              # dk, dv
+    accs = 2 * block_k * d * 4
+    tiles = 4 * block_q * block_k * 4
+    return 2 * (operands + outputs) + accs + tiles
+
+
+def derived_blocks(sq, sk, d, dtype):
+    """(block_q, block_k) from the shapes: the candidates (`_BLOCKS`)
+    that fit the sequence and the VMEM budget, of those the one that
+    pads the sequence least, of those the largest. A static function of
+    shapes; nothing is timed. The table is one sweep on a v5e at
+    (4, 16, 4096, 128) bfloat16 with packed documents (PERF.md, PR 38):
+    large blocks win until the tiles leave VMEM, because a grid step
+    costs the same whatever it holds."""
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def pick(s, fits):
+        ok = [c for c in _BLOCKS if c <= max(s, _BLOCKS[-1]) and fits(c)]
+        ok = ok or [_BLOCKS[-1]]
+        return min(ok, key=lambda c: (pl.cdiv(s, c) * c, -c))
+    block_q = pick(sq, lambda c: _vmem_bytes(c, c, d, itemsize)
+                   <= _VMEM_BUDGET)
+    block_k = pick(sk, lambda c: _vmem_bytes(block_q, c, d, itemsize)
+                   <= _VMEM_BUDGET)
+    return block_q, block_k
+
+
+def _vmem_limit(block_q, block_k, d, dtype, n_sri):
+    """What the kernels ask of VMEM: the budget the derived blocks were
+    held to, and the count itself where explicit blocks pass it."""
+    return max(_VMEM_BUDGET, 2 * _vmem_bytes(
+        block_q, block_k, d, jnp.dtype(dtype).itemsize, max(n_sri, 1)))
+
+
+def _blocks(block_q, block_k, sq, sk, d, dtype):
+    """The blocks a call runs at: the caller's, else PT_FLASH_BLOCK_Q/K
+    where the environment sets them, else derived from the shapes;
+    never past the sequence."""
+    dq, dk = derived_blocks(sq, sk, d, dtype)
+    if block_q is None:
+        block_q = env_int("PT_FLASH_BLOCK_Q", dq)
+    if block_k is None:
+        block_k = env_int("PT_FLASH_BLOCK_K", dk)
+    return min(block_q, sq), min(block_k, sk)
+
+
 def _prep(q, k, v, sri):
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -415,13 +584,7 @@ def _prep(q, k, v, sri):
     qr = q.reshape(bh, sq, d)
     kr = k.reshape(bh, sk, d)
     vr = v.reshape(bh, sk, d)
-    if sri is not None:
-        # (B,H,S_k,n) -> (bh, n, S_k): the kernel reads (n, block_k)
-        # tiles whose LANE dim is the 128-aligned key axis
-        n = sri.shape[-1]
-        srir = jnp.swapaxes(sri, -1, -2).reshape(bh, n, sk).astype(jnp.int32)
-    else:
-        srir = None
+    srir = None if sri is None else _sri_rows(sri)
     return qr, kr, vr, srir, b, h, sq, sk, d, bh
 
 
@@ -435,17 +598,9 @@ def _mk_kernel(fn, have_sri, **kw):
     if have_sri:
         return functools.partial(fn, **kw)
     return functools.partial(
-        lambda seed_, q_, k_, v_, *rest, **kw2:
-        fn(seed_, q_, k_, v_, None, *rest, **kw2),
+        lambda first_, last_, seed_, q_, k_, v_, *rest, **kw2:
+        fn(first_, last_, seed_, q_, k_, v_, None, *rest, **kw2),
         **kw)
-
-
-def _seed_spec():
-    # explicit index map: a memory_space-only BlockSpec gets a
-    # pallas-default map whose 0 constant is i64 under x64 — Mosaic
-    # rejects the transform func returning i64 ("func.return (i64)"
-    # legalization failure)
-    return pl.BlockSpec((1,), lambda *_: (Z,), memory_space=pltpu.SMEM)
 
 
 def _seed_arr(seed):
@@ -454,50 +609,76 @@ def _seed_arr(seed):
     return jnp.asarray(seed, jnp.int32).reshape((1,))
 
 
+def _index_maps(n_outer, inner_is_k):
+    """Index maps of a grid (bh, outer, inner) whose prefetched scalars
+    are (first, last, seed): the inner block index is clamped into the
+    line's live range, so a step outside it names the block the step
+    before it held and the pipeline issues no copy. -> maps for the
+    q-side, the k-side and the start/end blocks."""
+    def clamped(b, outer, inner, first, last, _seed):
+        line = b * n_outer + outer
+        return jnp.maximum(jnp.minimum(inner, last[line]), first[line])
+
+    def q_map(b, outer, inner, *s):
+        return (b, outer if inner_is_k else clamped(b, outer, inner, *s), Z)
+
+    def k_map(b, outer, inner, *s):
+        return (b, clamped(b, outer, inner, *s) if inner_is_k else outer, Z)
+
+    def sri_map(b, outer, inner, *s):
+        return (b, Z, k_map(b, outer, inner, *s)[1])
+    return q_map, k_map, sri_map
+
+
+def _call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
+          vmem_limit, interpret):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )
+
+
 def _fwd_pallas(q, k, v, sri, causal, window, scale, block_q, block_k,
                 interpret, dropout=0.0, seed=None):
     scale = np.float32(scale)
     qr, kr, vr, srir, b, h, sq, sk, d, bh = _prep(q, k, v, sri)
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype)
     n_q = pl.cdiv(sq, block_q)
     n_k = pl.cdiv(sk, block_k)
     n_sri = srir.shape[1] if srir is not None else 0
     spec = _mem_spec()
+    (k_first, k_last), _ = _live_ranges(srir, bh, causal, window, block_q,
+                                        block_k, sq, sk)
+    q_map, k_map, sri_map = _index_maps(n_q, inner_is_k=True)
 
-    in_specs = [
-        _seed_spec(),
-        spec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, Z)),
-        spec((1, block_k, d), lambda bh_, qi, ki: (bh_, ki, Z)),
-        spec((1, block_k, d), lambda bh_, qi, ki: (bh_, ki, Z)),
-    ]
-    args = [_seed_arr(seed), qr, kr, vr]
+    in_specs = [spec((1, block_q, d), q_map), spec((1, block_k, d), k_map),
+                spec((1, block_k, d), k_map)]
+    args = [k_first, k_last, _seed_arr(seed), qr, kr, vr]
     if srir is not None:
-        in_specs.append(spec((1, n_sri, block_k),
-                             lambda bh_, qi, ki: (bh_, Z, ki)))
+        in_specs.append(spec((1, n_sri, block_k), sri_map))
         args.append(srir)
     kernel = _mk_kernel(_fwd_kernel, srir is not None, scale=scale,
                         causal=causal, window=window, n_sri=n_sri,
-                        block_q=block_q, block_k=block_k, n_k=n_k,
+                        block_q=block_q, block_k=block_k, n_q=n_q, n_k=n_k,
                         sq=sq, sk=sk, dropout=dropout)
 
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=in_specs,
-        out_specs=[
-            spec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, Z)),
-            spec((1, block_q, LANES), lambda bh_, qi, ki: (bh_, qi, Z)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-        ],
+    o, lse = _call(
+        kernel, (bh, n_q, n_k), in_specs,
+        out_specs=[spec((1, block_q, d), q_map),
+                   spec((1, block_q, LANES), q_map)],
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, sq, LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32)],
+        vmem_limit=_vmem_limit(block_q, block_k, d, q.dtype, n_sri),
         interpret=interpret,
     )(*args)
     return o.reshape(b, h, sq, d), lse.reshape(b, h, sq, LANES)
@@ -507,12 +688,13 @@ def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
                 block_q, block_k, interpret, dropout=0.0, seed=None):
     scale = np.float32(scale)
     qr, kr, vr, srir, b, h, sq, sk, d, bh = _prep(q, k, v, sri)
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype)
     n_q = pl.cdiv(sq, block_q)
     n_k = pl.cdiv(sk, block_k)
     n_sri = srir.shape[1] if srir is not None else 0
     spec = _mem_spec()
+    k_range, q_range = _live_ranges(srir, bh, causal, window, block_q,
+                                    block_k, sq, sk)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dor = do.reshape(bh, sq, d)
@@ -520,65 +702,44 @@ def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
     deltar = jnp.broadcast_to(delta.reshape(bh, sq)[..., None],
                               (bh, sq, LANES))
 
-    def specs(order):
-        # order: index-map arg order differs between the two kernels
-        qspec = spec((1, block_q, d), order("q"))
-        return ([_seed_spec(), qspec,
-                 spec((1, block_k, d), order("k")),
-                 spec((1, block_k, d), order("k")),
-                 ] + ([spec((1, n_sri, block_k), order("sri"))]
-                      if srir is not None else []) +
-                [spec((1, block_q, d), order("q")),
-                 spec((1, block_q, LANES), order("q")),
-                 spec((1, block_q, LANES), order("q"))])
+    def specs(q_map, k_map, sri_map):
+        return ([spec((1, block_q, d), q_map), spec((1, block_k, d), k_map),
+                 spec((1, block_k, d), k_map)]
+                + ([spec((1, n_sri, block_k), sri_map)]
+                   if srir is not None else [])
+                + [spec((1, block_q, d), q_map),
+                   spec((1, block_q, LANES), q_map),
+                   spec((1, block_q, LANES), q_map)])
 
-    def dq_order(which):
-        return {"q": lambda b_, qi, ki: (b_, qi, Z),
-                "k": lambda b_, qi, ki: (b_, ki, Z),
-                "sri": lambda b_, qi, ki: (b_, Z, ki)}[which]
+    statics = dict(scale=scale, causal=causal, window=window, n_sri=n_sri,
+                   block_q=block_q, block_k=block_k, n_q=n_q, n_k=n_k,
+                   sq=sq, sk=sk, dropout=dropout)
+    operands = [qr, kr, vr] + ([srir] if srir is not None else []) + \
+        [dor, lser, deltar]
+    vmem_limit = _vmem_limit(block_q, block_k, d, q.dtype, n_sri)
 
-    def dkv_order(which):
-        return {"q": lambda b_, ki, qi: (b_, qi, Z),
-                "k": lambda b_, ki, qi: (b_, ki, Z),
-                "sri": lambda b_, ki, qi: (b_, Z, ki)}[which]
-
-    base_args = [_seed_arr(seed), qr, kr, vr] + \
-        ([srir] if srir is not None else [])
-
-    dq = pl.pallas_call(
-        _mk_kernel(_bwd_dq_kernel, srir is not None, scale=scale,
-                   causal=causal, window=window, n_sri=n_sri,
-                   block_q=block_q, block_k=block_k, n_k=n_k, sq=sq, sk=sk,
-                   dropout=dropout),
-        grid=(bh, n_q, n_k),
-        in_specs=specs(dq_order),
-        out_specs=[spec((1, block_q, d), dq_order("q"))],
+    dq_maps = _index_maps(n_q, inner_is_k=True)
+    dq = _call(
+        _mk_kernel(_bwd_dq_kernel, srir is not None, **statics),
+        (bh, n_q, n_k), specs(*dq_maps),
+        out_specs=[spec((1, block_q, d), dq_maps[0])],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(*base_args, dor, lser, deltar)[0]
+        vmem_limit=vmem_limit, interpret=interpret,
+    )(*k_range, _seed_arr(seed), *operands)[0]
 
-    dk, dv = pl.pallas_call(
-        _mk_kernel(_bwd_dkv_kernel, srir is not None, scale=scale,
-                   causal=causal, window=window, n_sri=n_sri,
-                   block_q=block_q, block_k=block_k, n_q=n_q, sq=sq, sk=sk,
-                   dropout=dropout),
-        grid=(bh, n_k, n_q),
-        in_specs=specs(dkv_order),
-        out_specs=[
-            spec((1, block_k, d), dkv_order("k")),
-            spec((1, block_k, d), dkv_order("k")),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*base_args, dor, lser, deltar)
+    dkv_maps = _index_maps(n_k, inner_is_k=False)
+    dk, dv = _call(
+        _mk_kernel(_bwd_dkv_kernel, srir is not None, **statics),
+        (bh, n_k, n_q), specs(*dkv_maps),
+        out_specs=[spec((1, block_k, d), dkv_maps[1]),
+                   spec((1, block_k, d), dkv_maps[1])],
+        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
+                   jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        vmem_limit=vmem_limit, interpret=interpret,
+    )(*q_range, _seed_arr(seed), *operands)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
 
@@ -619,13 +780,19 @@ _flashmask.defvjp(_flashmask_fwd, _flashmask_bwd)
 
 def flashmask_attention_bhsd(q, k, v, startend_row_indices=None, causal=True,
                              window=None, sm_scale=None,
-                             block_q=DEFAULT_BLOCK_Q,
-                             block_k=DEFAULT_BLOCK_K,
+                             block_q=None, block_k=None,
                              use_pallas=None, interpret=None,
                              dropout=0.0, dropout_seed=None):
     """Core entry: q,k,v (B,H,S,D), startend_row_indices (B,H,S_k,n)
     already broadcast to the q heads. O(S·block) memory on the kernel
     path; dense reference off-TPU unless interpret is forced.
+
+    block_q / block_k: None (the default) takes PT_FLASH_BLOCK_Q / _K
+    where the environment sets them and else derives the blocks from
+    the shapes (`derived_blocks`). The kernels walk only the blocks
+    inside each line's live range (`_live_ranges`, computed here from
+    the mask, prefetched as scalars); `flashmask_live_blocks` counts
+    them for a mask.
 
     dropout: attention-probability dropout applied IN-KERNEL from a
     deterministic counter-based mask keyed by (dropout_seed, coords) —
@@ -636,9 +803,7 @@ def flashmask_attention_bhsd(q, k, v, startend_row_indices=None, causal=True,
     """
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    if window is not None:
-        window = (int(window), int(window)) if np.isscalar(window) \
-            else (int(window[0]), int(window[1]))
+    window = _window_pair(window)
     if dropout > 0.0 and dropout_seed is None:
         raise ValueError("flashmask dropout requires dropout_seed")
     if use_pallas is None:

@@ -12,7 +12,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.ops.flashmask_attention import (flashmask_attention_bhsd,
+from paddle_tpu.ops.flashmask_attention import (_live_ranges,
+                                                derived_blocks,
+                                                flashmask_attention_bhsd,
+                                                flashmask_live_blocks,
                                                 flashmask_reference)
 
 
@@ -34,21 +37,169 @@ def _grads(fn, *args):
     return jax.value_and_grad(loss, (0, 1, 2))(*args)
 
 
+def _doc_sri(lens, s=None):
+    """(1, 1, S, 1) causal n = 1 document mask: every key column masks
+    the rows from its document's end on."""
+    ends = np.repeat(np.cumsum(lens), lens)
+    s = s or len(ends)
+    return jnp.asarray(ends[:s, None][None, None], jnp.int32)
+
+
+# packed documents the live walk has to get right: boundaries off every
+# block edge, one document filling the sequence, runs of one-token
+# documents, S not a multiple of the block, q blocks whose range is dead
+_PACKED = {
+    "off_edges": ([100, 57, 171, 56], 128),
+    "one_document": ([384], 128),
+    "one_token_runs": ([1] * 130 + [120, 1, 1, 1, 131], 128),
+    "ragged_tail": ([90, 130, 100], 128),           # S = 320
+    "derived_blocks": ([300, 1, 83], None),
+    "rectangular_blocks": ([200, 3, 181], (256, 128)),
+}
+
+
+_MODES = ["causal_n1", "causal_n2", "noncausal_n2", "noncausal_n4"]
+
+
+def _mode_sri(mode, s, seed=0):
+    """(1, 2, S, n): head 0 a structured mask that kills whole blocks
+    (documents of uneven length, bands), head 1 random indices."""
+    rng = np.random.RandomState(seed)
+    lens = []
+    while sum(lens) < s:
+        lens.append(int(rng.choice([1, 40, 130, 300, 700])))
+    ends = np.repeat(np.cumsum(lens), lens)[:s]
+    starts = ends - np.repeat(lens, lens)[:s]
+    ends = np.minimum(ends, s)
+    r = lambda lo, hi: rng.randint(lo, hi, s)
+    if mode == "causal_n1":
+        cols = [[ends], [r(1, s + 1)]]
+    elif mode == "causal_n2":       # masked: start <= row < end
+        cols = [[ends, np.minimum(ends + 300, s)],
+                [r(0, s), np.minimum(r(0, s) + r(0, s // 2), s)]]
+    elif mode == "noncausal_n2":    # masked: row >= start or row < end
+        cols = [[ends, starts], [r(s // 2, s + 1), r(0, s // 2)]]
+    else:                           # two masked bands
+        cols = [[np.maximum(starts - 200, 0), starts, ends,
+                 np.minimum(ends + 200, s)],
+                [r(0, s // 4), r(s // 4, s // 2), r(s // 2, s),
+                 np.full(s, s)]]
+    sri = np.stack([np.stack(c, -1) for c in cols])[None]
+    return jnp.asarray(sri, jnp.int32)
+
+
+_DENSE = {}
+
+
+def _dense_keep(mode, window, s):
+    """The dense (2, S, S) keep mask by flashmask_reference's own rule:
+    with zero scores its probabilities are uniform over the kept keys,
+    and V = identity hands them out as the output."""
+    key = (mode, window, s)
+    if key not in _DENSE:
+        sri = _mode_sri(mode, s)
+        z = jnp.zeros((1, 2, s, s), jnp.float32)
+        v = jnp.broadcast_to(jnp.eye(s, dtype=jnp.float32), (1, 2, s, s))
+        o, _ = flashmask_reference(z, z, v, sri, mode.startswith("causal"),
+                                   window)
+        _DENSE[key] = (sri, np.asarray(o)[0] > 0)
+    return _DENSE[key]
+
+
+def _block_any(keep, block_q, block_k):
+    """(bh, n_q, n_k): the block holds an unmasked pair."""
+    bh, sq, sk = keep.shape
+    n_q, n_k = -(-sq // block_q), -(-sk // block_k)
+    pad = np.zeros((bh, n_q * block_q, n_k * block_k), bool)
+    pad[:, :sq, :sk] = keep
+    return pad.reshape(bh, n_q, block_q, n_k, block_k).any((2, 4))
+
+
+class TestLiveRanges:
+    @pytest.mark.parametrize("block", [128, 256, 512])
+    @pytest.mark.parametrize("window", [None, (200, 64)])
+    @pytest.mark.parametrize("mode", _MODES)
+    def test_no_unmasked_pair_outside_the_range(self, mode, window, block):
+        s = 1500                    # not a multiple of any block
+        causal = mode.startswith("causal")
+        sri, keep = _dense_keep(mode, window, s)
+        srir = jnp.swapaxes(sri, -1, -2).reshape(2, -1, s)
+        k_range, q_range = _live_ranges(srir, 2, causal, window, block,
+                                        block, s, s)
+        live = _block_any(keep, block, block)
+        n = live.shape[1]
+        idx = np.arange(n)
+        for (first, last), lv in ((k_range, live),
+                                  (q_range, live.swapaxes(1, 2))):
+            first = np.asarray(first).reshape(2, n, 1)
+            last = np.asarray(last).reshape(2, n, 1)
+            inside = (idx >= first) & (idx <= last)
+            assert not (lv & ~inside).any()
+            # the envelope is tight: its ends are live blocks, and a
+            # line with no live block has the empty range (0, -1)
+            has = lv.any(-1)
+            assert (np.take_along_axis(lv, first, -1)[..., 0] == has).all()
+            assert (last[..., 0][~has] == -1).all()
+            assert (first[..., 0][~has] == 0).all()
+        if mode == "causal_n1" and window is None:
+            # documents: the range is exact, and the public count is it
+            inside = (idx >= np.asarray(k_range[0]).reshape(2, n, 1)) & \
+                (idx <= np.asarray(k_range[1]).reshape(2, n, 1))
+            assert (inside[0] == live[0]).all()
+            got, grid = flashmask_live_blocks(sri, True, None, block, block)
+            assert grid == 2 * n * n
+            assert got == int(inside.sum())
+
+    def test_no_mask_is_the_causal_triangle(self):
+        (first, last), (qf, ql) = _live_ranges(None, 3, True, None, 128,
+                                               256, 512, 512)
+        assert np.asarray(first).tolist() == [0] * 12
+        assert np.asarray(last).tolist() == [0, 0, 1, 1] * 3
+        assert np.asarray(qf).tolist() == [0, 2] * 3
+        assert np.asarray(ql).tolist() == [3, 3] * 3
+
+    @pytest.mark.parametrize("shape,want", [
+        ((4096, 4096, 128, jnp.bfloat16), (512, 512)),   # pretrain_4k
+        ((4096, 4096, 128, jnp.float32), (512, 512)),
+        ((192, 192, 64, jnp.float32), (128, 128)),       # pads least
+        ((64, 64, 64, jnp.float32), (128, 128)),         # then min(., S)
+        ((300, 1024, 128, jnp.bfloat16), (128, 512)),
+        ((8192, 8192, 256, jnp.float32), (512, 512)),
+    ])
+    def test_blocks_derived_from_the_shapes(self, shape, want):
+        assert derived_blocks(*shape) == want
+
+    def test_environment_and_arguments_still_override(self, monkeypatch):
+        from paddle_tpu.ops.flashmask_attention import _blocks
+        args = (4096, 4096, 128, jnp.bfloat16)
+        assert _blocks(None, None, *args) == (512, 512)
+        assert _blocks(256, None, *args) == (256, 512)
+        monkeypatch.setenv("PT_FLASH_BLOCK_Q", "128")
+        assert _blocks(None, None, *args) == (128, 512)
+        monkeypatch.setenv("PT_FLASH_BLOCK_K", "256")
+        assert _blocks(None, None, *args) == (128, 256)
+        assert _blocks(512, 512, *args) == (512, 512)
+        assert _blocks(None, None, 64, 64, 64, jnp.float32) == (64, 64)
+
+
 class TestFlashMaskKernel:
     def _check(self, sri, causal, s=256, window=None, seed=0, b=2, h=2,
                d=64, block=128):
+        """block: an int, a (block_q, block_k) pair, or None for the
+        blocks the entry derives."""
+        bq, bk = block if isinstance(block, tuple) else (block, block)
         q, k, v = _qkv(b, h, s, d, seed)
         o_ref, _ = flashmask_reference(q, k, v, sri, causal, window)
         o_ker = flashmask_attention_bhsd(
             q, k, v, sri, causal=causal, window=window, use_pallas=True,
-            interpret=True, block_q=block, block_k=block)
+            interpret=True, block_q=bq, block_k=bk)
         _close(o_ker, o_ref)
         # backward
         ref_fn = lambda q_, k_, v_: flashmask_reference(
             q_, k_, v_, sri, causal, window)[0]
         ker_fn = lambda q_, k_, v_: flashmask_attention_bhsd(
             q_, k_, v_, sri, causal=causal, window=window, use_pallas=True,
-            interpret=True, block_q=block, block_k=block)
+            interpret=True, block_q=bq, block_k=bk)
         _, g_ref = _grads(ref_fn, q, k, v)
         _, g_ker = _grads(ker_fn, q, k, v)
         for a, b_ in zip(g_ker, g_ref):
@@ -127,6 +278,56 @@ class TestFlashMaskKernel:
         sri = jnp.full((1, 2, s, 1), 256, jnp.int32)
         self._check(sri, causal=True, s=s, b=1)
 
+    @pytest.mark.parametrize("case", sorted(_PACKED))
+    def test_packed_documents(self, case):
+        """Forward and all three gradients on packed documents: the
+        live walk clamps its index maps into each line's range, and
+        what it skips must be exactly what the mask kills."""
+        lens, block = _PACKED[case]
+        s = sum(lens)
+        sri = jnp.broadcast_to(_doc_sri(lens), (1, 2, s, 1))
+        self._check(sri, causal=True, s=s, b=1, block=block)
+
+    @pytest.mark.parametrize("block", [128, (256, 128)])
+    def test_dead_q_blocks_give_zeros_and_a_finite_lse(self, block):
+        """Every key column masks the rows from 100 on: the q blocks
+        past the first have an empty range (no step of theirs runs the
+        body), and must still return zeros and a finite lse."""
+        from paddle_tpu.ops.flashmask_attention import _fwd_pallas
+        s = 512
+        bq, bk = block if isinstance(block, tuple) else (block, block)
+        sri = jnp.full((1, 2, s, 1), 100, jnp.int32)
+        srir = jnp.swapaxes(sri, -1, -2).reshape(2, 1, s)
+        (first, last), _ = _live_ranges(srir, 2, True, None, bq, bk, s, s)
+        assert (np.asarray(last).reshape(2, -1)[:, 1:] == -1).all()
+        q, k, v = _qkv(1, 2, s, 64, seed=21)
+        o, lse = _fwd_pallas(q, k, v, sri, True, None, 0.125, bq, bk, True)
+        assert np.all(np.asarray(o)[0, :, 100:] == 0.0)
+        assert np.isfinite(np.asarray(lse)).all()
+        self._check(sri, causal=True, s=s, b=1, block=block, seed=21)
+
+    def test_bf16_gradients(self):
+        """bfloat16 operands enter the MXU as they are and the computed
+        tiles (p, ds) are cast to them; float32 accumulation. Against
+        the float32 reference on the same (rounded) inputs."""
+        lens = [100, 57, 99]
+        s = sum(lens)
+        sri = jnp.broadcast_to(_doc_sri(lens), (1, 2, s, 1))
+        q, k, v = _qkv(1, 2, s, 64, seed=31, dtype=jnp.bfloat16)
+        f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+        ref_fn = lambda q_, k_, v_: flashmask_reference(
+            q_, k_, v_, sri, True, None)[0]
+        ker_fn = lambda q_, k_, v_: flashmask_attention_bhsd(
+            q_, k_, v_, sri, causal=True, use_pallas=True, interpret=True,
+            block_q=128, block_k=128).astype(jnp.float32)
+        _, g_ref = _grads(ref_fn, *f32)
+        _, g_ker = _grads(ker_fn, q, k, v)
+        for a, b_ in zip(g_ker, g_ref):
+            assert a.dtype == jnp.bfloat16
+            _close(a, b_, tol=3e-2)
+            a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+            assert np.linalg.norm(a - b_) / np.linalg.norm(b_) < 1e-2
+
     def test_bf16(self):
         s = 256
         rng = np.random.RandomState(8)
@@ -200,13 +401,18 @@ class TestFlashMaskKernel:
             training=False).numpy())
         assert np.allclose(o_eval, o_plain, atol=2e-3)
 
-    def test_dropout_kernel_matches_reference_same_seed(self):
+    @pytest.mark.parametrize("s,block_q,block_k", [(256, 128, 128),
+                                                   (512, 256, 256),
+                                                   (512, 128, 256)])
+    def test_dropout_kernel_matches_reference_same_seed(self, s, block_q,
+                                                        block_k):
         """VERDICT r4 item 5: in-kernel counter-based dropout. The
         dense reference regenerates the identical mask from
         (seed, coords), so kernel fwd AND grads must match it exactly
         (not just statistically) — including through the hand-seeded
-        backward kernels that re-derive the mask."""
-        s, seed = 256, 12345
+        backward kernels that re-derive the mask. The mask is keyed by
+        absolute coordinates, so no block size may change it."""
+        seed = 12345
         rng = np.random.RandomState(3)
         q, k, v = _qkv(2, 2, s, 64, seed=3)
         sri = jnp.asarray(rng.randint(1, s + 1, (2, 2, s, 1)), jnp.int32)
@@ -216,7 +422,7 @@ class TestFlashMaskKernel:
                 dropout_seed=seed)[0]
             ker_fn = lambda q_, k_, v_: flashmask_attention_bhsd(
                 q_, k_, v_, sri, causal=True, use_pallas=True,
-                interpret=True, block_q=128, block_k=128,
+                interpret=True, block_q=block_q, block_k=block_k,
                 dropout=rate, dropout_seed=seed)
             _close(ker_fn(q, k, v), ref_fn(q, k, v))
             _, g_ref = _grads(ref_fn, q, k, v)

@@ -211,6 +211,35 @@ def test_ragged_compiles_for_v5e_at_the_serving_shape(one_chip, cache):
         assert "tpu_custom_call" in text or "custom-call" in text
 
 
+def test_flashmask_compiles_for_v5e_at_the_training_shape(one_chip):
+    """`pretrain_4k`'s per-chip shape (4 rows x 16 heads, 4,096 tokens,
+    head 128, bfloat16, a document mask), forward and backward, through
+    the TPU compiler at the blocks the entry derives: 512 x 512, and the
+    three kernels fit the VMEM they ask for (Mosaic refuses one that
+    does not)."""
+    import re
+    from paddle_tpu.ops import flashmask_attention as fm
+    b, h, s = 4, 16, 4096
+    assert fm.derived_blocks(s, s, D, jnp.bfloat16) == (512, 512)
+    limit = fm._vmem_limit(512, 512, D, jnp.bfloat16, 1)
+    assert fm._vmem_bytes(512, 512, D, 2) <= limit == 16 * 2 ** 20
+
+    def loss(q, k, v, sri):
+        return fm.flashmask_attention_bhsd(
+            q, k, v, sri, causal=True, use_pallas=True,
+            interpret=False).astype(jnp.float32).sum()
+
+    qkv = jax.ShapeDtypeStruct((b, h, s, D), jnp.bfloat16, sharding=one_chip)
+    sri = jax.ShapeDtypeStruct((b, h, s, 1), jnp.int32, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        qkv, qkv, qkv, sri).compile().as_text()
+    assert text.count("tpu_custom_call") == 3          # fwd, dq, dkv
+    asked = [re.search(r'scoped_memory_configs":\[\{"memory_space":"1",'
+                       r'"offset":"\d+","size":"(\d+)"', line).group(1)
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert asked == [str(limit)] * 3
+
+
 @pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
 def test_unified_step_compiles_for_v5e_with_its_pools_in_place(one_chip,
                                                                cache):
