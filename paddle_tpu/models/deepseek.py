@@ -7,9 +7,13 @@ TPU-native design notes:
   * **MLA (Multi-head Latent Attention)**: K/V are generated from a
     low-rank latent `c_kv = x·W_dkv` (dim kv_lora_rank ≪ H), plus a
     decoupled RoPE branch of dim qk_rope_head_dim shared across heads.
-    The latent is what a serving cache would store — cache bytes drop by
-    ~an order of magnitude vs full K/V. Projections are plain matmuls
-    (MXU); attention runs through our flash kernel after up-projection.
+    This eager stack up-projects K and V and keeps no cache. The served
+    path that caches the latent itself (one row a token for all heads,
+    the keys' up-projection absorbed into the query) is
+    `models/glm_dsa.py` over `kernels/ragged_latent.py`
+    (docs/serving.md § Cache groups by plane). Projections are plain
+    matmuls (MXU); attention runs through our flash kernel after
+    up-projection.
   * **MoE FFN**: shared experts + routed experts with top-k gating and
     the load-balance aux loss, reusing parallel.moe's EP dispatch.
 """

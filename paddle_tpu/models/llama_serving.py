@@ -46,8 +46,8 @@ from ..serving.kvtier import HostTier, _dequantize_host, _quantize_host
 from ..serving.model_spec import CacheGroup, ServingModel
 from ..ops.rope import rope_cos_sin, apply_rotary_emb
 from ..ops.flash_attention import flash_attention_bhsd
-from ..ops.paged_attention import (paged_attention, paged_verify_attention,
-                                   quantize_kv)
+from ..ops.paged_attention import (LANES, paged_attention,
+                                   paged_verify_attention, quantize_kv)
 from ..ops.varlen_attention import (flash_attention_varlen,
                                     seg_ids_from_cu_seqlens)
 from .llama import LlamaConfig
@@ -1048,9 +1048,12 @@ def llama_serving_model(config: LlamaConfig):
 
 class _GroupCache:
     """One cache group's share of the engine: its pool arrays on the
-    device, and on the host its page table, its allocator and the pages
-    each slot holds. `base[s]` is the ordinal of slot s's first HELD
-    page: 0 unless a window has given pages back."""
+    device, one list (an array a stack) for each of the group's planes
+    and one for each plane's int8 scales, and on the host its page
+    table, its allocator and the pages each slot holds. `base[s]` is the
+    ordinal of slot s's first HELD page: 0 unless a window has given
+    pages back. `k`, `v`, `ks`, `vs` are the K/V case's names for the
+    first two planes and their scales."""
 
     def __init__(self, spec, num_pages, slot_cap, max_seqs, pages_per_seq,
                  page_size, pool_dtype, quant, placement, prefix_cache=None):
@@ -1060,14 +1063,19 @@ class _GroupCache:
         # a window plus one buffer of rows can still see
         self.slot_cap = slot_cap
 
-        def pools(dt, last):
-            return [jnp.zeros((n, spec.kv_heads, num_pages, page_size, last),
+        def pools(plane, dt, last):
+            heads = spec.kv_heads if plane.per_head else 1
+            return [jnp.zeros((n, heads, num_pages, page_size, last),
                               dt, device=placement) for n in spec.stacks]
-        self.k = pools(pool_dtype, spec.head_dim)
-        self.v = pools(pool_dtype, spec.head_dim)
-        none = [None] * len(spec.stacks)
-        self.ks = pools(jnp.float32, 1) if quant else list(none)
-        self.vs = pools(jnp.float32, 1) if quant else list(none)
+        self.names = [p.name for p in spec.planes]
+        # a row shared by all heads is fetched whole by DMA: whole lane
+        # tiles of it (the TPU tiles a row so anyway)
+        self.pools = [pools(p, jnp.dtype(p.dtype or pool_dtype),
+                            p.width if p.per_head
+                            else -(-p.width // LANES) * LANES)
+                      for p in spec.planes]
+        self.scales = [pools(p, jnp.float32, 1) if quant
+                       else [None] * len(spec.stacks) for p in spec.planes]
         # unassigned entries point at the trash page (the last), never
         # page 0: a stale row must alias a page no live slot reads
         self.table = np.full((max_seqs, pages_per_seq), num_pages - 1,
@@ -1077,39 +1085,58 @@ class _GroupCache:
         self.base = np.zeros((max_seqs,), np.int64)
         self.released = 0       # pages a window gave back
 
+    def _plane(kind, i):  # noqa: N805 - a property factory
+        def get(self):
+            return getattr(self, kind)[i]
+
+        def put(self, value):
+            getattr(self, kind)[i] = value
+        return property(get, put)
+
+    k, v = _plane("pools", 0), _plane("pools", 1)
+    ks, vs = _plane("scales", 0), _plane("scales", 1)
+    del _plane
+
     def device(self):
-        return tuple(zip(self.k, self.v, self.ks, self.vs))
+        """A tuple a stack: its planes' pools, then their scales."""
+        return tuple(zip(*self.pools, *self.scales))
 
     def take(self, caches):
-        self.k, self.v, self.ks, self.vs = (list(x) for x in zip(*caches))
+        cols = [list(x) for x in zip(*caches)]
+        self.pools, self.scales = cols[:len(self.names)], \
+            cols[len(self.names):]
 
     def end(self, s):
         """Ordinal one past slot s's last held page."""
         return int(self.base[s]) + len(self.seq_pages[s])
 
+    def keys(self):
+        """The names `gather` files a page's arrays under: a plane's own,
+        and with an `s` its scales' (`k`, `v`, `ks`, `vs`)."""
+        return self.names + [n + "s" for n in self.names]
+
     def gather(self, pg):
-        """Pages `pg` of every layer, to the host: (layers, KVH, n,
-        page, D) arrays, scales None unless the pool is int8."""
+        """Pages `pg` of every layer, to the host: (layers, heads, n,
+        page, row) arrays by `keys()`, scales None unless the pool is
+        int8."""
         def cat(arrs):
             return None if arrs[0] is None else np.concatenate(
                 [np.asarray(a[:, :, pg]) for a in arrs])
-        return {"k": cat(self.k), "v": cat(self.v),
-                "ks": cat(self.ks), "vs": cat(self.vs)}
+        return dict(zip(self.keys(),
+                        map(cat, self.pools + self.scales)))
 
-    def scatter(self, pg, k, v, ks, vs):
-        """The inverse of `gather`: host arrays into pages `pg`."""
-        def put(arrs, host, dt=None):
+    def scatter(self, pg, host):
+        """The inverse of `gather`: host arrays, by `keys()`, into pages
+        `pg`."""
+        for key, arrs in zip(self.keys(), self.pools + self.scales):
+            if arrs[0] is None:
+                continue
             at = 0
             for i, a in enumerate(arrs):
                 n = a.shape[0]
                 arrs[i] = a.at[:, :, pg].set(
-                    jnp.asarray(host[at:at + n], dt or a.dtype))
+                    jnp.asarray(host[key][at:at + n], a.dtype))
                 at += n
-        put(self.k, k)
-        put(self.v, v)
-        if self.ks[0] is not None:
-            put(self.ks, ks, jnp.float32)
-            put(self.vs, vs, jnp.float32)
 
 
 class ServingEngine:
@@ -1189,7 +1216,8 @@ class ServingEngine:
                 ("tensor_parallel", tp_), ("prefix_cache", prefix_cache),
                 ("host_tier", host_tier_bytes),
                 ("spec_decode", int(spec_decode) > 1 or chunked_prefill),
-                ("bucketed", ragged is not None and not ragged)):
+                ("bucketed", ragged is not None and not ragged),
+                ("int8_cache", cache_dtype in ("int8", jnp.int8))):
             if asked and feature in model.unsupported:
                 raise ValueError(model.unsupported[feature])
         # mesh with a 'tp' axis: tensor-parallel serving — weights get
@@ -1310,7 +1338,9 @@ class ServingEngine:
                 "PT_SERVE_RAGGED=0) to keep the bucketed entry points")
         G_ = max(self.spec_decode, 1)
         if ragged_tokens is None:
-            ragged_tokens = 1 << math.ceil(
+            # the model's own word (`ServingModel.rows`), else a power
+            # of two over the slots
+            ragged_tokens = model.rows or 1 << math.ceil(
                 math.log2(max(max_seqs * G_, 16)))
         self.ragged_buf = int(ragged_tokens)
         if self.ragged and self.ragged_buf < max_seqs * G_:
@@ -1341,6 +1371,14 @@ class ServingEngine:
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_rows_max_expert = 0
+        # a share of a layer's experts: the rows each held expert got,
+        # and the assignments that went to experts held elsewhere
+        self.moe_rows_by_expert = None
+        self.moe_rows_elsewhere = 0
+        # groups whose layers select (`CacheGroup.select`): rows, columns
+        # scored, positions kept, rows that kept every column, a layer
+        self.dsa_by_type = {g.name: [0, 0, 0, 0] for g in model.groups
+                            if g.select is not None}
         # ... and how the kernel goes about it (pt_ragged_runs /
         # pt_ragged_kv_blocks): the runs of rows it launches a program
         # for, and its loop trips over KV blocks
@@ -2144,13 +2182,11 @@ class ServingEngine:
         p = self.host_tier.stash_take(id(req))
         for i, (gc, n_pages, base) in enumerate(zip(
                 self._caches, o["pages"], o["base"])):
-            part = {k: p[k + (f".{i}" if i else "")]
-                    for k in ("k", "v", "ks", "vs")}
+            part = {k: p[k + (f".{i}" if i else "")] for k in gc.keys()}
             gc.seq_pages[slot] = []
             gc.base[slot] = base
             pages = self._alloc_pages(slot, n_pages, gc)
-            self._scatter_host_kv(pages, part["k"], part["v"], part["ks"],
-                                  part["vs"], gc)
+            self._scatter_host_pages(pages, part, gc)
         self.lengths[slot] = S
         req._offload = None
         req._resume = False
@@ -2167,7 +2203,13 @@ class ServingEngine:
         host-tier restore. Scatters at the fixed pages_per_seq width
         (tail -> trash page), mirroring the offload gather: one compile
         total, not one per page count."""
-        gc = gc or self._caches[0]
+        self._scatter_host_pages(
+            pages, {"k": k, "v": v, "ks": ks, "vs": vs},
+            gc or self._caches[0])
+
+    def _scatter_host_pages(self, pages, host, gc):
+        """`_scatter_host_kv` for any cache group: `host` holds the
+        group's arrays by `gc.keys()`."""
         n = len(pages)
         ppseq = self.pages_per_seq
         pg = np.full((ppseq,), gc.num_pages - 1, np.int32)
@@ -2179,7 +2221,7 @@ class ServingEngine:
             out = np.zeros(a.shape[:2] + (ppseq,) + a.shape[3:], a.dtype)
             out[:, :, :n] = a
             return out
-        gc.scatter(pg, pad(k), pad(v), pad(ks), pad(vs))
+        gc.scatter(pg, {k: pad(a) for k, a in host.items()})
 
     def _drop_offload(self, req):
         """Forget a waiting request's host-stashed KV (cancel/failure
@@ -2764,6 +2806,13 @@ class ServingEngine:
             # window, and a slot's rows read from the first column its
             # first row sees
             w, by = gc.spec.window, self.ragged_by_type[gc.spec.name]
+            if gc.spec.select is not None:
+                seen = live[on]
+                dsa = self.dsa_by_type[gc.spec.name]
+                dsa[0] += int(seen.size)
+                dsa[1] += int(seen.sum())
+                dsa[2] += int(np.minimum(seen, gc.spec.select).sum())
+                dsa[3] += int((seen <= gc.spec.select).sum())
             if w is None:
                 by[0] += kv_tokens
                 by[1] += pairs
@@ -2826,6 +2875,13 @@ class ServingEngine:
                 self.moe_assignments += int(rows.sum())
                 self.moe_experts_touched += int((rows > 0).sum())
                 self.moe_rows_max_expert += int(rows.max(axis=1).sum())
+                if "moe_elsewhere" in aux:
+                    # the model holds a share of each layer's experts
+                    self.moe_rows_elsewhere += int(aux["moe_elsewhere"].sum())
+                    held = rows.sum(axis=0).astype(np.int64)
+                    self.moe_rows_by_expert = held if \
+                        self.moe_rows_by_expert is None else \
+                        self.moe_rows_by_expert + held
             self._ragged_consume(ticket, inflight, nxt, done, lp,
                                  seed_rows)
         return len(ticket.slots)

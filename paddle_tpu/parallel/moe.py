@@ -183,33 +183,71 @@ def grouped_product(lhs, rhs, group_sizes):
                               preferred_element_type=jnp.float32)
 
 
-def dropless_experts(x, expert, weight, w_gate, w_up, w_down):
-    """Every assignment computed, none dropped: SwiGLU experts over the
-    step's flat rows.
+def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
+                     num_experts=None):
+    """Every assignment to an expert held here computed, none dropped:
+    SwiGLU experts over the step's flat rows.
 
     x (T, H); expert (T, k) i32, the expert each of a row's k assignments
-    goes to (negative: routes nowhere, a slack row); weight (T, k) f32,
-    what its result is weighted by. w_gate / w_up (E, H, F) and w_down
-    (E, F, H): all the layer's experts.
+    goes to, numbered over ALL the layer's experts (negative: routes
+    nowhere, a slack row); weight (T, k) f32, what its result is weighted
+    by. w_gate / w_up (E, H, F) and w_down (E, F, H): the experts HELD,
+    the layer's experts `first .. first + E` of the `num_experts` the
+    router chose among (None: E, the whole layer, `models/laguna.py`). A
+    chip that holds a share (`models/glm_dsa.py`: 16 of 256) routes over
+    all of them all the same and computes its own: an assignment to an
+    absent expert sorts past the end, as a slack row's does, and adds
+    nothing here; the chips that hold the others add theirs, and the
+    shares' sums over every chip are the whole layer's
+    (tests/test_glm_dsa_serving.py).
 
     Rows are sorted by expert, three grouped products run over the
-    experts, and each row gets the weighted sum of its assignments
-    back. -> (out (T, H) f32, rows (E,) i32: the rows each expert got).
+    sorted rows that can hold an assignment, and each row gets the
+    weighted sum of its assignments back. A whole layer's are all T k;
+    a share's are four times its mean (T k E / num_experts) when they
+    hold every held assignment, as they all but always do, and all T k
+    in a step where they do not (`lax.cond`: a router that sends every
+    row to the held experts is slow, not wrong).
+    -> (out (T, H) f32, rows (E,) i32: the rows each held expert got).
     """
     T, k = expert.shape
     E = w_gate.shape[0]
-    routed = expert >= 0
+    if num_experts in (None, E):
+        routed, few = expert >= 0, T * k
+    else:
+        expert = expert - first
+        routed = (expert >= 0) & (expert < E)
+        few = min(T * k, -(-4 * T * k * E // num_experts // 128) * 128)
     key = jnp.where(routed, expert, E).reshape(-1)    # E sorts last
     order = jnp.argsort(key, stable=True)           # sorted -> assignment
     rows = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
-    xs = x[order // k]                              # (T*k, H)
-    h = jax.nn.silu(grouped_product(xs, w_gate, rows)) \
-        * grouped_product(xs, w_up, rows)
-    y = grouped_product(h.astype(x.dtype), w_down, rows)   # (T*k, H) f32
-    # rows past the experts' are nobody's: whatever is there, drop it
-    y = jnp.where((jnp.arange(T * k) < jnp.sum(rows))[:, None], y, 0.0)
+
+    def products(n):
+        """(n, H) f32: the first n sorted rows through their experts."""
+        xs = x[(order if n == T * k else order[:n]) // k]
+        h = jax.nn.silu(grouped_product(xs, w_gate, rows)) \
+            * grouped_product(xs, w_up, rows)
+        y = grouped_product(h.astype(x.dtype), w_down, rows)
+        # rows past the experts' are nobody's: whatever is there, drop it
+        return jnp.where((jnp.arange(n) < jnp.sum(rows))[:, None], y, 0.0)
+
+    def gathered(n, back):
+        """() -> (T*k, H): each assignment's row of `products(n)`, zero
+        for one that sorted past them (not held)."""
+        return lambda: jnp.where((back < n)[:, None],
+                                 products(n)[jnp.minimum(back, n - 1)], 0.0)
+
+    # the whole layer's products are traced before `back`, where they
+    # always were: `laguna_step`'s lowered text is held to the parent's
+    if few == T * k:
+        y = products(few)
     back = jnp.zeros((T * k,), jnp.int32).at[order].set(
         jnp.arange(T * k, dtype=jnp.int32))         # assignment -> sorted
+    if few < T * k:
+        y = jax.lax.cond(jnp.sum(rows) <= few, gathered(few, back),
+                         gathered(T * k, back))
     w = jnp.where(routed, weight, 0.0).astype(jnp.float32)
-    out = jnp.einsum("tkh,tk->th", y[back].reshape(T, k, -1), w)
+    if few == T * k:
+        y = y[back]
+    out = jnp.einsum("tkh,tk->th", y.reshape(T, k, -1), w)
     return out, rows

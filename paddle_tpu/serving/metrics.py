@@ -428,12 +428,18 @@ class EngineMetrics:
         self.moe_rows_max_expert = r.counter(
             "pt_moe_rows_max_expert",
             "Rows of the fullest expert, a sparse layer and step.")
+        self.moe_rows_elsewhere = r.counter(
+            "pt_moe_rows_elsewhere",
+            "Assignments that went to experts this chip does not hold "
+            "(a share of a layer's experts): routed here, computed by "
+            "the chips that hold them.")
+        self._moe_rows = []     # pt_moe_rows{expert=}, made on first report
         self._tok_seen = {"pad_tokens": 0, "ragged_tokens": 0,
                           "logit_rows": 0, "logit_rows_skipped": 0,
                           "ragged_attn_pairs": 0, "ragged_kv_tokens": 0,
                           "ragged_runs": 0, "ragged_kv_blocks": 0,
                           "moe_assignments": 0, "moe_experts_touched": 0,
-                          "moe_rows_max_expert": 0}
+                          "moe_rows_max_expert": 0, "moe_rows_elsewhere": 0}
         # by cache group, made when a group first reports (on_step):
         # pages held and given back, the kernel's work by layer type
         self._by_group = {}
@@ -664,14 +670,20 @@ class EngineMetrics:
                               ("moe_experts_touched",
                                self.moe_experts_touched),
                               ("moe_rows_max_expert",
-                               self.moe_rows_max_expert)):
+                               self.moe_rows_max_expert),
+                              ("moe_rows_elsewhere",
+                               self.moe_rows_elsewhere)):
             cur = getattr(engine, attr, 0)
             delta = cur - seen[attr]
             if delta > 0:
                 counter.inc(delta)
                 seen[attr] = cur
+        by_expert = getattr(engine, "moe_rows_by_expert", None)
+        if by_expert is not None:
+            self._on_experts(by_expert)
         for gc in getattr(engine, "_caches", ()):
-            self._on_group(gc, engine.ragged_by_type[gc.spec.name])
+            self._on_group(gc, engine.ragged_by_type[gc.spec.name],
+                           engine.dsa_by_type.get(gc.spec.name))
         self.on_handoff(engine)
         pc = getattr(engine, "prefix_cache", None)
         if pc is not None:
@@ -696,10 +708,26 @@ class EngineMetrics:
             self.queue_depth.set(depth)
             self.queue_depth_peak.set_to_max(depth)
 
-    def _on_group(self, gc, by_type):
+    def _on_experts(self, rows):
+        """`pt_moe_rows{expert=}`: the rows each expert held here got,
+        summed over sparse layers and steps (a share's experts are few:
+        a counter each)."""
+        for e in range(len(self._moe_rows), len(rows)):
+            self._moe_rows.append([self.registry.counter(
+                "pt_moe_rows", "Rows an expert held here got, summed "
+                "over sparse layers and steps.",
+                labels={"expert": str(e)}), 0])
+        for entry, cur in zip(self._moe_rows, rows):
+            if cur > entry[1]:
+                entry[0].inc(int(cur) - entry[1])
+                entry[1] = int(cur)
+
+    def _on_group(self, gc, by_type, dsa=None):
         """One cache group's gauges and counters (`pool=` /
         `layer_type=` its name), mirrored from the engine's ints."""
         name, r = gc.spec.name, self.registry
+        if dsa is not None:
+            self._on_select(name, gc, dsa)
         g = self._by_group.get(name)
         if g is None:
             g = self._by_group[name] = {
@@ -728,6 +756,35 @@ class EngineMetrics:
                                         ("pairs", by_type[1]))):
             if cur > g["seen"][i]:
                 g[key].inc(cur - g["seen"][i])
+                g["seen"][i] = cur
+
+    def _on_select(self, name, gc, dsa):
+        """A group whose layers select (`CacheGroup.select`): rows, the
+        columns they scored, the positions they kept, the rows that kept
+        every column, a layer of the group; and the pages its planes hold."""
+        g = self._by_group.get("dsa:" + name)
+        if g is None:
+            lab = {"layer_type": name}
+            g = self._by_group["dsa:" + name] = {
+                "counters": [self.registry.counter(n, h, labels=lab)
+                             for n, h in (
+                    ("pt_dsa_rows", "Rows that went through a layer's "
+                     "learned selection."),
+                    ("pt_dsa_context_tokens", "Columns those rows scored: "
+                     "each row's whole context, a layer."),
+                    ("pt_dsa_selected_tokens", "Positions those rows kept "
+                     "and attended over: min(context, index_topk) a row."),
+                    ("pt_dsa_dense_rows", "Rows whose context is no longer "
+                     "than index_topk: the selection keeps it all."))],
+                "pages": self.registry.gauge(
+                    "pt_latent_pages_in_use",
+                    "Pages of latent rows and index keys held by live "
+                    "slots.", labels={"pool": name}),
+                "seen": [0, 0, 0, 0]}
+        g["pages"].set(gc.pool.num_pages - gc.pool.available())
+        for i, (counter, cur) in enumerate(zip(g["counters"], dsa)):
+            if cur > g["seen"][i]:
+                counter.inc(cur - g["seen"][i])
                 g["seen"][i] = cur
 
     def observe_ttft(self, dt):

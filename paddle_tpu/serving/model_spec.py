@@ -13,35 +13,68 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Mapping, Optional, Tuple
 
-__all__ = ["CacheGroup", "ServingModel"]
+__all__ = ["CacheGroup", "Plane", "ServingModel"]
+
+
+@dataclasses.dataclass(frozen=True)
+class Plane:
+    """One thing a layer keeps a token: `width` values, for each of the
+    group's `kv_heads` (`per_head`) or once for all heads, in the cache's
+    type or in the plane's own `dtype` (a name: `float8_e4m3fn`)."""
+    name: str
+    width: int
+    per_head: bool = True
+    dtype: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
 class CacheGroup:
     """The layers of one type and what a token costs them.
 
+    `planes`: what a layer keeps a token, each a pool array of its own
+    width on the group's one page table (a page lives and dies in all of
+    them together). None is the K/V case, keys and values of `head_dim`
+    for each of `kv_heads`; a latent-attention layer states its own
+    (`models/glm_dsa.py`: one shared latent row and one index key).
     `stacks`: how the group's layers are laid out in pool arrays, each
-    `(layers, kv_heads, pages, page, head_dim)`: `(L,)` is one array
+    `(layers, heads, pages, page, row)` a plane: `(L,)` is one array
     the model scans over (`unified_step`, which writes and reads the
     carried stack through a layer index), `(1,) * L` one array a layer
     (an unrolled model); donated, either is updated in place.
     `window`: None, a slot holds every page of its context; W, a row at
     position p sees columns j with 0 <= p - j < W, and the engine gives
     a page back in the turn its last column falls behind every row the
-    slot will still feed."""
+    slot will still feed.
+    `select`: None, a row attends to all it sees; k, the model picks at
+    most k of those positions a row and layer (a learned sparse
+    selection): nothing the engine acts on, it counts the rows, the
+    columns scored and the positions kept (`pt_dsa_*`)."""
     name: str
     stacks: Tuple[int, ...]
     kv_heads: int
     head_dim: int
     window: Optional[int] = None
+    planes: Optional[Tuple[Plane, ...]] = None
+    select: Optional[int] = None
+
+    def __post_init__(self):
+        if self.planes is None:
+            object.__setattr__(self, "planes", (
+                Plane("k", self.head_dim), Plane("v", self.head_dim)))
 
     @property
     def layers(self):
         return sum(self.stacks)
 
     def bytes_per_token(self, itemsize):
-        """Keys and values of one token in all the group's layers."""
-        return 2 * self.layers * self.kv_heads * self.head_dim * itemsize
+        """What one token keeps in all the group's layers, by plane:
+        keys and values in the K/V case. Every plane at the cache's
+        `itemsize`: an upper count where a plane states a narrower
+        `dtype` of its own (plain data, no jax: no type is looked up
+        here)."""
+        return self.layers * itemsize * sum(
+            p.width * (self.kv_heads if p.per_head else 1)
+            for p in self.planes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,8 +83,9 @@ class ServingModel:
     heads that share a KV head (the ragged kernel's tile is derived for
     it). `step(params, caches, tables, tokens, tok_slot, tok_pos,
     config, page_size, **kw)` is `unified_step`'s descriptor contract
-    over every group at once: `caches[g][i]` is `(k, v, k_scale,
-    v_scale)` of group g's i-th stack, `tables[g]` its page table; it
+    over every group at once: `caches[g][i]` is group g's i-th stack,
+    its planes' pools and then their scales (`(k, v, k_scale, v_scale)`
+    in the K/V case), `tables[g]` its page table; it
     returns `(caches, logits, rec, tok_buf, aux)`, `aux` a dict of
     small device arrays the step's record carries beside the tokens
     (`moe_rows`: a sparse layer x the rows each expert got).
@@ -61,8 +95,13 @@ class ServingModel:
     further copy of them: that is what lets the scheduler run a ragged
     engine one step deep. A caller rebinds the pools from the result.
     `unsupported`: engine feature -> why this model cannot run under it;
-    the engine refuses at construction with that reason."""
+    the engine refuses at construction with that reason.
+    `rows`: the rows a step should hold (the engine's flat row buffer),
+    where the model knows better than the engine's rule, a power of two
+    over its slots: a model whose prompts run to tens of thousands of
+    tokens starves its slots on a buffer sized for chat."""
     groups: Tuple[CacheGroup, ...]
     q_group: int
     step: Callable
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
+    rows: Optional[int] = None

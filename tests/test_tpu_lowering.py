@@ -355,3 +355,83 @@ def test_window_none_traces_the_program_it_always_did():
             block_pages=2, **kw))(q, kp))
     assert text() == text(window=None)
     assert text(window=8) != text()
+
+
+@pytest.mark.parametrize("keys", ["bfloat16", "float8_e4m3fn"])
+def test_latent_kernels_compile_for_v5e_at_glm5s_shapes(one_chip, keys):
+    """`longctx_reason_saturated`'s three latent parts at GLM-5's widths
+    (512 rows; 32 index heads of 128 and 64 heads over a latent row of 576
+    in 640 lanes; pages of 128, 352 a sequence, a pool of 5,632; the top
+    2,048) through Mosaic's own compile step, the index keys in the
+    cache's type and in float8 (`GlmDsaConfig.index_key_dtype`)."""
+    from paddle_tpu.kernels import ragged_latent as rl
+    t, pages, n_pages, slots, nb = 512, 5632, 352, 48, 88
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    desc = [arg((slots, n_pages)), arg((t,)), arg((t,))]
+    scores = arg((nb, t, 512), jnp.float32)
+    for fn, args in [
+        (lambda q, w, keys, *d: rl.ragged_index_scores(
+            q, w, keys, *d, use_pallas=True),
+         [arg((t, 32, D), jnp.bfloat16), arg((t, 32), jnp.float32),
+          arg((1, pages, 128, D), jnp.dtype(keys))] + desc),
+        (lambda sc, pos: rl.dsa_select(sc, pos, 2048, use_pallas=True),
+         [scores, arg((t,))]),
+        (lambda q, lat, sc, thr, at, *d: rl.ragged_sparse_latent_attention(
+            q, lat, sc, thr, at, *d, rank=512, sm_scale=1 / 16,
+            use_pallas=True),
+         [arg((t, 64, 640), jnp.bfloat16),
+          arg((1, pages, 128, 640), jnp.bfloat16), scores, arg((t,)),
+          arg((t,))] + desc)]:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text or "custom-call" in text
+
+
+def test_glm_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
+    """`glm_step` at `glm-5.serve1`'s widths and pool (one dense and one
+    expert layer of its five are enough) through the TPU compiler: every
+    pool of both planes is donated and aliased to an output, the
+    temporaries stay far under one layer's pool, and the scatters update
+    the pools seen flat where they lie."""
+    import re
+    from paddle_tpu.models import glm_dsa as g
+    t, pages, page, slots, max_len = 512, 5632, 128, 48, 45056
+    c = g.GlmDsaConfig(vocab_size=19360, num_hidden_layers=2,
+                       first_k_dense_replace=1, experts_held=16)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda p, s: arg(s, jnp.float32 if p[-1].key == "router_bias"
+                         else jnp.bfloat16),
+        g.param_shapes(c), is_leaf=lambda x: isinstance(x, tuple))
+    stack = (arg((1, 1, pages, page, 640)), arg((1, 1, pages, page, 128)),
+             None, None)
+    sample = {"temp": arg((slots,), jnp.float32),
+              "top_k": arg((slots,), jnp.int32),
+              "top_p": arg((slots,), jnp.float32),
+              "key": arg((slots, 2), jnp.uint32),
+              "eos": arg((slots,), jnp.int32),
+              "remaining": arg((slots,), jnp.int32)}
+    compiled = g.glm_step.__wrapped__.lower(
+        params, ((stack, stack),), (arg((slots, max_len // page), jnp.int32),),
+        arg((t,), jnp.int32), arg((t,), jnp.int32), arg((t,), jnp.int32), c,
+        page, use_pallas=True, interpret=False, sample=sample,
+        need_rows=arg((slots,), jnp.int32),
+        tok_buf=arg((slots, max_len + 1), jnp.int32),
+        buf_write=arg((slots,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    pools = 2 * pages * page * (640 + 128) * 2
+    assert mem.alias_size_in_bytes == pools
+    assert mem.temp_size_in_bytes < 0.6e9
+    text = compiled.as_text()
+    for kernel in ("ragged_index_scores", "dsa_select",
+                   "ragged_sparse_latent_attention"):
+        assert kernel in text
+    flat = [ln for ln in text.splitlines()
+            if re.search(r"= \w+\[%d,(640|128)\]\S* fusion\(" % (pages * page),
+                         ln)]
+    assert len(flat) == 4 and all("scatter" in ln for ln in flat)
