@@ -181,27 +181,32 @@ def _sample_record(logits, lengths, active, sample):
     return tok, done, lp
 
 
-def _filter_draw(lg, temp, top_k, top_p, key, fold):
+def _filter_draw(lg, temp, top_k, top_p, key, fold, lt=None):
     """Filtered categorical draw shared by the decode record and the
     verify grid: lg (N, V) f32 logits; temp/top_k/top_p/fold (N,)
     traced; key (N, 2) u32. Returns (token (N,) i32, raw-model logprob
     at that token (N,) f32). temp == 0 rows take the argmax.
 
-    top_k/top_p are TRACED (a lax.top_k would need static k), so the
-    filter is ONE descending value sort + threshold arithmetic — no
-    argsort/unsort round trip, which matters because this graph is
-    inlined into every decode_step/verify_step compile. top_p keeps
-    the include-crossing-token convention measured on the top-k-
-    renormalized distribution (same as the host sampler's
-    filter-then-renormalize order): with Z = cumulative prob mass of
-    the top-k set, `cum - prob <= p * Z` over UNfiltered probs is
-    exactly `cum_f - prob_f <= p` over the filtered ones."""
+    The draw does what the wave's rows ask for: the filter, the
+    (seed, position) keys and the categorical draw (a Gumbel variate a
+    vocabulary entry) run under a `lax.cond` on "some row has
+    temp > 0", and an all-greedy wave, whose rows would each throw the
+    draw away below, runs the argmax alone. The predicate is a traced
+    scalar of the rows' own parameters: one program, and a request
+    that samples never retraces. A drawing wave runs the draw for
+    every row, so a row's token is the same whatever its neighbours.
+    `lt` is the rows' `_filtered_logits` where the caller holds them
+    already (a speculative step shares them with `_cand_probs`)."""
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
     sampled_on = temp > 0.0
-    lt = _filtered_logits(lg, temp, top_k, top_p)
-    step_key = jax.vmap(jax.random.fold_in)(key, fold)
-    drawn = jax.vmap(jax.random.categorical)(step_key, lt) \
-        .astype(jnp.int32)
+
+    def draw():
+        flt = _filtered_logits(lg, temp, top_k, top_p) if lt is None else lt
+        step_key = jax.vmap(jax.random.fold_in)(key, fold)
+        return jax.vmap(jax.random.categorical)(step_key, flt) \
+            .astype(jnp.int32)
+
+    drawn = jax.lax.cond(jnp.any(sampled_on), draw, lambda: greedy)
     tok = jnp.where(sampled_on, drawn, greedy)
     lp = jnp.take_along_axis(jax.nn.log_softmax(lg, axis=-1),
                              tok[:, None], axis=-1)[:, 0]
@@ -210,27 +215,48 @@ def _filter_draw(lg, temp, top_k, top_p, key, fold):
 
 def _filtered_logits(lg, temp, top_k, top_p):
     """The temperature/top_k/top_p filter HALF of `_filter_draw`:
-    lg (N, V) f32 → filtered temperature-scaled logits (kept tokens
+    lg (N, V) f32 -> filtered temperature-scaled logits (kept tokens
     untouched, dropped ones -1e30). ONE definition shared by the
     device draw and the spec-decode candidate-probability path, so the
     distribution a rejection sampler accepts against is exactly the
-    distribution the device sampler draws from."""
+    distribution the device sampler draws from.
+
+    top_k/top_p are TRACED (a lax.top_k would need static k), so the
+    cut is ONE descending value sort + threshold arithmetic, no
+    argsort/unsort round trip. top_p keeps the include-crossing-token
+    convention measured on the top-k-renormalized distribution (same
+    as the host sampler's filter-then-renormalize order): with Z =
+    cumulative prob mass of the top-k set, `cum - prob <= p * Z` over
+    UNfiltered probs is exactly `cum_f - prob_f <= p` over the
+    filtered ones.
+
+    The sort is most of a sampler's time (a third of a step at a
+    vocabulary of 100k), so it runs under a `lax.cond` on "some
+    sampled row cuts" (top_k > 0 or top_p < 1). A wave that holds such
+    a row runs the arithmetic for every row and cuts the rows that
+    ask; a row that does not keeps every token there as in any other
+    wave (its threshold would be its own minimum, give or take the
+    cumulative sum's rounding), so a row's filtered logits follow from
+    its own parameters alone, whatever its neighbours are."""
     V = lg.shape[-1]
     sampled_on = temp > 0.0
-    # greedy rows run the sampler arithmetic too (masked out by the
-    # caller's final where): a per-row branch would be value-dependent
-    # control flow. Guard the divide so temp=0 rows cannot overflow.
+    row_cuts = sampled_on & ((top_k > 0) | (top_p < 1.0))
+    # guard the divide so temp=0 rows cannot overflow
     lt = lg / jnp.where(sampled_on, jnp.maximum(temp, 1e-6), 1.0)[:, None]
-    k = jnp.where(top_k > 0, jnp.minimum(top_k, V), V)
-    sv = -jnp.sort(-lt, axis=-1)                     # descending values
-    probs = jax.nn.softmax(sv, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    z = jnp.take_along_axis(cum, (k - 1)[:, None], axis=-1)
-    keep = (jnp.arange(V)[None, :] < k[:, None]) & \
-        (cum - probs <= top_p[:, None] * z)
-    nkeep = jnp.maximum(keep.sum(-1), 1)             # crossing token stays
-    thresh = jnp.take_along_axis(sv, (nkeep - 1)[:, None], axis=-1)
-    return jnp.where(lt < thresh, -1e30, lt)
+
+    def cut(lt):
+        k = jnp.where(top_k > 0, jnp.minimum(top_k, V), V)
+        sv = -jnp.sort(-lt, axis=-1)                 # descending values
+        probs = jax.nn.softmax(sv, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        z = jnp.take_along_axis(cum, (k - 1)[:, None], axis=-1)
+        keep = (jnp.arange(V)[None, :] < k[:, None]) & \
+            (cum - probs <= top_p[:, None] * z)
+        nkeep = jnp.maximum(keep.sum(-1), 1)         # crossing token stays
+        thresh = jnp.take_along_axis(sv, (nkeep - 1)[:, None], axis=-1)
+        return jnp.where(row_cuts[:, None] & (lt < thresh), -1e30, lt)
+
+    return jax.lax.cond(jnp.any(row_cuts), cut, lambda lt: lt, lt)
 
 
 @jax.jit
@@ -244,14 +270,14 @@ def _spec_dist_rows(lg, temp, top_k, top_p):
         axis=-1)
 
 
-def _sample_grid(logits, lengths, sample):
+def _sample_grid(logits, lengths, sample, lt=None):
     """Verify-chunk twin of `_sample_record`: logits (B, G, V), one
     draw per chunk position. The emission following chunk token g sits
     at cache position lengths+g+1 pre-advanced — exactly the fold the
     plain decode path uses for that emission index, so an un-drafted
     sampled request in a verify chunk draws the IDENTICAL token the
-    plain engine would (cross-mode seeded parity). Returns
-    (token (B, G) i32, logprob (B, G) f32)."""
+    plain engine would (cross-mode seeded parity). `lt` (B * G, V):
+    `_filter_draw`'s. Returns (token (B, G) i32, logprob (B, G) f32)."""
     B, G, V = logits.shape
     lg = logits.astype(jnp.float32).reshape(B * G, V)
     pos = (lengths[:, None] + jnp.arange(G)[None, :] + 1).reshape(-1)
@@ -259,11 +285,11 @@ def _sample_grid(logits, lengths, sample):
     def rep(a):
         return jnp.repeat(a, G, axis=0)
     tok, lp = _filter_draw(lg, rep(sample["temp"]), rep(sample["top_k"]),
-                           rep(sample["top_p"]), rep(sample["key"]), pos)
+                           rep(sample["top_p"]), rep(sample["key"]), pos, lt)
     return tok.reshape(B, G), lp.reshape(B, G)
 
 
-def _sample_flat(logits, tok_slot, tok_pos, row_on, sample):
+def _sample_flat(logits, tok_slot, tok_pos, row_on, sample, lt=None):
     """Flat-row twin of `_sample_record`/`_sample_grid` for the unified
     ragged step: logits (T, V), one draw per buffer row. Per-slot
     sampling params gather through `tok_slot`; the PRNG fold is
@@ -273,14 +299,15 @@ def _sample_flat(logits, tok_slot, tok_pos, row_on, sample):
     ragged engine draws the identical token stream for identical
     logits, across sync and pipelined pumps. Spec engines evaluate
     stop conditions on host (their sample pytree carries no
-    eos/remaining) — their rows return done=False. Returns
+    eos/remaining) — their rows return done=False. `lt` (T, V):
+    `_filter_draw`'s. Returns
     (next_token (T,) i32, done (T,) bool, logprob (T,) f32)."""
 
     def g(a):
         return a[tok_slot]
     tok, lp = _filter_draw(logits.astype(jnp.float32), g(sample["temp"]),
                            g(sample["top_k"]), g(sample["top_p"]),
-                           g(sample["key"]), tok_pos + 1)
+                           g(sample["key"]), tok_pos + 1, lt)
     if "remaining" in sample:
         done = row_on & ((g(sample["remaining"]) <= 1) |
                          ((g(sample["eos"]) >= 0) & (tok == g(sample["eos"]))))
@@ -292,17 +319,20 @@ def _sample_flat(logits, tok_slot, tok_pos, row_on, sample):
 def _cand_probs(logits, tok_slot, sample, cand):
     """Per-row filtered-distribution probability of a CANDIDATE token
     (the spec-decode draft that follows the row): logits (R, V), cand
-    (R,) i32 → (R,) f32. Shares `_filtered_logits` with the device
-    draw, so the probability the rejection sampler accepts a draft
-    with is computed under exactly the distribution the device would
-    sample from — and the host fetches R floats instead of R vocab
-    rows (XLA CSEs the filter against `_sample_flat`'s)."""
+    (R,) i32 → ((R,) f32, the filtered logits (R, V) f32). Shares
+    `_filtered_logits` with the device draw, so the probability the
+    rejection sampler accepts a draft with is computed under exactly
+    the distribution the device would sample from — and the host
+    fetches R floats instead of R vocab rows. The filtered logits go
+    back to the caller, which hands them to the step's draw
+    (`_sample_flat(lt=)`, `_sample_grid(lt=)`): the step filters once
+    (two conditionals are not the compiler's to merge)."""
     def g(a):
         return a[tok_slot]
     lt = _filtered_logits(logits.astype(jnp.float32), g(sample["temp"]),
                           g(sample["top_k"]), g(sample["top_p"]))
     dist = jax.nn.softmax(lt, axis=-1)
-    return jnp.take_along_axis(dist, cand[:, None], axis=-1)[:, 0]
+    return jnp.take_along_axis(dist, cand[:, None], axis=-1)[:, 0], lt
 
 
 def _attn_tp(fn, mesh, quant):
@@ -599,12 +629,14 @@ def verify_step(params, k_pool, v_pool, page_table, lengths, tokens,
     # rejection sampler rides `cand_tok` candidate probabilities
     # (computed under the device filter) and pulls a distribution row
     # only on divergence.
-    rec = _sample_grid(logits, lengths, sample)
-    if cand_tok is not None:
+    if cand_tok is None:
+        rec = _sample_grid(logits, lengths, sample)
+    else:
         slot_of = jnp.repeat(jnp.arange(B, dtype=jnp.int32), G)
-        cand_p = _cand_probs(logits.reshape(B * G, -1), slot_of,
-                             sample, cand_tok.reshape(-1))
-        rec = rec + (cand_p.reshape(B, G),)
+        cand_p, lt = _cand_probs(logits.reshape(B * G, -1), slot_of,
+                                 sample, cand_tok.reshape(-1))
+        rec = _sample_grid(logits, lengths, sample, lt) \
+            + (cand_p.reshape(B, G),)
     return k_pool, v_pool, k_scale, v_scale, logits, rec
 
 
@@ -751,9 +783,12 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
     logits = h @ params["lm_head"]                       # (N, V)
     if sample is None:
         return k_pool, v_pool, k_scale, v_scale, logits
-    rec = _sample_flat(logits, tok_slot, tok_pos, row_on, sample)
-    if cand_tok is not None:
-        rec = rec + (_cand_probs(logits, tok_slot, sample, cand_tok),)
+    if cand_tok is None:
+        rec = _sample_flat(logits, tok_slot, tok_pos, row_on, sample)
+    else:
+        cand_p, lt = _cand_probs(logits, tok_slot, sample, cand_tok)
+        rec = _sample_flat(logits, tok_slot, tok_pos, row_on, sample, lt) \
+            + (cand_p,)
     if tok_buf is not None:
         # scatter this wave's sampled tokens back into the ring: the
         # token sampled at position p is the one position p+1 consumes.
@@ -1318,6 +1353,11 @@ class ServingEngine:
         self.spec_drafted = 0    # draft tokens fed to verify
         self.spec_accepted = 0   # draft tokens accepted
         self.device_steps = 0    # decode/verify device calls
+        # steps whose wave held a row that samples with a top_k / top_p
+        # that cuts, and a row that samples at all: how often the step's
+        # `_filtered_logits` and `_filter_draw` conditionals engage
+        self.sampler_filter_steps = 0
+        self.sampler_draw_steps = 0
         # unified ragged step (docs/serving.md § Unified ragged step):
         # every device dispatch — admission prefills, prefix-cache
         # suffix tails, spec-verify grids, single-token decodes — rides
@@ -2507,6 +2547,7 @@ class ServingEngine:
         active = np.zeros((B,), bool)
         active[launch] = True
         self.lengths = np.where(active, self.lengths + 1, self.lengths)
+        self._note_sampler(temps, top_ks, top_ps)
         sample = {"temp": jnp.asarray(temps),
                   "top_k": jnp.asarray(top_ks),
                   "top_p": jnp.asarray(top_ps),
@@ -2682,6 +2723,16 @@ class ServingEngine:
                     gc.base[s] = f
                     gc.released += n
 
+    def _note_sampler(self, temps, top_ks, top_ps):
+        """Count a wave for `pt_sampler_filter_steps` /
+        `pt_sampler_draw_steps` from the per-slot arrays the step is
+        about to get: the predicates of `_filtered_logits` and
+        `_filter_draw`, in the same float32."""
+        sampled = temps > 0.0
+        self.sampler_draw_steps += int(sampled.any())
+        self.sampler_filter_steps += int(
+            (sampled & ((top_ks > 0) | (top_ps < 1.0))).any())
+
     def _ragged_plan(self, carry):
         """The host half of `_ragged_launch` up to the transfers: page
         growth, the decode and prefill plans, and the wave's numpy
@@ -2835,6 +2886,7 @@ class ServingEngine:
             (-(-live[ends] // self._ragged_kv_block)).sum())
         n_decode = len(decode_plan)
         self.last_rows = (n_decode, row - n_decode)
+        self._note_sampler(temps, top_ks, top_ps)
         sampling = {"temp": temps, "top_k": top_ks, "top_p": top_ps,
                     "key": keys, "eos": eos, "remaining": remaining}
         # need-row descriptor: decode rows sit at buffer rows
@@ -2986,6 +3038,7 @@ class ServingEngine:
             top_ps[s] = req.top_p
             if req._base_key is not None:
                 keys[s] = req._base_key
+        self._note_sampler(temps, top_ks, top_ps)
         sample = {"temp": jnp.asarray(temps),
                   "top_k": jnp.asarray(top_ks),
                   "top_p": jnp.asarray(top_ps),

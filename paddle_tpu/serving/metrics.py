@@ -439,7 +439,9 @@ class EngineMetrics:
                           "ragged_attn_pairs": 0, "ragged_kv_tokens": 0,
                           "ragged_runs": 0, "ragged_kv_blocks": 0,
                           "moe_assignments": 0, "moe_experts_touched": 0,
-                          "moe_rows_max_expert": 0, "moe_rows_elsewhere": 0}
+                          "moe_rows_max_expert": 0, "moe_rows_elsewhere": 0,
+                          "sampler_filter_steps": 0,
+                          "sampler_draw_steps": 0}
         # by cache group, made when a group first reports (on_step):
         # pages held and given back, the kernel's work by layer type
         self._by_group = {}
@@ -453,6 +455,16 @@ class EngineMetrics:
             for part in TURN_PARTS}
         self.steps = r.counter(
             "pt_serving_device_steps", "Decode/verify device calls.")
+        # how often the step's sampler conditionals engage (ISSUE 40)
+        self.sampler_filter_steps = r.counter(
+            "pt_sampler_filter_steps",
+            "Device steps whose wave held a row with temperature > 0 "
+            "and a top_k or top_p that cuts: the steps that sort the "
+            "vocabulary.")
+        self.sampler_draw_steps = r.counter(
+            "pt_sampler_draw_steps",
+            "Device steps whose wave held a row with temperature > 0: "
+            "the steps that draw.")
         self.tokens = r.counter(
             "pt_serving_generated_tokens", "Output tokens emitted.")
         self.preemptions = r.counter(
@@ -672,7 +684,11 @@ class EngineMetrics:
                               ("moe_rows_max_expert",
                                self.moe_rows_max_expert),
                               ("moe_rows_elsewhere",
-                               self.moe_rows_elsewhere)):
+                               self.moe_rows_elsewhere),
+                              ("sampler_filter_steps",
+                               self.sampler_filter_steps),
+                              ("sampler_draw_steps",
+                               self.sampler_draw_steps)):
             cur = getattr(engine, attr, 0)
             delta = cur - seen[attr]
             if delta > 0:

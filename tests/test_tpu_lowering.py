@@ -182,6 +182,56 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _sample_shapes(arg, slots):
+    """A step's per-slot sampling arrays (`_sample_record`), as shapes."""
+    return {"temp": arg((slots,), jnp.float32),
+            "top_k": arg((slots,), jnp.int32),
+            "top_p": arg((slots,), jnp.float32),
+            "key": arg((slots, 2), jnp.uint32),
+            "eos": arg((slots,), jnp.int32),
+            "remaining": arg((slots,), jnp.int32)}
+
+
+def _vocab_sorts(text, rows, vocab):
+    """The `sort`s over a (rows, vocab) operand in a compiled step's
+    text -> (in the step's own body, under a `conditional`'s branch).
+    The body is every computation the entry reaches without going
+    through a conditional's branch: fusions, calls, loops."""
+    import re
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"\s*(ENTRY\s+)?%?([\w.-]+)\s.*->.*\{\s*$", line)
+        if m:
+            name = "ENTRY" if m.group(1) else m.group(2)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    called = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
+                        r"false_computation)=%?([\w.-]+)")
+    branches = re.compile(r"branch_computations=\{([^}]*)\}")
+    # the result is the values, or (values, their places) on the chip
+    sort = re.compile(r"= [^=]*\[%d,%d\][^=]* sort\(" % (rows, vocab))
+
+    def reach(through_conditionals):
+        seen, todo = set(), ["ENTRY"]
+        while todo:
+            c = todo.pop()
+            if c in seen:
+                continue
+            seen.add(c)
+            for line in comps[c]:
+                if through_conditionals or " conditional(" not in line:
+                    todo += called.findall(line)
+                    todo += [x.strip().lstrip("%") for grp in
+                             branches.findall(line) for x in grp.split(",")]
+        return seen
+
+    def count(names):
+        return sum(bool(sort.search(ln)) for c in names for ln in comps[c])
+    body = reach(False)
+    return count(body), count(reach(True) - body)
+
+
 @pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
 def test_ragged_compiles_for_v5e_at_the_serving_shape(one_chip, cache):
     """The serving cell's shape (32 rows, 32 / 8 heads of 128, pages of
@@ -271,12 +321,7 @@ def test_unified_step_compiles_for_v5e_with_its_pools_in_place(one_chip,
     pool = arg((L, kvh, pages, PAGE, D), cache)
     sc = arg((L, kvh, pages, PAGE, 1), jnp.float32) if cache == jnp.int8 \
         else None
-    sample = {"temp": arg((slots,), jnp.float32),
-              "top_k": arg((slots,), jnp.int32),
-              "top_p": arg((slots,), jnp.float32),
-              "key": arg((slots, 2), jnp.uint32),
-              "eos": arg((slots,), jnp.int32),
-              "remaining": arg((slots,), jnp.int32)}
+    sample = _sample_shapes(arg, slots)
     ring = arg((slots, 4097), jnp.int32)
     compiled = ls.unified_step.__wrapped__.lower(
         params, pool, pool, arg((slots, 256), jnp.int32),
@@ -302,6 +347,9 @@ def test_unified_step_compiles_for_v5e_with_its_pools_in_place(one_chip,
                          % (L * kvh * pages * PAGE, D), ln)]
     assert len(flat) == 2 and all(
         "scatter" in ln and '"aliasing_operands"' in ln for ln in flat)
+    # the sampler's sort over the vocabulary runs where a wave's rows
+    # ask for it: under a conditional, never in the step's own body
+    assert _vocab_sorts(text, slots, V) == (0, 1)
 
 
 @pytest.mark.parametrize("heads, window", [(48, None), (64, 512)],
@@ -410,12 +458,7 @@ def test_glm_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
         g.param_shapes(c), is_leaf=lambda x: isinstance(x, tuple))
     stack = (arg((1, 1, pages, page, 640)), arg((1, 1, pages, page, 128)),
              None, None)
-    sample = {"temp": arg((slots,), jnp.float32),
-              "top_k": arg((slots,), jnp.int32),
-              "top_p": arg((slots,), jnp.float32),
-              "key": arg((slots, 2), jnp.uint32),
-              "eos": arg((slots,), jnp.int32),
-              "remaining": arg((slots,), jnp.int32)}
+    sample = _sample_shapes(arg, slots)
     compiled = g.glm_step.__wrapped__.lower(
         params, ((stack, stack),), (arg((slots, max_len // page), jnp.int32),),
         arg((t,), jnp.int32), arg((t,), jnp.int32), arg((t,), jnp.int32), c,
@@ -435,3 +478,39 @@ def test_glm_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
             if re.search(r"= \w+\[%d,(640|128)\]\S* fusion\(" % (pages * page),
                          ln)]
     assert len(flat) == 4 and all("scatter" in ln for ln in flat)
+    assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
+
+
+def test_laguna_step_compiles_for_v5e_with_its_sort_under_a_conditional(
+        one_chip):
+    """`laguna_step` at `laguna-xs.2.serve1`'s widths (its leading dense
+    layer and one sliding layer of experts; 128 rows, 96 slots, a
+    vocabulary of 100,352) through the TPU compiler: the sampler's sort
+    of (96, 100,352), a third of `reason_saturated`'s step while it ran
+    every step, lies under a conditional and nowhere in the step's own
+    body."""
+    from paddle_tpu.models import laguna as lg
+    t, slots, page, max_len = 128, 96, 16, 8192
+    c = lg.LagunaConfig(num_hidden_layers=2)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        arg, lg.param_shapes(c), is_leaf=lambda x: isinstance(x, tuple))
+    groups = c.serving_model().groups
+    pages = {"full": 18432, "window": 4096}
+    caches = tuple(
+        tuple((arg((1, g.kv_heads, pages[g.name], page, D)),) * 2
+              + (None, None) for _ in g.stacks)
+        for g in groups)
+    tables = tuple(arg((slots, max_len // page), jnp.int32) for _ in groups)
+    sample = _sample_shapes(arg, slots)
+    text = lg.laguna_step.__wrapped__.lower(
+        params, caches, tables, arg((t,), jnp.int32), arg((t,), jnp.int32),
+        arg((t,), jnp.int32), c, page, use_pallas=True, interpret=False,
+        sample=sample, need_rows=arg((slots,), jnp.int32), block_pages=16,
+        tok_buf=arg((slots, max_len + 1), jnp.int32),
+        buf_write=arg((slots,), jnp.bool_)).compile().as_text()
+    assert "ragged_paged_attention" in text
+    assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
