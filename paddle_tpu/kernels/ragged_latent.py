@@ -26,6 +26,11 @@ key. A row of the step's flat buffer then goes through three parts:
     rows). What it reads beyond the selection earns no credit in the
     benchmark's roofline share.
 
+A layer that attends its WHOLE context (`models/longcat_flash.py`) has no
+index key, no scores and no selection: `ragged_latent_attention` is the
+third part alone, on the same walk and the same online softmax
+(`_attend`), masked by the causal limit only.
+
 Each has the plain `jax.numpy` path the CPU tests run (gathers a row's
 whole context, so only for small shapes) and a Pallas kernel, tested
 against it under `interpret=True`. Pools are one layer's, `(1, pages,
@@ -46,8 +51,8 @@ from ..ops.paged_attention import F0, F1, LANES, NEG_INF, Z, _on_tpu
 from .ragged_paged_attention import ragged_runs
 
 __all__ = ["ragged_index_scores", "dsa_select", "score_keys",
-           "ragged_sparse_latent_attention", "latent_block_pages",
-           "INDEX_ROWS", "ATTN_ROWS"]
+           "ragged_sparse_latent_attention", "ragged_latent_attention",
+           "latent_block_pages", "INDEX_ROWS", "ATTN_ROWS"]
 
 INDEX_ROWS, SELECT_ROWS, ATTN_ROWS = 8, 8, 16   # buffer rows a program
 _BLOCK_TOKENS = 512                             # columns a trip of the walk
@@ -345,6 +350,45 @@ def dsa_select(scores, tok_pos, k, *, use_pallas=None, interpret=False):
     return thr, at
 
 
+# -- what both attention kernels do to a block ---------------------------------
+def _begin(j, buf, m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j == 0)
+    def _finite_buffer():
+        # the tail of a run's last block is never fetched: what P = 0
+        # multiplies there must be finite, and stale pages are
+        buf[...] = jnp.zeros_like(buf)
+
+
+def _attend(q_ref, m_ref, l_ref, acc_ref, hs, kv, seen, *, scale, blk, rank):
+    """Online softmax of rows `hs` (a slice of rows x heads) over the
+    block: scores on the whole latent row, values its first `rank`."""
+    s = jax.lax.dot_general(
+        q_ref[hs], kv, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    s = jnp.where(seen, s, NEG_INF)
+    m_prev, l_prev = m_ref[hs], l_ref[hs]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(seen, jnp.exp(s - _fit_lanes(m_new, blk)),
+                  jnp.zeros_like(s))
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[hs] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[hs] = acc_ref[hs] * _fit_lanes(alpha, rank) + \
+        jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    m_ref[hs] = m_new
+
+
+def _finish(o_ref, l_ref, acc_ref, rank):
+    l = l_ref[...]
+    l_safe = jnp.where(l == F0, F1, l)          # rows of no run: 0 / 1
+    o_ref[...] = (acc_ref[...] / _fit_lanes(l_safe, rank)).astype(o_ref.dtype)
+
+
 # -- attention over the selected rows ---------------------------------------
 def _latent_kernel(runs_ref, qb_ref, ptab_ref, q_ref, sc_ref, thr_ref, at_ref,
                    spread_ref, pool, o_ref, buf, sem, m_ref, l_ref, acc_ref,
@@ -360,15 +404,7 @@ def _latent_kernel(runs_ref, qb_ref, ptab_ref, q_ref, sc_ref, thr_ref, at_ref,
     rows = q_ref.shape[0] // heads
     blk = block_pages * page_size
     j = pl.program_id(0)
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j == 0)
-    def _finite_buffer():
-        # the tail of a run's last block is never fetched: what P = 0
-        # multiplies there must be finite, and stale pages are
-        buf[...] = jnp.zeros_like(buf)
+    _begin(j, buf, m_ref, l_ref, acc_ref)
 
     def seen_by(i, b, col, lim):
         """(1, blk) bool: what row i's selection keeps of block b, under
@@ -379,25 +415,8 @@ def _latent_kernel(runs_ref, qb_ref, ptab_ref, q_ref, sc_ref, thr_ref, at_ref,
         return ((key > thr) | ((key == thr) & (
             col <= _fit_lanes(at_ref[i:i + 1, :], blk)))) & (col < lim)
 
-    def attend(hs, kv, seen):
-        """Online softmax of rows `hs` (a slice of rows x heads) over the
-        block: scores on the whole latent row, values its first `rank`."""
-        s = jax.lax.dot_general(
-            q_ref[hs], kv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev, l_prev = m_ref[hs], l_ref[hs]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(seen, jnp.exp(s - _fit_lanes(m_new, blk)),
-                      jnp.zeros_like(s))
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[hs] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[hs] = acc_ref[hs] * _fit_lanes(alpha, rank) + \
-            jax.lax.dot_general(
-                p.astype(kv.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        m_ref[hs] = m_new
-
+    attend = functools.partial(_attend, q_ref, m_ref, l_ref, acc_ref,
+                               scale=scale, blk=blk, rank=rank)
     seen_ref[...] = jnp.zeros_like(seen_ref)
 
     def block(r, b, slot_):
@@ -429,9 +448,7 @@ def _latent_kernel(runs_ref, qb_ref, ptab_ref, q_ref, sc_ref, thr_ref, at_ref,
 
     _walk(runs_ref, qb_ref, ptab_ref, (pool,), (buf,), sem,
           page_size=page_size, block_pages=block_pages, block=block)
-    l = l_ref[...]
-    l_safe = jnp.where(l == F0, F1, l)          # rows of no run: 0 / 1
-    o_ref[...] = (acc_ref[...] / _fit_lanes(l_safe, rank)).astype(o_ref.dtype)
+    _finish(o_ref, l_ref, acc_ref, rank)
 
 
 def _selected(scores, thr, at):
@@ -444,6 +461,19 @@ def _selected(scores, thr, at):
                                    & (cols <= at[:, None]))
 
 
+def _softmax_over(s, seen, kv, rank, dtype):
+    """The `jax.numpy` paths' softmax: scores s (T, heads, C) over each
+    row's context kv (T, C, row) f32 where `seen` (T, C) says so, values
+    the context's first `rank`; a row that sees nothing comes back zero."""
+    s = jnp.where(seen[:, None, :], s, NEG_INF)
+    p = jnp.where(seen[:, None, :],
+                  jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+    l = jnp.sum(p, -1, keepdims=True)
+    o = jnp.einsum("thc,tcr->thr", p / jnp.where(l == 0, 1.0, l),
+                   kv[..., :rank])
+    return o.astype(dtype)
+
+
 def _latent_reference(q, pages_, scores, thr, at, page_table, tok_slot,
                       tok_pos, rank, scale):
     nb, t, blk = scores.shape
@@ -452,13 +482,7 @@ def _latent_reference(q, pages_, scores, thr, at, page_table, tok_slot,
     s = jnp.einsum("thr,tcr->thc", q.astype(jnp.float32), kv) * scale
     seen = _selected(scores, thr, at) & (
         jnp.arange(nb * blk)[None, :] <= tok_pos[:, None])
-    s = jnp.where(seen[:, None, :], s, NEG_INF)
-    p = jnp.where(seen[:, None, :],
-                  jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
-    l = jnp.sum(p, -1, keepdims=True)
-    o = jnp.einsum("thc,tcr->thr", p / jnp.where(l == 0, 1.0, l),
-                   kv[..., :rank])
-    return o.astype(q.dtype)
+    return _softmax_over(s, seen, kv, rank, q.dtype)
 
 
 def ragged_sparse_latent_attention(q, latent_pages, scores, thr, at,
@@ -530,4 +554,108 @@ def ragged_sparse_latent_attention(q, latent_pages, scores, thr, at,
         name="ragged_sparse_latent_attention",
     )(runs, qb_first, page_table.astype(jnp.int32), q2, sc, lanes(thr),
       lanes(at), spread, latent_pages)
+    return o.reshape(t_pad, heads, rank)[:t]
+
+
+# -- attention over the whole context ---------------------------------------
+def _dense_latent_kernel(runs_ref, qb_ref, ptab_ref, q_ref, rowid_ref, pool,
+                         o_ref, buf, sem, m_ref, l_ref, acc_ref, *, scale,
+                         page_size, block_pages, heads, rank):
+    """`_latent_kernel` without a selection: a row sees every column under
+    its causal limit. A run that fills the q block goes through as one
+    product, its rows' limits from `rowid_ref` (rows * heads, LANES), the
+    row each row x head belongs to; any other a live row at a time. Rows
+    kept in a narrower type than the queries' (float8) are widened to it
+    in fast memory."""
+    rows = q_ref.shape[0] // heads
+    blk = block_pages * page_size
+    j = pl.program_id(0)
+    _begin(j, buf, m_ref, l_ref, acc_ref)
+    attend = functools.partial(_attend, q_ref, m_ref, l_ref, acc_ref,
+                               scale=scale, blk=blk, rank=rank)
+
+    def block(r, b, slot_):
+        kv = buf[slot_, 0].reshape(blk, buf.shape[-1]).astype(q_ref.dtype)
+        col = _cols(b, blk)
+        row0 = j * np.int32(rows)
+        whole = runs_ref[1, r] == np.int32(rows)    # a prompt's chunk
+
+        @pl.when(whole)
+        def _every_row_at_once():
+            attend(slice(None), kv, col < _row_limits(runs_ref, r, row0)[1]
+                   + _fit_lanes(rowid_ref[...], blk))
+
+        for i in range(rows):
+            mine, lim = _row_limits(runs_ref, r, row0 + np.int32(i))
+
+            @pl.when(mine & ~whole)
+            def _(i=i, lim=lim):
+                attend(slice(i * heads, (i + 1) * heads), kv, col < lim)
+
+    _walk(runs_ref, qb_ref, ptab_ref, (pool,), (buf,), sem,
+          page_size=page_size, block_pages=block_pages, block=block)
+    _finish(o_ref, l_ref, acc_ref, rank)
+
+
+def _dense_latent_reference(q, pages_, page_table, tok_slot, tok_pos, rank,
+                            scale):
+    """`_latent_reference` with every column under the causal limit seen."""
+    kv = _context(pages_, page_table, tok_slot,
+                  page_table.shape[1]).astype(jnp.float32)
+    s = jnp.einsum("thr,tcr->thc", q.astype(jnp.float32), kv) * scale
+    seen = jnp.arange(kv.shape[1])[None, :] <= tok_pos[:, None]
+    return _softmax_over(s, seen, kv, rank, q.dtype)
+
+
+def ragged_latent_attention(q, latent_pages, page_table, tok_slot, tok_pos,
+                            *, rank, sm_scale, use_pallas=None,
+                            interpret=False, block_pages=None, runs=None):
+    """`ragged_sparse_latent_attention` for a layer that selects nothing:
+    q (T, heads, row) against latent_pages (1, P, page, row), in the
+    queries' type or a narrower one (float8_e4m3fn) -> (T, heads, rank),
+    the softmax over EVERY position s <= tok_pos[t] of its first `rank`
+    values. Rows with pos -1 come back zero. `runs`: `ragged_runs(
+    tok_slot, tok_pos, heads, ATTN_ROWS)`."""
+    t, heads, row = q.shape
+    page = latent_pages.shape[-2]
+    bp = latent_block_pages(page, page_table.shape[1], block_pages)
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    if not use_pallas and not interpret:
+        return _dense_latent_reference(q, latent_pages, page_table, tok_slot,
+                                       tok_pos, rank, sm_scale)
+    if runs is None:
+        runs = ragged_runs(tok_slot, tok_pos, heads, ATTN_ROWS)
+    runs, qb_first = runs
+    n_qb = qb_first.shape[0] - 1
+    t_pad = n_qb * ATTN_ROWS
+    q2 = jnp.pad(q, ((0, t_pad - t), (0, 0), (0, 0))).reshape(
+        t_pad * heads, row)
+    rowid = jnp.broadcast_to(
+        (jnp.arange(ATTN_ROWS * heads, dtype=jnp.int32) // heads)[:, None],
+        (ATTN_ROWS * heads, LANES))
+    stat = pltpu.VMEM((ATTN_ROWS * heads, LANES), jnp.float32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(n_qb,),
+        in_specs=[
+            pl.BlockSpec((ATTN_ROWS * heads, row), lambda j, *_: (j, Z)),
+            pl.BlockSpec((ATTN_ROWS * heads, LANES), lambda j, *_: (Z, Z)),
+            pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((ATTN_ROWS * heads, rank),
+                               lambda j, *_: (j, Z)),
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, bp) + latent_pages.shape[-2:],
+                       latent_pages.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)), stat, stat,
+            pltpu.VMEM((ATTN_ROWS * heads, rank), jnp.float32)])
+    o = pl.pallas_call(
+        functools.partial(_dense_latent_kernel, scale=np.float32(sm_scale),
+                          page_size=page, block_pages=bp, heads=heads,
+                          rank=rank),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t_pad * heads, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="ragged_latent_attention",
+    )(runs, qb_first, page_table.astype(jnp.int32), q2, rowid, latent_pages)
     return o.reshape(t_pad, heads, rank)[:t]

@@ -1412,9 +1412,12 @@ class ServingEngine:
         self.moe_experts_touched = 0
         self.moe_rows_max_expert = 0
         # a share of a layer's experts: the rows each held expert got,
-        # and the assignments that went to experts held elsewhere
+        # and the assignments that went to experts held elsewhere; and
+        # those that went to identity experts, which cost no product and
+        # are nobody's to compute
         self.moe_rows_by_expert = None
         self.moe_rows_elsewhere = 0
+        self.moe_assignments_zero = 0
         # groups whose layers select (`CacheGroup.select`): rows, columns
         # scored, positions kept, rows that kept every column, a layer
         self.dsa_by_type = {g.name: [0, 0, 0, 0] for g in model.groups
@@ -2934,6 +2937,8 @@ class ServingEngine:
                     self.moe_rows_by_expert = held if \
                         self.moe_rows_by_expert is None else \
                         self.moe_rows_by_expert + held
+                if "moe_zero" in aux:
+                    self.moe_assignments_zero += int(aux["moe_zero"].sum())
             self._ragged_consume(ticket, inflight, nxt, done, lp,
                                  seed_rows)
         return len(ticket.slots)

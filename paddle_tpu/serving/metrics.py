@@ -432,7 +432,12 @@ class EngineMetrics:
             "pt_moe_rows_elsewhere",
             "Assignments that went to experts this chip does not hold "
             "(a share of a layer's experts): routed here, computed by "
-            "the chips that hold them.")
+            "the chips that hold them. Identity experts are held "
+            "nowhere and not counted.")
+        self.moe_assignments_zero = r.counter(
+            "pt_moe_assignments_zero",
+            "Assignments to identity (zero-compute) experts: their "
+            "weight times the row, added with no product.")
         self._moe_rows = []     # pt_moe_rows{expert=}, made on first report
         self._tok_seen = {"pad_tokens": 0, "ragged_tokens": 0,
                           "logit_rows": 0, "logit_rows_skipped": 0,
@@ -440,6 +445,7 @@ class EngineMetrics:
                           "ragged_runs": 0, "ragged_kv_blocks": 0,
                           "moe_assignments": 0, "moe_experts_touched": 0,
                           "moe_rows_max_expert": 0, "moe_rows_elsewhere": 0,
+                          "moe_assignments_zero": 0,
                           "sampler_filter_steps": 0,
                           "sampler_draw_steps": 0}
         # by cache group, made when a group first reports (on_step):
@@ -685,6 +691,8 @@ class EngineMetrics:
                                self.moe_rows_max_expert),
                               ("moe_rows_elsewhere",
                                self.moe_rows_elsewhere),
+                              ("moe_assignments_zero",
+                               self.moe_assignments_zero),
                               ("sampler_filter_steps",
                                self.sampler_filter_steps),
                               ("sampler_draw_steps",

@@ -40,7 +40,11 @@ class CacheGroup:
     `(layers, heads, pages, page, row)` a plane: `(L,)` is one array
     the model scans over (`unified_step`, which writes and reads the
     carried stack through a layer index), `(1,) * L` one array a layer
-    (an unrolled model); donated, either is updated in place.
+    (an unrolled model); donated, either is updated in place. A layer
+    here is a CACHE layer, something that keeps a row a token: a model
+    layer with two attention sublayers is two of them
+    (`models/longcat_flash.py`: `(1,) * 2L`), and `layers`,
+    `bytes_per_token` and the per-group counters count these.
     `window`: None, a slot holds every page of its context; W, a row at
     position p sees columns j with 0 <= p - j < W, and the engine gives
     a page back in the turn its last column falls behind every row the
@@ -88,7 +92,9 @@ class ServingModel:
     in the K/V case), `tables[g]` its page table; it
     returns `(caches, logits, rec, tok_buf, aux)`, `aux` a dict of
     small device arrays the step's record carries beside the tokens
-    (`moe_rows`: a sparse layer x the rows each expert got).
+    (`moe_rows`: a sparse layer x the rows each expert held here got;
+    `moe_elsewhere`: its assignments to real experts held elsewhere;
+    `moe_zero`: its assignments to identity experts, nobody's to hold).
     `step` DONATES `caches` and the pools come back where they lay
     (`unified_step`, `laguna_step`; held to the compiled programs by
     `tests/test_tpu_lowering.py`), so a second step in flight needs no

@@ -514,3 +514,58 @@ def test_laguna_step_compiles_for_v5e_with_its_sort_under_a_conditional(
         buf_write=arg((slots,), jnp.bool_)).compile().as_text()
     assert "ragged_paged_attention" in text
     assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
+
+
+@pytest.mark.parametrize("stored", ["bfloat16", "float8_e4m3fn"])
+def test_dense_latent_kernel_compiles_for_v5e_at_longcats_shapes(one_chip,
+                                                                 stored):
+    """`agent_rollout_saturated`'s attention (256 rows, 64 heads over a
+    latent row of 576 in 640 lanes; pages of 128, 96 a sequence, a pool of
+    3,072) through Mosaic's own compile step, the latent rows in the
+    cache's type and in float8 (`LongcatFlashConfig.latent_dtype`)."""
+    from paddle_tpu.kernels import ragged_latent as rl
+    t, pages, n_pages, slots = 256, 3072, 96, 96
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda q, lat, *d: rl.ragged_latent_attention(
+        q, lat, *d, rank=512, sm_scale=192 ** -0.5, use_pallas=True)).lower(
+        arg((t, 64, 640), jnp.bfloat16),
+        arg((1, pages, 128, 640), jnp.dtype(stored)),
+        arg((slots, n_pages)), arg((t,)), arg((t,))).compile().as_text()
+    assert "ragged_latent_attention" in text
+
+
+def test_longcat_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
+    """`longcat_step` at `longcat-flash-chat.serve1`'s widths and pool (one
+    double layer of its four is enough) through the TPU compiler: both
+    sublayers' pools are donated and aliased to an output, the
+    temporaries stay far under one pool, the dense kernel runs twice and
+    the experts' grouped products are there."""
+    from paddle_tpu.models import longcat_flash as lc
+    t, pages, page, slots, max_len = 256, 3072, 128, 96, 12288
+    c = lc.LongcatFlashConfig(vocab_size=16384, num_layers=1, experts_held=16)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda p, s: arg(s, jnp.float32 if p[-1].key == "router_bias"
+                         else jnp.bfloat16),
+        lc.param_shapes(c), is_leaf=lambda x: isinstance(x, tuple))
+    stack = (arg((1, 1, pages, page, 640)), None)
+    compiled = lc.longcat_step.__wrapped__.lower(
+        params, ((stack, stack),), (arg((slots, max_len // page), jnp.int32),),
+        arg((t,), jnp.int32), arg((t,), jnp.int32), arg((t,), jnp.int32), c,
+        page, use_pallas=True, interpret=False,
+        sample=_sample_shapes(arg, slots), need_rows=arg((slots,), jnp.int32),
+        tok_buf=arg((slots, max_len + 1), jnp.int32),
+        buf_write=arg((slots,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * pages * page * 640 * 2
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    assert "ragged_latent_attention" in text and "ragged-dot" in text
+    assert "ragged_sparse_latent_attention" not in text
+    assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
