@@ -31,6 +31,14 @@ index key, no scores and no selection: `ragged_latent_attention` is the
 third part alone, on the same walk and the same online softmax
 (`_attend`), masked by the causal limit only.
 
+Both attention kernels are `_attention_walk`, which has two bodies and
+picks one a run by the run's row count: a run that fills its q block of
+`ATTN_ROWS` rows (`whole`: a prompt's chunk) is one product a trip; any
+other (`row`: a decode row; `piece`: the 2-15 rows of a chunk at a q
+block's edge) is walked a row at a time by ONE body entered at the run's
+row, whose trip holds only what changes with the trip (the engine counts
+the kinds: `pt_latent_runs`, `pt_latent_trips`).
+
 Each has the plain `jax.numpy` path the CPU tests run (gathers a row's
 whole context, so only for small shapes) and a Pallas kernel, tested
 against it under `interpret=True`. Pools are one layer's, `(1, pages,
@@ -78,13 +86,20 @@ def score_keys(scores):
 
 
 def _walk(runs_ref, qb_ref, ptab_ref, pools, bufs, sem, *, page_size,
-          block_pages, block):
+          block_pages, visit, passes=None):
     """`_ragged_kernel`'s walk: program j visits the runs of q block j
     and, a run, its KV blocks of `block_pages` pages, each page fetched
     through the page table into the buffer the NEXT trip reads (the
-    prefetch crosses from a run's last block to the next run's first);
-    `block(r, b, slot)` computes on what has arrived. A page the run does
-    not own is neither fetched nor waited for."""
+    prefetch crosses from a run's last block to the next walk's first).
+    A page the run does not own is neither fetched nor waited for.
+
+    A run's context is walked `passes(r)` times (once where None).
+    `visit(r, i, trips)` is called once a walk, pass i of run r: what is
+    the walk's and not a trip's is computed there, and it calls
+    `trips(body, init)` exactly ONCE on the path it takes, which runs
+    `body(b, slot_, carry) -> carry` on what has arrived, a block b
+    (`slot_`: the buffer's half it lies in), and returns the last
+    carry."""
     j = pl.program_id(0)
     r_lo, r_hi = qb_ref[j], qb_ref[j + 1]
     blk = np.int32(block_pages * page_size)
@@ -107,23 +122,35 @@ def _walk(runs_ref, qb_ref, ptab_ref, pools, bufs, sem, *, page_size,
     def _first_fetch():
         for_pages(r_lo, Z, Z, "start")
 
-    def run(r, slot_):
+    def run(r, slot0):
         n_blocks = pl.cdiv(runs_ref[3, r], blk)
 
-        def trip(b, slot_):
-            last = b + _ONE >= n_blocks
-            r_next = jnp.where(last, r + _ONE, r)
-            b_next = jnp.where(last, Z, b + _ONE)
+        def walk(i, slot0, again):
+            def trips(body, init):
+                def trip(b, carry):
+                    slot_ = (slot0 + b) & _ONE
+                    last = b + _ONE >= n_blocks
+                    r_next = jnp.where(last & ~again, r + _ONE, r)
+                    b_next = jnp.where(last, Z, b + _ONE)
 
-            @pl.when(r_next < r_hi)
-            def _prefetch():
-                for_pages(r_next, b_next, _ONE - slot_, "start")
+                    @pl.when(r_next < r_hi)
+                    def _prefetch():
+                        for_pages(r_next, b_next, _ONE - slot_, "start")
 
-            for_pages(r, b, slot_, "wait")
-            block(r, b, slot_)
-            return _ONE - slot_
+                    for_pages(r, b, slot_, "wait")
+                    return body(b, slot_, carry)
 
-        return jax.lax.fori_loop(Z, n_blocks, trip, slot_)
+                return jax.lax.fori_loop(Z, n_blocks, trip, init)
+
+            visit(r, i, trips)
+            return (slot0 + n_blocks) & _ONE
+
+        if passes is None:
+            return walk(Z, slot0, np.bool_(False))
+        n_passes = passes(r)
+        return jax.lax.fori_loop(
+            Z, n_passes, lambda i, slot0: walk(i, slot0, i + _ONE < n_passes),
+            slot0)
 
     jax.lax.fori_loop(r_lo, r_hi, run, Z)
 
@@ -161,23 +188,28 @@ def _index_kernel(runs_ref, qb_ref, ptab_ref, q_ref, w_ref, pool, o_ref,
     j = pl.program_id(0)
     o_ref[...] = jnp.full_like(o_ref, _NO_SCORE)
 
-    def block(r, b, slot_):
-        k = buf[slot_, 0].reshape(blk, buf.shape[-1]).astype(q_ref.dtype)
-        s = jax.lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        col = _cols(b, blk)
-        for i in range(rows):
-            mine, lim = _row_limits(runs_ref, r, j * np.int32(rows)
-                                    + np.int32(i))
+    def visit(r, _, trips):
+        def block(b, slot_, carry):
+            k = buf[slot_, 0].reshape(blk, buf.shape[-1]).astype(q_ref.dtype)
+            s = jax.lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            col = _cols(b, blk)
+            for i in range(rows):
+                mine, lim = _row_limits(runs_ref, r, j * np.int32(rows)
+                                        + np.int32(i))
 
-            @pl.when(mine)
-            def _(i=i, lim=lim):
-                tot = _weighted_relu(s[i * heads:(i + 1) * heads],
-                                     _fit_lanes(w_ref[i], blk))
-                o_ref[b, i:i + 1, :] = jnp.where(col < lim, tot, _NO_SCORE)
+                @pl.when(mine)
+                def _(i=i, lim=lim):
+                    tot = _weighted_relu(s[i * heads:(i + 1) * heads],
+                                         _fit_lanes(w_ref[i], blk))
+                    o_ref[b, i:i + 1, :] = jnp.where(col < lim, tot,
+                                                     _NO_SCORE)
+            return carry
+
+        trips(block, Z)
 
     _walk(runs_ref, qb_ref, ptab_ref, (pool,), (buf,), sem,
-          page_size=page_size, block_pages=block_pages, block=block)
+          page_size=page_size, block_pages=block_pages, visit=visit)
 
 
 def _context(pages_, page_table, tok_slot, n_pages):
@@ -363,24 +395,99 @@ def _begin(j, buf, m_ref, l_ref, acc_ref):
         buf[...] = jnp.zeros_like(buf)
 
 
-def _attend(q_ref, m_ref, l_ref, acc_ref, hs, kv, seen, *, scale, blk, rank):
-    """Online softmax of rows `hs` (a slice of rows x heads) over the
-    block: scores on the whole latent row, values its first `rank`."""
+def _attend(q, kv, seen, m_prev, l_prev, acc_prev, *, scale, blk, rank):
+    """One block of the online softmax of q's rows (rows x heads, row):
+    scores on the whole latent row, values its first `rank`; the rows'
+    running maximum, sum and accumulator in, the new ones out."""
     s = jax.lax.dot_general(
-        q_ref[hs], kv, (((1,), (1,)), ((), ())),
+        q, kv, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     s = jnp.where(seen, s, NEG_INF)
-    m_prev, l_prev = m_ref[hs], l_ref[hs]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.where(seen, jnp.exp(s - _fit_lanes(m_new, blk)),
                   jnp.zeros_like(s))
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[hs] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[hs] = acc_ref[hs] * _fit_lanes(alpha, rank) + \
-        jax.lax.dot_general(
-            p.astype(kv.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    m_ref[hs] = m_new
+    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc = acc_prev * _fit_lanes(alpha, rank) + jax.lax.dot_general(
+        p.astype(kv.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc
+
+
+def _attention_walk(runs_ref, qb_ref, ptab_ref, q_ref, pool, o_ref, buf, sem,
+                    m_ref, l_ref, acc_ref, *, scale, page_size, block_pages,
+                    heads, rank, whole_seen, row_seen):
+    """What both attention kernels are. Program j: q block j's rows x
+    heads (rows * heads, row) over the latent pages of the runs in it, by
+    one of TWO bodies, chosen a run by its row count:
+
+      * a run that fills the q block (a prompt's chunk) walks its context
+        once, all its rows against a block as ONE product; the mask is
+        `whole_seen(r, row0, b, col)` (rows * heads, blk), `row0` the q
+        block's first buffer row; the state is the q block's, in `m_ref`,
+        `l_ref`, `acc_ref`;
+      * any other run (a decode row; the 2-15 rows of a chunk's piece at
+        a q block's edge) walks it once a ROW, so that no product is made
+        for rows of other runs. What is the row's and not the trip's is
+        taken before its first trip: its heads' queries, and
+        `row_seen(at, lim)` for the q block's row `at` under its causal
+        limit, which returns the trip's `seen(b, col)` (1, blk). Maximum,
+        sum and accumulator start as a fresh row's, are carried through
+        the trips as values and stored when the walk ends.
+
+    Latent rows kept in a narrower type than the queries' (float8) are
+    widened to it in fast memory."""
+    rows = q_ref.shape[0] // heads
+    blk = block_pages * page_size
+    j = pl.program_id(0)
+    row0 = j * np.int32(rows)
+    _begin(j, buf, m_ref, l_ref, acc_ref)
+    attend = functools.partial(_attend, scale=scale, blk=blk, rank=rank)
+
+    def block_of(slot_):
+        return buf[slot_, 0].reshape(blk, buf.shape[-1]).astype(q_ref.dtype)
+
+    def visit(r, i, trips):
+        first, n_rows, kv_len = runs_ref[0, r], runs_ref[1, r], runs_ref[3, r]
+        whole = n_rows == np.int32(rows)
+
+        @pl.when(whole)
+        def _every_row_at_once():
+            def block(b, slot_, carry):
+                m, l, acc = attend(
+                    q_ref[...], block_of(slot_),
+                    whole_seen(r, row0, b, _cols(b, blk)), m_ref[...],
+                    l_ref[...], acc_ref[...])
+                m_ref[...] = m
+                l_ref[...] = l
+                acc_ref[...] = acc
+                return carry
+
+            trips(block, Z)
+
+        @pl.when(~whole)
+        def _a_row_by_itself():
+            at = first - row0 + i
+            hs = pl.ds(pl.multiple_of(at * np.int32(heads), heads), heads)
+            q = q_ref[hs, :]
+            seen = row_seen(at, kv_len - n_rows + i + _ONE)
+
+            def block(b, slot_, carry):
+                return attend(q, block_of(slot_), seen(b, _cols(b, blk)),
+                              *carry)
+
+            _, l, acc = trips(block, (
+                jnp.full((heads, LANES), NEG_INF, jnp.float32),
+                jnp.zeros((heads, LANES), jnp.float32),
+                jnp.zeros((heads, rank), jnp.float32)))
+            l_ref[hs, :] = l
+            acc_ref[hs, :] = acc
+
+    _walk(runs_ref, qb_ref, ptab_ref, (pool,), (buf,), sem,
+          page_size=page_size, block_pages=block_pages, visit=visit,
+          passes=lambda r: jnp.where(runs_ref[1, r] == np.int32(rows), _ONE,
+                                     runs_ref[1, r]))
+    _finish(o_ref, l_ref, acc_ref, rank)
 
 
 def _finish(o_ref, l_ref, acc_ref, rank):
@@ -391,64 +498,35 @@ def _finish(o_ref, l_ref, acc_ref, rank):
 
 # -- attention over the selected rows ---------------------------------------
 def _latent_kernel(runs_ref, qb_ref, ptab_ref, q_ref, sc_ref, thr_ref, at_ref,
-                   spread_ref, pool, o_ref, buf, sem, m_ref, l_ref, acc_ref,
-                   seen_ref, *, scale, page_size, block_pages, heads, rank):
-    """Program j: q block j's rows x heads (rows * heads, row) over the
-    latent pages of the runs in it. A trip computes the run's rows' heads
-    against the block: scores on the whole latent row, the selection's
-    mask from the row's index scores of that block, online softmax, and
-    the first `rank` values of the same rows as values. A run that fills
-    the q block (a prompt's chunk) goes through as one product; any other
-    (a decode row) a live row at a time, so that no product is made for
-    rows of other runs."""
+                   pool, o_ref, buf, sem, m_ref, l_ref, acc_ref, *, heads,
+                   **kw):
+    """`_attention_walk` under a selection: a row sees what its index
+    scores of the block (`sc_ref`, (blocks, rows, blk)) keep under its
+    threshold, and under its causal limit."""
     rows = q_ref.shape[0] // heads
-    blk = block_pages * page_size
-    j = pl.program_id(0)
-    _begin(j, buf, m_ref, l_ref, acc_ref)
+    blk = sc_ref.shape[-1]
 
-    def seen_by(i, b, col, lim):
-        """(1, blk) bool: what row i's selection keeps of block b, under
-        its causal limit."""
-        bits = pltpu.bitcast(sc_ref[b, i:i + 1, :], jnp.int32)
-        key = bits ^ ((bits >> 31) & _LOW31)
-        thr = _fit_lanes(thr_ref[i:i + 1, :], blk)
-        return ((key > thr) | ((key == thr) & (
-            col <= _fit_lanes(at_ref[i:i + 1, :], blk)))) & (col < lim)
+    def row_seen(at, lim):
+        me = pl.ds(at, 1)
+        thr = _fit_lanes(thr_ref[me, :], blk)
+        upto = _fit_lanes(at_ref[me, :], blk)
 
-    attend = functools.partial(_attend, q_ref, m_ref, l_ref, acc_ref,
-                               scale=scale, blk=blk, rank=rank)
-    seen_ref[...] = jnp.zeros_like(seen_ref)
+        def seen(b, col):
+            bits = pltpu.bitcast(sc_ref[b, me, :], jnp.int32)
+            key = bits ^ ((bits >> 31) & _LOW31)
+            return ((key > thr) | ((key == thr) & (col <= upto))) & (
+                col < lim)
+        return seen
 
-    def block(r, b, slot_):
-        kv = buf[slot_, 0].reshape(blk, buf.shape[-1])
-        col = _cols(b, blk)
-        row0 = j * np.int32(rows)
-        whole = runs_ref[1, r] == np.int32(rows)    # a prompt's chunk
+    def whole_seen(r, row0, b, col):
+        # row x head -> its row's mask: each row's spread over its heads
+        return jnp.concatenate([jnp.broadcast_to(row_seen(i, _row_limits(
+            runs_ref, r, row0 + np.int32(i))[1])(b, col), (heads, blk))
+            for i in range(rows)], axis=0)
 
-        @pl.when(whole)
-        def _every_row_at_once():
-            # one product for the q block: its rows share the run's keys
-            for i in range(rows):
-                seen_ref[i:i + 1, :] = jnp.where(
-                    seen_by(i, b, col, _row_limits(runs_ref, r, row0
-                                                   + np.int32(i))[1]), F1, F0)
-            # row x head -> its row's mask, as a product with `spread`
-            # (rows * heads, LANES) of 0 / 1
-            attend(slice(None), kv, jax.lax.dot_general(
-                spread_ref[...], seen_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) > np.float32(0.5))
-
-        for i in range(rows):
-            mine, lim = _row_limits(runs_ref, r, row0 + np.int32(i))
-
-            @pl.when(mine & ~whole)
-            def _(i=i, lim=lim):
-                attend(slice(i * heads, (i + 1) * heads), kv,
-                       seen_by(i, b, col, lim))
-
-    _walk(runs_ref, qb_ref, ptab_ref, (pool,), (buf,), sem,
-          page_size=page_size, block_pages=block_pages, block=block)
-    _finish(o_ref, l_ref, acc_ref, rank)
+    _attention_walk(runs_ref, qb_ref, ptab_ref, q_ref, pool, o_ref, buf, sem,
+                    m_ref, l_ref, acc_ref, heads=heads, whole_seen=whole_seen,
+                    row_seen=row_seen, **kw)
 
 
 def _selected(scores, thr, at):
@@ -522,16 +600,12 @@ def ragged_sparse_latent_attention(q, latent_pages, scores, thr, at,
         return jnp.broadcast_to(jnp.pad(x.astype(jnp.int32), (
             0, t_pad - t))[:, None], (t_pad, LANES))
     stat = pl.BlockSpec((ATTN_ROWS, LANES), lambda j, *_: (j, Z))
-    # (row x head, lane i) = 1 where the row is the q block's i-th
-    spread = (jnp.arange(ATTN_ROWS * heads)[:, None] // heads
-              == jnp.arange(LANES)[None, :]).astype(jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(n_qb,),
         in_specs=[
             pl.BlockSpec((ATTN_ROWS * heads, row), lambda j, *_: (j, Z)),
             pl.BlockSpec((nb, ATTN_ROWS, blk), lambda j, *_: (Z, j, Z)),
             stat, stat,
-            pl.BlockSpec((ATTN_ROWS * heads, LANES), lambda j, *_: (Z, Z)),
             pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((ATTN_ROWS * heads, rank),
                                lambda j, *_: (j, Z)),
@@ -541,8 +615,7 @@ def ragged_sparse_latent_attention(q, latent_pages, scores, thr, at,
             pltpu.SemaphoreType.DMA((1, 2)),
             pltpu.VMEM((ATTN_ROWS * heads, LANES), jnp.float32),
             pltpu.VMEM((ATTN_ROWS * heads, LANES), jnp.float32),
-            pltpu.VMEM((ATTN_ROWS * heads, rank), jnp.float32),
-            pltpu.VMEM((LANES, blk), jnp.float32)])
+            pltpu.VMEM((ATTN_ROWS * heads, rank), jnp.float32)])
     o = pl.pallas_call(
         functools.partial(_latent_kernel, scale=np.float32(sm_scale),
                           page_size=page, block_pages=bp, heads=heads,
@@ -553,48 +626,28 @@ def ragged_sparse_latent_attention(q, latent_pages, scores, thr, at,
         interpret=pltpu.InterpretParams() if interpret else False,
         name="ragged_sparse_latent_attention",
     )(runs, qb_first, page_table.astype(jnp.int32), q2, sc, lanes(thr),
-      lanes(at), spread, latent_pages)
+      lanes(at), latent_pages)
     return o.reshape(t_pad, heads, rank)[:t]
 
 
 # -- attention over the whole context ---------------------------------------
 def _dense_latent_kernel(runs_ref, qb_ref, ptab_ref, q_ref, rowid_ref, pool,
-                         o_ref, buf, sem, m_ref, l_ref, acc_ref, *, scale,
-                         page_size, block_pages, heads, rank):
-    """`_latent_kernel` without a selection: a row sees every column under
-    its causal limit. A run that fills the q block goes through as one
-    product, its rows' limits from `rowid_ref` (rows * heads, LANES), the
-    row each row x head belongs to; any other a live row at a time. Rows
-    kept in a narrower type than the queries' (float8) are widened to it
-    in fast memory."""
-    rows = q_ref.shape[0] // heads
+                         o_ref, buf, sem, m_ref, l_ref, acc_ref, *,
+                         page_size, block_pages, **kw):
+    """`_attention_walk` without a selection: a row sees every column
+    under its causal limit; a whole q block's rows' limits from
+    `rowid_ref` (rows * heads, LANES), the row each row x head belongs
+    to."""
     blk = block_pages * page_size
-    j = pl.program_id(0)
-    _begin(j, buf, m_ref, l_ref, acc_ref)
-    attend = functools.partial(_attend, q_ref, m_ref, l_ref, acc_ref,
-                               scale=scale, blk=blk, rank=rank)
 
-    def block(r, b, slot_):
-        kv = buf[slot_, 0].reshape(blk, buf.shape[-1]).astype(q_ref.dtype)
-        col = _cols(b, blk)
-        row0 = j * np.int32(rows)
-        whole = runs_ref[1, r] == np.int32(rows)    # a prompt's chunk
+    def whole_seen(r, row0, b, col):
+        return col < _row_limits(runs_ref, r, row0)[1] + _fit_lanes(
+            rowid_ref[...], blk)
 
-        @pl.when(whole)
-        def _every_row_at_once():
-            attend(slice(None), kv, col < _row_limits(runs_ref, r, row0)[1]
-                   + _fit_lanes(rowid_ref[...], blk))
-
-        for i in range(rows):
-            mine, lim = _row_limits(runs_ref, r, row0 + np.int32(i))
-
-            @pl.when(mine & ~whole)
-            def _(i=i, lim=lim):
-                attend(slice(i * heads, (i + 1) * heads), kv, col < lim)
-
-    _walk(runs_ref, qb_ref, ptab_ref, (pool,), (buf,), sem,
-          page_size=page_size, block_pages=block_pages, block=block)
-    _finish(o_ref, l_ref, acc_ref, rank)
+    _attention_walk(runs_ref, qb_ref, ptab_ref, q_ref, pool, o_ref, buf, sem,
+                    m_ref, l_ref, acc_ref, page_size=page_size,
+                    block_pages=block_pages, whole_seen=whole_seen,
+                    row_seen=lambda at, lim: lambda b, col: col < lim, **kw)
 
 
 def _dense_latent_reference(q, pages_, page_table, tok_slot, tok_pos, rank,

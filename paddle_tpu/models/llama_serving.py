@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import _tuning_defaults as _tuning
+from ..kernels.ragged_latent import ATTN_ROWS, latent_block_pages
 from ..kernels.ragged_paged_attention import (ragged_paged_attention,
                                               ragged_runs, ragged_tile)
 from ..observability import compile_telemetry as _compile
@@ -1174,6 +1175,26 @@ class _GroupCache:
                 at += n
 
 
+def _latent_walk(on, cont, live, block):
+    """How `kernels/ragged_latent.py`'s attention kernels walk a step, a
+    layer, from the plan's own descriptors (`on`: rows of a run; `cont`:
+    row i + 1 continues row i's stretch; `live`: a row's position + 1):
+    runs by kind, then trips by kind, in `serving.metrics.LATENT_KINDS`'
+    order. A run ends at a q block's edge (`ATTN_ROWS` rows). One that
+    fills the q block (`whole`) walks its context once, one product a
+    trip; a decode row (`row`) once by itself; the 2-15 rows of a chunk's
+    `piece` at a q block's edge once EACH, a row's product a trip."""
+    cont = cont & (np.arange(1, len(on)) % ATTN_ROWS != 0)
+    first = np.nonzero(on & ~np.append(False, cont))[0]
+    last = np.nonzero(on & ~np.append(cont, False))[0]
+    rows = last - first + 1
+    trips = -(-live[last] // block)
+    kinds = (rows == ATTN_ROWS, rows == 1, (rows > 1) & (rows < ATTN_ROWS))
+    return ([int(k.sum()) for k in kinds]
+            + [int((trips * np.where(rows == ATTN_ROWS, 1, rows))[k].sum())
+               for k in kinds])
+
+
 class ServingEngine:
     """Continuous-batching decode loop over the paged cache.
 
@@ -1427,6 +1448,11 @@ class ServingEngine:
         # for, and its loop trips over KV blocks
         self.ragged_runs = 0
         self.ragged_kv_blocks = 0
+        # ... and the latent kernels about theirs, at their own tile
+        # (pt_latent_runs / pt_latent_trips{kind=}): a group's runs and
+        # the trips of their walks (`_latent_walk`)
+        self.latent_walk = {g.name: [0] * 6 for g in model.groups
+                            if g.latent}
         self.last_rows = (0, 0)
         # the row-sparse lm_head epilogue (docs/serving.md § The
         # epilogue): every unified dispatch and the suffix prefill pass a
@@ -1462,6 +1488,8 @@ class ServingEngine:
             model.q_group, page_size, self.pages_per_seq)
         self._ragged_q_rows = q_rows
         self._ragged_kv_block = kv_pages * page_size
+        self._latent_block = page_size * latent_block_pages(
+            page_size, self.pages_per_seq)
         # device-resident token ring (ROADMAP item-1 last follow-on):
         # (max_seqs, max_seq_len+1) i32 where column p holds the token
         # a slot CONSUMES at cache position p. `unified_step` gathers
@@ -2887,6 +2915,11 @@ class ServingEngine:
         ends = on & ~np.append(cont & ~edge, False)   # a run's last row
         self.ragged_kv_blocks += int(
             (-(-live[ends] // self._ragged_kv_block)).sum())
+        if self.latent_walk:
+            walk = _latent_walk(on, cont, live, self._latent_block)
+            for booked in self.latent_walk.values():
+                for i, n in enumerate(walk):
+                    booked[i] += n
         n_decode = len(decode_plan)
         self.last_rows = (n_decode, row - n_decode)
         self._note_sampler(temps, top_ks, top_ps)

@@ -40,6 +40,11 @@ TURN_PARTS = ("admit", "plan", "dispatch", "fetch", "consume", "publish",
               "telemetry")
 
 
+# the runs of the latent attention kernels by the body they take
+# (`llama_serving._latent_walk` counts them in this order)
+LATENT_KINDS = ("whole", "row", "piece")
+
+
 def _escape_label_value(v):
     """Prometheus text-format label-value escaping: backslash, double
     quote, and newline must be escaped or the exposition line is
@@ -708,6 +713,8 @@ class EngineMetrics:
         for gc in getattr(engine, "_caches", ()):
             self._on_group(gc, engine.ragged_by_type[gc.spec.name],
                            engine.dsa_by_type.get(gc.spec.name))
+        for name, walk in getattr(engine, "latent_walk", {}).items():
+            self._on_latent_walk(name, walk)
         self.on_handoff(engine)
         pc = getattr(engine, "prefix_cache", None)
         if pc is not None:
@@ -780,6 +787,31 @@ class EngineMetrics:
                                         ("pairs", by_type[1]))):
             if cur > g["seen"][i]:
                 g[key].inc(cur - g["seen"][i])
+                g["seen"][i] = cur
+
+    def _on_latent_walk(self, name, walk):
+        """A group the latent attention kernels walk: its runs and their
+        trips a layer, by the kind of run (`llama_serving._latent_walk`,
+        whose order `walk` has)."""
+        g = self._by_group.get("walk:" + name)
+        if g is None:
+            g = self._by_group["walk:" + name] = {
+                "counters": [self.registry.counter(
+                    n, h, labels={"layer_type": name, "kind": kind})
+                    for n, h in (
+                    ("pt_latent_runs", "Runs of the latent attention "
+                     "kernels a layer, at their tile of 16 rows: whole "
+                     "(fills the q block: one product a trip), row (a "
+                     "decode row), piece (2-15 rows of a chunk at a q "
+                     "block's edge, walked a row at a time)."),
+                    ("pt_latent_trips", "Trips of those runs' walks a "
+                     "layer: blocks of 512 tokens met by a whole q block "
+                     "(whole) or by one row (row, piece)."))
+                    for kind in LATENT_KINDS],
+                "seen": [0] * 6}
+        for i, (counter, cur) in enumerate(zip(g["counters"], walk)):
+            if cur > g["seen"][i]:
+                counter.inc(cur - g["seen"][i])
                 g["seen"][i] = cur
 
     def _on_select(self, name, gc, dsa):

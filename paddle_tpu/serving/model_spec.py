@@ -70,6 +70,13 @@ class CacheGroup:
     def layers(self):
         return sum(self.stacks)
 
+    @property
+    def latent(self):
+        """Whether a layer keeps a token ONE row for all its heads: the
+        kernels of `kernels/ragged_latent.py` walk such a group, at a
+        tile of their own (`pt_latent_runs`, `pt_latent_trips`)."""
+        return not self.planes[0].per_head
+
     def bytes_per_token(self, itemsize):
         """What one token keeps in all the group's layers, by plane:
         keys and values in the K/V case. Every plane at the cache's

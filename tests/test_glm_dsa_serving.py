@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.kernels import ragged_latent as rl
 
+import latent_buffers
 from glm_tiny import (against_reference, engine, init, program_config,
                       reference, requests, tiny_model)
 
@@ -279,13 +280,57 @@ def test_the_selection_is_the_stable_sorts_top_k(k, ties):
             assert set(best) == set(np.nonzero(chosen[t])[0]), (kw, t)
 
 
-@pytest.mark.parametrize("k", [6, 64])
-def test_sparse_latent_attention_interpreted_against_jnp(k):
-    d = _rows()
-    scores = rl.ragged_index_scores(d["qi"], d["w"], d["keys"], d["table"],
-                                    d["slot"], d["pos"], use_pallas=False,
-                                    block_pages=2)
+def _as_blocks(d, sc):
+    """Scores (T, 32) as `ragged_index_scores` leaves them, (blocks, T,
+    block) at a block of 8, -inf past a row's own position."""
+    pos = np.asarray(d["pos"])
+    sc = np.where(np.arange(32)[None, :] <= pos[:, None], sc, -np.inf)
+    return jnp.asarray(sc.reshape(len(pos), 4, 8).swapaxes(0, 1),
+                       jnp.float32)
+
+
+def _drawn_scores(d, seed=1):
+    return _as_blocks(d, np.random.default_rng(seed).normal(
+        size=(len(np.asarray(d["pos"])), 32)))
+
+
+def _tied_at_the_edge(d):
+    """Four positions above every other and four tied behind them, two on
+    each side of the first block's edge (6, 7 | 8, 9): a selection of six
+    takes the ties up to position 7, the block's last column, and leaves
+    the next block's first."""
+    sc = np.full((len(np.asarray(d["pos"])), 32), -1.0)
+    sc[:, :4], sc[:, 6:10] = 5.0, 1.0
+    return _as_blocks(d, sc)
+
+
+# (buffer, k, how the scores are made)
+SPARSE_CASES = [
+    ("mixed", 6, "index"), ("mixed", 64, "index"),
+    ("decode_only", 6, "drawn"), ("chunk_inside", 6, "drawn"),
+    ("one_page_tail", 6, "drawn"), ("gaps", 6, "drawn"),
+    ("sixteen_slots", 6, "drawn"), ("sixteen_slots", 64, "drawn"),
+    ("mixed", 6, "tied"), ("decode_only", 6, "tied")]
+
+
+@pytest.mark.parametrize("buffer,k,how", SPARSE_CASES,
+                         ids=["-".join(map(str, c)) for c in SPARSE_CASES])
+def test_sparse_latent_attention_interpreted_against_jnp(buffer, k, how):
+    """Every kind of run at its edges (`latent_buffers.BUFFERS`) under a
+    selection, against the `jax.numpy` path and against the definition:
+    softmax over the chosen positions only; rows of no run zero."""
+    if how == "index":
+        d = _rows()
+        scores = rl.ragged_index_scores(
+            d["qi"], d["w"], d["keys"], d["table"], d["slot"], d["pos"],
+            use_pallas=False, block_pages=2)
+    else:
+        d = latent_buffers.rows(buffer)
+        scores = _tied_at_the_edge(d) if how == "tied" else _drawn_scores(d)
     thr, at = rl.dsa_select(scores, d["pos"], k, use_pallas=False)
+    pos = np.asarray(d["pos"])
+    if how == "tied":
+        assert (np.asarray(at)[pos >= 9] == 7).all()
     a = (d["q"], d["latent"], scores, thr, at, d["table"], d["slot"],
          d["pos"])
     ref = np.asarray(rl.ragged_sparse_latent_attention(
@@ -293,16 +338,11 @@ def test_sparse_latent_attention_interpreted_against_jnp(k):
     got = np.asarray(rl.ragged_sparse_latent_attention(
         *a, rank=16, sm_scale=0.2, interpret=True))
     assert np.abs(ref - got).max() < 1e-5
-    assert not got[np.asarray(d["pos"]) < 0].any()
-    # and against the definition: softmax over the chosen positions only
+    assert not got[pos < 0].any()
     chosen = _chosen(scores, thr, at, d["pos"])
-    table, lat = np.asarray(d["table"]), np.asarray(d["latent"])[0]
-    for t in (0, 9, 20, 31):
-        ctx = lat[table[int(d["slot"][t])]].reshape(-1, 24)
-        s = np.asarray(d["q"])[t] @ ctx[chosen[t][:len(ctx)]].T * 0.2
-        p = np.exp(s - s.max(-1, keepdims=True))
-        want = (p / p.sum(-1, keepdims=True)) @ ctx[chosen[t][:len(ctx)], :16]
-        assert np.abs(want - got[t]).max() < 1e-5
+    for t in np.nonzero(pos >= 0)[0]:
+        want = latent_buffers.by_definition(d, t, seen=chosen[t])
+        assert np.abs(want - got[t]).max() < 1e-5, t
 
 
 # -- the shares add up ------------------------------------------------------------

@@ -17,6 +17,7 @@ from paddle_tpu.kernels import ragged_latent as rl
 from paddle_tpu.models import longcat_flash as lc
 from paddle_tpu.parallel.moe import dropless_experts
 
+import latent_buffers
 from longcat_tiny import (against_reference, engine, init, program_config,
                           reference, requests, tiny_model)
 
@@ -109,6 +110,42 @@ def test_the_metrics_export_the_identity_assignments(model):
     assert zero > 0
     assert zero + snap["pt_moe_assignments"]["value"] \
         + snap["pt_moe_rows_elsewhere"]["value"] == 2 * 3 * (11 + 5)
+
+
+def test_the_plan_counts_the_latent_walk_by_kind(model):
+    """`pt_latent_runs` / `pt_latent_trips` from the plan's descriptors, at
+    the latent kernels' tile of 16 rows: a decode row, a chunk of 32 rows
+    from buffer row 13 on (a piece of 3 rows 3 deep, a whole q block 19
+    deep, a piece of 13 rows 32 deep), at a block of 8 tokens."""
+    from paddle_tpu.models.llama_serving import _latent_walk
+    slot, pos = latent_buffers.BUFFERS["chunk_inside"]()
+    on = pos >= 0
+    cont = (on[1:] & on[:-1] & (slot[1:] == slot[:-1])
+            & (pos[1:] == pos[:-1] + 1))
+    live = (pos + 1).astype(np.int64)
+    #        whole, row, piece; a piece's rows each walk the context
+    assert _latent_walk(on, cont, live, 8) == [
+        1, 1, 2, 3, 2, 3 * 1 + 13 * 4]
+    # and a served engine books them under its group, the metrics export them
+    from paddle_tpu.serving import RequestScheduler
+    m, params = model
+    eng = engine(m, params)
+    sched = RequestScheduler(eng, max_queue=8)
+    try:
+        h = sched.submit(list(range(1, 12)), max_new_tokens=6, eos_id=None)
+        assert len(list(h.result())) == 6
+        text = sched.registry.render_prometheus()
+        walk = list(eng.latent_walk[lc.GROUP])
+    finally:
+        sched.shutdown(drain=False, timeout=30)
+    # the prompt's 11 rows are one piece, each row a walk of its own; then
+    # a decode row a step (the pump may have planned one step more)
+    assert walk[0] == walk[3] == 0 and walk[2] == 1 and walk[1] in (5, 6)
+    assert walk[4] >= 5 and walk[5] >= 11
+    for kind, n in zip(("whole", "row", "piece"), walk[:3]):
+        want = 'pt_latent_runs_total{kind="%s",layer_type="%s"} %d' % (
+            kind, lc.GROUP, n)
+        assert want in text or n == 0, text
 
 
 def test_the_sync_loop_serves_the_same_tokens(model, served):
@@ -288,29 +325,56 @@ def _rows(seed=0, T=32, S=4, n_pages=8, page=4, P=40, dtype=jnp.float32):
                 q=draw(T, 4, 24))
 
 
-@pytest.mark.parametrize("block_pages", [2, 8], ids=["4_blocks", "1_block"])
-@pytest.mark.parametrize("stored", ["float32", "float8_e4m3fn"])
-def test_dense_latent_attention_interpreted_against_jnp(block_pages, stored):
+# (buffer, pages a trip, the latent rows' type): every kind of run at its
+# edges (`latent_buffers.BUFFERS`), in one block and in four, in float8
+DENSE_CASES = [
+    ("mixed", 2, "float32"), ("mixed", 8, "float32"),
+    ("mixed", 2, "float8_e4m3fn"), ("mixed", 8, "float8_e4m3fn"),
+    ("decode_only", 2, "float32"), ("decode_only", 2, "float8_e4m3fn"),
+    ("chunk_inside", 2, "float32"), ("chunk_inside", 2, "float8_e4m3fn"),
+    ("one_page_tail", 2, "float32"), ("gaps", 2, "float32"),
+    ("sixteen_slots", 2, "float32"), ("sixteen_slots", 8, "float32")]
+
+
+@pytest.mark.parametrize("buffer,block_pages,stored", DENSE_CASES,
+                         ids=["-".join(map(str, c)) for c in DENSE_CASES])
+def test_dense_latent_attention_interpreted_against_jnp(buffer, block_pages,
+                                                        stored):
     """Ragged rows of prompts and decodes in one call, against the
     `jax.numpy` path and against the definition: softmax over EVERY
-    position up to the row's own."""
-    d = _rows(dtype=stored)
+    position up to the row's own; rows of no run come back zero."""
+    d = latent_buffers.rows(buffer, dtype=stored)
     a = (d["q"], d["latent"], d["table"], d["slot"], d["pos"])
     ref = np.asarray(rl.ragged_latent_attention(
         *a, rank=16, sm_scale=0.2, use_pallas=False))
     got = np.asarray(rl.ragged_latent_attention(
         *a, rank=16, sm_scale=0.2, interpret=True, block_pages=block_pages))
     assert np.abs(ref - got).max() < 1e-5
-    assert not got[np.asarray(d["pos"]) < 0].any()
-    table = np.asarray(d["table"])
-    lat = np.asarray(d["latent"].astype(jnp.float32))[0]
-    for t in (0, 1, 9, 20, 31):
-        n = int(d["pos"][t]) + 1
-        ctx = lat[table[int(d["slot"][t])]].reshape(-1, 24)[:n]
-        s = np.asarray(d["q"])[t] @ ctx.T * 0.2
-        p = np.exp(s - s.max(-1, keepdims=True))
-        want = (p / p.sum(-1, keepdims=True)) @ ctx[:, :16]
-        assert np.abs(want - got[t]).max() < 1e-5
+    pos = np.asarray(d["pos"])
+    assert not got[pos < 0].any()
+    for t in np.nonzero(pos >= 0)[0]:
+        want = latent_buffers.by_definition(d, t)
+        assert np.abs(want - got[t]).max() < 1e-5, t
+
+
+def _dots(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("dot_general")
+
+
+def test_the_attention_kernels_hold_two_bodies_and_not_one_a_row():
+    """A kernel's jaxpr holds the products of TWO bodies (a whole q block;
+    one row, entered at the run's row): two each, four a kernel. One copy
+    a row of the q block (34 and 35 products) cannot come back unseen."""
+    d = latent_buffers.rows("mixed")
+    desc = (d["table"], d["slot"], d["pos"])
+    assert _dots(lambda q, lat: rl.ragged_latent_attention(
+        q, lat, *desc, rank=16, sm_scale=0.2, interpret=True, block_pages=2),
+        d["q"], d["latent"]) <= 4
+    scores = jnp.zeros((4, 32, 8), jnp.float32)
+    flat = jnp.full((32,), 32, jnp.int32)
+    assert _dots(lambda q, lat: rl.ragged_sparse_latent_attention(
+        q, lat, scores, flat, flat, *desc, rank=16, sm_scale=0.2,
+        interpret=True), d["q"], d["latent"]) <= 4
 
 
 def test_the_dense_kernel_is_the_sparse_one_with_everything_selected():
