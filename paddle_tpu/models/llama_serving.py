@@ -951,13 +951,17 @@ class RaggedTicket:
     lists (slot, req) whose prefill completed this wave — their
     first-token logits rows ride `seed_rows` and are picked HOST-side
     at finish (the PR 8 seeding convention). `aux` is what the model's
-    step adds to the record (`ServingModel.step`), read with it."""
+    step adds to the record (`ServingModel.step`), read with it. `rows`
+    is what the wave carried, (decode rows, prompt rows), and
+    `t_fetched` the `time.monotonic()` stamp at which its record reached
+    the host: the pump books its periods by the two
+    (docs/observability.md § A turn of the pump)."""
 
     __slots__ = ("reqs", "flat", "next_tok", "done", "logprob",
-                 "seeds", "seed_rows", "slots", "aux")
+                 "seeds", "seed_rows", "slots", "aux", "rows", "t_fetched")
 
     def __init__(self, reqs, flat, next_tok, done, logprob, seeds,
-                 seed_rows, slots, aux=None):
+                 seed_rows, slots, aux=None, rows=(0, 0)):
         self.reqs = reqs            # slot -> Request (decode rows only)
         self.flat = flat            # slot -> flat buffer row index
         self.next_tok = next_tok    # device (T,) i32
@@ -967,6 +971,8 @@ class RaggedTicket:
         self.seed_rows = seed_rows  # device (len(seeds), V) or None
         self.slots = slots          # slots with any row this wave
         self.aux = aux or {}        # device arrays of the step's record
+        self.rows = rows            # (decode rows, prompt rows) of the wave
+        self.t_fetched = None       # set by `step_finish`
 
 
 class Request:
@@ -1421,7 +1427,8 @@ class ServingEngine:
         # (pt_ragged_attn_pairs / pt_ragged_kv_tokens): the query-key
         # pairs of one layer and head, and the tokens of K/V one layer
         # must read at least once; `last_rows` is the newest wave's
-        # (decode, prefill) row mix for the pump's `serving.turn` span
+        # (decode, prefill) row mix for the pump's `serving.turn` span,
+        # and rides the wave's ticket (`RaggedTicket.rows`) to its fetch
         self.ragged_attn_pairs = 0
         self.ragged_kv_tokens = 0
         # the same two by layer type (a windowed group's rows see at
@@ -2705,7 +2712,7 @@ class ServingEngine:
         self._t_launch_end = time.perf_counter()
         self.device_steps += 1
         return RaggedTicket(reqs, flat, rec[0], rec[1], rec[2], seeds,
-                            seed_rows, slots, aux)
+                            seed_rows, slots, aux, rows=self.last_rows)
 
     def _grow_to(self, s, end, carry, what):
         """Pages for slot s in every cache group up to ordinal `end`
@@ -2952,10 +2959,11 @@ class ServingEngine:
                    rids=[str(r.rid) for r in ticket.reqs.values()
                          if r is not None] +
                         [str(r.rid) for _, r in ticket.seeds])
-        with record_span("serving.fetch", part="fetch"):
+        with record_span("serving.fetch", part="fetch") as fetch:
             nxt, done, lp, seed_rows, aux = self._fetch_results(
                 (ticket.next_tok, ticket.done, ticket.logprob,
                  ticket.seed_rows, ticket.aux))
+        ticket.t_fetched = fetch.t_end
         with record_span("serving.consume", part="consume"):
             if "moe_rows" in aux:
                 # (sparse layers, experts): the rows each expert got

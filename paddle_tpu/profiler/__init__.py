@@ -271,7 +271,9 @@ def record_span(name, args=None, part=None, ring=None):
     enclosing `part=TURN` span's `parts`. The annotation still lies in
     the profiler's trace on the thread that opened it; the flight
     recorder and the trace context get nothing unless `ring=True` (a
-    turn has nine parts, the crash ring 4,096 events)."""
+    turn has nine parts, the crash ring 4,096 events). `t_end` is the
+    stamp a part span closed at, for whoever bounds a stretch by it (the
+    pump's periods end where `serving.fetch` does)."""
     return RecordEvent(name, args=args, part=part, ring=ring)
 
 
@@ -284,6 +286,7 @@ class RecordEvent:
         self.ring = part is None if ring is None else ring
         self.parts = {} if part == TURN else None
         self.dur_s = None
+        self.t_end = None
         self._ctx = None
         self._span = None
 
@@ -311,7 +314,8 @@ class RecordEvent:
 
     def end(self):
         if self.part is not None:
-            self.dur_s = time.monotonic() - self._t0
+            self.t_end = time.monotonic()
+            self.dur_s = self.t_end - self._t0
             spans = _open.spans
             spans.pop()
             if spans:

@@ -40,6 +40,11 @@ TURN_PARTS = ("admit", "plan", "dispatch", "fetch", "consume", "publish",
               "telemetry")
 
 
+# what a step carried, for the pump's periods (docs/observability.md
+# § A turn of the pump): decode rows alone, or a prompt's rows as well
+CARRIED = ("decode", "prompt")
+
+
 # the runs of the latent attention kernels by the body they take
 # (`llama_serving._latent_walk` counts them in this order)
 LATENT_KINDS = ("whole", "row", "piece")
@@ -464,6 +469,28 @@ class EngineMetrics:
                 "spans; host work is the sum without part=fetch.",
                 labels={"part": part})
             for part in TURN_PARTS}
+        self.period_seconds = {
+            carried: r.counter(
+                "pt_serving_period_seconds",
+                "Seconds of the pump's periods by what the step carried: "
+                "a period ends when a step's results have been fetched "
+                "and starts where the stretch before it ended (the fetch "
+                "before, or the pump's waking); decode = decode rows "
+                "alone, prompt = at least one prompt row. With "
+                "pt_serving_parked_seconds they tile the pump's time.",
+                labels={"carried": carried})
+            for carried in CARRIED}
+        self.periods = {
+            carried: r.counter(
+                "pt_serving_periods",
+                "The pump's periods by what the step carried: one a "
+                "step fetched.", labels={"carried": carried})
+            for carried in CARRIED}
+        self.parked_seconds = r.counter(
+            "pt_serving_parked_seconds",
+            "Seconds the pump had nothing in flight: from the fetch that "
+            "left no work in the engine and no step pending to the "
+            "pump's waking, booked when it wakes.")
         self.steps = r.counter(
             "pt_serving_device_steps", "Decode/verify device calls.")
         # how often the step's sampler conditionals engage (ISSUE 40)
@@ -990,6 +1017,16 @@ class EngineMetrics:
             s = parts.get(part, 0.0)
             if s > 0:
                 self.turn_seconds[part].inc(s)
+
+    def observe_period(self, carried, s):
+        """One period of the pump, `s` seconds long, under what its step
+        carried (one of `CARRIED`)."""
+        self.period_seconds[carried].inc(s)
+        self.periods[carried].inc()
+
+    def observe_parked(self, s):
+        """The pump woke after `s` seconds with nothing in flight."""
+        self.parked_seconds.inc(s)
 
     def observe_scrape_self(self, dt):
         """Self-cost of one scrape/sample pass (scrape-thread side)."""

@@ -205,6 +205,13 @@ class RequestScheduler:
         # the parts of the previous turn that came after its
         # `serving.step` record (its publish): pump thread only
         self._tail_parts = {}
+        # the pump's time is tiled into periods, one a step fetched, and
+        # parked stretches (docs/observability.md § A turn of the pump):
+        # where the newest of them ended, and what the step this turn
+        # fetched carried, as the turn's span says it (empty: it fetched
+        # none); pump thread only
+        self._tiled_to = time.monotonic()
+        self._fetched = {}
         self.max_queue = int(max_queue)
         if self.max_queue < 1:
             raise ValueError(f"max_queue={max_queue}: want >= 1")
@@ -894,7 +901,29 @@ class RequestScheduler:
         ticket, self._pending = self._pending, None
         if ticket is None:
             return 0
-        return self._engine.step_finish(ticket, inflight=inflight)
+        n_active = self._engine.step_finish(ticket, inflight=inflight)
+        self._book_period(ticket.t_fetched, ticket.rows)
+        return n_active
+
+    def _book_period(self, t_fetched, rows):
+        """A step's results reached the host at `t_fetched`: the stretch
+        since the last one ended is its period, booked under what the
+        step carried, `rows` = (decode rows, prompt rows) of the step
+        FETCHED (under the one-step-deep pump `engine.last_rows` is
+        already the next step's)."""
+        carried = "prompt" if rows[1] else "decode"
+        self.metrics.observe_period(carried, t_fetched - self._tiled_to)
+        self._tiled_to = t_fetched
+        self._fetched = {"carried": carried, "fetched_decode_rows": rows[0],
+                         "fetched_prompt_rows": rows[1]}
+
+    def _unpark(self, parked):
+        """The pump parked and is awake again (or stopping): everything
+        since the last period ended had nothing in flight."""
+        parked.end()
+        now = time.monotonic()
+        self.metrics.observe_parked(now - self._tiled_to)
+        self._tiled_to = now
 
     def _step_pipelined(self):
         """One pipelined pump turn: launch step N+1 FIRST (its input
@@ -935,8 +964,11 @@ class RequestScheduler:
         post-step block, publish. Every piece runs under a span of
         `turn` (docs/observability.md § A turn of the pump), so the
         turn's `parts` hold the self seconds of each. A pump with
-        nothing to do parks INSIDE its turn: the wait is the turn's own
-        time and no part's. False once the pump should stop."""
+        nothing to do parks INSIDE its turn, under one `serving.parked`
+        span however often it polls: the wait is the turn's own time and
+        no part's, and `pt_serving_parked_seconds` books it. False once
+        the pump should stop."""
+        self._fetched = {}
         if self._pending is not None and self._drain_needed():
             # slow path (cancel/TTL/shutdown): catch the host up so
             # releases/cancels operate on consumed state only —
@@ -947,30 +979,47 @@ class RequestScheduler:
                 self._recover(e)
             self._publish()
         with self._cond:
-            while True:
-                with record_span("serving.sched_feed", part="admit"):
-                    self._expire_and_cancel_locked()
-                    self._feed_locked()
-                if self._engine_has_work() or self._pending is not None:
-                    break
-                if self._closed and not self._queued_locked():
-                    return False
-                # park until a submission/cancel/shutdown pokes us
-                # (or queued work is unfeedable: paused / no slot);
-                # the timeout bounds queued-deadline expiry latency
-                self._cond.wait(timeout=self._idle_poll_s)
+            parked = None
+            try:
+                while True:
+                    with record_span("serving.sched_feed", part="admit"):
+                        self._expire_and_cancel_locked()
+                        self._feed_locked()
+                    if self._engine_has_work() or \
+                            self._pending is not None:
+                        break
+                    if self._closed and not self._queued_locked():
+                        return False
+                    # park until a submission/cancel/shutdown pokes us
+                    # (or queued work is unfeedable: paused / no slot);
+                    # the timeout bounds queued-deadline expiry latency
+                    if parked is None:
+                        parked = record_span("serving.parked", ring=False)
+                        parked.begin()
+                    self._cond.wait(timeout=self._idle_poll_s)
+            finally:
+                if parked is not None:
+                    self._unpark(parked)
         t0 = time.perf_counter()
+        eng = self._engine
         try:
             if self._pipeline:
                 n_active = self._step_pipelined()
             else:
-                n_active = self._engine.step()
+                # launched and fetched in one call: what the step
+                # carried is what the engine fed meanwhile, every prompt
+                # row of every entry point being counted in
+                # `prefill_tokens`, and the slots it advanced
+                fed = eng.prefill_tokens
+                n_active = eng.step()
+                fed = eng.prefill_tokens - fed
+                if n_active or fed:
+                    self._book_period(time.monotonic(), (n_active, fed))
         except Exception as e:  # noqa: BLE001 — fail requests
             self._pending = None
             self._recover(e)
             return True
         dt = time.perf_counter() - t0
-        eng = self._engine
         with record_span("serving.telemetry", part="telemetry"):
             self.metrics.observe_step(dt)
             # slot-mix sample: host-side slot walk, no device traffic —
@@ -1009,9 +1058,11 @@ class RequestScheduler:
                 pipeline_depth=getattr(eng, "pipeline_depth", 0),
                 parts=parts)
         self._publish()
+        # the wave this turn LAUNCHED, and the step it FETCHED, whose
+        # period the turn's length is (one step deep: the wave before)
         rows = getattr(eng, "last_rows", (0, 0))
         turn.set_args(step=eng.device_steps, decode_rows=rows[0],
-                      prefill_rows=rows[1])
+                      prefill_rows=rows[1], **self._fetched)
         self.metrics.observe_turn(turn.parts)
         self._tail_parts = {p: s - seen.get(p, 0.0)
                             for p, s in turn.parts.items()}

@@ -131,7 +131,7 @@ def test_every_turn_yields_the_spans_well_nested(params, annotations,
         names = [k[1] for k in kids]
         seen.update(names)
         # (the bucketed entry points' dispatch spans belong to no part)
-        assert set(names) <= set(SPAN_PART) | (
+        assert set(names) <= set(SPAN_PART) | {"serving.parked"} | (
             set() if pipeline else {"serving.prefill",
                                     "serving.decode_step"}), names
         assert turn[2] == 0 and turn[4] is not None
@@ -148,11 +148,17 @@ def test_every_turn_yields_the_spans_well_nested(params, annotations,
         top = [k[1] for k in kids if k[2] == 1]
         assert top[0] == "serving.sched_feed"
         assert top[-2:] == ["serving.telemetry", "serving.publish"]
-        # (a pump that parked fed again on waking: the wait is inside
-        # the turn, between two feeds)
-        engine = [n for n in top[:-2] if n != "serving.sched_feed"]
-        assert top[:len(top) - 2 - len(engine)] == \
-            ["serving.sched_feed"] * (len(top) - 2 - len(engine))
+        # (a pump that parked did so after its first feed, under ONE
+        # `serving.parked` however often it polled, and fed again under
+        # it at every poll and on waking)
+        engine = [n for n in top[:-2]
+                  if n not in ("serving.sched_feed", "serving.parked")]
+        assert top[:len(top) - 2 - len(engine)] in (
+            ["serving.sched_feed"],
+            ["serving.sched_feed", "serving.parked"])
+        for p in (k for k in kids if k[1] == "serving.parked"):
+            assert {k[1] for k in kids if k[2] == 2 and
+                    p[3] <= k[3] and k[4] <= p[4]} <= {"serving.sched_feed"}
         if pipeline:
             # launch parts, then fetch and consume of the step before
             assert engine[:3] == ["serving.admit", "serving.plan",
