@@ -369,5 +369,6 @@ def _serving_model(c: GlmDsaConfig):
     return ServingModel(
         groups=(group,), q_group=c.num_attention_heads, step=glm_step,
         rows=ROWS_A_STEP,
+        experts=(c.num_experts_per_tok, c.n_routed_experts),
         unsupported={k: f"GlmDsaConfig does not serve under {k}: {v}"
                      for k, v in _NOT_YET.items()})

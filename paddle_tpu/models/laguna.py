@@ -343,5 +343,6 @@ def _serving_model(c: LagunaConfig):
         q_group=max(c.num_attention_heads_per_layer)
         // c.num_key_value_heads,
         step=laguna_step,
+        experts=(c.num_experts_per_tok, c.num_experts),
         unsupported={k: f"LagunaConfig does not serve under {k}: {v}"
                      for k, v in _NOT_YET.items()})

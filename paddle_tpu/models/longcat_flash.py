@@ -343,5 +343,6 @@ def _serving_model(c: LongcatFlashConfig):
     return ServingModel(
         groups=(group,), q_group=c.num_attention_heads, step=longcat_step,
         rows=ROWS_A_STEP,
+        experts=(c.moe_topk, c.n_routed_experts),
         unsupported={k: f"LongcatFlashConfig does not serve under {k}: {v}"
                      for k, v in _NOT_YET.items()})

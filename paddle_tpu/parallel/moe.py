@@ -12,6 +12,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .._core.tensor import Tensor, apply
@@ -172,13 +173,85 @@ class MoELayer(Layer):
 # Dropless expert layer over flat rows (the serving step's; the
 # capacity-bounded dispatch above stays for the eager models)
 # ---------------------------------------------------------------------------
+ROW_TILE_MIN, ROW_TILE_MAX = 32, 512
+
+
+def row_tile(rows, groups):
+    """The row tile a grouped product of `rows` sorted rows over `groups`
+    groups should run at: the power of two that holds the rows a group
+    gets when the buffer is full (`rows / groups`), from 32 up to the
+    compiler's own 512. Every (group, row tile) visit of the compiler's
+    kernel reads the group's matrix AND multiplies a whole tile of rows
+    by it, the masked ones too: at decode's three to five rows an expert
+    a tile of 512 spends 1.7 ms on products of rows nobody owns where
+    the weights' bytes take 0.64, and a tile smaller than a group's run
+    of rows reads its matrix once a tile (PERF.md, Findings PR 44:
+    `tools/grouped_product_bench.py`)."""
+    tile = ROW_TILE_MIN
+    while tile * groups < rows and tile < ROW_TILE_MAX:
+        tile *= 2
+    return tile
+
+
+def tiled_rows(rows, groups):
+    """The shortest row buffer of at least `rows` rows that the TPU
+    compiler gives `row_tile(rows, groups)`: it takes the largest power
+    of two (at most 512) that DIVIDES the buffer's length for its row
+    tile (`ragged_dot_tiling="tm,tk,tn"` on the compiled custom call;
+    `tests/test_tpu_lowering.py` holds the steps to it), so the length
+    is the next odd multiple of the tile: 1,056 for 1,024 rows over 256
+    groups (tile 32), 1,088 over 16 (tile 64), 416 for 384 over 16."""
+    tile = row_tile(rows, groups)
+    return (-(-rows // tile) | 1) * tile
+
+
+def row_tile_visits(rows, assignments, num_experts=None):
+    """The (expert, row tile) visits of one grouped product of
+    `dropless_experts`, summed over the layers of a step's `rows` record
+    ((..., E) counts, numpy) out of `assignments` = T k a layer: the
+    tiles each expert's run of sorted rows spans, from the cumulative
+    sums of `rows`. Runs are not tile-aligned, so an expert of 4 rows
+    can span two tiles; each visit past an expert's first reads its
+    matrices again. The buffer, and so the tile, are the ones
+    `dropless_experts` runs a layer at that step."""
+    rows = np.asarray(rows, np.int64)
+    held = rows.shape[-1]
+    end = np.cumsum(rows, -1)
+    start = end - rows
+
+    def visits(n):
+        tile = row_tile(n, held)
+        return np.where(rows > 0, (end - 1) // tile - start // tile + 1,
+                        0).sum(-1)
+
+    few = _product_rows(assignments, held, num_experts)
+    got = visits(few)
+    if few < assignments:
+        got = np.where(end[..., -1] <= few, got, visits(assignments))
+    return int(got.sum())
+
+
+def _product_rows(assignments, held, num_experts):
+    """The sorted rows `dropless_experts`' products run over: all T k for
+    a whole layer; for a share four times its mean, in whole 128s."""
+    if num_experts in (None, held):
+        return assignments
+    return min(assignments,
+               -(-4 * assignments * held // num_experts // 128) * 128)
+
+
 def grouped_product(lhs, rhs, group_sizes):
     """Rows of `lhs` (M, K), sorted by group, times their group's matrix
     of `rhs` (G, K, N) -> (M, N) float32. `group_sizes` (G,) i32 says how
     many consecutive rows each group owns; rows past their sum belong to
     nobody (callers mask them). `jax.lax.ragged_dot`: the TPU compiler
     lowers it to a grouped matmul (device operations `ragged-dot-*`)
-    that reads only the matrices of groups that own a row."""
+    that reads only the matrices of groups that own a row, a (group, row
+    tile) visit at a time. M DECIDES THE ROW TILE: the compiler takes
+    the largest power of two up to 512 that divides it, and multiplies a
+    whole tile of rows a visit. A caller that knows its rows are few a
+    group hands a buffer `tiled_rows(M, G)` long (`dropless_experts`
+    pads the gather that builds it, not a copy) and cuts the result."""
     return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                               preferred_element_type=jnp.float32)
 
@@ -207,47 +280,48 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     a share's are four times its mean (T k E / num_experts) when they
     hold every held assignment, as they all but always do, and all T k
     in a step where they do not (`lax.cond`: a router that sends every
-    row to the held experts is slow, not wrong).
+    row to the held experts is slow, not wrong). The products are
+    LAUNCHED over `tiled_rows` of those: the buffer's length is what
+    gives the compiler's kernel its row tile (`grouped_product`), and
+    the rows added are nobody's, like every row past the experts' sum.
     -> (out (T, H) f32, rows (E,) i32: the rows each held expert got).
     """
     T, k = expert.shape
     E = w_gate.shape[0]
+    few = _product_rows(T * k, E, num_experts)
     if num_experts in (None, E):
-        routed, few = expert >= 0, T * k
+        routed = expert >= 0
     else:
         expert = expert - first
         routed = (expert >= 0) & (expert < E)
-        few = min(T * k, -(-4 * T * k * E // num_experts // 128) * 128)
     key = jnp.where(routed, expert, E).reshape(-1)    # E sorts last
     order = jnp.argsort(key, stable=True)           # sorted -> assignment
     rows = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))         # assignment -> sorted
 
     def products(n):
         """(n, H) f32: the first n sorted rows through their experts."""
-        xs = x[(order if n == T * k else order[:n]) // k]
+        # the gather builds the buffer at the length the products are
+        # launched over; the rows it adds (row 0's) are nobody's
+        xs = x[jnp.pad(order[:n], (0, tiled_rows(n, E) - n)) // k]
         h = jax.nn.silu(grouped_product(xs, w_gate, rows)) \
             * grouped_product(xs, w_up, rows)
-        y = grouped_product(h.astype(x.dtype), w_down, rows)
+        y = grouped_product(h.astype(x.dtype), w_down, rows)[:n]
         # rows past the experts' are nobody's: whatever is there, drop it
         return jnp.where((jnp.arange(n) < jnp.sum(rows))[:, None], y, 0.0)
 
-    def gathered(n, back):
-        """() -> (T*k, H): each assignment's row of `products(n)`, zero
-        for one that sorted past them (not held)."""
-        return lambda: jnp.where((back < n)[:, None],
-                                 products(n)[jnp.minimum(back, n - 1)], 0.0)
-
-    # the whole layer's products are traced before `back`, where they
-    # always were: `laguna_step`'s lowered text is held to the parent's
     if few == T * k:
-        y = products(few)
-    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
-        jnp.arange(T * k, dtype=jnp.int32))         # assignment -> sorted
-    if few < T * k:
-        y = jax.lax.cond(jnp.sum(rows) <= few, gathered(few, back),
-                         gathered(T * k, back))
+        y = products(few)[back]
+    else:
+        def gathered(n):
+            """() -> (T*k, H): each assignment's row of `products(n)`,
+            zero for one that sorted past them (not held)."""
+            return lambda: jnp.where(
+                (back < n)[:, None], products(n)[jnp.minimum(back, n - 1)],
+                0.0)
+        y = jax.lax.cond(jnp.sum(rows) <= few, gathered(few),
+                         gathered(T * k))
     w = jnp.where(routed, weight, 0.0).astype(jnp.float32)
-    if few == T * k:
-        y = y[back]
     out = jnp.einsum("tkh,tk->th", y.reshape(T, k, -1), w)
     return out, rows

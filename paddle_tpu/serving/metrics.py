@@ -438,6 +438,14 @@ class EngineMetrics:
         self.moe_rows_max_expert = r.counter(
             "pt_moe_rows_max_expert",
             "Rows of the fullest expert, a sparse layer and step.")
+        self.moe_row_tiles = r.counter(
+            "pt_moe_row_tiles",
+            "Row tiles the experts' runs of sorted rows span, a sparse "
+            "layer and step, at the tile its grouped products run at: "
+            "the (expert, row tile) visits of one product. Over "
+            "pt_moe_experts_touched: 1.0 when every expert's rows lie "
+            "in one tile; each visit more reads that expert's weights "
+            "again.")
         self.moe_rows_elsewhere = r.counter(
             "pt_moe_rows_elsewhere",
             "Assignments that went to experts this chip does not hold "
@@ -454,7 +462,8 @@ class EngineMetrics:
                           "ragged_attn_pairs": 0, "ragged_kv_tokens": 0,
                           "ragged_runs": 0, "ragged_kv_blocks": 0,
                           "moe_assignments": 0, "moe_experts_touched": 0,
-                          "moe_rows_max_expert": 0, "moe_rows_elsewhere": 0,
+                          "moe_rows_max_expert": 0, "moe_row_tiles": 0,
+                          "moe_rows_elsewhere": 0,
                           "moe_assignments_zero": 0,
                           "sampler_filter_steps": 0,
                           "sampler_draw_steps": 0}
@@ -721,6 +730,7 @@ class EngineMetrics:
                                self.moe_experts_touched),
                               ("moe_rows_max_expert",
                                self.moe_rows_max_expert),
+                              ("moe_row_tiles", self.moe_row_tiles),
                               ("moe_rows_elsewhere",
                                self.moe_rows_elsewhere),
                               ("moe_assignments_zero",

@@ -112,9 +112,15 @@ class ServingModel:
     `rows`: the rows a step should hold (the engine's flat row buffer),
     where the model knows better than the engine's rule, a power of two
     over its slots: a model whose prompts run to tens of thousands of
-    tokens starves its slots on a buffer sized for chat."""
+    tokens starves its slots on a buffer sized for chat.
+    `experts`: (the experts a row picks, the experts it picks among) of
+    the model's dropless expert layers, None for a model that has none:
+    with the buffer's rows they say what `parallel/moe.dropless_experts`
+    launches its grouped products over, from which the engine counts the
+    row tiles the step's `moe_rows` record spans (`pt_moe_row_tiles`)."""
     groups: Tuple[CacheGroup, ...]
     q_group: int
     step: Callable
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
     rows: Optional[int] = None
+    experts: Optional[Tuple[int, int]] = None

@@ -22,15 +22,17 @@ def _problem(seed=0):
     return x, expert, weight, w
 
 
-def _loop(x, expert, weight, w):
+def _loop(x, expert, weight, w, first=0):
+    """The experts `w` holds are the layer's `first .. first + len`; an
+    assignment to any other, or to none (negative), adds nothing."""
     wg, wu, wd = (np.asarray(a, np.float64) for a in w)
     x = np.asarray(x, np.float64)
-    out = np.zeros((T, H))
-    rows = np.zeros((E,), np.int64)
-    for t in range(T):
-        for j in range(K):
-            e = int(expert[t, j])
-            if e < 0:
+    out = np.zeros(x.shape)
+    rows = np.zeros((len(wg),), np.int64)
+    for t in range(len(x)):
+        for j in range(expert.shape[1]):
+            e = int(expert[t, j]) - first
+            if not 0 <= e < len(wg):
                 continue
             g = x[t] @ wg[e]
             out[t] += float(weight[t, j]) * ((g / (1 + np.exp(-g)))
@@ -95,3 +97,150 @@ def test_grouped_product_is_a_matmul_per_group():
         np.testing.assert_allclose(got[at:at + n], lhs[at:at + n] @ rhs[g],
                                    atol=1e-5)
         at += n
+
+
+# ---------------------------------------------------------------------------
+# the row tile (ISSUE 44): the buffer a product is launched over
+# ---------------------------------------------------------------------------
+# (rows the products run over, groups) of the three MoE cells:
+# Laguna's whole layer, GLM-5's and LongCat's shares
+CELLS = [(1024, 256), (1024, 16), (384, 16)]
+
+
+@pytest.mark.parametrize("rows, groups, tile, length", [
+    (1024, 256, 32, 1056), (1024, 16, 64, 1088), (384, 16, 32, 416),
+    # a share's fall-back to all T k: 256 and 192 rows a group
+    (4096, 16, 256, 4352), (3072, 16, 256, 3328),
+    # many rows a group: the compiler's own 512, and no smaller
+    (65536, 16, 512, 66048), (24, 8, 32, 32), (1, 1, 32, 32)])
+def test_the_tile_holds_a_groups_rows_and_the_length_gives_it(rows, groups,
+                                                             tile, length):
+    from paddle_tpu.parallel.moe import row_tile, tiled_rows
+    assert row_tile(rows, groups) == tile
+    assert tiled_rows(rows, groups) == length >= rows
+    # the compiler's rule: the largest power of two up to 512 that
+    # divides the length (tests/test_tpu_lowering.py holds it to that)
+    assert length % tile == 0 and (length // tile) % 2 == 1
+
+
+@pytest.mark.parametrize("rows, groups", CELLS)
+def test_grouped_product_at_a_padded_length_is_a_matmul_per_group(rows,
+                                                                  groups):
+    from paddle_tpu.parallel.moe import tiled_rows
+    rng = np.random.default_rng(rows + groups)
+    length = tiled_rows(rows, groups)
+    real = rows * 7 // 8 if groups == 256 else rows // 8
+    sizes = rng.multinomial(real, np.full(groups, 1 / groups))
+    lhs = jnp.asarray(rng.standard_normal((length, 6)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((groups, 6, 5)), jnp.float32)
+    got = np.asarray(grouped_product(lhs, rhs, jnp.asarray(sizes, jnp.int32)))
+    assert got.shape == (length, 5)
+    group_of = np.repeat(np.arange(groups), sizes)
+    want = np.einsum("mk,mkn->mn", np.asarray(lhs)[:real],
+                     np.asarray(rhs)[group_of])
+    np.testing.assert_allclose(got[:real], want, atol=1e-5)
+
+
+def _share(t, k, held, among, hit, seed):
+    """A share's problem: `hit` of the t rows send every pick to the
+    held experts, the others none; row 0, which the padded gather reads,
+    is a slack row of NaNs."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(ks[0], (t, H)).at[0].set(jnp.nan)
+    w = [jax.random.normal(kk, s) * 0.3 for kk, s in zip(
+        ks[1:4], [(held, H, F), (held, H, F), (held, F, H)])]
+    mine = jax.random.randint(ks[4], (t, k), 3, 3 + held)
+    other = jax.random.randint(ks[4], (t, k), 3 + held, among)
+    expert = jnp.where((jnp.arange(t) <= hit)[:, None], mine, other)
+    expert = expert.at[0].set(-1)
+    return x, expert, jax.random.uniform(ks[5], (t, k)), w
+
+
+@pytest.mark.parametrize("hit, branch", [(10, "few"), (16, "few"),
+                                         (17, "all"), (63, "all")])
+def test_a_share_falls_back_to_every_row_when_the_few_cannot_hold_it(hit,
+                                                                     branch):
+    """64 rows x 8 picks, 4 of 64 experts held: the products run over 128
+    sorted rows (launched over 160) while they hold every held
+    assignment, `hit` x 8 of them, and over all 512 (launched over 640)
+    in a step where they do not. Either way a row gets its own, and the
+    rows past the experts' sum (NaNs here: the padded gather reads row
+    0) add nothing to any row."""
+    from paddle_tpu.parallel.moe import _product_rows, tiled_rows
+    t, k, held, among = 64, 8, 4, 64
+    assert _product_rows(t * k, held, among) == 128
+    assert (tiled_rows(128, held), tiled_rows(512, held)) == (160, 640)
+    x, expert, weight, w = _share(t, k, held, among, hit, seed=hit)
+    out, rows = dropless_experts(x, expert, weight, *w, first=3,
+                                 num_experts=among)
+    assert int(rows.sum()) == hit * k
+    assert (int(rows.sum()) <= 128) == (branch == "few")
+    assert np.isfinite(np.asarray(out)).all()
+    assert not np.asarray(out[0]).any() and not np.asarray(out[hit + 1:]).any()
+    want, want_rows = _loop(jnp.nan_to_num(x), expert, weight, w, first=3)
+    np.testing.assert_allclose(out, want, atol=5e-5)
+    assert rows.tolist() == want_rows.tolist()
+
+
+def test_rows_past_the_experts_sum_add_nothing_to_a_whole_layers_rows():
+    """The whole layer launched over 32 rows for its 24 assignments: the
+    slack rows' and the padded gather's rows are NaNs and nobody's."""
+    x, expert, weight, w = _problem(6)
+    expert = expert.at[0].set(-1).at[5].set(-1)
+    x = x.at[0].set(jnp.nan).at[5].set(jnp.nan)
+    out, rows = dropless_experts(x, expert, weight, *w)
+    assert np.isfinite(np.asarray(out)).all() and rows.sum() == (T - 2) * K
+    want, _ = _loop(jnp.nan_to_num(x), expert, weight, w)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("rows, assignments, among, visits", [
+    # a whole layer of 4 experts over 128 assignments: tile 32. Runs
+    # [0, 30) [30, 34) [] [34, 74): 1 + 2 + 0 + 2 tiles
+    ([30, 4, 0, 40], 128, None, 5),
+    # an expert of 4 rows can span two tiles; one of 65 spans three
+    ([31, 4, 65, 0], 128, None, 1 + 2 + 3),
+    # a share, 4 of 64 held, 512 assignments: the few are 128 rows at
+    # tile 32 ...
+    ([30, 4, 0, 40], 512, 64, 5),
+    # ... and past them all 512 at tile 128: [0, 100) [100, 140) [] [140,
+    # 180): 1 + 2 + 0 + 1
+    ([100, 40, 0, 40], 512, 64, 4),
+    ([0, 0, 0, 0], 128, None, 0)])
+def test_row_tile_visits_against_a_hand_count(rows, assignments, among,
+                                              visits):
+    from paddle_tpu.parallel.moe import row_tile_visits
+    assert row_tile_visits(np.asarray(rows), assignments, among) == visits
+    assert visits >= sum(r > 0 for r in rows)
+
+
+def test_the_engine_counts_the_row_tiles_its_steps_experts_span():
+    """`pt_moe_row_tiles` beside `pt_moe_experts_touched`: the toy model's
+    16 rows x 2 picks lie in one tile of 32, so every touched expert is
+    one visit and the ratio reads 1.0; the counter is the engine's sum
+    of `row_tile_visits` over the step's `moe_rows` record."""
+    from laguna_tiny import engine, tiny_model
+    from paddle_tpu.models.laguna import LagunaConfig, init_params
+    from paddle_tpu.parallel.moe import row_tile_visits
+    from paddle_tpu.serving import RequestScheduler
+    m = tiny_model()
+    eng = engine(m, init_params(LagunaConfig.from_dict(m), seed=1))
+    picks, among = eng.model.experts
+    seen, real = [], eng.model.step
+
+    def step(*a, **kw):
+        out = real(*a, **kw)
+        seen.append(out[-1]["moe_rows"])
+        return out
+
+    object.__setattr__(eng.model, "step", step)
+    sched = RequestScheduler(eng, max_queue=8)
+    try:
+        h = sched.submit(list(range(1, 24)), max_new_tokens=6, eos_id=None)
+        assert len(list(h.result())) == 6
+        snap = sched.registry.snapshot()
+    finally:
+        sched.shutdown(drain=False, timeout=30)
+    by_hand = row_tile_visits(np.stack(seen), eng.ragged_buf * picks, among)
+    assert snap["pt_moe_row_tiles"]["value"] == eng.moe_row_tiles == by_hand
+    assert eng.moe_row_tiles == eng.moe_experts_touched > 0

@@ -232,6 +232,19 @@ def _vocab_sorts(text, rows, vocab):
     return count(body), count(reach(True) - body)
 
 
+def _row_tiles(text):
+    """The row tile of every grouped product (`lax.ragged_dot`'s custom
+    call) in a compiled step's text, sorted: the TPU compiler writes
+    `ragged_dot_tiling="tm,tk,tn"` on each, `tm` the largest power of two
+    up to 512 that divides the row buffer's length. `parallel/moe.
+    tiled_rows` sizes the buffers by that rule; a libtpu that changes it
+    shows here and not as a silent loss (PERF.md, Findings PR 44)."""
+    import re
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
+    assert tilings and all(t[1:] == ("512", "512") for t in tilings), tilings
+    return sorted(int(t[0]) for t in tilings)
+
+
 @pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
 def test_ragged_compiles_for_v5e_at_the_serving_shape(one_chip, cache):
     """The serving cell's shape (32 rows, 32 / 8 heads of 128, pages of
@@ -479,6 +492,11 @@ def test_glm_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
                          ln)]
     assert len(flat) == 4 and all("scatter" in ln for ln in flat)
     assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
+    # the held experts' three products over 1,024 sorted rows of 16
+    # groups, launched over 1,088, and over all 4,096 (4,352) in the
+    # branch a step takes when they do not hold its assignments
+    assert "ragged-dot" in text
+    assert _row_tiles(text) == [64] * 3 + [256] * 3
 
 
 def test_laguna_step_compiles_for_v5e_with_its_sort_under_a_conditional(
@@ -514,6 +532,11 @@ def test_laguna_step_compiles_for_v5e_with_its_sort_under_a_conditional(
         buf_write=arg((slots,), jnp.bool_)).compile().as_text()
     assert "ragged_paged_attention" in text
     assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
+    # the sparse layer's three products over 128 x 8 sorted rows of 256
+    # experts, launched over 1,056: no tile of 512 rows for decode's
+    # three to five rows an expert
+    assert "ragged-dot" in text
+    assert _row_tiles(text) == [32] * 3
 
 
 @pytest.mark.parametrize("stored", ["bfloat16", "float8_e4m3fn"])
@@ -568,4 +591,7 @@ def test_longcat_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
     text = compiled.as_text()
     assert "ragged_latent_attention" in text and "ragged-dot" in text
     assert "ragged_sparse_latent_attention" not in text
+    # 384 sorted rows of 16 groups launched over 416; all 3,072 (3,328)
+    # in the branch that holds a step's every assignment
+    assert _row_tiles(text) == [32] * 3 + [256] * 3
     assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
