@@ -12,8 +12,13 @@ TPU-native design notes:
     the keys' up-projection absorbed into the query) is
     `models/glm_dsa.py` over `kernels/ragged_latent.py`
     (docs/serving.md § Cache groups by plane). Projections are plain
-    matmuls (MXU); attention runs through our flash kernel after
-    up-projection.
+    matmuls (MXU); attention runs through the flashmask kernels after
+    up-projection, keys `qk_nope + qk_rope` wide and values `v_head_dim`
+    (the kernels take a value width of their own). The TRAINED path of
+    the family is the functional step `models/deepseek_spmd.py`
+    (stacked parameters, sigmoid `noaux_tc` routing, dropless experts
+    in row blocks, one chip's share of a deployment); this eager stack
+    keeps the capacity-bounded softmax gate below.
   * **MoE FFN**: shared experts + routed experts with top-k gating and
     the load-balance aux loss, reusing parallel.moe's EP dispatch.
 """
@@ -28,7 +33,7 @@ import jax.numpy as jnp
 from .. import nn
 from .._core.tensor import Tensor, apply
 from ..nn.initializer import Normal
-from ..ops.flash_attention import flash_attention_bhsd
+from ..ops.flashmask_attention import flashmask_attention_bhsd
 from ..ops.rope import rope_cos_sin
 from .llama import LlamaConfig, LlamaMLP
 from .moe_llm import MoEDecoderLayer
@@ -47,6 +52,17 @@ class DeepSeekConfig(LlamaConfig):
     moe_intermediate_size: int = 0    # 0 = intermediate_size
     first_k_dense_replace: int = 1    # leading dense layers before MoE
     aux_loss_alpha: float = 0.001
+    # the published router (`modeling_deepseek.py`), read by the functional
+    # training step (`deepseek_spmd.py`); the eager stack below keeps the
+    # softmax gate of `parallel/moe.top_k_gating`
+    scoring_func: str = "softmax"     # or "sigmoid"
+    topk_method: str = "greedy"       # "noaux_tc": top k of score + bias
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # one chip's share of a deployment (as `GlmDsaConfig` names them): the
+    # routed experts HELD here, None = all, from `first_expert`
+    experts_held: int | None = None
+    first_expert: int = 0
 
     @classmethod
     def tiny_mla(cls, vocab=128, hidden=64, layers=2, heads=4):
@@ -119,16 +135,14 @@ class MLAttention(nn.Layer):
                                         (b, s, nh, dr))
             qh = jnp.concatenate([q_nope, q_rope], -1).swapaxes(1, 2)
             kh = jnp.concatenate([k_nope, k_rope_h], -1).swapaxes(1, 2)
-            vh = v.swapaxes(1, 2)
-            # pad v head dim to match qk dim for the kernel, slice after
-            if dv < dn + dr:
-                vh = jnp.pad(vh, ((0, 0),) * 3 + ((0, dn + dr - dv),))
-            # static python float: sm_scale is a nondiff argnum of the pallas
-            # custom_vjp — a traced array would fail under jit on TPU
-            o = flash_attention_bhsd(qh, kh, vh, causal=True,
-                                     sm_scale=1.0 / math.sqrt(dn + dr))
-            o = o[..., :dv].swapaxes(1, 2).reshape(b, s, nh * dv)
-            return o @ wo
+            # the flashmask entry takes the values at their own width
+            # (d_v != d_qk): no padded P V or dO V product. Static python
+            # float: sm_scale is a nondiff argnum of the pallas custom_vjp
+            # — a traced array would fail under jit on TPU
+            o = flashmask_attention_bhsd(qh, kh, v.swapaxes(1, 2),
+                                         causal=True,
+                                         sm_scale=1.0 / math.sqrt(dn + dr))
+            return o.swapaxes(1, 2).reshape(b, s, nh * dv) @ wo
 
         return apply(fn, x, self.q_proj.weight, self.kv_down.weight,
                      self.kv_norm.weight, self.kv_up.weight,

@@ -59,17 +59,20 @@ from .flash_attention import (F0, F1, NEG_INF, Z, LANES,
 def _zero_oob(qi, ki, q, k, v, do=None, *, block_q, block_k, sq, sk):
     """Zero out ragged-tail garbage: OOB lanes of a padded block read
     undefined values, and 0 * NaN would poison the accumulators even
-    where the keep-mask already zeroes p/ds."""
-    d = q.shape[-1]
+    where the keep-mask already zeroes p/ds. q / k are `d` wide, v / do
+    `d_v` (the same mask where the two are one width)."""
+    d, d_v = q.shape[-1], v.shape[-1]
     if sk % block_k != 0:
         km = _col_mask(ki * block_k, block_k, sk, d)
         k = jnp.where(km, k, jnp.zeros_like(k))
-        v = jnp.where(km, v, jnp.zeros_like(v))
+        vm = km if d_v == d else _col_mask(ki * block_k, block_k, sk, d_v)
+        v = jnp.where(vm, v, jnp.zeros_like(v))
     if sq % block_q != 0:
         qm = _col_mask(qi * block_q, block_q, sq, d)
         q = jnp.where(qm, q, jnp.zeros_like(q))
         if do is not None:
-            do = jnp.where(qm, do, jnp.zeros_like(do))
+            dm = qm if d_v == d else _col_mask(qi * block_q, block_q, sq, d_v)
+            do = jnp.where(dm, do, jnp.zeros_like(do))
     return (q, k, v) if do is None else (q, k, v, do)
 
 
@@ -383,7 +386,7 @@ def _fwd_kernel(first_ref, last_ref, seed_ref, q_ref, k_ref, v_ref, sri_ref,
     def body(keep_mask):
         q, k, v = _zero_oob(qi, ki, q_ref[0], k_ref[0], v_ref[0],
                             block_q=block_q, block_k=block_k, sq=sq, sk=sk)
-        d = q.shape[-1]
+        d = v.shape[-1]                 # the accumulator's width: the values'
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         keep = keep_mask()
@@ -521,55 +524,59 @@ _BLOCKS = (512, 256, 128)
 _VMEM_BUDGET = 16 * 2 ** 20
 
 
-def _vmem_bytes(block_q, block_k, d, itemsize, n_sri=1):
+def _vmem_bytes(block_q, block_k, d, itemsize, n_sri=1, d_v=None):
     """VMEM the backward dK/dV kernel, the largest of the three, holds
     at one block: its operands and outputs twice (the pipeline's double
     buffer), its float32 accumulators, and four (block_q, block_k)
-    float32 tiles (scores, p, dp, ds)."""
+    float32 tiles (scores, p, dp, ds). q, k and dk are `d` wide; v, do
+    and dv `d_v` (None: `d`), each in whole lanes."""
     sub = lambda n: -(-n // 8) * 8
-    operands = (2 * block_q * d * itemsize            # q, do
-                + 2 * block_k * d * itemsize          # k, v
+    d_v = d if d_v is None else d_v
+    if d_v != d:        # a width between lane multiples lies in whole lanes
+        d, d_v = (-(-w // LANES) * LANES for w in (d, d_v))
+    operands = ((block_q + block_k) * (d + d_v) * itemsize  # q, do; k, v
                 + 2 * block_q * LANES * 4             # lse, delta
                 + sub(n_sri) * block_k * 4)           # start/end rows
-    outputs = 2 * block_k * d * itemsize              # dk, dv
-    accs = 2 * block_k * d * 4
+    outputs = block_k * (d + d_v) * itemsize          # dk, dv
+    accs = block_k * (d + d_v) * 4
     tiles = 4 * block_q * block_k * 4
     return 2 * (operands + outputs) + accs + tiles
 
 
-def derived_blocks(sq, sk, d, dtype):
+def derived_blocks(sq, sk, d, dtype, d_v=None):
     """(block_q, block_k) from the shapes: the candidates (`_BLOCKS`)
     that fit the sequence and the VMEM budget, of those the one that
     pads the sequence least, of those the largest. A static function of
     shapes; nothing is timed. The table is one sweep on a v5e at
     (4, 16, 4096, 128) bfloat16 with packed documents (PERF.md, PR 38):
     large blocks win until the tiles leave VMEM, because a grid step
-    costs the same whatever it holds."""
+    costs the same whatever it holds. `d_v`: the values' width where it
+    is not the keys' (latent attention: 192 and 128)."""
     itemsize = jnp.dtype(dtype).itemsize
 
     def pick(s, fits):
         ok = [c for c in _BLOCKS if c <= max(s, _BLOCKS[-1]) and fits(c)]
         ok = ok or [_BLOCKS[-1]]
         return min(ok, key=lambda c: (pl.cdiv(s, c) * c, -c))
-    block_q = pick(sq, lambda c: _vmem_bytes(c, c, d, itemsize)
+    block_q = pick(sq, lambda c: _vmem_bytes(c, c, d, itemsize, d_v=d_v)
                    <= _VMEM_BUDGET)
-    block_k = pick(sk, lambda c: _vmem_bytes(block_q, c, d, itemsize)
+    block_k = pick(sk, lambda c: _vmem_bytes(block_q, c, d, itemsize, d_v=d_v)
                    <= _VMEM_BUDGET)
     return block_q, block_k
 
 
-def _vmem_limit(block_q, block_k, d, dtype, n_sri):
+def _vmem_limit(block_q, block_k, d, dtype, n_sri, d_v=None):
     """What the kernels ask of VMEM: the budget the derived blocks were
     held to, and the count itself where explicit blocks pass it."""
     return max(_VMEM_BUDGET, 2 * _vmem_bytes(
-        block_q, block_k, d, jnp.dtype(dtype).itemsize, max(n_sri, 1)))
+        block_q, block_k, d, jnp.dtype(dtype).itemsize, max(n_sri, 1), d_v))
 
 
-def _blocks(block_q, block_k, sq, sk, d, dtype):
+def _blocks(block_q, block_k, sq, sk, d, dtype, d_v=None):
     """The blocks a call runs at: the caller's, else PT_FLASH_BLOCK_Q/K
     where the environment sets them, else derived from the shapes;
     never past the sequence."""
-    dq, dk = derived_blocks(sq, sk, d, dtype)
+    dq, dk = derived_blocks(sq, sk, d, dtype, d_v)
     if block_q is None:
         block_q = env_int("PT_FLASH_BLOCK_Q", dq)
     if block_k is None:
@@ -578,14 +585,16 @@ def _blocks(block_q, block_k, sq, sk, d, dtype):
 
 
 def _prep(q, k, v, sri):
+    """Operands flat over (batch, head). `d`: the width of q and k;
+    `d_v`: of v (and so of o and do), the same or its own."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, d_v = k.shape[2], v.shape[-1]
     bh = b * h
     qr = q.reshape(bh, sq, d)
     kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d)
+    vr = v.reshape(bh, sk, d_v)
     srir = None if sri is None else _sri_rows(sri)
-    return qr, kr, vr, srir, b, h, sq, sk, d, bh
+    return qr, kr, vr, srir, b, h, sq, sk, d, d_v, bh
 
 
 def _mem_spec():
@@ -648,8 +657,8 @@ def _call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
 def _fwd_pallas(q, k, v, sri, causal, window, scale, block_q, block_k,
                 interpret, dropout=0.0, seed=None):
     scale = np.float32(scale)
-    qr, kr, vr, srir, b, h, sq, sk, d, bh = _prep(q, k, v, sri)
-    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype)
+    qr, kr, vr, srir, b, h, sq, sk, d, d_v, bh = _prep(q, k, v, sri)
+    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype, d_v)
     n_q = pl.cdiv(sq, block_q)
     n_k = pl.cdiv(sk, block_k)
     n_sri = srir.shape[1] if srir is not None else 0
@@ -659,7 +668,7 @@ def _fwd_pallas(q, k, v, sri, causal, window, scale, block_q, block_k,
     q_map, k_map, sri_map = _index_maps(n_q, inner_is_k=True)
 
     in_specs = [spec((1, block_q, d), q_map), spec((1, block_k, d), k_map),
-                spec((1, block_k, d), k_map)]
+                spec((1, block_k, d_v), k_map)]
     args = [k_first, k_last, _seed_arr(seed), qr, kr, vr]
     if srir is not None:
         in_specs.append(spec((1, n_sri, block_k), sri_map))
@@ -671,24 +680,24 @@ def _fwd_pallas(q, k, v, sri, causal, window, scale, block_q, block_k,
 
     o, lse = _call(
         kernel, (bh, n_q, n_k), in_specs,
-        out_specs=[spec((1, block_q, d), q_map),
+        out_specs=[spec((1, block_q, d_v), q_map),
                    spec((1, block_q, LANES), q_map)],
-        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d_v), q.dtype),
                    jax.ShapeDtypeStruct((bh, sq, LANES), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, d_v), jnp.float32),
                         pltpu.VMEM((block_q, LANES), jnp.float32),
                         pltpu.VMEM((block_q, LANES), jnp.float32)],
-        vmem_limit=_vmem_limit(block_q, block_k, d, q.dtype, n_sri),
+        vmem_limit=_vmem_limit(block_q, block_k, d, q.dtype, n_sri, d_v),
         interpret=interpret,
     )(*args)
-    return o.reshape(b, h, sq, d), lse.reshape(b, h, sq, LANES)
+    return o.reshape(b, h, sq, d_v), lse.reshape(b, h, sq, LANES)
 
 
 def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
                 block_q, block_k, interpret, dropout=0.0, seed=None):
     scale = np.float32(scale)
-    qr, kr, vr, srir, b, h, sq, sk, d, bh = _prep(q, k, v, sri)
-    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype)
+    qr, kr, vr, srir, b, h, sq, sk, d, d_v, bh = _prep(q, k, v, sri)
+    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype, d_v)
     n_q = pl.cdiv(sq, block_q)
     n_k = pl.cdiv(sk, block_k)
     n_sri = srir.shape[1] if srir is not None else 0
@@ -697,17 +706,17 @@ def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
                                     block_k, sq, sk)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    dor = do.reshape(bh, sq, d)
+    dor = do.reshape(bh, sq, d_v)
     lser = lse.reshape(bh, sq, LANES)
     deltar = jnp.broadcast_to(delta.reshape(bh, sq)[..., None],
                               (bh, sq, LANES))
 
     def specs(q_map, k_map, sri_map):
         return ([spec((1, block_q, d), q_map), spec((1, block_k, d), k_map),
-                 spec((1, block_k, d), k_map)]
+                 spec((1, block_k, d_v), k_map)]
                 + ([spec((1, n_sri, block_k), sri_map)]
                    if srir is not None else [])
-                + [spec((1, block_q, d), q_map),
+                + [spec((1, block_q, d_v), q_map),
                    spec((1, block_q, LANES), q_map),
                    spec((1, block_q, LANES), q_map)])
 
@@ -716,7 +725,7 @@ def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
                    sq=sq, sk=sk, dropout=dropout)
     operands = [qr, kr, vr] + ([srir] if srir is not None else []) + \
         [dor, lser, deltar]
-    vmem_limit = _vmem_limit(block_q, block_k, d, q.dtype, n_sri)
+    vmem_limit = _vmem_limit(block_q, block_k, d, q.dtype, n_sri, d_v)
 
     dq_maps = _index_maps(n_q, inner_is_k=True)
     dq = _call(
@@ -733,15 +742,15 @@ def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
         _mk_kernel(_bwd_dkv_kernel, srir is not None, **statics),
         (bh, n_k, n_q), specs(*dkv_maps),
         out_specs=[spec((1, block_k, d), dkv_maps[1]),
-                   spec((1, block_k, d), dkv_maps[1])],
+                   spec((1, block_k, d_v), dkv_maps[1])],
         out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bh, sk, d_v), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         vmem_limit=vmem_limit, interpret=interpret,
     )(*q_range, _seed_arr(seed), *operands)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
+            dv.reshape(b, h, sk, d_v))
 
 
 # ---------------------------------------------------------------------------
@@ -783,9 +792,16 @@ def flashmask_attention_bhsd(q, k, v, startend_row_indices=None, causal=True,
                              block_q=None, block_k=None,
                              use_pallas=None, interpret=None,
                              dropout=0.0, dropout_seed=None):
-    """Core entry: q,k,v (B,H,S,D), startend_row_indices (B,H,S_k,n)
-    already broadcast to the q heads. O(S·block) memory on the kernel
-    path; dense reference off-TPU unless interpret is forced.
+    """Core entry: q,k (B,H,S,D), v (B,H,S_k,D_v), startend_row_indices
+    (B,H,S_k,n) already broadcast to the q heads -> (B,H,S,D_v).
+    O(S·block) memory on the kernel path; dense reference off-TPU unless
+    interpret is forced.
+
+    D_v may differ from D (latent attention up-projected: keys of 192,
+    values of 128): the three kernels carry v, o, dO and dV at the
+    values' width and q, k, dQ and dK at the keys', so no product runs
+    over padding; `sm_scale` defaults to 1/sqrt(D). With D_v == D the
+    kernels are the ones they were.
 
     block_q / block_k: None (the default) takes PT_FLASH_BLOCK_Q / _K
     where the environment sets them and else derives the blocks from

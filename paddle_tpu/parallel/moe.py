@@ -8,6 +8,7 @@ over ICI — the NCCL alltoall of the reference, derived not hand-written.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -256,6 +257,22 @@ def grouped_product(lhs, rhs, group_sizes):
                               preferred_element_type=jnp.float32)
 
 
+def _sorted_by_expert(expert, held, first, num_experts):
+    """expert (T, k) over ALL the layer's experts -> (routed (T, k) bool:
+    the assignment goes to one of the `held` experts from `first`; order
+    (T k,): sorted row -> assignment, the held experts' runs first and in
+    order; rows (held,) i32: the rows each held expert got)."""
+    if num_experts in (None, held):
+        routed = expert >= 0
+    else:
+        expert = expert - first
+        routed = (expert >= 0) & (expert < held)
+    key = jnp.where(routed, expert, held).reshape(-1)   # `held` sorts last
+    order = jnp.argsort(key, stable=True)
+    rows = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    return routed, order, rows
+
+
 def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
                      num_experts=None):
     """Every assignment to an expert held here computed, none dropped:
@@ -284,19 +301,16 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     LAUNCHED over `tiled_rows` of those: the buffer's length is what
     gives the compiler's kernel its row tile (`grouped_product`), and
     the rows added are nobody's, like every row past the experts' sum.
+
+    This is the serving steps' form; a training step, whose rows are
+    thousands an expert and which is differentiated, takes
+    `dropless_experts_blocked`.
     -> (out (T, H) f32, rows (E,) i32: the rows each held expert got).
     """
     T, k = expert.shape
     E = w_gate.shape[0]
     few = _product_rows(T * k, E, num_experts)
-    if num_experts in (None, E):
-        routed = expert >= 0
-    else:
-        expert = expert - first
-        routed = (expert >= 0) & (expert < E)
-    key = jnp.where(routed, expert, E).reshape(-1)    # E sorts last
-    order = jnp.argsort(key, stable=True)           # sorted -> assignment
-    rows = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+    routed, order, rows = _sorted_by_expert(expert, E, first, num_experts)
     back = jnp.zeros((T * k,), jnp.int32).at[order].set(
         jnp.arange(T * k, dtype=jnp.int32))         # assignment -> sorted
 
@@ -325,3 +339,141 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     w = jnp.where(routed, weight, 0.0).astype(jnp.float32)
     out = jnp.einsum("tkh,tk->th", y.reshape(T, k, -1), w)
     return out, rows
+
+
+# sorted rows a block of `dropless_experts_blocked` holds: 26 kB a row of
+# intermediates at Moonlight's widths, 105 MB a block, six or seven blocks
+# walked at 3,072 rows a held expert. Same seed and batches on one chip (my
+# chip run, PR 45): 8,192 rows 28,609 tokens/s, 2,048 rows 28,759 (the
+# float32 sums of the weights' gradients are added to once a block), 4,096
+# rows 29,134; and a layer whose held rows lie just over a block's edge pays
+# a smaller block. 4,608 (nine 512-row tiles, so that 23,041-27,648 held rows
+# are always six blocks) is 3.6% slower over the same steps: a block then
+# costs 7.5 ms where one of 4,096 costs 4.5
+ROW_BLOCK = 4096
+
+
+def dropless_experts_blocked(x, expert, weight, w_gate, w_up, w_down,
+                             first=0, num_experts=None):
+    """`dropless_experts` for a step that is differentiated at thousands
+    of rows an expert (`models/deepseek_spmd.py`): the same arguments and
+    the same result, every assignment to a held expert computed in forward
+    and backward under any routing, an assignment to an absent expert
+    adding nothing and passing no gradient.
+
+    The sorted rows go through their experts a block of ROW_BLOCK at a
+    time, each block's weighted results added to its rows' output, and
+    the blocks past the held assignments are never entered; what is alive
+    at once is one block's products, whatever the routing, forward and
+    backward (`_blocked_experts`, which brings its own backward pass).
+    -> (out (T, H) f32, rows (E,) i32: the rows each held expert got).
+    """
+    k = expert.shape[1]
+    routed, order, rows = _sorted_by_expert(expert, w_gate.shape[0], first,
+                                            num_experts)
+    w = jnp.where(routed, weight, 0.0).astype(jnp.float32).reshape(-1)
+    return _blocked_experts(x, w, w_gate, w_up, w_down, order, rows, k,
+                            ROW_BLOCK), rows
+
+
+def _block_products(xs, wa, w_gate, w_up, w_down, sizes, live):
+    """One block of sorted rows through their experts: xs (R, H) the
+    rows, wa (R,) f32 their assignments' weights, sizes (E,) the part of
+    each expert's run that lies in the block, live (R,) which rows are
+    held assignments at all. -> (R, H) f32, weighted; 0 where not live.
+    Rows past the experts' sum are nobody's and the TPU's grouped matmul
+    leaves whatever was in memory there, in a product AND in the
+    products its transposes make in the backward pass: they are dropped
+    BEFORE the weight meets them (0 x garbage is not 0), and
+    `_blocked_bwd` drops their gradient by x again."""
+    h = jax.nn.silu(grouped_product(xs, w_gate, sizes)) \
+        * grouped_product(xs, w_up, sizes)
+    y = grouped_product(h.astype(xs.dtype), w_down, sizes)
+    return jnp.where(live[:, None], y, 0.0) * wa[:, None]
+
+
+def _block_plan(order, rows, k, row_block):
+    """How `_blocked_experts` walks the sorted rows -> (live blocks,
+    at(i)): as many blocks of R = `row_block` rows as hold the held
+    assignments; `at(i)` gives block i's assignments (R,), its rows'
+    tokens, the experts' sizes in it and which of its rows are live."""
+    n = order.shape[0]
+    R = min(int(row_block), -(-n // ROW_TILE_MIN) * ROW_TILE_MIN)
+    held = jnp.sum(rows)
+    end = jnp.cumsum(rows)
+    start = end - rows
+    order = jnp.pad(order, (0, -n % R))
+
+    def at(i):
+        lo = i * R
+        idx = jax.lax.dynamic_slice(order, (lo,), (R,))
+        sizes = jnp.clip(end - lo, 0, R) - jnp.clip(start - lo, 0, R)
+        return idx, idx // k, sizes, lo + jnp.arange(R) < held
+    return -(-held // R), at
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _blocked_experts(x, weight, w_gate, w_up, w_down, order, rows, k,
+                     row_block):
+    """`dropless_experts` under `jax.grad` at a training step's size.
+    x (T, H); weight (T k,) f32 by assignment; order (T k,) sorted row ->
+    assignment; rows (E,) the rows each held expert owns, the held
+    assignments being the first `sum(rows)` sorted rows. -> (T, H) f32.
+
+    Forward and backward each walk the LIVE blocks of `row_block` sorted
+    rows, as many as hold the held assignments (a loop whose trip count
+    the routing decides: every row to the held experts is slow, not
+    wrong, and a usual step walks an eighth of the layer's assignments).
+    A block gathers its rows of x, runs the three grouped products over
+    the part of each expert's run that lies in it, and adds each row's
+    weighted result to its token's output. The backward is written here,
+    not derived: reverse mode through a loop of blocks keeps a copy of
+    every loop-invariant operand a block (x and the three matrices, 6 GB
+    at the cell's size), so each block's products are made again from
+    the layer's inputs and differentiated on their own; what is alive at
+    once is one block's intermediates, 26 kB a sorted row, and the
+    float32 sums of the gradients."""
+    live_blocks, at = _block_plan(order, rows, k, row_block)
+
+    def body(i, out):
+        idx, tok, sizes, live = at(i)
+        return out.at[tok].add(_block_products(
+            x[tok], weight[idx], w_gate, w_up, w_down, sizes, live))
+    return jax.lax.fori_loop(0, live_blocks, body,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def _blocked_fwd(x, weight, w_gate, w_up, w_down, order, rows, k, row_block):
+    out = _blocked_experts(x, weight, w_gate, w_up, w_down, order, rows, k,
+                           row_block)
+    return out, (x, weight, w_gate, w_up, w_down, order, rows)
+
+
+def _blocked_bwd(k, row_block, res, d_out):
+    x, weight, w_gate, w_up, w_down, order, rows = res
+    live_blocks, at = _block_plan(order, rows, k, row_block)
+    f32 = lambda a: jnp.zeros(a.shape, jnp.float32)
+
+    def body(i, acc):
+        idx, tok, sizes, live = at(i)
+        _, pull = jax.vjp(
+            lambda xs, wa, *w: _block_products(xs, wa, *w, sizes, live),
+            x[tok], weight[idx], w_gate, w_up, w_down)
+        d_xs, d_wa, *d_w = pull(d_out[tok])
+        # a row nobody owns has no gradient; the transposed products
+        # leave there what they found (PERF.md, Findings PR 45)
+        d_xs = jnp.where(live[:, None], d_xs, 0)
+        dx, dweight, *dw = acc
+        return (dx.at[tok].add(d_xs.astype(jnp.float32)),
+                dweight.at[idx].add(d_wa),
+                *(a + b.astype(jnp.float32) for a, b in zip(dw, d_w)))
+    dx, dweight, *dw = jax.lax.fori_loop(
+        0, live_blocks, body,
+        (f32(x), f32(weight), f32(w_gate), f32(w_up), f32(w_down)))
+    ints = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (dx.astype(x.dtype), dweight,
+            *(a.astype(w.dtype) for a, w in zip(dw, (w_gate, w_up, w_down))),
+            ints(order), ints(rows))
+
+
+_blocked_experts.defvjp(_blocked_fwd, _blocked_bwd)

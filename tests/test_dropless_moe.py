@@ -7,7 +7,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.parallel.moe import dropless_experts, grouped_product
+from paddle_tpu.parallel import moe
+from paddle_tpu.parallel.moe import (dropless_experts,
+                                     dropless_experts_blocked,
+                                     grouped_product)
 
 T, H, F, E, K = 12, 16, 8, 8, 2
 
@@ -244,3 +247,108 @@ def test_the_engine_counts_the_row_tiles_its_steps_experts_span():
     by_hand = row_tile_visits(np.stack(seen), eng.ragged_buf * picks, among)
     assert snap["pt_moe_row_tiles"]["value"] == eng.moe_row_tiles == by_hand
     assert eng.moe_row_tiles == eng.moe_experts_touched > 0
+
+
+# ---------------------------------------------------------------------------
+# Under jax.grad, in row blocks (the training step's form)
+# ---------------------------------------------------------------------------
+def _dense_loop(x, expert, weight, w, first=0):
+    """`_loop` in jax.numpy, so that it can be differentiated: every held
+    expert over every row, masked by the assignments it got."""
+    wg, wu, wd = w
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(wg.shape[0]):
+        g = jnp.sum(jnp.where(expert == e + first, weight, 0.0), -1)
+        out += g[:, None] * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+    return out
+
+
+def _routers():
+    x, expert, weight, w = _problem(3)
+    return {
+        "mixed": (expert, 0, None),
+        # a share that holds experts 3..5 of 8
+        "share": (expert, 3, 8),
+        # every row's every assignment to ONE held expert
+        "all_to_one_held": (jnp.full((T, K), 4), 3, 8),
+        # no row to any held expert
+        "none_held": (jnp.full((T, K), 1), 3, 8),
+        "slack_rows": (expert.at[::3].set(-1), 0, None),
+    }
+
+
+@pytest.mark.parametrize("router", list(_routers()))
+@pytest.mark.parametrize("row_block", [32, 8])
+def test_gradients_through_row_blocks_match_a_dense_loop(router, row_block,
+                                                         monkeypatch):
+    """Output, and the gradient by x, by the assignments' weights and by
+    each expert matrix, under any routing; 8 rows a block walks three
+    blocks of the 24 assignments and splits an expert's run."""
+    monkeypatch.setattr(moe, "ROW_BLOCK", row_block)
+    x, _, weight, w = _problem(3)
+    expert, first, num = _routers()[router]
+    if num is not None:
+        w = [a[first:first + 3] for a in w]
+    probe = jax.random.normal(jax.random.key(9), (T, H))
+
+    def got(x, weight, w):
+        out, rows = dropless_experts_blocked(x, expert, weight, *w,
+                                             first=first, num_experts=num)
+        return jnp.sum(out * probe), rows
+
+    def want(x, weight, w):
+        return jnp.sum(_dense_loop(x, expert, weight, w, first) * probe)
+    (val, rows), g = jax.value_and_grad(got, (0, 1, 2), has_aux=True)(
+        x, weight, w)
+    val_w, g_w = jax.value_and_grad(want, (0, 1, 2))(x, weight, w)
+    np.testing.assert_allclose(val, val_w, rtol=1e-4, atol=1e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(g),
+                    jax.tree_util.tree_leaves(g_w)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    held = (np.asarray(expert) >= first) & (np.asarray(expert) < first + len(w[0]))
+    assert int(rows.sum()) == int(held.sum())
+    if router == "none_held":
+        assert float(val) == 0.0
+        assert all(float(jnp.abs(a).max()) == 0.0
+                   for a in jax.tree_util.tree_leaves(g))
+
+
+def test_row_blocks_give_what_the_serving_form_gives(monkeypatch):
+    monkeypatch.setattr(moe, "ROW_BLOCK", 8)
+    x, expert, weight, w = _problem(5)
+    a, ra = dropless_experts(x, expert, weight, *w)
+    b, rb = dropless_experts_blocked(x, expert, weight, *w)
+    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(ra, rb)
+
+
+def test_what_the_chip_leaves_in_nobodys_rows_reaches_no_gradient(monkeypatch):
+    """The TPU's grouped matmul leaves rows past the groups' sum as it
+    found them (PERF.md, Findings PR 45: a first chip run read gradients
+    20,000 times the reference's). Here every product's rows that nobody
+    owns are poisoned with NaN: output and gradients stay those of the
+    dense loop."""
+    real = moe.grouped_product
+
+    def poisoned(lhs, rhs, sizes):
+        out = real(lhs, rhs, sizes)
+        owned = jnp.arange(lhs.shape[0]) < jnp.sum(sizes)
+        return jnp.where(owned[:, None], out, jnp.nan)
+    monkeypatch.setattr(moe, "grouped_product", poisoned)
+    monkeypatch.setattr(moe, "ROW_BLOCK", 8)
+    x, expert, weight, w = _problem(3)
+    w = [a[3:6] for a in w]
+    probe = jax.random.normal(jax.random.key(9), (T, H))
+
+    def got(x, weight, w):
+        return jnp.sum(dropless_experts_blocked(
+            x, expert, weight, *w, first=3, num_experts=8)[0] * probe)
+
+    def want(x, weight, w):
+        return jnp.sum(_dense_loop(x, expert, weight, w, 3) * probe)
+    val, g = jax.value_and_grad(got, (0, 1, 2))(x, weight, w)
+    val_w, g_w = jax.value_and_grad(want, (0, 1, 2))(x, weight, w)
+    np.testing.assert_allclose(val, val_w, rtol=1e-4, atol=1e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(g),
+                    jax.tree_util.tree_leaves(g_w)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)

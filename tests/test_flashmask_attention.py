@@ -19,11 +19,11 @@ from paddle_tpu.ops.flashmask_attention import (_live_ranges,
                                                 flashmask_reference)
 
 
-def _qkv(b, h, s, d, seed=0, dtype=jnp.float32):
+def _qkv(b, h, s, d, seed=0, dtype=jnp.float32, d_v=None):
     rng = np.random.RandomState(seed)
     return (jnp.asarray(rng.randn(b, h, s, d), dtype) * 0.3,
             jnp.asarray(rng.randn(b, h, s, d), dtype) * 0.3,
-            jnp.asarray(rng.randn(b, h, s, d), dtype) * 0.3)
+            jnp.asarray(rng.randn(b, h, s, d_v or d), dtype) * 0.3)
 
 
 def _close(a, b, tol=2e-3):
@@ -530,3 +530,48 @@ class TestFlashMaskKernel:
             p /= p.sum()
             exp = p @ vn[0, 0, cols]
             assert np.allclose(o[0, 0, r], exp, atol=2e-3), r
+
+
+# ---------------------------------------------------------------------------
+# A value width of its own (latent attention up-projected: keys 192, values
+# 128): v, o, dO and dV at the values' width, q, k, dQ and dK at the keys'
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("d,d_v,lens,blocks", [
+    (24, 16, [100, 57, 171, 56], (128, 128)),
+    (24, 16, [90, 130, 100], (128, 128)),           # S = 320: a ragged tail
+    (16, 24, [200, 3, 181], (256, 128)),            # values the wider
+    (192, 128, [150, 106], (128, 128)),             # the published widths
+])
+def test_value_width_differs_from_key_width(d, d_v, lens, blocks):
+    """Forward and all three gradients against the dense reference,
+    causal with document ends; the scale is the keys' 1/sqrt(d)."""
+    s = sum(lens)
+    q, k, v = _qkv(1, 2, s, d, seed=d, d_v=d_v)
+    sri = jnp.broadcast_to(_doc_sri(lens), (1, 2, s, 1))
+    w = jnp.asarray(np.random.RandomState(1).randn(1, 2, s, d_v), jnp.float32)
+
+    def kernel(q, k, v):
+        return flashmask_attention_bhsd(
+            q, k, v, sri, causal=True, block_q=blocks[0], block_k=blocks[1],
+            use_pallas=True, interpret=True)
+
+    def dense(q, k, v):
+        return flashmask_reference(q, k, v, sri, causal=True,
+                                   sm_scale=1.0 / math.sqrt(d))[0]
+    loss = lambda fn: (lambda *a: (fn(*a) * w).sum())
+    got, g_got = jax.value_and_grad(loss(kernel), (0, 1, 2))(q, k, v)
+    want, g_want = jax.value_and_grad(loss(dense), (0, 1, 2))(q, k, v)
+    assert kernel(q, k, v).shape == (1, 2, s, d_v)
+    _close(kernel(q, k, v), dense(q, k, v))
+    _close(got, want, tol=2e-2)
+    for a, b, like in zip(g_got, g_want, (q, k, v)):
+        assert a.shape == like.shape
+        _close(a, b)
+
+
+def test_equal_widths_derive_the_blocks_they_did():
+    """`d_v` equal to `d` (or not given) changes nothing the dense
+    decoder's cell runs at; 192 / 128 still fits 512 x 512."""
+    assert derived_blocks(4096, 4096, 128, jnp.bfloat16) == \
+        derived_blocks(4096, 4096, 128, jnp.bfloat16, d_v=128) == (512, 512)
+    assert derived_blocks(8192, 8192, 192, jnp.bfloat16, d_v=128) == (512, 512)

@@ -167,6 +167,36 @@ def test_mesh_train_step_lowers(monkeypatch):
     assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
 
 
+def test_deepseek_train_step_lowers(monkeypatch):
+    """The functional step of the DeepSeek-V3 family with its kernels on:
+    the flashmask kernels at a value width of their own (keys 128 + 64,
+    values 128), the experts' grouped products in row blocks and their
+    hand-written backward pass, the fused loss."""
+    import importlib
+    from paddle_tpu.models import deepseek_spmd as ds
+    from paddle_tpu.models.deepseek import DeepSeekConfig
+    from paddle_tpu.parallel.mesh import create_mesh
+    fm = importlib.import_module("paddle_tpu.ops.flashmask_attention")
+    monkeypatch.setattr(fm, "_on_tpu", lambda: True)
+    cfg = DeepSeekConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+        kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, n_routed_experts=8, experts_held=2, first_expert=2,
+        n_shared_experts=2, num_experts_per_tok=3, moe_intermediate_size=128,
+        first_k_dense_replace=1, scoring_func="sigmoid",
+        topk_method="noaux_tc", routed_scaling_factor=2.446)
+    mesh = create_mesh({"dp": 1}, devices=jax.devices()[:1])
+    params = ds.init_params(cfg, seed=0, dtype=jnp.bfloat16)
+    step = ds.make_train_step(cfg, mesh)
+    ids = np.zeros((2, 256), np.int32)
+    text = _lower_tpu(step.jitted, params, ds.init_opt_state(params),
+                      jnp.asarray(0), (ids, ids, ids))
+    # fwd, dq, dkv in each of the two stacks (dense, expert layers)
+    assert text.count("tpu_custom_call") >= 6
+    assert "ragged_dot" in text
+
+
 # ---------------------------------------------------------------------------
 # Mosaic's own compile step, for a chip that is described and not attached
 # ---------------------------------------------------------------------------
@@ -363,6 +393,35 @@ def test_unified_step_compiles_for_v5e_with_its_pools_in_place(one_chip,
     # the sampler's sort over the vocabulary runs where a wave's rows
     # ask for it: under a conditional, never in the step's own body
     assert _vocab_sorts(text, slots, V) == (0, 1)
+
+
+def test_flashmask_compiles_for_v5e_at_latent_attentions_widths(one_chip):
+    """`sparse_pretrain_8k`'s shape (4 rows x 16 heads, 8,192 tokens, keys
+    128 + 64 = 192 wide and values 128, bfloat16, a document mask),
+    forward and backward, through the TPU compiler: the three kernels
+    carry each operand at its own width (dQ and dK 192, dV 128, no padded
+    product), at the 512 x 512 blocks the entry derives, inside the VMEM
+    they ask for."""
+    from paddle_tpu.ops import flashmask_attention as fm
+    b, h, s, d, dv = 4, 16, 8192, 192, 128
+    assert fm.derived_blocks(s, s, d, jnp.bfloat16, d_v=dv) == (512, 512)
+    assert fm._vmem_bytes(512, 512, d, 2, d_v=dv) <= 16 * 2 ** 20
+
+    def loss(q, k, v, sri):
+        return fm.flashmask_attention_bhsd(
+            q, k, v, sri, causal=True, use_pallas=True,
+            interpret=False).astype(jnp.float32).sum()
+
+    arg = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        arg(b, h, s, d), arg(b, h, s, d), arg(b, h, s, dv),
+        arg(b, h, s, 1, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3                  # fwd, dq, dkv
+    # dQ and dK at the keys' width, dV at the values': nothing padded
+    assert f"bf16[{b * h},{s},{d}]" in text and f"bf16[{b * h},{s},{dv}]" in text
+    assert f"bf16[{b * h},{s},256]" not in text
 
 
 @pytest.mark.parametrize("heads, window", [(48, None), (64, 512)],
