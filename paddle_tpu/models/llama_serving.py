@@ -36,7 +36,7 @@ from ..observability import compile_telemetry as _compile
 from ..observability.device_telemetry import device_generation
 from ..observability import flight_recorder as _flight
 from ..observability.compile_telemetry import track_jit
-from ..parallel.moe import row_tile_visits
+from ..parallel.moe import row_tile_visits, share_spills
 from ..profiler import record_span
 # host-side page bookkeeping only (numpy/stdlib — serving.kvcache,
 # serving.kvtier and serving.faults never import model/engine code, so
@@ -1445,6 +1445,10 @@ class ServingEngine:
         # over `moe_experts_touched`, how often an expert's run of sorted
         # rows spans a second tile and its weights are read again
         self.moe_row_tiles = 0
+        # the (layer, step) pairs of a share whose held assignments the
+        # few sorted rows could not hold, so that the layer's products
+        # ran over every assignment (`parallel/moe.share_spills`)
+        self.moe_share_spills = 0
         # a share of a layer's experts: the rows each held expert got,
         # and the assignments that went to experts held elsewhere; and
         # those that went to identity experts, which cost no product and
@@ -2978,8 +2982,9 @@ class ServingEngine:
                 self.moe_experts_touched += int((rows > 0).sum())
                 self.moe_rows_max_expert += int(rows.max(axis=1).sum())
                 picks, among = self.model.experts
-                self.moe_row_tiles += row_tile_visits(
-                    rows, self.ragged_buf * picks, among)
+                launched = rows, self.ragged_buf * picks, among
+                self.moe_row_tiles += row_tile_visits(*launched)
+                self.moe_share_spills += share_spills(*launched)
                 if "moe_elsewhere" in aux:
                     # the model holds a share of each layer's experts
                     self.moe_rows_elsewhere += int(aux["moe_elsewhere"].sum())

@@ -232,6 +232,17 @@ def row_tile_visits(rows, assignments, num_experts=None):
     return int(got.sum())
 
 
+def share_spills(rows, assignments, num_experts=None):
+    """The layers of a step's `rows` record ((..., E) counts, numpy)
+    whose held assignments exceed the sorted rows a share's products run
+    over (`_product_rows` of `assignments` = T k), so that
+    `dropless_experts` took its branch over all T k there: slow, not
+    wrong. A whole layer's products run over all T k, and it has none."""
+    rows = np.asarray(rows, np.int64)
+    few = _product_rows(assignments, rows.shape[-1], num_experts)
+    return int((rows.sum(-1) > few).sum())
+
+
 def _product_rows(assignments, held, num_experts):
     """The sorted rows `dropless_experts`' products run over: all T k for
     a whole layer; for a share four times its mean, in whole 128s."""
@@ -302,6 +313,15 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     gives the compiler's kernel its row tile (`grouped_product`), and
     the rows added are nobody's, like every row past the experts' sum.
 
+    A whole layer spreads its T k product rows back over the assignments
+    and sums a row's k. A share never makes that (T k, H) array: a
+    conditional's result is a buffer in memory, so it would be written
+    whole, copied out of the branch and read again by the sum, six
+    passes over 75 MB a layer of which the few rows hold anything
+    (PERF.md, Findings PR 47). Each branch weights its product rows
+    where they lie (sorted row i is assignment `order[i]`) and adds them
+    to their rows of the (T, H) sum it returns.
+
     This is the serving steps' form; a training step, whose rows are
     thousands an expert and which is differentiated, takes
     `dropless_experts_blocked`.
@@ -311,8 +331,6 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     E = w_gate.shape[0]
     few = _product_rows(T * k, E, num_experts)
     routed, order, rows = _sorted_by_expert(expert, E, first, num_experts)
-    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
-        jnp.arange(T * k, dtype=jnp.int32))         # assignment -> sorted
 
     def products(n):
         """(n, H) f32: the first n sorted rows through their experts."""
@@ -325,20 +343,41 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
         # rows past the experts' are nobody's: whatever is there, drop it
         return jnp.where((jnp.arange(n) < jnp.sum(rows))[:, None], y, 0.0)
 
-    if few == T * k:
-        y = products(few)[back]
-    else:
-        def gathered(n):
-            """() -> (T*k, H): each assignment's row of `products(n)`,
-            zero for one that sorted past them (not held)."""
-            return lambda: jnp.where(
-                (back < n)[:, None], products(n)[jnp.minimum(back, n - 1)],
-                0.0)
-        y = jax.lax.cond(jnp.sum(rows) <= few, gathered(few),
-                         gathered(T * k))
+    if few < T * k:
+        w = jnp.where(routed, weight, 0.0).astype(jnp.float32).reshape(-1)
+
+        def summed(n):
+            """() -> (T, H): the first n sorted rows' products, each
+            weighted by its assignment's weight (0 past the held ones,
+            and what the products left there is dropped BEFORE the
+            weight meets it) and added to its token's row."""
+            at = order[:n]
+            return lambda: _sum_by_row(products(n) * w[at][:, None],
+                                       at // k, T)
+        return jax.lax.cond(jnp.sum(rows) <= few, summed(few),
+                            summed(T * k)), rows
+    back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))         # assignment -> sorted
+    y = products(few)[back]
     w = jnp.where(routed, weight, 0.0).astype(jnp.float32)
     out = jnp.einsum("tkh,tk->th", y.reshape(T, k, -1), w)
     return out, rows
+
+
+def _sum_by_row(y, row, num_rows):
+    """y (n, H) f32, row (n,) the row each belongs to -> (num_rows, H)
+    f32, the sum of each row's: a product with the (num_rows, n) matrix
+    of ones where `row` says, exact at `Precision.HIGHEST` (one operand
+    is 0 or 1) and the MXU's, where a scatter-add visits a row at a time
+    (0.11 and 0.26 ms a layer over the three products against 0.23 and
+    0.37: PERF.md, Findings PR 47). 0 x NaN is NaN in a product, so a
+    y that is not finite is taken out first and its row made NaN after:
+    what one request's row holds stays that request's."""
+    hot = row[None, :] == jnp.arange(num_rows)[:, None]
+    fine = jnp.all(jnp.isfinite(y), -1)
+    out = jnp.dot(hot.astype(y.dtype), jnp.where(fine[:, None], y, 0.0),
+                  precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(jnp.any(hot & ~fine, -1)[:, None], jnp.nan, out)
 
 
 # sorted rows a block of `dropless_experts_blocked` holds: 26 kB a row of

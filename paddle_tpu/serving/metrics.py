@@ -446,6 +446,13 @@ class EngineMetrics:
             "pt_moe_experts_touched: 1.0 when every expert's rows lie "
             "in one tile; each visit more reads that expert's weights "
             "again.")
+        self.moe_share_spills = r.counter(
+            "pt_moe_share_spills",
+            "Sparse layers and steps of a share of a layer's experts "
+            "whose held assignments exceeded the sorted rows the "
+            "products run over (four times their mean), so that the "
+            "layer ran them over every assignment: slow, not wrong. 0 "
+            "while the router spreads its rows.")
         self.moe_rows_elsewhere = r.counter(
             "pt_moe_rows_elsewhere",
             "Assignments that went to experts this chip does not hold "
@@ -463,6 +470,7 @@ class EngineMetrics:
                           "ragged_runs": 0, "ragged_kv_blocks": 0,
                           "moe_assignments": 0, "moe_experts_touched": 0,
                           "moe_rows_max_expert": 0, "moe_row_tiles": 0,
+                          "moe_share_spills": 0,
                           "moe_rows_elsewhere": 0,
                           "moe_assignments_zero": 0,
                           "sampler_filter_steps": 0,
@@ -731,6 +739,7 @@ class EngineMetrics:
                               ("moe_rows_max_expert",
                                self.moe_rows_max_expert),
                               ("moe_row_tiles", self.moe_row_tiles),
+                              ("moe_share_spills", self.moe_share_spills),
                               ("moe_rows_elsewhere",
                                self.moe_rows_elsewhere),
                               ("moe_assignments_zero",

@@ -185,6 +185,127 @@ def test_a_share_falls_back_to_every_row_when_the_few_cannot_hold_it(hit,
     assert rows.tolist() == want_rows.tolist()
 
 
+# (rows, picks a row, experts held, real experts, experts routed over)
+# of the two shares at toy width: GLM-5's 8 of 256, and LongCat's 12 of
+# 768 of which the last 256 are identity experts, held by nobody
+SHARES = {"glm": (64, 8, 16, 256, 256), "longcat": (64, 12, 16, 512, 768)}
+
+
+def _routed_share(name, seed, first=32):
+    """A step's rows routed as the share's model routes them: distinct
+    picks over the router's whole width, the last six rows slack."""
+    t, k, held, among, width = SHARES[name]
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.key(seed), 4)
+    x = jax.random.normal(ks[0], (t, H))
+    w = [jax.random.normal(kk, s) * 0.3 for kk, s in zip(
+        ks[1:4], [(held, H, F), (held, H, F), (held, F, H)])]
+    expert = np.argsort(rng.random((t, width)), axis=1)[:, :k]
+    expert[t - 6:] = -1
+    weight = rng.random((t, k)).astype(np.float32)
+    return (x, jnp.asarray(expert, jnp.int32), jnp.asarray(weight), w,
+            dict(first=first, num_experts=among))
+
+
+def _every_row(monkeypatch, few=8):
+    """Make a share's few sorted rows fewer than any step holds, so that
+    `dropless_experts` takes its branch over all T k."""
+    monkeypatch.setattr(moe, "_product_rows", lambda *a: few)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("share", list(SHARES))
+def test_both_branches_of_a_share_give_each_row_the_same_sum(share, seed,
+                                                             monkeypatch):
+    """The branch over the few sorted rows and the one over all T k, on
+    the SAME routing: each returns the (T, H) sum, equal to float32
+    rounding (the order of a row's at most k terms may differ), and the
+    per-row loop's."""
+    t, k, held, among, _ = SHARES[share]
+    x, expert, weight, w, kw = _routed_share(share, seed)
+    few = moe._product_rows(t * k, held, among)
+    assert few == 128 < t * k
+    lean, rows = dropless_experts(x, expert, weight, *w, **kw)
+    assert 8 < int(rows.sum()) <= few
+    _every_row(monkeypatch)
+    every, rows_e = dropless_experts(x, expert, weight, *w, **kw)
+    assert lean.shape == every.shape == (t, H) and lean.dtype == jnp.float32
+    np.testing.assert_allclose(lean, every, rtol=1e-5, atol=1e-6)
+    assert rows.tolist() == rows_e.tolist()
+    want, want_rows = _loop(x, expert, weight, w, first=kw["first"])
+    np.testing.assert_allclose(lean, want, atol=5e-5)
+    assert rows.tolist() == want_rows.tolist()
+
+
+@pytest.mark.parametrize("branch", ["few", "every_row"])
+@pytest.mark.parametrize("left", [np.nan, np.inf, -3e38])
+def test_what_the_chip_leaves_in_nobodys_rows_reaches_no_row_of_a_share(
+        left, branch, monkeypatch):
+    """The serving twin of the training step's test below: the TPU's
+    grouped matmul leaves rows past the groups' sum as it found them.
+    Here every product's rows that nobody owns hold `left`: they are
+    dropped before a weight or a sum meets them, in both branches."""
+    real = moe.grouped_product
+
+    def poisoned(lhs, rhs, sizes):
+        out = real(lhs, rhs, sizes)
+        owned = jnp.arange(lhs.shape[0]) < jnp.sum(sizes)
+        return jnp.where(owned[:, None], out, left)
+    x, expert, weight, w, kw = _routed_share("longcat", 2)
+    want, _ = _loop(x, expert, weight, w, first=kw["first"])
+    monkeypatch.setattr(moe, "grouped_product", poisoned)
+    if branch == "every_row":
+        _every_row(monkeypatch)
+    out, _ = dropless_experts(x, expert, weight, *w, **kw)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(out, want, atol=5e-5)
+
+
+@pytest.mark.parametrize("branch", ["few", "every_row"])
+@pytest.mark.parametrize("share", list(SHARES))
+def test_a_row_with_nothing_held_here_gets_exact_zeros(share, branch,
+                                                       monkeypatch):
+    """Slack rows (`expert < 0`) and rows whose every assignment is held
+    elsewhere (or by nobody: an identity expert) add up nothing: exact
+    zeros, not small numbers, whatever their weights and their x."""
+    x, expert, weight, w, kw = _routed_share(share, 3)
+    held = (np.asarray(expert) >= kw["first"]) \
+        & (np.asarray(expert) < kw["first"] + w[0].shape[0])
+    idle = ~held.any(1)
+    assert idle[-6:].all() and 6 < idle.sum() < len(idle)
+    x = x.at[-6:].set(jnp.nan)
+    weight = jnp.where(idle[:, None], 1e30, weight).at[-6:].set(jnp.nan)
+    if branch == "every_row":
+        _every_row(monkeypatch)
+    out, rows = dropless_experts(x, expert, weight, *w, **kw)
+    assert int(rows.sum()) == held.sum()
+    assert (np.asarray(out)[idle] == 0).all()
+    assert np.asarray(out)[~idle].any(1).all()
+    assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("branch", ["few", "every_row", "whole_layer"])
+@pytest.mark.parametrize("left", [np.nan, np.inf])
+def test_a_row_that_is_not_finite_stays_its_own(left, branch, monkeypatch):
+    """One request's row holds NaN or infinity: its own sum is not
+    finite and every other row's is what it was, in a whole layer (a
+    gather) and in both branches of a share (a product with ones and
+    zeros, where 0 x NaN would reach every row)."""
+    x, expert, weight, w, kw = _routed_share("glm", 4)
+    if branch == "whole_layer":
+        expert, kw = jnp.where(expert < 0, -1, expert % 16), {}
+    bad = 7
+    clean, _ = dropless_experts(x, expert, weight, *w, **kw)
+    assert np.asarray(clean[bad]).any()
+    if branch == "every_row":
+        _every_row(monkeypatch)
+    out, _ = dropless_experts(x.at[bad].set(left), expert, weight, *w, **kw)
+    assert not np.isfinite(np.asarray(out[bad])).any()
+    others = np.arange(len(x)) != bad
+    np.testing.assert_allclose(np.asarray(out)[others],
+                               np.asarray(clean)[others], atol=1e-6)
+
+
 def test_rows_past_the_experts_sum_add_nothing_to_a_whole_layers_rows():
     """The whole layer launched over 32 rows for its 24 assignments: the
     slack rows' and the padded gather's rows are NaNs and nobody's."""
@@ -247,6 +368,56 @@ def test_the_engine_counts_the_row_tiles_its_steps_experts_span():
     by_hand = row_tile_visits(np.stack(seen), eng.ragged_buf * picks, among)
     assert snap["pt_moe_row_tiles"]["value"] == eng.moe_row_tiles == by_hand
     assert eng.moe_row_tiles == eng.moe_experts_touched > 0
+    # a whole layer has no few rows to exceed
+    assert snap["pt_moe_share_spills"]["value"] == eng.moe_share_spills == 0
+
+
+@pytest.mark.parametrize("rows, assignments, among, spills", [
+    # 4 of 64 held, 512 assignments: the few are 128 sorted rows
+    ([30, 4, 0, 40], 512, 64, 0), ([100, 28, 0, 0], 512, 64, 0),
+    ([100, 29, 0, 0], 512, 64, 1),
+    # (steps, layers, experts): a layer and step is one count
+    ([[[64, 64, 0, 0], [64, 64, 1, 0]], [[0, 0, 0, 129], [0, 0, 0, 0]]],
+     512, 64, 2),
+    # a whole layer's products run over every assignment: none
+    ([128, 0, 0, 0], 128, None, 0), ([128, 0, 0, 0], 128, 4, 0)])
+def test_share_spills_against_a_hand_count(rows, assignments, among, spills):
+    from paddle_tpu.parallel.moe import share_spills
+    assert share_spills(np.asarray(rows), assignments, among) == spills
+
+
+def test_the_engine_counts_the_steps_a_share_could_not_hold():
+    """`pt_moe_share_spills` beside `pt_moe_row_tiles`, from the same
+    record: the toy GLM-5 holds 1 of 8 experts over 128 rows x 2 picks,
+    so its products run over 128 of the 256 sorted rows. No step of its
+    own can exceed them (a row picks an expert once); a hand-made record
+    in the first step's place does, in one of its two expert layers."""
+    from glm_tiny import engine, init, tiny_model
+    from paddle_tpu.parallel.moe import _product_rows
+    from paddle_tpu.serving import RequestScheduler
+    m = tiny_model(held=1)
+    eng = engine(m, init(m), ragged_tokens=128)
+    picks, among = eng.model.experts
+    assert _product_rows(eng.ragged_buf * picks, 1, among) == 128 < 256
+    seen, real = [], eng.model.step
+
+    def step(*a, **kw):
+        out = real(*a, **kw)
+        if not seen:
+            out[-1]["moe_rows"] = np.asarray([[129], [128]], np.int32)
+        seen.append(out[-1]["moe_rows"])
+        return out
+
+    object.__setattr__(eng.model, "step", step)
+    sched = RequestScheduler(eng, max_queue=8)
+    try:
+        h = sched.submit(list(range(1, 24)), max_new_tokens=4, eos_id=None)
+        assert len(list(h.result())) == 4
+        snap = sched.registry.snapshot()
+    finally:
+        sched.shutdown(drain=False, timeout=30)
+    assert len(seen) > 1 and all(np.asarray(r).shape == (2, 1) for r in seen)
+    assert snap["pt_moe_share_spills"]["value"] == eng.moe_share_spills == 1
 
 
 # ---------------------------------------------------------------------------

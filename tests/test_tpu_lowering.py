@@ -222,11 +222,13 @@ def _sample_shapes(arg, slots):
             "remaining": arg((slots,), jnp.int32)}
 
 
-def _vocab_sorts(text, rows, vocab):
-    """The `sort`s over a (rows, vocab) operand in a compiled step's
-    text -> (in the step's own body, under a `conditional`'s branch).
-    The body is every computation the entry reaches without going
-    through a conditional's branch: fusions, calls, loops."""
+_CALLED = r"(?:calls|to_apply|body|condition|true_computation|" \
+          r"false_computation)=%?([\w.-]+)"
+
+
+def _computations(text):
+    """A compiled module's text -> {computation: its lines}, the entry
+    under `ENTRY`."""
     import re
     comps, name = {}, None
     for line in text.splitlines():
@@ -236,30 +238,80 @@ def _vocab_sorts(text, rows, vocab):
             comps[name] = []
         elif name is not None:
             comps[name].append(line)
-    called = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
-                        r"false_computation)=%?([\w.-]+)")
-    branches = re.compile(r"branch_computations=\{([^}]*)\}")
+    return comps
+
+
+def _branches(line):
+    """The branch computations of a `conditional`'s line, in order."""
+    import re
+    return [x.strip().lstrip("%") for grp in
+            re.findall(r"branch_computations=\{([^}]*)\}", line)
+            for x in grp.split(",")]
+
+
+def _reach(comps, start, through_conditionals=True):
+    """Every computation `start` reaches: fusions, calls, loops and, if
+    asked, the branches of conditionals."""
+    import re
+    seen, todo = set(), [start]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            if through_conditionals or " conditional(" not in line:
+                todo += re.findall(_CALLED, line) + _branches(line)
+    return seen
+
+
+def _vocab_sorts(text, rows, vocab):
+    """The `sort`s over a (rows, vocab) operand in a compiled step's
+    text -> (in the step's own body, under a `conditional`'s branch).
+    The body is every computation the entry reaches without going
+    through a conditional's branch: fusions, calls, loops."""
+    import re
+    comps = _computations(text)
     # the result is the values, or (values, their places) on the chip
     sort = re.compile(r"= [^=]*\[%d,%d\][^=]* sort\(" % (rows, vocab))
 
-    def reach(through_conditionals):
-        seen, todo = set(), ["ENTRY"]
-        while todo:
-            c = todo.pop()
-            if c in seen:
-                continue
-            seen.add(c)
-            for line in comps[c]:
-                if through_conditionals or " conditional(" not in line:
-                    todo += called.findall(line)
-                    todo += [x.strip().lstrip("%") for grp in
-                             branches.findall(line) for x in grp.split(",")]
-        return seen
-
     def count(names):
         return sum(bool(sort.search(ln)) for c in names for ln in comps[c])
-    body = reach(False)
-    return count(body), count(reach(True) - body)
+    body = _reach(comps, "ENTRY", False)
+    return count(body), count(_reach(comps, "ENTRY") - body)
+
+
+def _share_sums(text, rows, picks, width):
+    """Hold a compiled step to where a share's results go (`parallel/moe.
+    dropless_experts`, PERF.md Findings PR 47): every conditional whose
+    branches launch grouped products returns the (rows, width) float32
+    SUM and nothing else, and no (rows x picks, width) float32 value,
+    the spread over every assignment, is defined anywhere but under the
+    branch that launches the products over every assignment (the one
+    whose row tiles read 256). -> the number of such conditionals."""
+    import re
+    comps = _computations(text)
+    tiling = re.compile(r'ragged_dot_tiling="(\d+),')
+    spread = re.compile(r"= f32\[%d,%d\]" % (rows * picks, width))
+    every_row, layers = set(), 0
+    for lines in comps.values():
+        for line in lines:
+            if " conditional(" not in line:
+                continue
+            under = [_reach(comps, b) for b in _branches(line)]
+            tiles = [sorted({int(t) for c in names for ln in comps[c]
+                             for t in tiling.findall(ln)}) for names in under]
+            if not any(tiles):
+                continue
+            layers += 1
+            assert len(tiles) == 2 and [256] in tiles, tiles
+            assert re.search(r"= \(f32\[%d,%d\]\S*\) conditional\("
+                             % (rows, width), line), line[:300]
+            every_row |= under[tiles.index([256])]
+    made = [ln.strip()[:200] for c in set(comps) - every_row
+            for ln in comps[c] if spread.search(ln)]
+    assert not made, made
+    return layers
 
 
 def _row_tiles(text):
@@ -556,6 +608,9 @@ def test_glm_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
     # branch a step takes when they do not hold its assignments
     assert "ragged-dot" in text
     assert _row_tiles(text) == [64] * 3 + [256] * 3
+    # and the layer's conditional returns each row's sum: no spread of
+    # the products over 512 x 8 assignments but in the 256-tile branch
+    assert _share_sums(text, t, c.num_experts_per_tok, c.hidden_size) == 1
 
 
 def test_laguna_step_compiles_for_v5e_with_its_sort_under_a_conditional(
@@ -653,4 +708,7 @@ def test_longcat_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
     # 384 sorted rows of 16 groups launched over 416; all 3,072 (3,328)
     # in the branch that holds a step's every assignment
     assert _row_tiles(text) == [32] * 3 + [256] * 3
+    # and the layer's conditional returns each row's sum: no spread of
+    # the products over 256 x 12 assignments but in the 256-tile branch
+    assert _share_sums(text, t, c.moe_topk, c.hidden_size) == 1
     assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)

@@ -74,8 +74,10 @@ LAYERS = {
                     experts=512, picks_over=768),
 }
 SMOKE_SHAPE = dict(rows=48, k=32, n=16, groups=6, real=20)
-SMOKE_LAYER = dict(t=16, live=13, k=2, h=32, f=16, held=4, experts=8,
-                   picks_over=8)
+# a share whose few sorted rows (128) are fewer than its assignments
+# (512), so that the smoke goes through `dropless_experts`' conditional
+SMOKE_LAYER = dict(t=64, live=50, k=8, h=32, f=16, held=4, experts=64,
+                   picks_over=64)
 
 
 def tile_lengths(n, tiles=TILES):
