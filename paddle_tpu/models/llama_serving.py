@@ -1218,7 +1218,8 @@ class ServingEngine:
     parity: fleet BlockManager swap-out/swap-in in
     paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu's
     serving stack):
-      * "offload" (default): the victim's KV pages are copied to HOST
+      * "offload" (the default, unless the model cannot be offloaded:
+        `ServingModel.unsupported`): the victim's KV pages are copied to HOST
         memory on eviction and scattered back into fresh device pages on
         resume — zero recompute, one device<->host round trip of
         n_pages*page_size tokens of KV.
@@ -1260,7 +1261,7 @@ class ServingEngine:
     def __init__(self, params, config: LlamaConfig, max_seqs=4,
                  max_seq_len=512, page_size=16, dtype=jnp.float32,
                  use_pallas=None, interpret=False, num_pages=None,
-                 cache_dtype=None, preempt_policy="offload",
+                 cache_dtype=None, preempt_policy=None,
                  spec_decode=0, spec_ngram=2, chunked_prefill=False,
                  spec_sample=False, mesh=None, prefix_cache=False,
                  host_tier_bytes=0, tier_quantize=True, faults=None,
@@ -1275,7 +1276,11 @@ class ServingEngine:
         tp_ = mesh is not None and mesh.shape.get("tp", 1) > 1
         if ragged is None and "bucketed" in model.unsupported:
             ragged = True
+        if preempt_policy is None:
+            preempt_policy = "recompute" if "offload" in model.unsupported \
+                else "offload"
         for feature, asked in (
+                ("offload", preempt_policy == "offload"),
                 ("tensor_parallel", tp_), ("prefix_cache", prefix_cache),
                 ("host_tier", host_tier_bytes),
                 ("spec_decode", int(spec_decode) > 1 or chunked_prefill),
@@ -1572,6 +1577,25 @@ class ServingEngine:
                 f"groups are {[g.name for g in model.groups]}")
         self._windowed = [gc for gc in self._caches
                           if gc.spec.window is not None]
+        # what the model keeps a SLOT beside its pages (`SlotState`): one
+        # zero array a spec, `(layers, max_seqs) + shape`, donated to the
+        # step with the pools and rebound from its result. The engine
+        # never writes them: the step starts a run at position 0 from
+        # zero state, so admission, release and preemption by recompute
+        # (which feeds a victim again from its first token) send the
+        # device nothing for them
+        self._slot_state = [
+            jnp.zeros((st.layers, max_seqs) + tuple(st.shape),
+                      jnp.dtype(st.dtype or dtype),
+                      device=self._pool_placement)
+            for st in model.slot_states]
+        self.slot_state_bytes = sum(a.nbytes for a in self._slot_state)
+        # from the step's record, one layer's: the slots whose state a
+        # step read and wrote (a slot has ONE run of rows a step, so they
+        # are the runs too), the runs that began from zero state, the rows
+        self.ssm_state_slots = 0
+        self.ssm_runs_fresh = 0
+        self.ssm_rows = 0
         # page_table/lengths are HOST numpy state, transferred once per
         # device call: the admission/growth bookkeeping reads and writes
         # them element-wise every step, and each element access on a
@@ -2705,7 +2729,8 @@ class ServingEngine:
         with record_span("serving.unified_step", part="dispatch",
                          ring=True):
             caches, logits, rec, self.tok_buf, aux = self.model.step(
-                self.params, tuple(gc.device() for gc in self._caches),
+                self.params, tuple(gc.device() for gc in self._caches)
+                + tuple(self._slot_state),
                 tables, *staged, self.config, self.page_size,
                 use_pallas=self._use_pallas, interpret=self._interpret,
                 sample=sample, need_rows=need_rows,
@@ -2713,6 +2738,7 @@ class ServingEngine:
                 tok_buf=self.tok_buf, buf_write=buf_write)
         for gc, got in zip(self._caches, caches):
             gc.take(got)
+        self._slot_state = list(caches[len(self._caches):])
         seed_rows = None
         if seeds:
             with record_span("serving.seed_gather", part="dispatch"):
@@ -2994,6 +3020,11 @@ class ServingEngine:
                         self.moe_rows_by_expert + held
                 if "moe_zero" in aux:
                     self.moe_assignments_zero += int(aux["moe_zero"].sum())
+            if "ssm_runs" in aux:
+                # a slot has one run a step: as many states moved
+                self.ssm_state_slots += int(aux["ssm_runs"])
+                self.ssm_runs_fresh += int(aux["ssm_runs_fresh"])
+                self.ssm_rows += int(aux["ssm_rows"])
             self._ragged_consume(ticket, inflight, nxt, done, lp,
                                  seed_rows)
         return len(ticket.slots)

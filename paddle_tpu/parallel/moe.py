@@ -17,6 +17,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .._core.tensor import Tensor, apply
+from ..kernels import grouped_matmul
 from ..nn.layer.layers import Layer
 from ..nn.initializer import XavierUniform
 
@@ -284,10 +285,24 @@ def _sorted_by_expert(expert, held, first, num_experts):
     return routed, order, rows
 
 
+def _small_blocks(w):
+    """Whether the TPU compiler's grouped matmul would walk `w` (G, K, N)
+    in blocks under 512 a side: it takes for each side the largest power
+    of two up to 512 that divides it (`ragged_dot_tiling="tm,tk,tn"`), and
+    a side that is a multiple of 128 and of nothing larger gives blocks of
+    32 KB whose grid steps, not whose bytes, set the time
+    (`kernels/grouped_matmul.py`)."""
+    return any(side % 512 for side in w.shape[1:])
+
+
 def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
-                     num_experts=None):
-    """Every assignment to an expert held here computed, none dropped:
-    SwiGLU experts over the step's flat rows.
+                     num_experts=None, up_transposed=False, use_pallas=False,
+                     interpret=False):
+    """Every assignment to an expert held here computed, none dropped,
+    over the step's flat rows. The experts are gated,
+    `down(silu(gate x) * up x)` (three grouped products), or, where
+    `w_gate` is None, plain with a squared ReLU, `down(relu(up x)^2)`
+    (two; `models/nemotron_h.py`).
 
     x (T, H); expert (T, k) i32, the expert each of a row's k assignments
     goes to, numbered over ALL the layer's experts (negative: routes
@@ -302,7 +317,7 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     shares' sums over every chip are the whole layer's
     (tests/test_glm_dsa_serving.py).
 
-    Rows are sorted by expert, three grouped products run over the
+    Rows are sorted by expert, the grouped products run over the
     sorted rows that can hold an assignment, and each row gets the
     weighted sum of its assignments back. A whole layer's are all T k;
     a share's are four times its mean (T k E / num_experts) when they
@@ -312,6 +327,16 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     LAUNCHED over `tiled_rows` of those: the buffer's length is what
     gives the compiler's kernel its row tile (`grouped_product`), and
     the rows added are nobody's, like every row past the experts' sum.
+    `up_transposed`: w_gate / w_up are (E, F, H), a matrix's rows its
+    outputs, as a checkpoint keeps a linear layer (a width F that is not
+    whole lane tiles then lies where the device keeps it anyway).
+    Where the compiler's kernel would walk a matrix in small blocks
+    (`_small_blocks`: a width that 512 does not divide) and the matrix
+    fits fast memory, the product is `kernels/grouped_matmul`, which
+    fetches a touched expert's matrix whole and once, if the caller hands
+    on its engine's `use_pallas` / `interpret` pair (as it does to every
+    kernel of its step); the compiler's kernel everywhere else, so a model
+    whose widths 512 divides compiles to what it did.
 
     A whole layer spreads its T k product rows back over the assignments
     and sums a row's k. A share never makes that (T k, H) array: a
@@ -328,18 +353,29 @@ def dropless_experts(x, expert, weight, w_gate, w_up, w_down, first=0,
     -> (out (T, H) f32, rows (E,) i32: the rows each held expert got).
     """
     T, k = expert.shape
-    E = w_gate.shape[0]
+    E = w_up.shape[0]
     few = _product_rows(T * k, E, num_experts)
     routed, order, rows = _sorted_by_expert(expert, E, first, num_experts)
+
+    def product(n, lhs, w, transposed=False):
+        if (use_pallas or interpret) and _small_blocks(w) \
+                and grouped_matmul.fits(w):
+            return grouped_matmul.grouped_matmul(
+                lhs, w, rows, row_tile(n, E), transposed, interpret)
+        return grouped_product(
+            lhs, jnp.swapaxes(w, 1, 2) if transposed else w, rows)
 
     def products(n):
         """(n, H) f32: the first n sorted rows through their experts."""
         # the gather builds the buffer at the length the products are
         # launched over; the rows it adds (row 0's) are nobody's
         xs = x[jnp.pad(order[:n], (0, tiled_rows(n, E) - n)) // k]
-        h = jax.nn.silu(grouped_product(xs, w_gate, rows)) \
-            * grouped_product(xs, w_up, rows)
-        y = grouped_product(h.astype(x.dtype), w_down, rows)[:n]
+        up = functools.partial(product, n, xs, transposed=up_transposed)
+        if w_gate is None:
+            h = jnp.square(jax.nn.relu(up(w_up)))
+        else:
+            h = jax.nn.silu(up(w_gate)) * up(w_up)
+        y = product(n, h.astype(x.dtype), w_down)[:n]
         # rows past the experts' are nobody's: whatever is there, drop it
         return jnp.where((jnp.arange(n) < jnp.sum(rows))[:, None], y, 0.0)
 

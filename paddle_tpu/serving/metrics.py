@@ -463,6 +463,31 @@ class EngineMetrics:
             "pt_moe_assignments_zero",
             "Assignments to identity (zero-compute) experts: their "
             "weight times the row, added with no product.")
+        # what a step's record says of the state its state-space layers
+        # keep a slot (`SlotState`), ONE layer's, summed over steps
+        self.ssm_state_slots = r.counter(
+            "pt_ssm_state_slots",
+            "Slots whose recurrent state a step read and wrote, a "
+            "state-space layer: the state's bytes a layer moves are "
+            "twice this times a slot's. A slot has one run of rows a "
+            "step (a decoding slot's one row, a prompt's chunk), so "
+            "these are the runs the scan walked too.")
+        self.ssm_rows = r.counter(
+            "pt_ssm_rows",
+            "Rows the selective-state scan advanced a state over, a "
+            "state-space layer.")
+        self.ssm_runs_fresh = r.counter(
+            "pt_ssm_runs_fresh",
+            "Runs that began at position 0 and so from zero state: one "
+            "for each request admitted, one more each time a preempted "
+            "one is fed again: over pt_serving_requests_started it is "
+            "the times a request's tokens were fed (1 with no "
+            "preemption).")
+        self.ssm_state_bytes = r.gauge(
+            "pt_ssm_state_bytes",
+            "Bytes allocated for what the model keeps a slot and not a "
+            "token (all layers, all slots), whatever the contexts' "
+            "lengths.")
         self._moe_rows = []     # pt_moe_rows{expert=}, made on first report
         self._tok_seen = {"pad_tokens": 0, "ragged_tokens": 0,
                           "logit_rows": 0, "logit_rows_skipped": 0,
@@ -473,6 +498,8 @@ class EngineMetrics:
                           "moe_share_spills": 0,
                           "moe_rows_elsewhere": 0,
                           "moe_assignments_zero": 0,
+                          "ssm_state_slots": 0, "ssm_rows": 0,
+                          "ssm_runs_fresh": 0,
                           "sampler_filter_steps": 0,
                           "sampler_draw_steps": 0}
         # by cache group, made when a group first reports (on_step):
@@ -744,6 +771,9 @@ class EngineMetrics:
                                self.moe_rows_elsewhere),
                               ("moe_assignments_zero",
                                self.moe_assignments_zero),
+                              ("ssm_state_slots", self.ssm_state_slots),
+                              ("ssm_rows", self.ssm_rows),
+                              ("ssm_runs_fresh", self.ssm_runs_fresh),
                               ("sampler_filter_steps",
                                self.sampler_filter_steps),
                               ("sampler_draw_steps",
@@ -753,6 +783,7 @@ class EngineMetrics:
             if delta > 0:
                 counter.inc(delta)
                 seen[attr] = cur
+        self.ssm_state_bytes.set(getattr(engine, "slot_state_bytes", 0))
         by_expert = getattr(engine, "moe_rows_by_expert", None)
         if by_expert is not None:
             self._on_experts(by_expert)

@@ -4,16 +4,17 @@ The engine owns slots, pages, plans, tickets and the pump; a model owns
 its layers. They meet here. A configuration class answers
 `serving_model()` with a `ServingModel`, and the engine builds one page
 pool, one page table and one allocator for each `CacheGroup` (a layer
-TYPE: layers whose pages live and die together), then calls `step` with
-all of them every wave. Plain data, no jax: `serving/` stays free of
-model code.
+TYPE: layers whose pages live and die together) and one array for each
+`SlotState` (what a layer keeps a SLOT, whatever the context's length),
+then calls `step` with all of them every wave. Plain data, no jax:
+`serving/` stays free of model code.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Callable, Mapping, Optional, Tuple
 
-__all__ = ["CacheGroup", "Plane", "ServingModel"]
+__all__ = ["CacheGroup", "Plane", "ServingModel", "SlotState"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,24 +90,59 @@ class CacheGroup:
 
 
 @dataclasses.dataclass(frozen=True)
+class SlotState:
+    """What each of `layers` layers keeps a SLOT and not a token: `shape`
+    values in `dtype` (a name; None: the engine's own type), the same
+    bytes at position 10 and at position 100,000 (a state-space layer's
+    recurrent state, the last rows of its convolution). The engine
+    allocates `(layers, max_seqs) + shape`, zero, donates it to `step`
+    with the pools and rebinds it from the result. It never touches the
+    values: a run of rows that begins at position 0 begins from zero
+    state, which the step reads from `tok_pos`, so a slot taken again
+    (a new request, or one preempted and fed again from its first
+    token) costs the device nothing and leaks nothing; a prompt longer
+    than the row buffer carries its state from step to step because the
+    slot does."""
+    name: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Optional[str] = None
+
+    def bytes_per_slot(self, itemsize):
+        """What one slot keeps in all the layers, at `itemsize` bytes a
+        value (plain data, no jax: no type is looked up here)."""
+        n = self.layers * itemsize
+        for d in self.shape:
+            n *= d
+        return n
+
+
+@dataclasses.dataclass(frozen=True)
 class ServingModel:
-    """`groups`: the cache spec. `q_group`: the largest number of query
-    heads that share a KV head (the ragged kernel's tile is derived for
-    it). `step(params, caches, tables, tokens, tok_slot, tok_pos,
-    config, page_size, **kw)` is `unified_step`'s descriptor contract
-    over every group at once: `caches[g][i]` is group g's i-th stack,
-    its planes' pools and then their scales (`(k, v, k_scale, v_scale)`
-    in the K/V case), `tables[g]` its page table; it
+    """`groups`: the cache spec by token; `slot_states`: what is kept a
+    slot beside it (none, for a model whose every layer keeps pages).
+    `q_group`: the largest number of query heads that share a KV head
+    (the ragged kernel's tile is derived for it). `step(params, caches,
+    tables, tokens, tok_slot, tok_pos, config, page_size, **kw)` is
+    `unified_step`'s descriptor contract over every group at once:
+    `caches[g][i]` is group g's i-th stack, its planes' pools and then
+    their scales (`(k, v, k_scale, v_scale)` in the K/V case),
+    `tables[g]` its page table; after the groups' entries `caches`
+    holds one array for each of `slot_states`, in their order. It
     returns `(caches, logits, rec, tok_buf, aux)`, `aux` a dict of
     small device arrays the step's record carries beside the tokens
     (`moe_rows`: a sparse layer x the rows each expert held here got;
     `moe_elsewhere`: its assignments to real experts held elsewhere;
-    `moe_zero`: its assignments to identity experts, nobody's to hold).
-    `step` DONATES `caches` and the pools come back where they lay
-    (`unified_step`, `laguna_step`; held to the compiled programs by
-    `tests/test_tpu_lowering.py`), so a second step in flight needs no
-    further copy of them: that is what lets the scheduler run a ragged
-    engine one step deep. A caller rebinds the pools from the result.
+    `moe_zero`: its assignments to identity experts, nobody's to hold;
+    `ssm_runs`, `ssm_runs_fresh`, `ssm_rows`: the runs of rows a slot's
+    state advanced over in one layer, those that began from zero, and
+    their rows).
+    `step` DONATES `caches`, and pools and states come back where they
+    lay (`unified_step`, `laguna_step`, `nemotron_step`; held to the
+    compiled programs by `tests/test_tpu_lowering.py`), so a second
+    step in flight needs no further copy of them: that is what lets the
+    scheduler run a ragged engine one step deep. A caller rebinds them
+    from the result.
     `unsupported`: engine feature -> why this model cannot run under it;
     the engine refuses at construction with that reason.
     `rows`: the rows a step should hold (the engine's flat row buffer),
@@ -124,3 +160,4 @@ class ServingModel:
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
     rows: Optional[int] = None
     experts: Optional[Tuple[int, int]] = None
+    slot_states: Tuple[SlotState, ...] = ()

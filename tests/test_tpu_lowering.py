@@ -712,3 +712,128 @@ def test_longcat_step_compiles_for_v5e_with_its_pools_in_place(one_chip):
     # the products over 256 x 12 assignments but in the 256-tile branch
     assert _share_sums(text, t, c.moe_topk, c.hidden_size) == 1
     assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
+
+
+@pytest.mark.parametrize("kept", ["float32", "bfloat16"])
+def test_ssm_kernels_compile_for_v5e_at_nemotrons_shapes(one_chip, kept):
+    """`kernels/ragged_ssm.py` at `nemotron-3-nano-30b-a3b.serve1`'s widths
+    (256 rows, 128 slots, 64 heads of 64 over a state of 128 in 8 groups,
+    6,144 convolution channels, 4 layers' state in one array) through the
+    TPU compiler, the state float32 and in the control's bfloat16: each
+    state is donated and aliased to an output whole, and nothing of its
+    size is made beside it."""
+    from paddle_tpu.kernels import ragged_ssm
+    t, slots, layers, heads, p, g, n, k = 256, 128, 4, 64, 64, 8, 128, 4
+    conv_dim = heads * p + 2 * g * n
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def scan(x, dt, a, b, c, state, slot, pos):
+        return ragged_ssm.ragged_scan(x, dt, a, b, c, state, 2, slot, pos,
+                                      use_pallas=True)
+
+    def conv(u, state, w, bias, slot, pos):
+        return ragged_ssm.ragged_conv(u, state, 1, w, bias, slot, pos,
+                                      use_pallas=True)
+
+    rows = [arg((t,), jnp.int32)] * 2
+    ssm = arg((layers, slots, n, heads * p), jnp.dtype(kept))
+    carried = arg((layers, slots, k - 1, conv_dim // 128, 128), jnp.bfloat16)
+    for fn, args, at, name in (
+            (scan, [arg((t, heads, p)), arg((t, heads)), arg((heads,)),
+                    arg((t, g, n)), arg((t, g, n)), ssm] + rows, 5,
+             "ragged_ssm_scan"),
+            (conv, [arg((t, conv_dim)), carried, arg((k, conv_dim)),
+                    arg((conv_dim,))] + rows, 1, "ragged_ssm_conv")):
+        compiled = jax.jit(fn, donate_argnums=(at,)).lower(*args).compile()
+        mem = compiled.memory_analysis()
+        state = args[at]
+        assert mem.alias_size_in_bytes == math.prod(state.shape) \
+            * state.dtype.itemsize
+        assert mem.temp_size_in_bytes < 32e6
+        assert name in compiled.as_text()
+
+
+@pytest.mark.parametrize("k, n, transposed", [(2688, 1856, True),
+                                              (1856, 2688, False)])
+def test_the_whole_matrix_grouped_product_compiles_for_v5e(one_chip, k, n,
+                                                           transposed):
+    """`kernels/grouped_matmul` at Nemotron-3-Nano's expert widths (1,568
+    sorted rows at tile 32 over 128 matrices of 10 MB, two of them in fast
+    memory at once) through the TPU compiler, the up matrix rows its
+    outputs and no copy of it made; the compiler's own kernel would take
+    these in blocks of 128 a side."""
+    import re
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = arg((1568, k)), arg((128, k, n)), arg((128,), jnp.int32)
+    mine = (args[0], arg((128, n, k) if transposed else (128, k, n)), args[2])
+    text = jax.jit(lambda x, w, gs: grouped_matmul(
+        x, w, gs, 32, transposed)).lower(*mine).compile().as_text()
+    assert "ragged_dot_experts" in text
+    assert not re.search(r"bf16\[128,\d+,\d+\]\S* copy\(", text)
+    theirs = jax.jit(lambda x, w, gs: jax.lax.ragged_dot(
+        x, w, gs, preferred_element_type=jnp.float32)).lower(
+        *args).compile().as_text()
+    assert re.findall(r'ragged_dot_tiling="([\d,]+)"', theirs) == \
+        ["32,128,128"]
+
+
+def test_nemotron_step_compiles_for_v5e_with_pools_and_state_in_place(
+        one_chip):
+    """`nemotron_step` at `nemotron-3-nano-30b-a3b.serve1`'s widths, pool
+    and slots (the pattern's first six letters, `MEMEM*`, hold every kind
+    of layer) through the TPU compiler: the attention layer's pages and
+    both slot states are donated and aliased to an output, no weight and
+    no state is copied, the scan and the convolution run once a Mamba
+    layer under their own names, the attention kernel takes a query group
+    of 16, and the experts' two grouped products, over widths that 512
+    does not divide, are the whole-matrix kernel and not the compiler's
+    walk over blocks of 128 a side."""
+    import re
+    from paddle_tpu.models import nemotron_h as nh
+    t, pages, page, slots, max_len = 256, 4096, 128, 128, 12288
+    c = nh.NemotronHConfig(num_hidden_layers=6)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        # no layout stated: the compiler lays a parameter out as the device
+        # does. An up matrix kept (experts, 2,688, 1,856) came out with
+        # 2,688 last there and was copied row-major for the kernel every
+        # step, 1.28 GB a layer (PERF.md, Findings PR 48); kept (experts,
+        # 1,856, 2,688), rows its outputs, it lies as the kernel reads it
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda p, s: arg(s, jnp.float32 if p[-1].key in nh.FLOAT32
+                         else jnp.bfloat16),
+        nh.param_shapes(c), is_leaf=lambda x: isinstance(x, tuple))
+    pool = arg((1, 2, pages, page, 128))
+    ssm = arg((3, slots, 128, 4096), jnp.float32)
+    conv = arg((3, slots, 3, 48, 128))
+    compiled = nh.nemotron_step.__wrapped__.lower(
+        params, (((pool, pool, None, None),), ssm, conv),
+        (arg((slots, max_len // page), jnp.int32),),
+        arg((t,), jnp.int32), arg((t,), jnp.int32), arg((t,), jnp.int32), c,
+        page, use_pallas=True, interpret=False,
+        sample=_sample_shapes(arg, slots), need_rows=arg((slots,), jnp.int32),
+        tok_buf=arg((slots, max_len + 1), jnp.int32),
+        buf_write=arg((slots,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    kept = 2 * 2 * pages * page * 128 * 2 + 3 * slots * (
+        128 * 4096 * 4 + 3 * 6144 * 2)
+    assert mem.alias_size_in_bytes == kept
+    assert mem.temp_size_in_bytes < 0.6e9
+    text = compiled.as_text()
+    assert "copy(%params" not in text
+    assert not re.search(r"\[3,128,128,4096\]\S* copy\(", text)
+    for name, calls in (("ragged_ssm_scan", 3), ("ragged_ssm_conv", 3),
+                        ("ragged_paged_attention", 1),
+                        ("ragged_dot_experts", 4)):
+        assert len(re.findall(r'custom_call_target="tpu_custom_call".*'
+                              + name, text)) >= calls, name
+    assert "ragged_dot_tiling" not in text
+    assert _vocab_sorts(text, slots, c.vocab_size) == (0, 1)
