@@ -15,17 +15,19 @@ blockwise online-softmax structure, plus
   * per (q-block, k-block), block-level aggregates (max of starts, min
     of ends over the k-block's columns) decide SKIP: a block whose every
     (row, col) pair is masked is skipped via @pl.when before any MXU
-    work, mirroring the reference kernel's block-skip. Aggregates over
-    the ragged tail's padding lanes only weaken the skip predicate
-    (max grows / min shrinks), never falsify it;
+    work, mirroring the reference kernel's block-skip. A ragged tail
+    is grown to a whole block with its last column again, so its
+    aggregates are its real columns';
   * the same aggregates, taken once outside the kernels, give every
-    line of the grid its live range: the first and last inner block
-    that may hold an unmasked pair (`_live_ranges`). The ranges are
-    prefetched scalars; the index maps clamp into them, so a step
-    outside its range names the block already resident (no copy) and
-    its body is skipped on a comparison of two scalars. The grid stays
-    (bh, outer, inner): a dead step still costs a pipeline step, about
-    0.3 us on a v5e, which is why the blocks are large;
+    line of a grid its live range: the first and last inner block that
+    may hold an unmasked pair (`_live_ranges`), prefetched scalars.
+    The grids are (bh, outer) and hold no inner dimension: a step walks
+    its line's range in a loop of `last - first + 1` trips (`_walk`),
+    with the inner side's operands left in HBM and copied in by hand
+    into two buffers, the next block's copy (across the end of a line,
+    the next line's first block) in flight behind the current block's
+    products. A block outside a line's range costs nothing: no step,
+    no copy, no test;
   * surviving blocks apply the exact per-pair mask built from row iota
     vs the streamed start/end columns;
   * blocks come from the shapes (`derived_blocks`) unless the caller
@@ -51,29 +53,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu._env import env_int
-from .flash_attention import (F0, F1, NEG_INF, Z, LANES,
-                              _col_mask, _fit_lanes, _on_tpu,
-                              pallas_disabled)
-
-
-def _zero_oob(qi, ki, q, k, v, do=None, *, block_q, block_k, sq, sk):
-    """Zero out ragged-tail garbage: OOB lanes of a padded block read
-    undefined values, and 0 * NaN would poison the accumulators even
-    where the keep-mask already zeroes p/ds. q / k are `d` wide, v / do
-    `d_v` (the same mask where the two are one width)."""
-    d, d_v = q.shape[-1], v.shape[-1]
-    if sk % block_k != 0:
-        km = _col_mask(ki * block_k, block_k, sk, d)
-        k = jnp.where(km, k, jnp.zeros_like(k))
-        vm = km if d_v == d else _col_mask(ki * block_k, block_k, sk, d_v)
-        v = jnp.where(vm, v, jnp.zeros_like(v))
-    if sq % block_q != 0:
-        qm = _col_mask(qi * block_q, block_q, sq, d)
-        q = jnp.where(qm, q, jnp.zeros_like(q))
-        if do is not None:
-            dm = qm if d_v == d else _col_mask(qi * block_q, block_q, sq, d_v)
-            do = jnp.where(dm, do, jnp.zeros_like(do))
-    return (q, k, v) if do is None else (q, k, v, do)
+from .flash_attention import (F0, F1, NEG_INF, Z, LANES, _fit_lanes,
+                              _on_tpu, pallas_disabled)
 
 
 def dropout_keep_mask(rows, cols, bh, seed, dropout):
@@ -183,18 +164,21 @@ def _block_keep(qi, ki, block_q, block_k, sq, sk, causal, window, srib, n):
 # ---------------------------------------------------------------------------
 # The live ranges: which blocks the kernels walk
 # ---------------------------------------------------------------------------
-def _live_ranges(srir, bh, causal, window, block_q, block_k, sq, sk):
-    """-> ((k_first, k_last), (q_first, q_last)): per (bh, q block) the
-    first and last k block that may hold an unmasked pair, flat
-    (bh * n_q,) int32, and per (bh, k block) the first and last q
-    block, (bh * n_k,). An empty range reads (0, -1).
+def _pad_to(x, n, axis, mode="constant"):
+    """x with `axis` grown to n at its end (zeros, or mode="edge": the
+    last entry again); x itself where it is n long already."""
+    if x.shape[axis] == n:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, n - x.shape[axis])
+    return jnp.pad(x, widths, mode=mode)
 
-    srir: (bh, n, S_k) int32 start/end indices or None. The block
-    predicate is `_block_keep`'s, on the max / min of each index column
-    over a k block's real columns, so a block outside a range holds no
-    unmasked pair; inside, a general mask may still have dead blocks
-    (the range is an envelope: the body keeps the exact predicate).
-    For causal document masks (n = 1) the range is exact."""
+
+def _live_blocks(srir, bh, causal, window, block_q, block_k, sq, sk):
+    """-> (bh, n_q, n_k) bool: block (q, k) may hold an unmasked pair.
+    srir: (bh, n, S_k) int32 start/end indices or None. The predicate is
+    `_block_keep`'s, on the max / min of each index column over a k
+    block's real columns (the ragged tail repeats its last one)."""
     n_q, n_k = pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)
     r_first = jnp.arange(n_q, dtype=jnp.int32)[:, None] * block_q
     r_last = jnp.minimum(r_first + block_q, sq) - 1
@@ -204,28 +188,39 @@ def _live_ranges(srir, bh, causal, window, block_q, block_k, sq, sk):
         r_first, r_last, c_first, c_last, causal, window)
     live = live[None]
     if srir is not None:
-        pad = n_k * block_k - sk
-
-        def agg(op, fill):
-            def of(i):
-                col = jnp.pad(srir[:, i], ((0, 0), (0, pad)),
-                              constant_values=fill)
-                return op(col.reshape(bh, 1, n_k, block_k), axis=-1)
-            return of
-        info = jnp.iinfo(jnp.int32)
+        def agg(op):
+            return lambda i: op(_pad_to(srir[:, i], n_k * block_k, 1, "edge")
+                                .reshape(bh, 1, n_k, block_k), axis=-1)
         live = live & ~_sri_all_masked(
-            r_first[None], r_last[None], agg(jnp.max, info.min),
-            agg(jnp.min, info.max), causal, srir.shape[1])
-    live = jnp.broadcast_to(live, (bh, n_q, n_k))
+            r_first[None], r_last[None], agg(jnp.max), agg(jnp.min), causal,
+            srir.shape[1])
+    return jnp.broadcast_to(live, (bh, n_q, n_k))
 
-    def first_last(axis, n):
+
+def _ranges(live):
+    """(bh, n_q, n_k) live blocks -> ((k_first, k_last), (q_first,
+    q_last)): the first and last live k block of every (bh, q block),
+    flat (bh * n_q,) int32, and the first and last live q block of
+    every (bh, k block), (bh * n_k,). An empty range reads (0, -1)."""
+    def first_last(axis):
+        n = live.shape[axis]
         idx = jnp.arange(n, dtype=jnp.int32).reshape(
             (1, n, 1) if axis == 1 else (1, 1, n))
         last = jnp.max(jnp.where(live, idx, -1), axis=axis)
         first = jnp.min(jnp.where(live, idx, n), axis=axis)
         first = jnp.where(last < 0, 0, first)
         return first.reshape(-1), last.reshape(-1)
-    return first_last(2, n_k), first_last(1, n_q)
+    return first_last(2), first_last(1)
+
+
+def _live_ranges(srir, bh, causal, window, block_q, block_k, sq, sk):
+    """The ranges the kernels walk (`_ranges` of `_live_blocks`). A
+    block outside a range holds no unmasked pair; inside, a general
+    mask may still have dead blocks (the range is an envelope: the
+    kernels keep the exact predicate). For causal document masks
+    (n = 1) the range is exact."""
+    return _ranges(_live_blocks(srir, bh, causal, window, block_q, block_k,
+                                sq, sk))
 
 
 def _sri_rows(sri):
@@ -245,24 +240,25 @@ def _window_pair(window):
 
 def flashmask_live_blocks(startend_row_indices, causal=True, window=None,
                           block_q=None, block_k=None):
-    """-> (live, grid): how many (q block, k block) steps of the
-    flashmask kernels' grids do work for this mask, and how many the
-    grid has, summed over batch and heads. `live` counts the blocks
-    inside the ranges the kernels walk (`_live_ranges`): for causal
-    document masks exactly the blocks that hold an unmasked pair, for
-    a general mask an envelope of them. startend_row_indices:
-    (B, H, S, n), queries and keys both S long; block_q / block_k
-    default to what the kernel entry derives for S at head 128 in
-    bfloat16."""
+    """-> (live, grid): how many (q block, k block) pairs may hold an
+    unmasked pair under this mask, and how many the flashmask kernels
+    launch for it (a line's grid step times its loop's trips: the
+    blocks inside the ranges `_live_ranges` gives, which is all a
+    kernel visits), each summed over batch and heads and counted for
+    the forward's grid. `grid - live` is the launched blocks that do
+    nothing: 0 for causal document masks, whose ranges are exact, and
+    the holes inside the envelope for a general mask.
+    startend_row_indices: (B, H, S, n), queries and keys both S long;
+    block_q / block_k default to what the kernel entry derives for S at
+    head 128 in bfloat16."""
     b, h, s, _ = startend_row_indices.shape
     dq, dk = derived_blocks(s, s, LANES, jnp.bfloat16)
     block_q = min(block_q or dq, s)
     block_k = min(block_k or dk, s)
-    (first, last), _ = _live_ranges(
-        _sri_rows(jnp.asarray(startend_row_indices)), b * h, causal,
-        _window_pair(window), block_q, block_k, s, s)
-    grid = b * h * pl.cdiv(s, block_q) * pl.cdiv(s, block_k)
-    return int(jnp.sum(last - first + 1)), grid
+    live = _live_blocks(_sri_rows(jnp.asarray(startend_row_indices)), b * h,
+                        causal, _window_pair(window), block_q, block_k, s, s)
+    (first, last), _ = _ranges(live)
+    return int(jnp.sum(live)), int(jnp.sum(last - first + 1))
 
 
 # ---------------------------------------------------------------------------
@@ -348,78 +344,134 @@ def _drop_keep(seed_ref, bh, qi, ki, block_q, block_k, dropout):
     return keep, np.float32(1.0 / (1.0 - dropout))
 
 
-def _when_live(first_ref, last_ref, line, inner, sri_ref, qi, ki, *,
-               block_q, block_k, sq, sk, causal, window, n_sri):
-    """Decorator: run body(keep_mask) when block (qi, ki) holds an
-    unmasked pair. First a comparison of prefetched scalars: `inner`
-    (the grid's inner block index) lies in line `line`'s live range;
-    outside it the index maps name the block already resident
-    (`_index_maps`), so the step moves no bytes and reads nothing. Then
-    `_block_keep`'s exact predicate on the start/end block, since a
-    general mask's range is an envelope."""
-    def deco(body):
-        @pl.when((inner >= first_ref[line]) & (inner <= last_ref[line]))
-        def _in_range():
-            srib = sri_ref[0] if sri_ref is not None else None
-            compute, keep_mask = _block_keep(qi, ki, block_q, block_k, sq,
-                                             sk, causal, window, srib, n_sri)
-            pl.when(compute)(lambda: body(keep_mask))
-    return deco
+def _walk(first_ref, last_ref, slot_ref, sem, streamed, block, tile):
+    """One grid step (b, outer) of a kernel: run tile(inner, slot) for
+    every inner block of the line's live range [first, last], in order,
+    and for no other. The inner side's operands stay in HBM and come in
+    by hand, double-buffered: `streamed` pairs each with its (2, ...)
+    VMEM buffer and says whether its blocks run along the lanes (the
+    start/end rows, axis 2) or the rows (axis 1). A trip starts the copy
+    of the block the next trip reads, which after a line's last block is
+    the first block of the next line of the same (batch, head), then
+    waits for its own; an empty line starts the next line's. The copies
+    of one (batch, head) are so one stream across its lines, and
+    `slot_ref` carries which buffer the next block lands in from step
+    to step (the outer grid dimension is "arbitrary": in order)."""
+    b, outer, n_outer = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    line = b * n_outer + outer
+    first, last = first_ref[line], last_ref[line]
+    nxt = jnp.minimum(line + 1, (b + 1) * n_outer - 1)
+    next_first = first_ref[nxt]
+    feeds_next = (outer + 1 < n_outer) & (last_ref[nxt] >= next_first)
+    empty = last < first
+
+    def copy(inner, slot, op):
+        at = pl.ds(pl.multiple_of(inner * block, block), block)
+        for n, (src, buf, on_lanes) in enumerate(streamed):
+            src = src.at[b, :, at] if on_lanes else src.at[b, at]
+            getattr(pltpu.make_async_copy(
+                src, buf.at[slot], sem.at[np.int32(n), slot]), op)()
+
+    @pl.when(outer == 0)
+    def _open():
+        slot_ref[0] = Z
+        pl.when(~empty)(lambda: copy(first, Z, "start"))
+
+    @pl.when(empty & feeds_next)
+    def _pass_on():
+        copy(next_first, slot_ref[0], "start")
+
+    def trip(inner, slot):
+        at_end = inner == last
+
+        @pl.when(~at_end | feeds_next)
+        def _prefetch():
+            copy(jnp.where(at_end, next_first, inner + 1), 1 - slot, "start")
+
+        copy(inner, slot, "wait")
+        tile(inner, slot)
+        return 1 - slot
+
+    slot_ref[0] = jax.lax.fori_loop(first, last + 1, trip, slot_ref[0])
 
 
-def _fwd_kernel(first_ref, last_ref, seed_ref, q_ref, k_ref, v_ref, sri_ref,
-                o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale, causal,
-                window, n_sri, block_q, block_k, n_q, n_k, sq, sk, dropout):
+def _split(refs, n_streamed, n_out):
+    """A kernel's refs after its resident inputs -> the streamed
+    operands (in HBM), the outputs, the streamed operands' buffers, and
+    the rest of the scratch (DMA semaphores, the slot, accumulators)."""
+    cuts = [0, n_streamed, n_streamed + n_out, 2 * n_streamed + n_out]
+    return [refs[a:b] for a, b in zip(cuts, cuts[1:])] + [refs[cuts[-1]:]]
+
+
+def _rows(buf, slot, width):
+    """Block `slot` of a streamed operand's buffer at the operand's own
+    width: the buffer's lanes are whole tiles (`_call`)."""
+    return buf[slot, :, :width]
+
+
+def _keys_streamed(hbm, bufs, n_sri):
+    """`_walk`'s `streamed` for the kernels whose inner side is the
+    keys': k, v and, where there is a mask, its start/end rows."""
+    return [(hbm[0], bufs[0], False), (hbm[1], bufs[1], False)] + (
+        [(hbm[2], bufs[2], True)] if n_sri else [])
+
+
+def _fwd_kernel(first_ref, last_ref, seed_ref, q_ref, *refs, scale, causal,
+                window, n_sri, block_q, block_k, sq, sk, dropout):
+    hbm, (o_ref, lse_ref), bufs, (sem, slot_ref, acc_ref, m_ref, l_ref) = \
+        _split(refs, 2 + bool(n_sri), 2)
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def tile(ki, slot):
+        compute, keep_mask = _block_keep(
+            qi, ki, block_q, block_k, sq, sk, causal, window,
+            bufs[2][slot] if n_sri else None, n_sri)
 
-    @_when_live(first_ref, last_ref, bh * n_q + qi, ki, sri_ref, qi, ki,
-                block_q=block_q, block_k=block_k, sq=sq, sk=sk, causal=causal,
-                window=window, n_sri=n_sri)
-    def body(keep_mask):
-        q, k, v = _zero_oob(qi, ki, q_ref[0], k_ref[0], v_ref[0],
-                            block_q=block_q, block_k=block_k, sq=sq, sk=sk)
-        d = v.shape[-1]                 # the accumulator's width: the values'
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        keep = keep_mask()
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - _fit_lanes(m_new, s.shape[-1]))
-        p = jnp.where(keep, p, jnp.zeros_like(p))
-        alpha = jnp.exp(m_prev - m_new)
-        # l (→ lse) accumulates the UNdropped p: dropout applies to the
-        # normalized probabilities (reference kernel semantics), which
-        # post-normalization equals dropping unnormalized p
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        pd = p
-        if dropout > 0.0:
-            dkeep, inv = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
-                                    dropout)
-            pd = jnp.where(dkeep, p * inv, F0)
-        acc_ref[:] = acc_ref[:] * _fit_lanes(alpha, d) + jax.lax.dot_general(
-            pd.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
-        l_ref[:] = l_new
+        @pl.when(compute)
+        def _body():
+            d = o_ref.shape[-1]         # the accumulator's width: the values'
+            q, k, v = q_ref[0], _rows(bufs[0], slot, q_ref.shape[-1]), \
+                _rows(bufs[1], slot, d)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            keep = keep_mask()
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_ref[:]
+            l_prev = l_ref[:]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - _fit_lanes(m_new, s.shape[-1]))
+            p = jnp.where(keep, p, jnp.zeros_like(p))
+            alpha = jnp.exp(m_prev - m_new)
+            # l (→ lse) accumulates the UNdropped p: dropout applies to
+            # the normalized probabilities (reference kernel semantics),
+            # which post-normalization equals dropping unnormalized p
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            pd = p
+            if dropout > 0.0:
+                dkeep, inv = _drop_keep(seed_ref, bh, qi, ki, block_q,
+                                        block_k, dropout)
+                pd = jnp.where(dkeep, p * inv, F0)
+            acc_ref[:] = acc_ref[:] * _fit_lanes(alpha, d) + \
+                jax.lax.dot_general(pd.astype(v.dtype), v,
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            m_ref[:] = m_new
+            l_ref[:] = l_new
 
-    @pl.when(ki == n_k - 1)
-    def _finalize():
-        l = l_ref[:]
-        l_safe = jnp.where(l == F0, F1, l)
-        d = o_ref.shape[-1]
-        o_ref[0] = (acc_ref[:] / _fit_lanes(l_safe, d)).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:] + jnp.log(l_safe)
+    _walk(first_ref, last_ref, slot_ref, sem,
+          _keys_streamed(hbm, bufs, n_sri), block_k, tile)
+
+    # a line with an empty range lands here with l = 0: zeros, finite lse
+    l = l_ref[:]
+    l_safe = jnp.where(l == F0, F1, l)
+    o_ref[0] = (acc_ref[:] / _fit_lanes(l_safe, o_ref.shape[-1])
+                ).astype(o_ref.dtype)
+    lse_ref[0] = m_ref[:] + jnp.log(l_safe)
 
 
 def _p_and_ds(q, k, v, do, lse, delta, keep, dkeep_inv, scale):
@@ -445,74 +497,75 @@ def _p_and_ds(q, k, v, do, lse, delta, keep, dkeep_inv, scale):
     return p, ds
 
 
-def _bwd_dq_kernel(first_ref, last_ref, seed_ref, q_ref, k_ref, v_ref,
-                   sri_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc, *,
-                   scale, causal, window, n_sri, block_q, block_k, n_q, n_k,
-                   sq, sk, dropout):
+def _bwd_dq_kernel(first_ref, last_ref, seed_ref, q_ref, do_ref, lse_ref,
+                   delta_ref, *refs, scale, causal, window, n_sri, block_q,
+                   block_k, sq, sk, dropout):
+    hbm, (dq_ref,), bufs, (sem, slot_ref, dq_acc) = \
+        _split(refs, 2 + bool(n_sri), 1)
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+    def tile(ki, slot):
+        compute, keep_mask = _block_keep(
+            qi, ki, block_q, block_k, sq, sk, causal, window,
+            bufs[2][slot] if n_sri else None, n_sri)
 
-    @_when_live(first_ref, last_ref, bh * n_q + qi, ki, sri_ref, qi, ki,
-                block_q=block_q, block_k=block_k, sq=sq, sk=sk, causal=causal,
-                window=window, n_sri=n_sri)
-    def body(keep_mask):
-        q, k, v, do = _zero_oob(qi, ki, q_ref[0], k_ref[0], v_ref[0],
-                                do_ref[0], block_q=block_q, block_k=block_k,
-                                sq=sq, sk=sk)
-        drop = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
-                          dropout) if dropout > 0.0 else None
-        _, ds = _p_and_ds(q, k, v, do, lse_ref[0], delta_ref[0], keep_mask(),
-                          drop, scale)
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        @pl.when(compute)
+        def _body():
+            k = _rows(bufs[0], slot, q_ref.shape[-1])
+            v = _rows(bufs[1], slot, do_ref.shape[-1])
+            drop = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
+                              dropout) if dropout > 0.0 else None
+            _, ds = _p_and_ds(q_ref[0], k, v, do_ref[0], lse_ref[0],
+                              delta_ref[0], keep_mask(), drop, scale)
+            dq_acc[:] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(ki == n_k - 1)
-    def _fin():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+    _walk(first_ref, last_ref, slot_ref, sem,
+          _keys_streamed(hbm, bufs, n_sri), block_k, tile)
+    dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(first_ref, last_ref, seed_ref, q_ref, k_ref, v_ref,
-                    sri_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    dk_acc, dv_acc, *, scale, causal, window, n_sri, block_q,
-                    block_k, n_q, n_k, sq, sk, dropout):
+def _bwd_dkv_kernel(first_ref, last_ref, seed_ref, k_ref, v_ref, *refs, scale,
+                    causal, window, n_sri, block_q, block_k, sq, sk, dropout):
+    sri_ref = None
+    if n_sri:
+        sri_ref, *refs = refs
+    hbm, (dk_ref, dv_ref), bufs, (sem, slot_ref, dk_acc, dv_acc) = \
+        _split(refs, 4, 2)
     bh = pl.program_id(0)
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+    dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def tile(qi, slot):
+        compute, keep_mask = _block_keep(
+            qi, ki, block_q, block_k, sq, sk, causal, window,
+            sri_ref[0] if n_sri else None, n_sri)
 
-    @_when_live(first_ref, last_ref, bh * n_k + ki, qi, sri_ref, qi, ki,
-                block_q=block_q, block_k=block_k, sq=sq, sk=sk, causal=causal,
-                window=window, n_sri=n_sri)
-    def body(keep_mask):
-        q, k, v, do = _zero_oob(qi, ki, q_ref[0], k_ref[0], v_ref[0],
-                                do_ref[0], block_q=block_q, block_k=block_k,
-                                sq=sq, sk=sk)
-        drop = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
-                          dropout) if dropout > 0.0 else None
-        p, ds = _p_and_ds(q, k, v, do, lse_ref[0], delta_ref[0], keep_mask(),
-                          drop, scale)
-        pd = p if drop is None else jnp.where(drop[0], p * drop[1], F0)
-        dv_acc[:] += jax.lax.dot_general(
-            pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        @pl.when(compute)
+        def _body():
+            q = _rows(bufs[0], slot, k_ref.shape[-1])
+            do = _rows(bufs[1], slot, v_ref.shape[-1])
+            lse, delta = bufs[2][slot], bufs[3][slot]
+            drop = _drop_keep(seed_ref, bh, qi, ki, block_q, block_k,
+                              dropout) if dropout > 0.0 else None
+            p, ds = _p_and_ds(q, k_ref[0], v_ref[0], do, lse, delta,
+                              keep_mask(), drop, scale)
+            pd = p if drop is None else jnp.where(drop[0], p * drop[1], F0)
+            dv_acc[:] += jax.lax.dot_general(
+                pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_acc[:] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(qi == n_q - 1)
-    def _fin():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    _walk(first_ref, last_ref, slot_ref, sem,
+          [(ref, buf, False) for ref, buf in zip(hbm, bufs)], block_q, tile)
+    dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -548,9 +601,17 @@ def derived_blocks(sq, sk, d, dtype, d_v=None):
     that fit the sequence and the VMEM budget, of those the one that
     pads the sequence least, of those the largest. A static function of
     shapes; nothing is timed. The table is one sweep on a v5e at
-    (4, 16, 4096, 128) bfloat16 with packed documents (PERF.md, PR 38):
-    large blocks win until the tiles leave VMEM, because a grid step
-    costs the same whatever it holds. `d_v`: the values' width where it
+    (4, 16, 4096, 128) bfloat16 with packed documents (PERF.md, PR 38),
+    made while a block outside a line's range still cost a grid step,
+    which by itself favoured large blocks. No such step is launched
+    now; what a large block still saves is a trip's fixed work (its
+    copies' starts and waits, the mask's iotas, the row statistics),
+    and what it costs is the pairs it covers and the mask kills (30% of
+    `packed_8k`'s at 512 x 512, 45% of `packed_4k`'s).
+    `tools/flashmask_bench.py` read 512 / 256 a side again at both
+    training shapes on a v5e: 512 x 512 stays faster by 10-37%
+    (PERF.md, PR 49), so the table stands; it moves only where another
+    choice is faster by 3% at both. `d_v`: the values' width where it
     is not the keys' (latent attention: 192 and 128)."""
     itemsize = jnp.dtype(dtype).itemsize
 
@@ -573,9 +634,10 @@ def _vmem_limit(block_q, block_k, d, dtype, n_sri, d_v=None):
 
 
 def _blocks(block_q, block_k, sq, sk, d, dtype, d_v=None):
-    """The blocks a call runs at: the caller's, else PT_FLASH_BLOCK_Q/K
+    """The blocks a call asks for: the caller's, else PT_FLASH_BLOCK_Q/K
     where the environment sets them, else derived from the shapes;
-    never past the sequence."""
+    never past the sequence (`_prep` rounds them up to whole lane
+    tiles)."""
     dq, dk = derived_blocks(sq, sk, d, dtype, d_v)
     if block_q is None:
         block_q = env_int("PT_FLASH_BLOCK_Q", dq)
@@ -584,32 +646,27 @@ def _blocks(block_q, block_k, sq, sk, d, dtype, d_v=None):
     return min(block_q, sq), min(block_k, sk)
 
 
-def _prep(q, k, v, sri):
-    """Operands flat over (batch, head). `d`: the width of q and k;
-    `d_v`: of v (and so of o and do), the same or its own."""
+def _prep(q, k, v, sri, block_q, block_k):
+    """Operands flat over (batch, head), each sequence grown with zeros
+    to a whole number of its blocks (the start/end rows with their last
+    column again, which leaves a block's max and min what they are): a
+    kernel copies and multiplies whole blocks, and the per-pair mask
+    keeps rows and columns past the real lengths out. -> the operands
+    and the blocks the call runs at."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[-1]
     bh = b * h
-    qr = q.reshape(bh, sq, d)
-    kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d_v)
-    srir = None if sri is None else _sri_rows(sri)
-    return qr, kr, vr, srir, b, h, sq, sk, d, d_v, bh
-
-
-def _mem_spec():
-    return functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-
-
-def _mk_kernel(fn, have_sri, **kw):
-    """Bind statics; when sri is absent, shim a None into the kernel's
-    sri_ref slot so one kernel body serves both signatures."""
-    if have_sri:
-        return functools.partial(fn, **kw)
-    return functools.partial(
-        lambda first_, last_, seed_, q_, k_, v_, *rest, **kw2:
-        fn(first_, last_, seed_, q_, k_, v_, None, *rest, **kw2),
-        **kw)
+    # whole lane tiles (and so whole sublane tiles of every type): what
+    # a copy by hand can slice out of an array on either axis
+    block_q, block_k = (pl.cdiv(c, LANES) * LANES for c in _blocks(
+        block_q, block_k, sq, sk, d, q.dtype, d_v))
+    n_q, n_k = pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)
+    qr = _pad_to(q.reshape(bh, sq, d), n_q * block_q, 1)
+    kr = _pad_to(k.reshape(bh, sk, d), n_k * block_k, 1)
+    vr = _pad_to(v.reshape(bh, sk, d_v), n_k * block_k, 1)
+    srir = None if sri is None else _pad_to(_sri_rows(sri), n_k * block_k, 2,
+                                            "edge")
+    return qr, kr, vr, srir, block_q, block_k
 
 
 def _seed_arr(seed):
@@ -618,139 +675,110 @@ def _seed_arr(seed):
     return jnp.asarray(seed, jnp.int32).reshape((1,))
 
 
-def _index_maps(n_outer, inner_is_k):
-    """Index maps of a grid (bh, outer, inner) whose prefetched scalars
-    are (first, last, seed): the inner block index is clamped into the
-    line's live range, so a step outside it names the block the step
-    before it held and the pipeline issues no copy. -> maps for the
-    q-side, the k-side and the start/end blocks."""
-    def clamped(b, outer, inner, first, last, _seed):
-        line = b * n_outer + outer
-        return jnp.maximum(jnp.minimum(inner, last[line]), first[line])
+def _call(kernel, scalars, resident, streamed, outs, accs, outer_block,
+          inner_block, vmem_limit, interpret):
+    """One kernel over the grid (bh, outer blocks). `resident` and
+    `outs`: (array or its shape, on_lanes), blocked by the grid's step
+    along the rows (axis 1) or, the start/end rows, the lanes (axis 2);
+    `streamed`: (array, on_lanes), left in HBM for `_walk`, each with a
+    two-block buffer, its rows grown to whole lane tiles where they are
+    not (a copy by hand takes no narrower slice; the kernels read their
+    own width of the buffer, so no product runs over the padding);
+    `scalars`: (first, last, seed), prefetched; `accs`: the
+    accumulators' shapes, float32."""
+    streamed = [(x if on_lanes else
+                 _pad_to(x, pl.cdiv(x.shape[2], LANES) * LANES, 2), on_lanes)
+                for x, on_lanes in streamed]
 
-    def q_map(b, outer, inner, *s):
-        return (b, outer if inner_is_k else clamped(b, outer, inner, *s), Z)
+    def blocked(x, on_lanes):
+        if on_lanes:
+            return pl.BlockSpec((1, x.shape[1], outer_block),
+                                lambda b, i, *_: (b, Z, i))
+        return pl.BlockSpec((1, outer_block, x.shape[2]),
+                            lambda b, i, *_: (b, i, Z))
 
-    def k_map(b, outer, inner, *s):
-        return (b, clamped(b, outer, inner, *s) if inner_is_k else outer, Z)
-
-    def sri_map(b, outer, inner, *s):
-        return (b, Z, k_map(b, outer, inner, *s)[1])
-    return q_map, k_map, sri_map
-
-
-def _call(kernel, grid, in_specs, out_specs, out_shape, scratch_shapes,
-          vmem_limit, interpret):
+    def buffer(x, on_lanes):
+        return pltpu.VMEM((2, x.shape[1], inner_block) if on_lanes
+                          else (2, inner_block, x.shape[2]), x.dtype)
+    bh, rows = resident[0][0].shape[:2]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
-            out_specs=out_specs, scratch_shapes=scratch_shapes),
-        out_shape=out_shape,
+            num_scalar_prefetch=len(scalars), grid=(bh, rows // outer_block),
+            in_specs=[blocked(*r) for r in resident]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(streamed),
+            out_specs=[blocked(*o) for o in outs],
+            scratch_shapes=[buffer(*s) for s in streamed]
+            + [pltpu.SemaphoreType.DMA((len(streamed), 2)),
+               pltpu.SMEM((1,), jnp.int32)]
+            + [pltpu.VMEM(shape, jnp.float32) for shape in accs]),
+        out_shape=[o for o, _ in outs],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
-        interpret=interpret,
-    )
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(*scalars, *(r for r, _ in resident), *(s for s, _ in streamed))
 
 
 def _fwd_pallas(q, k, v, sri, causal, window, scale, block_q, block_k,
                 interpret, dropout=0.0, seed=None):
-    scale = np.float32(scale)
-    qr, kr, vr, srir, b, h, sq, sk, d, d_v, bh = _prep(q, k, v, sri)
-    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype, d_v)
-    n_q = pl.cdiv(sq, block_q)
-    n_k = pl.cdiv(sk, block_k)
-    n_sri = srir.shape[1] if srir is not None else 0
-    spec = _mem_spec()
+    b, h, sq, d = q.shape
+    sk, d_v = k.shape[2], v.shape[-1]
+    qr, kr, vr, srir, block_q, block_k = _prep(q, k, v, sri, block_q, block_k)
+    bh, n_sri = b * h, 0 if srir is None else srir.shape[1]
     (k_first, k_last), _ = _live_ranges(srir, bh, causal, window, block_q,
                                         block_k, sq, sk)
-    q_map, k_map, sri_map = _index_maps(n_q, inner_is_k=True)
-
-    in_specs = [spec((1, block_q, d), q_map), spec((1, block_k, d), k_map),
-                spec((1, block_k, d_v), k_map)]
-    args = [k_first, k_last, _seed_arr(seed), qr, kr, vr]
-    if srir is not None:
-        in_specs.append(spec((1, n_sri, block_k), sri_map))
-        args.append(srir)
-    kernel = _mk_kernel(_fwd_kernel, srir is not None, scale=scale,
-                        causal=causal, window=window, n_sri=n_sri,
-                        block_q=block_q, block_k=block_k, n_q=n_q, n_k=n_k,
-                        sq=sq, sk=sk, dropout=dropout)
-
+    kernel = functools.partial(
+        _fwd_kernel, scale=np.float32(scale), causal=causal, window=window,
+        n_sri=n_sri, block_q=block_q, block_k=block_k, sq=sq, sk=sk,
+        dropout=dropout)
     o, lse = _call(
-        kernel, (bh, n_q, n_k), in_specs,
-        out_specs=[spec((1, block_q, d_v), q_map),
-                   spec((1, block_q, LANES), q_map)],
-        out_shape=[jax.ShapeDtypeStruct((bh, sq, d_v), q.dtype),
-                   jax.ShapeDtypeStruct((bh, sq, LANES), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, d_v), jnp.float32),
-                        pltpu.VMEM((block_q, LANES), jnp.float32),
-                        pltpu.VMEM((block_q, LANES), jnp.float32)],
-        vmem_limit=_vmem_limit(block_q, block_k, d, q.dtype, n_sri, d_v),
-        interpret=interpret,
-    )(*args)
-    return o.reshape(b, h, sq, d_v), lse.reshape(b, h, sq, LANES)
+        kernel, (k_first, k_last, _seed_arr(seed)), [(qr, False)],
+        [(kr, False), (vr, False)] + ([(srir, True)] if n_sri else []),
+        [(jax.ShapeDtypeStruct((bh, qr.shape[1], d_v), q.dtype), False),
+         (jax.ShapeDtypeStruct((bh, qr.shape[1], LANES), jnp.float32), False)],
+        [(block_q, d_v), (block_q, LANES), (block_q, LANES)],
+        block_q, block_k, _vmem_limit(block_q, block_k, d, q.dtype, n_sri, d_v),
+        interpret)
+    return (o[:, :sq].reshape(b, h, sq, d_v),
+            lse[:, :sq].reshape(b, h, sq, LANES))
 
 
 def _bwd_pallas(q, k, v, sri, o, lse, do, causal, window, scale,
                 block_q, block_k, interpret, dropout=0.0, seed=None):
-    scale = np.float32(scale)
-    qr, kr, vr, srir, b, h, sq, sk, d, d_v, bh = _prep(q, k, v, sri)
-    block_q, block_k = _blocks(block_q, block_k, sq, sk, d, q.dtype, d_v)
-    n_q = pl.cdiv(sq, block_q)
-    n_k = pl.cdiv(sk, block_k)
-    n_sri = srir.shape[1] if srir is not None else 0
-    spec = _mem_spec()
+    b, h, sq, d = q.shape
+    sk, d_v = k.shape[2], v.shape[-1]
+    qr, kr, vr, srir, block_q, block_k = _prep(q, k, v, sri, block_q, block_k)
+    bh, n_sri = b * h, 0 if srir is None else srir.shape[1]
     k_range, q_range = _live_ranges(srir, bh, causal, window, block_q,
                                     block_k, sq, sk)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    dor = do.reshape(bh, sq, d_v)
-    lser = lse.reshape(bh, sq, LANES)
-    deltar = jnp.broadcast_to(delta.reshape(bh, sq)[..., None],
-                              (bh, sq, LANES))
+    rows = qr.shape[1]
+    dor = _pad_to(do.reshape(bh, sq, d_v), rows, 1)
+    lser = _pad_to(lse.reshape(bh, sq, LANES), rows, 1)
+    deltar = _pad_to(jnp.broadcast_to(delta.reshape(bh, sq)[..., None],
+                                      (bh, sq, LANES)), rows, 1)
 
-    def specs(q_map, k_map, sri_map):
-        return ([spec((1, block_q, d), q_map), spec((1, block_k, d), k_map),
-                 spec((1, block_k, d_v), k_map)]
-                + ([spec((1, n_sri, block_k), sri_map)]
-                   if srir is not None else [])
-                + [spec((1, block_q, d_v), q_map),
-                   spec((1, block_q, LANES), q_map),
-                   spec((1, block_q, LANES), q_map)])
-
-    statics = dict(scale=scale, causal=causal, window=window, n_sri=n_sri,
-                   block_q=block_q, block_k=block_k, n_q=n_q, n_k=n_k,
-                   sq=sq, sk=sk, dropout=dropout)
-    operands = [qr, kr, vr] + ([srir] if srir is not None else []) + \
-        [dor, lser, deltar]
+    statics = dict(scale=np.float32(scale), causal=causal, window=window,
+                   n_sri=n_sri, block_q=block_q, block_k=block_k, sq=sq, sk=sk,
+                   dropout=dropout)
+    q_side = [(qr, False), (dor, False), (lser, False), (deltar, False)]
+    k_side = [(kr, False), (vr, False)] + ([(srir, True)] if n_sri else [])
     vmem_limit = _vmem_limit(block_q, block_k, d, q.dtype, n_sri, d_v)
+    like = lambda x: (jax.ShapeDtypeStruct(x.shape, x.dtype), False)
 
-    dq_maps = _index_maps(n_q, inner_is_k=True)
-    dq = _call(
-        _mk_kernel(_bwd_dq_kernel, srir is not None, **statics),
-        (bh, n_q, n_k), specs(*dq_maps),
-        out_specs=[spec((1, block_q, d), dq_maps[0])],
-        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        vmem_limit=vmem_limit, interpret=interpret,
-    )(*k_range, _seed_arr(seed), *operands)[0]
-
-    dkv_maps = _index_maps(n_k, inner_is_k=False)
+    dq, = _call(
+        functools.partial(_bwd_dq_kernel, **statics),
+        (*k_range, _seed_arr(seed)), q_side, k_side, [like(qr)],
+        [(block_q, d)], block_q, block_k, vmem_limit, interpret)
     dk, dv = _call(
-        _mk_kernel(_bwd_dkv_kernel, srir is not None, **statics),
-        (bh, n_k, n_q), specs(*dkv_maps),
-        out_specs=[spec((1, block_k, d), dkv_maps[1]),
-                   spec((1, block_k, d_v), dkv_maps[1])],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d_v), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d_v), jnp.float32)],
-        vmem_limit=vmem_limit, interpret=interpret,
-    )(*q_range, _seed_arr(seed), *operands)
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d_v))
+        functools.partial(_bwd_dkv_kernel, **statics),
+        (*q_range, _seed_arr(seed)), k_side, q_side, [like(kr), like(vr)],
+        [(block_k, d), (block_k, d_v)], block_k, block_q, vmem_limit,
+        interpret)
+    return (dq[:, :sq].reshape(b, h, sq, d), dk[:, :sk].reshape(b, h, sk, d),
+            dv[:, :sk].reshape(b, h, sk, d_v))
 
 
 # ---------------------------------------------------------------------------

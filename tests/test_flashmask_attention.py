@@ -146,9 +146,39 @@ class TestLiveRanges:
             inside = (idx >= np.asarray(k_range[0]).reshape(2, n, 1)) & \
                 (idx <= np.asarray(k_range[1]).reshape(2, n, 1))
             assert (inside[0] == live[0]).all()
+            # ... and so is what the kernels launch: a line's trips are
+            # its live blocks, and no block of the (bh, n, n) rectangle
+            # outside them costs a step
             got, grid = flashmask_live_blocks(sri, True, None, block, block)
-            assert grid == 2 * n * n
-            assert got == int(inside.sum())
+            assert grid == got == int(inside.sum())
+            assert grid < 2 * n * n
+
+    @pytest.mark.parametrize("s,median", [(8192, 1400), (4096, 700)])
+    def test_packed_documents_launch_their_live_blocks_only(self, s, median):
+        """The training cells' masks (four sequences of lognormal
+        documents, sixteen heads) at the 512 x 512 blocks they run at:
+        the kernels launch the live blocks and none beside them, well
+        under the rectangle the grid used to hold."""
+        import importlib.util
+        import os
+        spec = importlib.util.spec_from_file_location(
+            "flashmask_bench", os.path.join(os.path.dirname(__file__), "..",
+                                            "tools", "flashmask_bench.py"))
+        bench = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(bench)
+        ends = bench.doc_ends(4, s, median, seed=s)
+        sri = jnp.broadcast_to(jnp.asarray(ends)[:, None, :, None],
+                               (4, 16, s, 1))
+        live, grid = flashmask_live_blocks(sri, True, None, 512, 512)
+        whole = 64 * (s // 512) ** 2
+        assert grid - live == 0
+        assert 64 * (s // 512) <= grid < 0.5 * whole
+        # a general mask's range is an envelope: the holes are counted
+        cols = jnp.arange(s, dtype=jnp.int32)[None, None]
+        band = jnp.stack([cols + 512, cols + 2048], -1)     # (1, 1, s, 2)
+        live, grid = flashmask_live_blocks(band, True, None, 512, 512)
+        n = s // 512
+        assert grid - live > 0 and grid <= n * (n + 1) // 2
 
     def test_no_mask_is_the_causal_triangle(self):
         (first, last), (qf, ql) = _live_ranges(None, 3, True, None, 128,
@@ -280,9 +310,9 @@ class TestFlashMaskKernel:
 
     @pytest.mark.parametrize("case", sorted(_PACKED))
     def test_packed_documents(self, case):
-        """Forward and all three gradients on packed documents: the
-        live walk clamps its index maps into each line's range, and
-        what it skips must be exactly what the mask kills."""
+        """Forward and all three gradients on packed documents: a
+        line's loop walks its range and nothing else, and what it
+        leaves out must be exactly what the mask kills."""
         lens, block = _PACKED[case]
         s = sum(lens)
         sri = jnp.broadcast_to(_doc_sri(lens), (1, 2, s, 1))
@@ -305,6 +335,74 @@ class TestFlashMaskKernel:
         assert np.all(np.asarray(o)[0, :, 100:] == 0.0)
         assert np.isfinite(np.asarray(lse)).all()
         self._check(sri, causal=True, s=s, b=1, block=block, seed=21)
+
+    @pytest.mark.parametrize("block", [128, (256, 128)])
+    def test_lines_a_band_hides_give_zeros_and_zero_gradients(self, block):
+        """n = 2, causal: every key column masks the rows 128 .. 255 (a
+        q block with an empty range: its loop makes no trip), and the
+        columns 256 .. 383 mask every row (a k block with an empty
+        range in dK/dV's grid). Zeros, a finite lse and zero gradients
+        there; everywhere the dense reference's numbers."""
+        from paddle_tpu.ops.flashmask_attention import _fwd_pallas
+        s = 512
+        bq, bk = block if isinstance(block, tuple) else (block, block)
+        cols = np.arange(s)
+        hidden = (cols >= 256) & (cols < 384)
+        start = np.where(hidden, 0, 128)
+        end = np.where(hidden, s, 256)
+        sri = jnp.broadcast_to(jnp.asarray(
+            np.stack([start, end], -1)[None, None], jnp.int32), (1, 2, s, 2))
+        srir = jnp.swapaxes(sri, -1, -2).reshape(2, 2, s)
+        k_range, q_range = _live_ranges(srir, 2, True, None, bq, bk, s, s)
+        q_dead = np.asarray(k_range[1]).reshape(2, -1) < 0
+        k_dead = np.asarray(q_range[1]).reshape(2, -1) < 0
+        assert q_dead[:, 128 // bq].all() == (bq == 128)
+        assert k_dead[:, 256 // bk].all()
+        q, k, v = _qkv(1, 2, s, 64, seed=22)
+        o, lse = _fwd_pallas(q, k, v, sri, True, None, 0.125, bq, bk, True)
+        assert np.all(np.asarray(o)[0, :, 128:256] == 0.0)
+        assert np.isfinite(np.asarray(lse)).all()
+        ker_fn = lambda q_, k_, v_: flashmask_attention_bhsd(
+            q_, k_, v_, sri, causal=True, use_pallas=True, interpret=True,
+            block_q=bq, block_k=bk)
+        dq, dk, dv = jax.grad(lambda *a: (ker_fn(*a) * q).sum(), (0, 1, 2))(
+            k, q, v)                    # weights that are no operand
+        assert np.all(np.asarray(dq)[0, :, 128:256] == 0.0)
+        assert np.all(np.asarray(dk)[0, :, 256:384] == 0.0)
+        assert np.all(np.asarray(dv)[0, :, 256:384] == 0.0)
+        self._check(sri, causal=True, s=s, b=1, block=block, seed=22)
+
+    @pytest.mark.parametrize("sq,sk,mode,window,block", [
+        (300, 520, "causal_n1", (100, 0), 128),
+        (520, 300, "noncausal_n2", (64, 32), (256, 128)),
+        (333, 333, None, (50, 0), 128),
+        (700, 450, "noncausal_n4", None, (128, 256)),
+        (100, 100, "causal_n2", (30, 0), None),
+    ])
+    def test_rectangles_and_lengths_no_block_divides(self, sq, sk, mode,
+                                                     window, block):
+        """`sq != sk`, lengths off every block edge and a window: the
+        sequences grow to whole blocks outside the kernels and the mask
+        keeps the growth out. Forward and all three gradients."""
+        bq, bk = block if isinstance(block, tuple) else (block, block)
+        causal = mode is None or mode.startswith("causal")
+        sri = None if mode is None else _mode_sri(mode, sk)
+        rng = np.random.RandomState(sq)
+        q = jnp.asarray(rng.randn(1, 2, sq, 64), jnp.float32) * 0.3
+        k, v = (jnp.asarray(rng.randn(1, 2, sk, 64), jnp.float32) * 0.3
+                for _ in range(2))
+        w = jnp.asarray(rng.randn(1, 2, sq, 64), jnp.float32)
+        ref_fn = lambda *a: flashmask_reference(*a, sri, causal, window)[0]
+        ker_fn = lambda *a: flashmask_attention_bhsd(
+            *a, sri, causal=causal, window=window, use_pallas=True,
+            interpret=True, block_q=bq, block_k=bk)
+        _close(ker_fn(q, k, v), ref_fn(q, k, v))
+        loss = lambda fn: (lambda *a: (fn(*a) * w).sum())
+        g_ref = jax.grad(loss(ref_fn), (0, 1, 2))(q, k, v)
+        g_ker = jax.grad(loss(ker_fn), (0, 1, 2))(q, k, v)
+        for a, b_, like in zip(g_ker, g_ref, (q, k, v)):
+            assert a.shape == like.shape
+            _close(a, b_, tol=5e-3)
 
     def test_bf16_gradients(self):
         """bfloat16 operands enter the MXU as they are and the computed

@@ -201,15 +201,44 @@ def test_deepseek_train_step_lowers(monkeypatch):
 # Mosaic's own compile step, for a chip that is described and not attached
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no libtpu, or its lock is held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e.devices[0])
+
+
+def _kernel_grids(text):
+    """The grid of every Pallas kernel in a compiled program's text, in
+    the program's order: a `tpu_custom_call` carries its Mosaic module as
+    bytecode, whose function states its `iteration_bounds`."""
+    import base64
+    import json
+    import re
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    grids = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        at = line.index("backend_config=") + len("backend_config=")
+        config, _ = json.JSONDecoder().raw_decode(line[at:])
+        body = base64.b64decode(config["custom_call_config"]["body"])
+        with ctx:
+            bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]*)>",
+                               str(ir.Module.parse(body)))
+        grids.append(tuple(int(n) for n in bounds.group(1).split(",")))
+    return grids
 
 
 def _sample_shapes(arg, slots):
@@ -356,23 +385,30 @@ def test_ragged_compiles_for_v5e_at_the_serving_shape(one_chip, cache):
         assert "tpu_custom_call" in text or "custom-call" in text
 
 
-def test_flashmask_compiles_for_v5e_at_the_training_shape(one_chip):
+def test_flashmask_compiles_for_v5e_at_the_training_shape(v5e, one_chip):
     """`pretrain_4k`'s per-chip shape (4 rows x 16 heads, 4,096 tokens,
     head 128, bfloat16, a document mask), forward and backward, through
     the TPU compiler at the blocks the entry derives: 512 x 512, and the
     three kernels fit the VMEM they ask for (Mosaic refuses one that
-    does not)."""
+    does not). Each kernel's grid is a step a line, (64, 8): the inner
+    blocks are a loop's trips inside the step, as many as the line's
+    range holds, and no kernel iterates over (64, 8, 8). The same inside
+    the step's `shard_map` over a 2 x 2 mesh (a Mosaic kernel is not
+    partitioned by the compiler)."""
     import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from paddle_tpu.ops import flashmask_attention as fm
     b, h, s = 4, 16, 4096
     assert fm.derived_blocks(s, s, D, jnp.bfloat16) == (512, 512)
     limit = fm._vmem_limit(512, 512, D, jnp.bfloat16, 1)
     assert fm._vmem_bytes(512, 512, D, 2) <= limit == 16 * 2 ** 20
 
+    def attend(q, k, v, sri):
+        return fm.flashmask_attention_bhsd(q, k, v, sri, causal=True,
+                                           use_pallas=True, interpret=False)
+
     def loss(q, k, v, sri):
-        return fm.flashmask_attention_bhsd(
-            q, k, v, sri, causal=True, use_pallas=True,
-            interpret=False).astype(jnp.float32).sum()
+        return attend(q, k, v, sri).astype(jnp.float32).sum()
 
     qkv = jax.ShapeDtypeStruct((b, h, s, D), jnp.bfloat16, sharding=one_chip)
     sri = jax.ShapeDtypeStruct((b, h, s, 1), jnp.int32, sharding=one_chip)
@@ -383,6 +419,21 @@ def test_flashmask_compiles_for_v5e_at_the_training_shape(one_chip):
                        r'"offset":"\d+","size":"(\d+)"', line).group(1)
              for line in text.splitlines() if "tpu_custom_call" in line]
     assert asked == [str(limit)] * 3
+    assert _kernel_grids(text) == [(b * h, s // 512)] * 3
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(2, 2), ("dp", "tp"))
+    spec = P("dp", "tp", None, None)
+
+    def sharded_loss(q, k, v, sri):
+        o = jax.shard_map(attend, mesh=mesh, in_specs=(spec,) * 4,
+                          out_specs=spec, check_vma=False)(q, k, v, sri)
+        return o.astype(jnp.float32).sum()
+
+    whole = lambda n, dtype: jax.ShapeDtypeStruct(
+        (2 * b, 2 * h, s, n), dtype, sharding=NamedSharding(mesh, spec))
+    text = jax.jit(jax.grad(sharded_loss, (0, 1, 2))).lower(
+        *[whole(D, jnp.bfloat16)] * 3, whole(1, jnp.int32)).compile().as_text()
+    assert _kernel_grids(text) == [(b * h, s // 512)] * 3
 
 
 @pytest.mark.parametrize("cache", [jnp.bfloat16, jnp.int8])
@@ -471,9 +522,27 @@ def test_flashmask_compiles_for_v5e_at_latent_attentions_widths(one_chip):
         arg(b, h, s, 1, dtype=jnp.int32)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3                  # fwd, dq, dkv
-    # dQ and dK at the keys' width, dV at the values': nothing padded
+    # dQ and dK at the keys' width, dV at the values': no kernel returns
+    # a padded result (the operands a kernel streams lie in whole lane
+    # tiles, 256 for the keys' 192, and are read 192 wide)
     assert f"bf16[{b * h},{s},{d}]" in text and f"bf16[{b * h},{s},{dv}]" in text
-    assert f"bf16[{b * h},{s},256]" not in text
+    assert not [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+                and f"bf16[{b * h},{s},256]" in ln.split(" custom-call(")[0]]
+    assert _kernel_grids(text) == [(b * h, s // 512)] * 3
+
+    # as the layer runs them: under `jax.checkpoint` in a `lax.scan`, so
+    # the forward twice, and every kernel still a step a line
+    def layers(q, k, v, sri):
+        def layer(x, _):
+            o = fm.flashmask_attention_bhsd(x, k, v, sri, causal=True,
+                                            use_pallas=True, interpret=False)
+            return x + jnp.pad(o, ((0, 0),) * 3 + ((0, d - dv),)), None
+        x, _ = jax.lax.scan(jax.checkpoint(layer), q, None, length=2)
+        return x.astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(layers, (0, 1, 2))).lower(
+        arg(b, h, s, d), arg(b, h, s, d), arg(b, h, s, dv),
+        arg(b, h, s, 1, dtype=jnp.int32)).compile().as_text()
+    assert _kernel_grids(text) == [(b * h, s // 512)] * 4   # fwd, fwd, dq, dkv
 
 
 @pytest.mark.parametrize("heads, window", [(48, None), (64, 512)],

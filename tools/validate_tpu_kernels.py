@@ -187,35 +187,62 @@ def paged_decode_and_verify(interpret=False, small=False):
 
 
 def flashmask_fwd_bwd(interpret=False, small=False):
+    """Every mask mode the kernels take (n = 1 / 2 / 4, causal or not),
+    a window, in-kernel dropout, keys wider than values (latent
+    attention's 192 / 128), a head of 64, and a rectangle whose lengths
+    no block divides."""
     import jax.numpy as jnp
     from paddle_tpu.ops.flashmask_attention import (flashmask_attention_bhsd,
                                                     flashmask_reference)
     rng = np.random.RandomState(3)
     b, h, s = (1, 2, 256) if small else (2, 4, 1024)
+
+    def sri_of(causal, n, sk):
+        r = lambda lo, hi: rng.randint(lo, hi, (b, h, sk, 1))
+        if causal and n == 1:       # document-causal cutoff
+            cols = [r(1, sk + 1)]
+        elif causal:                # masked: start <= row < end
+            start = r(0, sk)
+            cols = [start, np.minimum(start + r(0, sk // 2), sk)]
+        elif n == 2:                # masked: row >= start or row < end
+            cols = [r(sk // 2, sk + 1), r(0, sk // 2)]
+        else:                       # two masked bands
+            cols = [r(0, sk // 4), r(sk // 4, sk // 2), r(sk // 2, sk),
+                    np.full((b, h, sk, 1), sk)]
+        return jnp.asarray(np.concatenate(cols, -1), jnp.int32)
+
+    # key: (causal, n, window, dropout, sq, sk, d, d_v)
+    odd = (200, 150) if small else (1000, 700)
+    cases = {
+        "cn1": (True, 1, None, 0.0, s, s, D, D),
+        "cn2": (True, 2, None, 0.0, s, s, D, D),
+        "bn2": (False, 2, None, 0.0, s, s, D, D),
+        "bn4": (False, 4, None, 0.0, s, s, D, D),
+        "cn1_drop0.3": (True, 1, None, 0.3, s, s, D, D),
+        "cn1_window": (True, 1, (s // 4, 0), 0.0, s, s, D, D),
+        "bn2_window": (False, 2, (s // 4, s // 8), 0.0, s, s, D, D),
+        "cn1_192_128": (True, 1, None, 0.0, s, s, 192, D),
+        "cn1_head64": (True, 1, None, 0.0, s, s, 64, 64),
+        "cn1_odd_lengths": (True, 1, (odd[1] // 2, 0), 0.0, *odd, D, D),
+    }
     out = {}
-    # document-causal cutoff; bidirectional start/end; in-kernel dropout
-    for causal, n, drop in ((True, 1, 0.0), (False, 2, 0.0), (True, 1, 0.3)):
-        q, k, v = (_randn(rng, b, h, s, D) for _ in range(3))
-        if causal:
-            sri = rng.randint(1, s + 1, (b, h, s, 1))
-        else:
-            sri = np.concatenate([rng.randint(s // 2, s + 1, (b, h, s, 1)),
-                                  rng.randint(0, s // 2, (b, h, s, 1))], -1)
-        sri = jnp.asarray(sri, jnp.int32)
+    for key, (causal, n, window, drop, sq, sk, d, d_v) in cases.items():
+        q, k, v = (_randn(rng, b, h, sq, d), _randn(rng, b, h, sk, d),
+                   _randn(rng, b, h, sk, d_v))
+        w = _randn(rng, b, h, sq, d_v)
+        sri = sri_of(causal, n, sk)
         kw = dict(dropout=drop, dropout_seed=123) if drop else {}
 
         def loss_k(q, k, v):
             o = flashmask_attention_bhsd(q, k, v, sri, causal=causal,
-                                         use_pallas=True,
+                                         window=window, use_pallas=True,
                                          interpret=interpret, **kw)
-            return (o * v).astype(jnp.float32).sum(), o
+            return (o * w).astype(jnp.float32).sum(), o
 
         def loss_r(q, k, v):
-            o, _ = flashmask_reference(q, k, v, sri, causal, None, **kw)
-            return (o * v).astype(jnp.float32).sum(), o
+            o, _ = flashmask_reference(q, k, v, sri, causal, window, **kw)
+            return (o * w).astype(jnp.float32).sum(), o
 
-        key = f"{'c' if causal else 'b'}n{n}" + (f"_drop{drop}" if drop
-                                                 else "")
         out[key] = _assert_close(
             f"flashmask {key}", *_fwd_bwd_errs(loss_k, loss_r, (q, k, v)))
     return out
