@@ -747,9 +747,17 @@ def unified_step(params, k_pool, v_pool, page_table, tokens, tok_slot,
         h, kp, vp, ksp, vsp = carry
         lp, li = xs
         x = _rms(h, lp["ln1"], c.rms_norm_eps)
-        q = (x @ lp["wq"]).reshape(t, nh, hd)
-        k = (x @ lp["wk"]).reshape(t, nkv, hd)
-        v = (x @ lp["wv"]).reshape(t, nkv, hd)
+        # the three products END at the barrier, so the compiler cannot
+        # fold the reshapes to heads into them: folded, it wanted each
+        # weight as [heads, head_dim, hidden] and transposed the whole
+        # slice in fast memory, every layer of every step; now it reads
+        # the weights from HBM as they lie (docs/serving.md § The three
+        # products; held by tests/test_tpu_lowering.py)
+        q, k, v = jax.lax.optimization_barrier(
+            (x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]))
+        q = q.reshape(t, nh, hd)
+        k = k.reshape(t, nkv, hd)
+        v = v.reshape(t, nkv, hd)
         q, k = apply_rotary_emb(q, k, cos[:, None], sin[:, None])
         kt = k.swapaxes(0, 1)                            # (KVH, T, D)
         vt = v.swapaxes(0, 1)

@@ -366,6 +366,20 @@ def test_run_derivation_matches_a_numpy_loop():
 # ---------------------------------------------------------------------------
 # The stack written and read where it lies (ISSUE 31, [donate-pools])
 # ---------------------------------------------------------------------------
+def _random_stacks(rng, shape, quant):
+    """(k, v, k_scale, v_scale) stacks of `shape` = (layers, kv heads,
+    pages, page, head_dim): bfloat16 and no scales, or int8 with a
+    float32 scale a row."""
+    if quant:
+        return tuple(
+            [jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+             for _ in "kv"] +
+            [jnp.asarray(rng.uniform(0.01, 0.1, shape[:4] + (1,)),
+                         jnp.float32) for _ in "kv"])
+    return tuple([jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                  for _ in "kv"] + [None, None])
+
+
 @pytest.mark.parametrize("cache", ["bf16", "int8"])
 def test_stacked_scatter_and_layer_indexed_read_give_the_per_layer_bits(
         cache):
@@ -381,18 +395,7 @@ def test_stacked_scatter_and_layer_indexed_read_give_the_per_layer_bits(
     L, kvh, qh, pages, page, d, t = 3, 2, 4, 12, 8, 16, 10
     quant = cache == "int8"
     rng = np.random.default_rng(31)
-    shape = (L, kvh, pages, page, d)
-    if quant:
-        kp = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
-        ksp = jnp.asarray(rng.uniform(0.01, 0.1, shape[:4] + (1,)),
-                          jnp.float32)
-        vsp = jnp.asarray(rng.uniform(0.01, 0.1, shape[:4] + (1,)),
-                          jnp.float32)
-    else:
-        kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-        vp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-        ksp = vsp = None
+    kp, vp, ksp, vsp = _random_stacks(rng, (L, kvh, pages, page, d), quant)
     ptab = jnp.asarray(rng.permutation(pages - 1)[:8].reshape(2, 4),
                        jnp.int32)
     # a 5-row prefill chunk, two decode rows, three slack rows that all
@@ -426,6 +429,116 @@ def test_stacked_scatter_and_layer_indexed_read_give_the_per_layer_bits(
                 q, kl, vl, ptab, slot, pos, k_scale=ksl, v_scale=vsl, **kw)
             assert np.asarray(by_index[:7]).any()
             assert np.array_equal(np.asarray(by_index), np.asarray(sliced))
+
+
+# ---------------------------------------------------------------------------
+# The q, k and v products end where the step says (ISSUE 51,
+# [weight-slices]): same values, same weights, no second copy
+# ---------------------------------------------------------------------------
+def _plain_step(params, kp, vp, ksp, vsp, ptab, tokens, slot, pos, page,
+                need, quant):
+    """`unified_step`'s arithmetic written out a layer at a time: three
+    plain products reshaped to heads as they come, the per-layer scatter,
+    the attention reference over the layer sliced out."""
+    import jax
+    from paddle_tpu.models.llama_serving import (_rms, _scatter_kv,
+                                                 apply_rotary_emb,
+                                                 rope_cos_sin)
+    c = CFG
+    nh, nkv = c.num_attention_heads, c.num_key_value_heads
+    hd, t = c.hidden_size // nh, tokens.shape[0]
+    on, at = pos >= 0, jnp.maximum(pos, 0)
+    cos, sin = rope_cos_sin(None, hd, base=c.rope_theta, position_ids=at)
+    page_ids = jnp.where(on, ptab[slot, at // page], kp.shape[2] - 1)
+    h = params["embed"][tokens]
+    for li in range(c.num_hidden_layers):
+        lp = {name: w[li] for name, w in params["layers"].items()}
+        x = _rms(h, lp["ln1"], c.rms_norm_eps)
+        q = (x @ lp["wq"]).reshape(t, nh, hd)
+        k = (x @ lp["wk"]).reshape(t, nkv, hd)
+        v = (x @ lp["wv"]).reshape(t, nkv, hd)
+        q, k = apply_rotary_emb(q, k, cos[:, None], sin[:, None])
+        kp, vp, ksp, vsp, kl, vl, ksl, vsl = _scatter_kv(
+            kp, vp, ksp, vsp, li, page_ids, at % page, k.swapaxes(0, 1),
+            v.swapaxes(0, 1), quant)
+        o = ragged_paged_attention(q, kl, vl, ptab, slot, pos,
+                                   use_pallas=False, k_scale=ksl,
+                                   v_scale=vsl)
+        h = h + o.reshape(t, -1).astype(h.dtype) @ lp["wo"]
+        x = _rms(h, lp["ln2"], c.rms_norm_eps)
+        h = h + (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) \
+            @ lp["w_down"]
+    h = _rms(h, params["final_norm"], c.rms_norm_eps)[need]
+    return kp, vp, ksp, vsp, h @ params["lm_head"]
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+def test_unified_step_gives_what_three_plain_products_give(cache):
+    """The barrier behind a layer's q, k and v products moves no value:
+    over bfloat16 weights `unified_step` gives the logits, the greedy
+    tokens and the pools of the same layers written out with `x @ wq`,
+    `x @ wk`, `x @ wv` reshaped as they come (a prefill chunk, two decode
+    rows and slack rows in one wave, both cache types)."""
+    from paddle_tpu.models.llama_serving import unified_step
+    L, kvh, hd = CFG.num_hidden_layers, CFG.num_key_value_heads, \
+        CFG.hidden_size // CFG.num_attention_heads
+    pages, page, slots = 12, 8, 3
+    quant = cache == "int8"
+    weights = M.init_params(CFG, seed=51, dtype=jnp.bfloat16)
+    rng = np.random.default_rng(51)
+
+    def pools():
+        # made anew for each side: the step donates what it is given
+        return _random_stacks(np.random.default_rng(5151),
+                              (L, kvh, pages, page, hd), quant)
+    ptab = jnp.asarray(rng.permutation(pages - 1)[:9].reshape(slots, 3),
+                       jnp.int32)
+    tokens = jnp.asarray(rng.integers(1, CFG.vocab_size, 10), jnp.int32)
+    slot = jnp.asarray([0, 0, 0, 0, 0, 1, 2, 0, 0, 0], jnp.int32)
+    pos = jnp.asarray([3, 4, 5, 6, 7, 17, 9, -1, -1, -1], jnp.int32)
+    need = jnp.asarray([4, 5, 6], jnp.int32)
+    sample = {"temp": jnp.zeros((slots,), jnp.float32),
+              "top_k": jnp.zeros((slots,), jnp.int32),
+              "top_p": jnp.ones((slots,), jnp.float32),
+              "key": jnp.zeros((slots, 2), jnp.uint32)}
+    kp, vp, ksp, vsp = pools()
+    want = _plain_step(weights, kp, vp, ksp, vsp, ptab, tokens, slot, pos,
+                       page, need, quant)
+    kp, vp, ksp, vsp = pools()
+    *got, rec = unified_step(weights, kp, vp, ptab, tokens, slot, pos, CFG,
+                             page, need_rows=need, k_scale=ksp,
+                             v_scale=vsp, sample=sample)
+    logits, ref = (np.asarray(a, np.float32) for a in (got[4], want[4]))
+    # a bf16 ulp of the largest logit: the products are the same
+    # products, summed in float32 and rounded once
+    assert np.abs(logits - ref).max() <= 2.0 ** -8 * np.abs(ref).max()
+    assert np.array_equal(np.asarray(rec[0]), ref.argmax(-1))
+    for a, b in zip(got[:4], want[:4]):
+        if a is None:
+            assert b is None
+            continue
+        # an int8 value may round one step apart where its row's scale
+        # does; everything else within a bf16 ulp of the largest
+        step = a.dtype == jnp.int8
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= (1.0 if step
+                                       else 2.0 ** -7 * np.abs(b).max())
+
+
+@pytest.mark.parametrize("ragged", [True, False])
+def test_engine_steps_over_the_weights_it_was_given(params, ragged):
+    """No engine lays a second copy of a weight beside the caller's: the
+    tree the step gets holds the caller's `wq`, `wk` and `wv` (and no
+    stack made of them) on the engine's device, ragged or bucketed."""
+    eng = ServingEngine(params, CFG, max_seqs=2, max_seq_len=32,
+                        page_size=8, use_pallas=False, ragged=ragged)
+    assert sorted(eng.params["layers"]) == sorted(params["layers"])
+    for name in ("wq", "wk", "wv"):
+        mine, theirs = eng.params["layers"][name], params["layers"][name]
+        assert mine.devices() == {eng.device}
+        assert mine.unsafe_buffer_pointer() == theirs.unsafe_buffer_pointer()
+    eng.submit(Request("a", [1, 5, 9, 3, 7], max_new_tokens=4))
+    assert len(eng.run()[0].output) == 4
 
 
 # ---------------------------------------------------------------------------
